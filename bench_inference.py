@@ -36,7 +36,7 @@ import time
 
 import numpy as np
 
-from bench import (emit_error_json, peak_for, safe_default_backend,
+from bench import (emit_error_json, peak_for, require_tpu,
                    scratch_telemetry_dir)
 
 
@@ -44,23 +44,16 @@ def main():
     import jax
     import deepspeed_tpu as deepspeed
     from deepspeed_tpu.models import gpt2
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
     from deepspeed_tpu.utils.monitor import ServingMetrics
 
-    on_tpu = safe_default_backend() == "tpu"
-    if on_tpu:
-        cfg = gpt2.config_for("gpt2_medium", max_seq_len=1024, remat=False)
-        inference = {"max_batch_size": 16, "dtype": "bf16",
-                     "prefill_buckets": [128, 256, 512],
-                     "max_new_tokens": 64, "greedy": True}
-        n_requests, prompt_lens = 48, (64, 180, 400)
-    else:
-        cfg = gpt2.GPT2Config(vocab_size=512, max_seq_len=256, n_layers=2,
-                              n_heads=4, d_model=128,
-                              use_flash_attention=False, remat=False)
-        inference = {"max_batch_size": 4, "dtype": "fp32",
-                     "prefill_buckets": [16, 32, 64],
-                     "max_new_tokens": 8, "greedy": True}
-        n_requests, prompt_lens = 8, (5, 12, 30)
+    enable_compile_cache()
+    require_tpu()
+    cfg = gpt2.config_for("gpt2_medium", max_seq_len=1024, remat=False)
+    inference = {"max_batch_size": 16, "dtype": "bf16",
+                 "prefill_buckets": [128, 256, 512],
+                 "max_new_tokens": 64, "greedy": True}
+    n_requests, prompt_lens = 48, (64, 180, 400)
 
     n_params = gpt2.num_params(cfg)
     model = gpt2.make_gpt2_model(config=cfg)
@@ -113,8 +106,9 @@ def main():
             "wall_seconds": round(wall, 2),
             "params": n_params,
             "kv_cache_mb": round(engine.kv.nbytes / 2 ** 20, 1),
-            "device": getattr(jax.devices()[0], "device_kind", "cpu"),
-            "backend": jax.default_backend(),
+            "device": jax.devices()[0].device_kind,
+            "device_count": jax.device_count(),
+            "backend": jax.devices()[0].platform,
             # omitted (not {}) on non-writer processes: the schema
             # checker rejects an empty snapshot (bin/check_bench_schema)
             **({"telemetry": engine.telemetry_snapshot()}

@@ -13,13 +13,14 @@ from .rules import (ProgramSpec, RECOMPILE_STORM_THRESHOLD_DEFAULT,
                     REPLICATED_LEAF_BYTES_DEFAULT, audit_program,
                     recompile_storm_finding, replicated_leaf_finding)
 from .auditor import (AuditFindingsError, audit_engine, audit_programs,
-                      dispose)
+                      lower_engine_program, dispose)
 from .config import ANALYSIS, DeepSpeedAnalysisConfig, KNOWN_ANALYSIS_KEYS
 
 __all__ = [
     "AnalysisReport", "Finding", "Suppressions",
     "validate_analysis_report", "ProgramSpec", "audit_program",
-    "audit_programs", "audit_engine", "dispose", "AuditFindingsError",
+    "audit_programs", "audit_engine", "lower_engine_program", "dispose",
+    "AuditFindingsError",
     "DeepSpeedAnalysisConfig", "ANALYSIS", "KNOWN_ANALYSIS_KEYS",
     "replicated_leaf_finding", "recompile_storm_finding",
     "RECOMPILE_STORM_THRESHOLD_DEFAULT", "REPLICATED_LEAF_BYTES_DEFAULT",

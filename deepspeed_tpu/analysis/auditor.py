@@ -268,6 +268,40 @@ def audit_plan(engine, report):
     return plan
 
 
+def engine_program_specs(engine, batch=None):
+    """The engine's step programs as ProgramSpecs (training or inference
+    engine; ``batch`` as for :func:`audit_engine`)."""
+    from . import programs as collectors
+    if hasattr(engine, "prefill_buckets"):           # inference engine
+        return collectors.collect_inference_programs(engine)
+    return collectors.collect_train_programs(engine, batch=batch)
+
+
+def lower_engine_program(engine, name, batch=None):
+    """Lower ONE of the engine's step programs ahead of time — the
+    engine's OWN jitted program, at the argument types its step will
+    pass, so jax traces and lowers it once: ``.compile()`` on the
+    result leaves the executable where the step's first call finds it.
+    Returns the jax ``Lowered``; its ``.compile()`` gives ``as_text()``
+    — whether a kernel is in the program (``tpu_custom_call``), which
+    collectives the compiler put in — and ``memory_analysis()``, its
+    bytes per device. Training engines: ``micro`` / ``apply`` /
+    ``fused_train``; inference engines: ``decode`` / ``spec_verify``
+    (greedy)."""
+    specs = {s.name: s for s in engine_program_specs(engine, batch=batch)}
+    if name not in specs:
+        raise KeyError("engine has no step program {!r}: {}".format(
+            name, sorted(specs)))
+    spec = specs[name]
+    if hasattr(engine, "prefill_buckets"):           # inference engine
+        widths = {"decode": 1, "spec_verify": engine.spec_k + 1}
+        fn = engine._get_decode_fn(True, 0, width=widths[name])
+    else:
+        key = name if name == "micro" else engine._regime_jit_key(name)
+        fn = engine._get_jit(key, spec.build, donate=spec.donate)
+    return fn.lower(*spec.args)
+
+
 def audit_engine(engine, batch=None, hlo=None, report_path=None,
                  strict=None):
     """Ahead-of-time shard-lint over one engine's resolved step
@@ -283,16 +317,15 @@ def audit_engine(engine, batch=None, hlo=None, report_path=None,
     ``analysis.hlo`` (compile + collective census + output drift).
     """
     from . import programs as collectors
+    specs = engine_program_specs(engine, batch=batch)
     if hasattr(engine, "prefill_buckets"):           # inference engine
         config = engine.analysis_config
-        specs = collectors.collect_inference_programs(engine)
         sequence = collectors.inference_step_sequence(engine)
         mesh = engine.mesh
         wire_est = None
         job = "serve"
     else:
         config = engine._config.analysis_config
-        specs = collectors.collect_train_programs(engine, batch=batch)
         sequence = collectors.train_step_sequence(engine)
         mesh = engine.mesh
         wire_est = None

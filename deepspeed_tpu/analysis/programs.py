@@ -42,11 +42,8 @@ def _sds(x):
         sharding = getattr(x, "sharding", None)
         if not isinstance(sharding, NamedSharding):
             sharding = None
-        try:
-            return jax.ShapeDtypeStruct(tuple(x.shape), x.dtype,
-                                        sharding=sharding)
-        except TypeError:               # jax without SDS sharding kwarg
-            return jax.ShapeDtypeStruct(tuple(x.shape), x.dtype)
+        return jax.ShapeDtypeStruct(tuple(x.shape), x.dtype,
+                                    sharding=sharding)
     return x
 
 

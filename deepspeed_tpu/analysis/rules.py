@@ -97,12 +97,10 @@ class ProgramSpec:
                          (the engine's ``*_fn`` builder output);
     ``args``             tuple pytree of arrays / ShapeDtypeStructs /
                          scalars — the program's example operands;
-    ``donate``           the donation set (argnums) the engine uses on
-                         an accelerator (CPU-gated donations still
-                         declare the accelerator set here) — the same
-                         spelling ``runtime/executor/jit.jit_program``
-                         takes, so the audited declaration IS the
-                         executed one;
+    ``donate``           the donation set (argnums) the engine uses —
+                         the same spelling ``runtime/executor/jit.
+                         jit_program`` takes, so the audited declaration
+                         IS the executed one;
     ``taint_paths``      flat-path prefixes ("0/params") whose low-
                          precision leaves seed the dtype-promotion
                          taint;

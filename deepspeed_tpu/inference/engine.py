@@ -19,7 +19,7 @@ Two KV layouts (``inference.kv_layout``):
 
   * ``slot`` (default, the numerics oracle): one contiguous
     ``(slots, layers, heads, max_seq, d_head)`` buffer pair;
-  * ``paged``: a pooled ``(pages, layers, heads, page_size, d_head)``
+  * ``paged``: a pooled ``(pages, layers, page_size, heads * d_head)``
     buffer pair plus host-side page tables (inference/paging.py) —
     pages allocate on demand as sequences grow, shared prompt prefixes
     map one set of pages into many tables (copy-on-write), and HBM
@@ -27,7 +27,8 @@ Two KV layouts (``inference.kv_layout``):
 
 Tensor parallelism: params are placed via the model's
 ``partition_spec_fn`` (Megatron column/row layout) and both cache
-layouts shard their heads axis (kv_cache.KV_CACHE_SPEC), so XLA runs
+layouts shard their heads (kv_cache.KV_CACHE_SPEC /
+PAGED_KV_CACHE_SPEC), so XLA runs
 decode with each model shard attending over exactly the heads it owns.
 """
 import dataclasses
@@ -137,7 +138,7 @@ class InferenceEngine:
             model_config, dropout=0.0, scan_blocks=False,
             sequence_parallel=None, sp_mesh=None, sparse_attention=None,
             sparse_embedding_grads=False, embedding_grad_mesh=None,
-            paged_attention_kernel="xla")
+            paged_attention_kernel="xla", kernel_mesh=mesh)
 
         ic = self.inference_config
         self.max_seq_len = ic.max_seq_len or model_config.max_seq_len
@@ -352,9 +353,10 @@ class InferenceEngine:
     def _resolve_paged_attention_kernel(self):
         """``inference.paged_attention_kernel`` tri-state -> the decode
         family's concrete read path ("pallas" | "xla"). Fallbacks are
-        LOUD: a "pallas" request the engine cannot honor (slot layout,
-        tensor-parallel mesh) warns and runs the XLA oracle instead of
-        silently doing nothing."""
+        LOUD: a "pallas" request the engine cannot honor (the slot
+        layout) warns and runs the XLA oracle instead of silently doing
+        nothing. On a mesh the kernel runs under a shard_map over it,
+        heads split over ``model`` (ops/pallas/paged_attention.py)."""
         key = self.inference_config.paged_attention_kernel
         if self.kv_layout != "paged":
             if key == "pallas":
@@ -365,18 +367,6 @@ class InferenceEngine:
                     "\"paged\")", self.kv_layout)
             return "xla"
         if key == "xla":
-            return "xla"
-        from ..parallel.topology import MODEL_AXIS
-        tp = self.mesh is not None and \
-            int(dict(self.mesh.shape).get(MODEL_AXIS, 1)) > 1
-        if tp:
-            if key == "pallas":
-                logger.warning(
-                    "inference.paged_attention_kernel='pallas' is not "
-                    "certified under a tensor-parallel mesh (the jitted "
-                    "decode would need a shard_map wrapper around the "
-                    "kernel over the heads shards) — falling back to "
-                    "the XLA gather path")
             return "xla"
         if key == "pallas":
             return "pallas"

@@ -2,7 +2,7 @@
 
 A *page slice* is one finished request's KV state, lifted out of the
 prefill engine's paged pool: the page payloads (``(n_pages, layers,
-heads, page_size, d_head)`` K and V stacks, gathered by physical page
+page_size, heads * d_head)`` K and V stacks, gathered by physical page
 id) plus the table metadata a decode engine needs to resume — resident
 token count, the pending first sampled token, and the context tokens
 (for prefix registration and preemption-recompute on the decode side).

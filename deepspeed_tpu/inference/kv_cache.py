@@ -8,9 +8,15 @@ index. Every admitted request pays ``max_seq`` worth of HBM regardless of
 its actual length.
 
 **Paged layout** (:class:`PagedKVCache`): a global pool of fixed-size
-pages ``(pages, layers, heads, page_size, d_head)`` plus host-side
-per-sequence page tables (inference/paging.py). Sequences allocate pages
-on demand as they grow, so HBM scales with LIVE tokens, not with
+pages ``(pages, layers, page_size, heads * d_head)`` plus host-side
+per-sequence page tables (inference/paging.py). The heads ride PACKED in
+the minor dimension: a d_head-64 minor dimension is padded to the chip's
+128 lanes in HBM (double the bytes) and the chip's compiler refuses a
+page DMA out of it, while ``heads * d_head`` is lane-aligned at every
+GPT-2 width — one page of one layer is one contiguous, tile-aligned
+``(page_size, heads * d_head)`` slab (ops/pallas/paged_attention.py).
+Sequences allocate pages on demand as they grow, so HBM scales with LIVE
+tokens, not with
 ``slots * max_seq`` — and shared prompt prefixes map one set of pages
 into many tables (prefix sharing). Physical page 0 is the reserved
 garbage page: never allocated, the target of every masked/padded write.
@@ -21,8 +27,10 @@ absolute-position causal mask in the model's cached attention
 entries unreachable in both layouts, for any garbage content including
 NaN (pinned by tests/unit/test_serving.py poison tests).
 
-Sharding: the ``heads`` axis carries the tensor-parallel partition in
-both layouts, matching ``models/gpt2.py::partition_spec_fn``'s Megatron
+Sharding: the heads carry the tensor-parallel partition in both layouts
+(the slot cache's ``heads`` axis, the paged pool's packed ``heads *
+d_head`` axis — contiguous per head, so an even split lands on head
+boundaries), matching ``models/gpt2.py::partition_spec_fn``'s Megatron
 layout on the ``model`` mesh axis (QKV column-parallel => each model
 shard produces its own heads' K/V, so the cache entries it writes are
 exactly the entries it owns and decode inserts no cross-shard cache
@@ -37,9 +45,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..parallel.topology import MODEL_AXIS
 
 # (slots, layers, heads, max_seq, d_head): heads sharded over the model
-# axis. The paged pool (pages, layers, heads, page_size, d_head) shards
-# the same axis index, so one spec serves both layouts.
+# axis.
 KV_CACHE_SPEC = P(None, None, MODEL_AXIS, None, None)
+# the paged pool (pages, layers, page_size, heads * d_head): the packed
+# heads axis is the minor one.
+PAGED_KV_CACHE_SPEC = P(None, None, None, MODEL_AXIS)
 
 
 @dataclass
@@ -55,15 +65,9 @@ class KVCache:
     def allocate(cls, slots, layers, heads, max_seq, d_head, dtype,
                  mesh=None):
         shape = (slots, layers, heads, max_seq, d_head)
-        k = jnp.zeros(shape, dtype)
-        v = jnp.zeros(shape, dtype)
-        if mesh is not None and MODEL_AXIS in mesh.shape:
-            assert heads % mesh.shape[MODEL_AXIS] == 0, \
-                "n_heads {} not divisible by model-parallel degree {}".format(
-                    heads, mesh.shape[MODEL_AXIS])
-            sharding = NamedSharding(mesh, KV_CACHE_SPEC)
-            k = jax.device_put(k, sharding)
-            v = jax.device_put(v, sharding)
+        k, v = _shard_heads(jnp.zeros(shape, dtype),
+                            jnp.zeros(shape, dtype), heads, mesh,
+                            KV_CACHE_SPEC)
         return cls(k, v)
 
     @property
@@ -89,12 +93,12 @@ class KVCache:
         self.k, self.v = buffers
 
 
-def _shard_heads(k, v, heads, mesh):
+def _shard_heads(k, v, heads, mesh, spec):
     if mesh is not None and MODEL_AXIS in mesh.shape:
         assert heads % mesh.shape[MODEL_AXIS] == 0, \
             "n_heads {} not divisible by model-parallel degree {}".format(
                 heads, mesh.shape[MODEL_AXIS])
-        sharding = NamedSharding(mesh, KV_CACHE_SPEC)
+        sharding = NamedSharding(mesh, spec)
         k = jax.device_put(k, sharding)
         v = jax.device_put(v, sharding)
     return k, v
@@ -102,8 +106,8 @@ def _shard_heads(k, v, heads, mesh):
 
 @dataclass
 class PagedKVCache:
-    """The paged ``(k, v)`` pool: ``(num_pages + 1, layers, heads,
-    page_size, d_head)`` — physical page 0 is the reserved garbage page
+    """The paged ``(k, v)`` pool: ``(num_pages + 1, layers, page_size,
+    heads * d_head)`` — physical page 0 is the reserved garbage page
     (inference/paging.py), so ``num_pages`` counts USABLE pages. Buffers
     are jax arrays updated functionally; the engine's jitted programs
     donate them, so steady-state serving writes in place."""
@@ -115,9 +119,10 @@ class PagedKVCache:
     @classmethod
     def allocate(cls, num_pages, layers, heads, page_size, d_head, dtype,
                  mesh=None):
-        shape = (num_pages + 1, layers, heads, page_size, d_head)
+        shape = (num_pages + 1, layers, page_size, heads * d_head)
         k, v = _shard_heads(jnp.zeros(shape, dtype),
-                            jnp.zeros(shape, dtype), heads, mesh)
+                            jnp.zeros(shape, dtype), heads, mesh,
+                            PAGED_KV_CACHE_SPEC)
         return cls(k, v, int(page_size))
 
     @property

@@ -15,6 +15,7 @@ configs 2/4/5; reference tests/model/Megatron_GPT2). TPU-first design:
 Model size table matches GPT-2 family: 125M/350M/760M/1.5B (gpt2_small..xl).
 """
 import math
+import dataclasses
 from dataclasses import dataclass, field
 from functools import partial
 
@@ -39,8 +40,7 @@ class GPT2Config:
     remat_policy: str = "full"     # "full" | "dots" (save MXU outputs)
     loss_chunk: int = 128          # CE seq-chunking (0 = dense logits)
     # lax.scan over stacked block params: one compiled block body instead
-    # of n_layers unrolled copies — compile time O(1) in depth (a 48-layer
-    # unrolled build takes ~20 min through a remote compiler). Off by
+    # of n_layers unrolled copies — compile time O(1) in depth. Off by
     # default: the pipeline path owns its own stacking.
     scan_blocks: bool = False
     use_flash_attention: bool = True
@@ -51,6 +51,12 @@ class GPT2Config:
     # "pallas" off-TPU runs the interpreter instead of silently going
     # dense.
     flash_attention_backend: object = None
+    # The mesh the engine's step programs span (Model.bind_mesh /
+    # InferenceEngine set it on their own copy of the config). GSPMD
+    # cannot partition a Mosaic kernel, so the kernel dispatch sites
+    # hand it on and the kernels run under a shard_map over it
+    # (ops/pallas/common.py shard_kernel).
+    kernel_mesh: object = None
     dtype: object = jnp.float32    # param dtype at init (engine recasts)
     # Sequence/context parallelism: "ring" | "ulysses" | None. When set,
     # attention runs via shard_map over sp_mesh's ``sequence`` axis
@@ -248,7 +254,8 @@ def _attn_ctx(x, block, config, train):
     else:
         ctx = causal_attention(q, k, v,
                                use_flash=config.use_flash_attention,
-                               backend=config.flash_attention_backend)
+                               backend=config.flash_attention_backend,
+                               mesh=config.kernel_mesh)
     return ctx.reshape(b, s, d)
 
 
@@ -313,6 +320,11 @@ def _use_fused_attn(config):
     takes it too, under the Pallas interpreter."""
     if config.sequence_parallel or config.sparse_attention:
         return False
+    mesh = config.kernel_mesh
+    if mesh is not None and int(mesh.shape.get(MODEL_AXIS, 1)) > 1:
+        # the fused op takes the whole (d, 3d) QKV weight; under tensor
+        # parallelism the unfused path splits heads over ``model``
+        return False
     if config.flash_attention_backend is not None:
         return config.flash_attention_backend in ("pallas", "interpret")
     return (config.use_flash_attention
@@ -340,13 +352,14 @@ def _block_rest(x, ctx, block_params, config, rng, train):
 
 
 def _fused_attn_ctx(x, block_params, config):
-    from ..ops.transformer.flash_attention import fused_ln_qkv_attention
+    from ..ops.transformer.attention import fused_causal_attention
     # block sizes resolve by width inside the op (auto_blocks)
-    return fused_ln_qkv_attention(
+    return fused_causal_attention(
         x, block_params["ln1"]["scale"], block_params["ln1"]["bias"],
         block_params["attn"]["qkv_kernel"],
         block_params["attn"]["qkv_bias"], config.n_heads,
-        interpret=(config.flash_attention_backend == "interpret"))
+        interpret=(config.flash_attention_backend == "interpret"),
+        mesh=config.kernel_mesh)
 
 
 def _qkv_for_cache(x, block, config):
@@ -433,11 +446,13 @@ def _paged_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
                     positions, page_tables, valid_lens, page_size):
     """Incremental attention against the PAGED KV cache.
 
-    The cache is a global pool ``(pages, layers, heads, page_size,
-    d_head)``; ``page_tables`` (b, max_pages) int32 maps each slot's
-    logical page j to a physical page (entry 0 = the reserved garbage
-    page). Token i of row b writes at physical ``(page_tables[b, pos //
-    page_size], pos % page_size)`` via one masked scatter — padded
+    The cache is a global pool ``(pages, layers, page_size, heads *
+    d_head)`` — heads packed in the lane-aligned minor dimension
+    (inference/kv_cache.py); ``page_tables`` (b, max_pages) int32 maps
+    each slot's logical page j to a physical page (entry 0 = the
+    reserved garbage page). Token i of row b writes at physical
+    ``(page_tables[b, pos // page_size], pos % page_size)`` via one
+    masked scatter — padded
     tokens (``i >= valid_lens[b]``) and positions past the logical
     window redirect to the garbage page, so a bucket-padded prefill can
     never touch another sequence's pages. Reads: the default "xla" path
@@ -465,28 +480,29 @@ def _paged_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
     page = jnp.take_along_axis(page_tables, logical, axis=1)
     page = jnp.where(valid, page, 0)                # garbage-page redirect
 
-    # scatter the new K/V: value layout (b*s, h, dh) — the advanced
-    # (page, offset) indices broadcast to the front
+    # scatter the new K/V: one packed (h*dh) row per token — the
+    # advanced (page, offset) indices broadcast to the front
     flat_page, flat_off = page.reshape(-1), offset.reshape(-1)
-    k_new = k.transpose(0, 2, 1, 3).reshape(b * s, -1, dh)
-    v_new = v.transpose(0, 2, 1, 3).reshape(b * s, -1, dh)
-    k_cache = k_cache.at[flat_page, layer_idx, :, flat_off, :].set(
+    k_new = k.transpose(0, 2, 1, 3).reshape(b * s, -1)
+    v_new = v.transpose(0, 2, 1, 3).reshape(b * s, -1)
+    k_cache = k_cache.at[flat_page, layer_idx, flat_off, :].set(
         k_new.astype(k_cache.dtype))
-    v_cache = v_cache.at[flat_page, layer_idx, :, flat_off, :].set(
+    v_cache = v_cache.at[flat_page, layer_idx, flat_off, :].set(
         v_new.astype(v_cache.dtype))
 
     if config.paged_attention_kernel == "pallas":
         from ..ops.pallas.paged_attention import paged_attention
         ctx = paged_attention(q, k_cache, v_cache, page_tables,
                               positions, valid_lens,
-                              layer_idx=layer_idx, page_size=page_size)
+                              layer_idx=layer_idx, page_size=page_size,
+                              mesh=config.kernel_mesh)
     else:
         def rows_of(cache):
-            # (P, h, ps, dh) --gather--> (b, max_pages, h, ps, dh)
+            # (P, ps, h*dh) --gather--> (b, max_pages, ps, h*dh)
             # -> contiguous logical rows (b, h, max_pages*ps, dh)
             gathered = jnp.take(cache[:, layer_idx], page_tables, axis=0)
-            return gathered.transpose(0, 2, 1, 3, 4).reshape(
-                b, gathered.shape[2], max_pages * page_size, dh)
+            return gathered.reshape(
+                b, max_pages * page_size, -1, dh).transpose(0, 2, 1, 3)
 
         ctx = _attend_cache_rows(q, rows_of(k_cache), rows_of(v_cache),
                                  positions, dh, valid_lens=valid_lens)
@@ -500,7 +516,7 @@ def _forward_hidden_cached(params, input_ids, config, cache, positions,
 
     ``cache`` is ``(k, v)``: the slot layout (slots, layers, heads,
     max_seq, d_head) by default, or — when ``page_tables`` is given —
-    the paged pool (pages, layers, heads, page_size, d_head) indexed
+    the paged pool (pages, layers, page_size, heads * d_head) indexed
     per slot through ``page_tables`` (b, max_pages) with ``valid_lens``
     (b,) masking padded writes (inference/kv_cache.py). ``positions``
     (b,) int32 is the absolute position of input_ids[:, 0] per slot.
@@ -736,7 +752,6 @@ def profile_spec(config, batch_size, seq=None, seed=0):
     Each node prices one forward sub-function via XLA cost_analysis.
     ``seq`` should be the ACTUAL training sequence length (attention is
     quadratic in it); defaults to config.max_seq_len."""
-    import dataclasses
     import jax
     # per-module pricing stays on the dense math (cost_analysis cannot
     # attribute flops inside a shard_map'd fused collective-matmul)
@@ -811,6 +826,10 @@ def make_gpt2_model(config=None, size="gpt2_small", seed=0, **overrides):
     from ..runtime.model import Model
     if config is None:
         config = config_for(size, **overrides)
+    # the model owns its config: what an engine resolves onto it (flash
+    # backend, kernel mesh, collective-matmul binding) never reaches the
+    # caller's object, nor another model built from it
+    config = dataclasses.replace(config)
     params = init_params(config, seed=seed)
 
     def apply_fn(params, input_ids, labels, rng=None, train=True):
@@ -819,6 +838,7 @@ def make_gpt2_model(config=None, size="gpt2_small", seed=0, **overrides):
     model = Model(apply_fn, params, partition_spec_fn=partition_spec_fn,
                   name="gpt2")
     model.config = config
+    model.bind_mesh = partial(setattr, config, "kernel_mesh")
     model.profile_spec_fn = lambda batch_size, seq=None: profile_spec(
         config, batch_size, seq=seq)
     if not (config.sequence_parallel or config.sparse_embedding_grads):

@@ -128,21 +128,19 @@ class FusedAdam:
             "weight_decay": float(self.weight_decay),
         }
 
+    def resolved_kernel(self):
+        """What :meth:`update` runs: "pallas" | "interpret" | "xla"
+        (ops/pallas_utils.resolve_fused_kernel)."""
+        from ..pallas_utils import resolve_fused_kernel
+        return resolve_fused_kernel(self.use_pallas, self.moments_dtype)
+
     def update(self, grads, state, params, lr, beta1, beta2, eps, weight_decay):
-        if self.moments_dtype != jnp.float32:
-            use_pallas = False              # pallas kernel is fp32-state
-        elif self.use_pallas is None:
-            from ..pallas_utils import default_use_pallas
-            use_pallas = default_use_pallas()
-        else:
-            use_pallas = self.use_pallas
-        # forced-pallas on a non-TPU backend runs the interpreter (the
-        # loud warning fires once at config resolution, engine side)
-        interpret = bool(use_pallas) and jax.default_backend() != "tpu"
+        kernel = self.resolved_kernel()
         return adam_update(grads, state, params, lr, beta1, beta2, eps,
                            weight_decay, bias_correction=self.bias_correction,
                            adam_w_mode=self.adam_w_mode,
-                           use_pallas=use_pallas, interpret=interpret)
+                           use_pallas=kernel != "xla",
+                           interpret=kernel == "interpret")
 
     def state_dict_names(self):
         return ["exp_avg", "exp_avg_sq", "step"]

@@ -33,6 +33,16 @@ Masking contract (bit-compatible with the slot oracle,
   inactive slots, whose outputs the scheduler ignores (exactly as on
   the oracle path).
 
+Pool layout: ``(pages + 1, layers, page_size, heads * d_head)`` — heads
+PACKED in the minor dimension, so one page of one layer is a contiguous
+``(page_size, heads * d_head)`` slab whose minor dimension is a multiple
+of the chip's 128 lanes at every GPT-2 width. (A ``(..., page_size,
+d_head)`` minor pair is refused by the chip's compiler at d_head 64:
+"Slice shape along dimension 4 must be aligned to tiling (128), but is
+64" — and padded to 128 lanes in HBM.) Heads are a static in-kernel
+loop over lane slices, the packed flash kernels' pattern
+(ops/transformer/flash_attention.py).
+
 The kernel is grid-parallel over slots; the page-table row, position
 and valid length ride ``PrefetchScalarGridSpec`` scalar prefetch so the
 DMA source indices are known before the body runs. Off-TPU it runs
@@ -51,7 +61,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import default_interpret
+from .common import default_interpret, shard_kernel, split_axes
 
 NEG_INF = -1e30
 
@@ -62,9 +72,9 @@ def _kernel(pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref, v_pool_ref,
     """One slot's page-table walk. Refs:
 
     pt_ref (b, max_pages) / pos_ref (b,) / vlen_ref (b,): SMEM scalar
-    prefetch; q_ref (1, s, h, dh) VMEM block; k/v_pool_ref the whole
-    paged pools (pages+1, L, h, page_size, dh) left in HBM; o_ref
-    (1, s, h, dh) fp32; k/v_buf (2, h, page_size, dh) double buffers.
+    prefetch; q_ref (1, s, h*dh) VMEM block; k/v_pool_ref the whole
+    paged pools (pages+1, L, page_size, h*dh) left in HBM; o_ref
+    (1, s, h*dh) fp32; k/v_buf (2, page_size, h*dh) double buffers.
     """
     i = pl.program_id(0)
     pos = pos_ref[i]
@@ -83,13 +93,13 @@ def _kernel(pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref, v_pool_ref,
     kd.start()
     vd.start()
 
-    qf = q_ref[0].astype(jnp.float32) * sm_scale          # (s, h, dh)
+    qf = q_ref[0].astype(jnp.float32) * sm_scale          # (s, h*dh)
     q_pos = pos + jax.lax.broadcasted_iota(jnp.int32, (seq, page_size), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (seq, page_size), 1)
     vcol = jax.lax.broadcasted_iota(jnp.int32, (page_size, 1), 0)
 
     def body(p, carry):
-        acc, m, l = carry                  # (s,h,dh), (s,h), (s,h) fp32
+        acc, m, l = carry                  # (s,h*dh), (s,h), (s,h) fp32
         slot = jax.lax.rem(p, 2)
 
         @pl.when(p + 1 < n_pages)
@@ -101,20 +111,21 @@ def _kernel(pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref, v_pool_ref,
         kw, vw = fetch(slot, p)
         kw.wait()
         vw.wait()
-        k_pg = k_buf[slot].astype(jnp.float32)            # (h, ps, dh)
+        k_pg = k_buf[slot].astype(jnp.float32)            # (ps, h*dh)
         v_pg = v_buf[slot].astype(jnp.float32)
 
         k_pos = p * page_size + col                       # (s, ps)
         mask = jnp.logical_and(k_pos <= q_pos, k_pos <= live)
         vmask = (p * page_size + vcol) <= live            # (ps, 1)
+        v_pg = jnp.where(vmask, v_pg, 0.0)
 
         new_acc, new_m, new_l = [], [], []
         for hi in range(num_heads):
+            sl = slice(hi * d_head, (hi + 1) * d_head)
             scores = jax.lax.dot_general(
-                qf[:, hi, :], k_pg[hi], (((1,), (1,)), ((), ())),
+                qf[:, sl], k_pg[:, sl], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)       # (s, ps)
             scores = jnp.where(mask, scores, NEG_INF)
-            vh = jnp.where(vmask, v_pg[hi], 0.0)
             m_old = m[:, hi:hi + 1]
             m_new = jnp.maximum(m_old,
                                 jnp.max(scores, axis=-1, keepdims=True))
@@ -123,29 +134,36 @@ def _kernel(pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref, v_pool_ref,
             new_m.append(m_new)
             new_l.append(l[:, hi:hi + 1] * corr
                          + jnp.sum(pexp, axis=-1, keepdims=True))
-            new_acc.append(acc[:, hi, :] * corr + jax.lax.dot_general(
-                pexp, vh, (((1,), (0,)), ((), ())),
+            new_acc.append(acc[:, sl] * corr + jax.lax.dot_general(
+                pexp, v_pg[:, sl], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32))
-        return (jnp.stack(new_acc, axis=1),
+        return (jnp.concatenate(new_acc, axis=1),
                 jnp.concatenate(new_m, axis=1),
                 jnp.concatenate(new_l, axis=1))
 
-    acc0 = jnp.zeros((seq, num_heads, d_head), jnp.float32)
+    acc0 = jnp.zeros((seq, num_heads * d_head), jnp.float32)
     m0 = jnp.full((seq, num_heads), NEG_INF, jnp.float32)
     l0 = jnp.zeros((seq, num_heads), jnp.float32)
     acc, _, l = jax.lax.fori_loop(0, n_pages, body, (acc0, m0, l0))
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = acc / l_safe[:, :, None]
+    # per-head rescale: (s, h) -> lane slices of (s, h*dh)
+    o_ref[0] = jnp.concatenate(
+        [acc[:, hi * d_head:(hi + 1) * d_head] / l_safe[:, hi:hi + 1]
+         for hi in range(num_heads)], axis=1)
 
 
 def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
-                    *, layer_idx, page_size, interpret=None):
+                    *, layer_idx, page_size, interpret=None, mesh=None):
     """Paged attention for ``s`` new queries per slot against the pool.
+    ``mesh``: the mesh the calling program spans — the kernel then runs
+    under a shard_map over it (common.shard_kernel), heads split over
+    its ``model`` axis like the pool's packed minor dimension
+    (inference/kv_cache.py PAGED_KV_CACHE_SPEC), the rest replicated.
 
     ``q``: (b, s, h, dh) — the new tokens' queries (cache writes for the
     SAME tokens must already have landed via the masked scatter, exactly
     as on the XLA gather path; this kernel replaces only the read side).
-    ``k_pool``/``v_pool``: (pages+1, layers, h, page_size, dh);
+    ``k_pool``/``v_pool``: (pages+1, layers, page_size, h*dh);
     ``page_tables``: (b, max_pages) int32; ``positions``/``valid_lens``:
     (b,) int32. ``layer_idx`` is trace-static (the model's python layer
     loop). Returns fp32 ctx (b, s, h, dh) — within 1e-5 of the slot
@@ -154,21 +172,37 @@ def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
     """
     if interpret is None:
         interpret = default_interpret()
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+        from ...parallel.topology import MODEL_AXIS
+        heads = split_axes(mesh, (MODEL_AXIS,), q.shape[2])
+        q_spec, pool_spec = P(None, None, heads), P(None, None, None, heads)
+        kernel = functools.partial(
+            paged_attention, layer_idx=layer_idx, page_size=page_size,
+            interpret=interpret)
+        return shard_kernel(
+            kernel, mesh, (q_spec, pool_spec, pool_spec, P(), P(), P()),
+            q_spec)(q, k_pool, v_pool, page_tables, positions, valid_lens)
     b, s, h, dh = q.shape
+    hd = h * dh
+    if k_pool.shape[2:] != (page_size, hd):
+        raise ValueError(
+            "paged_attention wants pools (pages+1, layers, page_size {}, "
+            "heads*d_head {}), got {}".format(page_size, hd, k_pool.shape))
     max_pages = page_tables.shape[1]
     full_window = max_pages * page_size
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, s, h, dh), lambda i, *_: (i, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((1, s, hd), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, s, h, dh), lambda i, *_: (i, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, s, hd), lambda i, *_: (i, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, h, page_size, dh), k_pool.dtype),
-            pltpu.VMEM((2, h, page_size, dh), v_pool.dtype),
+            pltpu.VMEM((2, page_size, hd), k_pool.dtype),
+            pltpu.VMEM((2, page_size, hd), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ])
@@ -179,17 +213,18 @@ def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
     # + p@v), the same count the XLA gather path's dots report — keeps
     # the cost-analysis pricing seam (telemetry/programs.py) honest.
     cost = pl.CostEstimate(
-        flops=4 * b * s * full_window * h * dh,
+        flops=4 * b * s * full_window * hd,
         bytes_accessed=(q.size * q.dtype.itemsize
-                        + 2 * b * full_window * h * dh
+                        + 2 * b * full_window * hd
                         * k_pool.dtype.itemsize
-                        + b * s * h * dh * 4),
+                        + b * s * hd * 4),
         transcendentals=b * s * full_window * h)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, s, h, dh), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, s, hd), jnp.float32),
         cost_estimate=cost,
         interpret=interpret,
     )(page_tables.astype(jnp.int32), positions.astype(jnp.int32),
-      valid_lens.astype(jnp.int32), q, k_pool, v_pool)
+      valid_lens.astype(jnp.int32), q.reshape(b, s, hd), k_pool, v_pool)
+    return out.reshape(b, s, h, dh)

@@ -64,6 +64,17 @@ from .common import (bound_axes, default_interpret,     # noqa: F401
 _AG_COLLECTIVE_ID = 11
 _RS_COLLECTIVE_ID = 12
 _GC_COLLECTIVE_ID = 13
+COLLECTIVE_IDS = (_AG_COLLECTIVE_ID, _RS_COLLECTIVE_ID, _GC_COLLECTIVE_ID)
+
+
+def _compiler_kwargs(collective_id, interpret):
+    """``pallas_call`` kwargs carrying the kernel's collective id — only
+    the compiled path takes ``compiler_params`` (the interpreter has no
+    barrier semaphore to key)."""
+    if interpret:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        collective_id=collective_id)}
 
 
 def pallas_ring_supported(x, w):
@@ -182,9 +193,7 @@ def ag_matmul_pallas(x, w, axis_name, wire_dtype=None, interpret=None):
     out_dtype = jnp.result_type(x.dtype, w.dtype)
     comm_dtype = jnp.dtype(wire_dtype) if wire_dtype is not None \
         else x.dtype
-    kw = {} if interpret else {
-        "compiler_params": pltpu.TPUCompilerParams(
-            collective_id=_AG_COLLECTIVE_ID)}
+    kw = _compiler_kwargs(_AG_COLLECTIVE_ID, interpret)
     return pl.pallas_call(
         functools.partial(_ag_kernel, axis_name=axis_name, n=n,
                           axes=_require_axes(), interpret=interpret),
@@ -252,9 +261,7 @@ def matmul_rs_pallas(x, w, axis_name, wire_dtype=None, interpret=None):
     out_dtype = jnp.result_type(x.dtype, w.dtype)
     comm_dtype = jnp.dtype(wire_dtype) if wire_dtype is not None \
         else out_dtype
-    kw = {} if interpret else {
-        "compiler_params": pltpu.TPUCompilerParams(
-            collective_id=_RS_COLLECTIVE_ID)}
+    kw = _compiler_kwargs(_RS_COLLECTIVE_ID, interpret)
     return pl.pallas_call(
         functools.partial(_rs_kernel, axis_name=axis_name, n=n,
                           axes=_require_axes(), out_dtype=out_dtype,
@@ -315,9 +322,7 @@ def gather_contract_pallas(rot, fixed, axis_name, wire_dtype=None,
     comm_dtype = jnp.dtype(wire_dtype) if wire_dtype is not None \
         else rot.dtype
     shape = (a, c) if rot_is_lhs else (c, a)
-    kw = {} if interpret else {
-        "compiler_params": pltpu.TPUCompilerParams(
-            collective_id=_GC_COLLECTIVE_ID)}
+    kw = _compiler_kwargs(_GC_COLLECTIVE_ID, interpret)
     return pl.pallas_call(
         functools.partial(_gc_kernel, axis_name=axis_name, n=n,
                           axes=_require_axes(), rot_is_lhs=rot_is_lhs,

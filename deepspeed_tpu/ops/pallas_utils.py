@@ -41,8 +41,28 @@ def default_use_pallas():
     single-chip TPU; under a multi-chip GSPMD mesh the kernel must go
     through shard_map (the engine wires that up), so default to the
     XLA-fused path there."""
-    import jax as _jax
-    return _jax.default_backend() == "tpu" and _jax.device_count() == 1
+    return jax.default_backend() == "tpu" and jax.device_count() == 1
+
+
+def resolve_fused_kernel(use_pallas, moments_dtype):
+    """What a FusedAdam/FusedLamb apply runs: ``"pallas"`` (compiled
+    kernel), ``"interpret"`` (the kernel under the Pallas interpreter —
+    a forced kernel off the TPU, parity/debug only) or ``"xla"``.
+    ``use_pallas`` None is "auto": :func:`default_use_pallas`, and the
+    XLA path for non-fp32 moments (the kernel is fp32-state). Forcing
+    the kernel onto non-fp32 moments raises — no quiet XLA fallback."""
+    fp32_state = moments_dtype == jnp.float32
+    if use_pallas and not fp32_state:
+        raise ValueError(
+            "fused optimizer kernel forced to 'pallas' with moments_dtype "
+            "{}: the Pallas apply kernel is fp32-state — use fused_kernel "
+            "'auto'/'xla' or fp32 moments".format(
+                jnp.dtype(moments_dtype).name))
+    if use_pallas is None:
+        use_pallas = fp32_state and default_use_pallas()
+    if not use_pallas:
+        return "xla"
+    return "pallas" if jax.default_backend() == "tpu" else "interpret"
 
 
 def row_mask(block_shape, block_index, total_rows):

@@ -59,13 +59,22 @@ def resolve_flash_backend(requested):
     return "interpret"
 
 
+def _batch_axes():
+    from ...parallel.topology import (DATA_AXIS, DATA_REPLICA_AXIS,
+                                      DATA_SHARD_AXIS)
+    return (DATA_AXIS, DATA_REPLICA_AXIS, DATA_SHARD_AXIS)
+
+
 def causal_attention(q, k, v, use_flash=True, sm_scale=None, interpret=None,
-                     backend=None):
+                     backend=None, mesh=None):
     """(b, s, h, d) in, (b, s, h, d) out.
 
     ``backend``: a RESOLVED tri-state ("pallas"|"interpret"|"xla", see
     :func:`resolve_flash_backend`) — wins over the legacy ``use_flash``
-    bool when given."""
+    bool when given. ``mesh``: the mesh the calling program spans; the
+    kernel then runs under a shard_map over it (ops/pallas/common.py
+    ``shard_kernel``), batch rows split over the data axes and heads
+    over ``model``."""
     if backend is None:
         if not use_flash:
             backend = "xla"
@@ -81,10 +90,38 @@ def causal_attention(q, k, v, use_flash=True, sm_scale=None, interpret=None,
     # transpose costs more than the attention math at d_head 64);
     # block sizes resolve by width inside the op (auto_blocks), so
     # wide models (gpt2-xl's h*d=1600) stay inside scoped vmem.
+    from ..pallas.common import shard_kernel, split_axes
     from .flash_attention import flash_attention_bshd
-    return flash_attention_bshd(q, k, v, sm_scale, True,
-                                interpret=(backend == "interpret")
-                                or bool(interpret))
+    kernel = _functools.partial(
+        flash_attention_bshd, sm_scale=sm_scale, causal=True,
+        interpret=(backend == "interpret") or bool(interpret))
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+        from ...parallel.topology import MODEL_AXIS
+        spec = P(split_axes(mesh, _batch_axes(), q.shape[0]), None,
+                 split_axes(mesh, (MODEL_AXIS,), q.shape[2]), None)
+        kernel = shard_kernel(kernel, mesh, (spec,) * 3, spec)
+    return kernel(q, k, v)
+
+
+def fused_causal_attention(x, ln_scale, ln_bias, qkv_w, qkv_b, num_heads,
+                           interpret=False, mesh=None):
+    """LN + QKV projection + causal flash attention as one op
+    (flash_attention.fused_ln_qkv_attention): ``x`` (b, s, d) in, the
+    attention context (b, s, d) out. Under ``mesh`` batch rows split
+    over the data axes and the four weight operands enter replicated
+    (their cotangents sum over the axes that split the batch); a
+    ``model`` axis replicates the op — callers keep it for meshes
+    without tensor parallelism."""
+    from ..pallas.common import shard_kernel, split_axes
+    from .flash_attention import fused_ln_qkv_attention
+    kernel = _functools.partial(fused_ln_qkv_attention,
+                                num_heads=num_heads, interpret=interpret)
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+        rows = P(split_axes(mesh, _batch_axes(), x.shape[0]))
+        kernel = shard_kernel(kernel, mesh, (rows,) + (P(),) * 4, rows)
+    return kernel(x, ln_scale, ln_bias, qkv_w, qkv_b)
 
 
 @_functools.lru_cache(maxsize=None)

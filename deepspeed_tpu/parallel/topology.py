@@ -56,30 +56,14 @@ def factor_data_axis(mesh, shard_size):
 
 
 def shard_map_compat(fn, mesh, in_specs, out_specs, axis_names=None):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., axis_names=..., check_vma=...)``
-    (some intermediate releases spell the flag ``check_rep``); 0.4.x only
-    has ``jax.experimental.shard_map.shard_map``. On 0.4.x the region runs
-    FULLY manual — its ``auto=`` partial-manual mode lowers PartitionId
-    ops its SPMD partitioner then rejects, while full-manual compiles and
-    matches (the pre-existing shims in ring_attention.py/compressed.py
-    rely on the same behavior). Replication checking is disabled
-    everywhere: callers return values they know to be replica-invariant
-    (post-psum/post-gather).
-    """
+    """``jax.shard_map`` with replication checking off: callers return
+    values they know to be replica-invariant (post-psum/post-gather).
+    ``axis_names`` (optional) makes the region manual over just those
+    axes."""
     import jax
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        try:
-            return jax.shard_map(fn, check_vma=False, **kwargs)
-        except TypeError:            # older spelling of the flag
-            return jax.shard_map(fn, check_rep=False, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+    kwargs = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False, **kwargs)
 
 
 def mesh_axis_groups(mesh, axes):

@@ -125,14 +125,11 @@ def model_parallel_cuda_manual_seed(seed, tp_rank=0):
 # remat policies
 # --------------------------------------------------------------------------
 def _offload_policy():
-    """Best-effort host-offload remat policy for PA_TO_CPU."""
-    try:
-        return jax.checkpoint_policies.save_and_offload_only_these_names(
-            names_which_can_be_saved=[],
-            names_which_can_be_offloaded=["checkpointed"],
-            offload_src="device", offload_dst="pinned_host")
-    except Exception:  # pragma: no cover - older jax
-        return jax.checkpoint_policies.nothing_saveable
+    """Host-offload remat policy for PA_TO_CPU."""
+    return jax.checkpoint_policies.save_and_offload_only_these_names(
+        names_which_can_be_saved=[],
+        names_which_can_be_offloaded=["checkpointed"],
+        offload_src="device", offload_dst="pinned_host")
 
 
 def _shard_over_model_axis(tree):

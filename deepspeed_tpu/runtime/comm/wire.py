@@ -47,9 +47,9 @@ _FP32_BYTES = 4
 
 # Nominal aggregate per-chip ICI bandwidth (bytes/s) for the analytic
 # overlap model in overlap_report(): order-of-magnitude public figures,
-# one home like mfu.PEAK_TFLOPS. The CPU entry is a nominal 10 GB/s so
-# CPU-rung overlap numbers stay nonzero and comparable across runs of
-# the same box, never meaningful in absolute terms.
+# one home like mfu.PEAK_TFLOPS, keyed by the exact device_kind; an
+# unknown kind raises. The cpu row is a nominal 10 GB/s kept only for
+# the tier-1 StepRecords priced against it (see telemetry/mfu.py).
 ICI_GBPS = {
     "TPU v2": 500.0, "TPU v3": 700.0, "TPU v4": 1200.0,
     "TPU v5 lite": 400.0, "TPU v5e": 400.0, "TPU v5": 1200.0,
@@ -60,13 +60,9 @@ ICI_GBPS = {
 
 def ici_bytes_per_s_for(device):
     """Nominal ICI bytes/s for one chip of ``device`` (a jax Device or a
-    device-kind string); unknown kinds get the CPU nominal."""
-    kind = device if isinstance(device, str) \
-        else getattr(device, "device_kind", "cpu")
-    for name, gbps in ICI_GBPS.items():
-        if kind.lower().startswith(name.lower()):
-            return gbps * 1e9
-    return ICI_GBPS["cpu"] * 1e9
+    device-kind string)."""
+    from ...telemetry.mfu import lookup_device_kind
+    return lookup_device_kind(ICI_GBPS, device, "ICI bandwidth") * 1e9
 
 
 def _ring_factor(group):

@@ -59,6 +59,10 @@ class Model:
         # optional StreamSpec for streamed parameter offload
         # (cpu_offload_params); models attach it post-construction
         self.stream_spec = None
+        # optional ``bind_mesh(mesh)``: a model whose kernels cannot be
+        # partitioned by GSPMD (Pallas) attaches it; the engine calls it
+        # once with the mesh its step programs span, before it traces
+        self.bind_mesh = None
         self.name = name or getattr(apply_fn, "__name__", "model")
         sig_params = _signature_params(apply_fn)
         self.accepts_rng = "rng" in sig_params or "rngs" in sig_params

@@ -228,14 +228,9 @@ class StreamedOffloadRunner:
     # ------------------------------------------------------------ jit fns
     def _jit(self, key, builder):
         if key not in self._jit_cache:
-            # donation is gated off the CPU rung like transfer.py's
-            # split program: CPU cannot alias the buffers and warns on
-            # every call; the declared (accelerator) set is what the
-            # shard-lint auditor verifies
             from ..executor.jit import jit_program
-            donate = STREAM_DONATE.get(key[0], ()) \
-                if jax.default_backend() != "cpu" else ()
-            self._jit_cache[key] = jit_program(builder(), donate=donate)
+            self._jit_cache[key] = jit_program(
+                builder(), donate=STREAM_DONATE.get(key[0], ()))
         return self._jit_cache[key]
 
     def _run(self, key, builder, *args):

@@ -299,13 +299,9 @@ class TelemetryCollector(AtexitCloseMixin):
                 start_step=tconfig.trace_start_step,
                 num_steps=tconfig.trace_num_steps,
                 trigger_file=tconfig.trace_trigger_file)
-        try:
-            import jax
-            self._device = getattr(jax.devices()[0], "device_kind", "cpu")
-            self._n_devices = jax.device_count()
-        except Exception:  # noqa: BLE001
-            self._device = "cpu"
-            self._n_devices = 1
+        import jax
+        self._device = jax.devices()[0].device_kind
+        self._n_devices = jax.device_count()
         self.peak_flops_per_chip = peak_flops_for(self._device)
         # per-host manifest: the structural discovery seam the fleet
         # merger joins on (fleet/aggregate.py) — written for EVERY live

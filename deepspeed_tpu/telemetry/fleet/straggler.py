@@ -56,13 +56,14 @@ FALLBACK_ICI_BYTES_PER_S = 10.0e9
 
 
 def nominal_ici_bytes_per_s(device="cpu"):
-    """Nominal per-chip ICI bytes/s for ``device`` from wire.ICI_GBPS;
-    the CPU nominal when wire.py (jax) is unavailable."""
+    """Nominal per-chip ICI bytes/s for ``device`` from wire.ICI_GBPS
+    (an unknown device kind raises); the CPU nominal when wire.py (jax)
+    is unimportable."""
     try:
         from deepspeed_tpu.runtime.comm.wire import ici_bytes_per_s_for
-        return ici_bytes_per_s_for(device)
-    except Exception:  # noqa: BLE001 - jax-less fleet doctor
+    except ImportError:                     # jax-less fleet doctor
         return FALLBACK_ICI_BYTES_PER_S
+    return ici_bytes_per_s_for(device)
 
 
 def ici_health_from_record(rec, nominal_bytes_per_s=None):

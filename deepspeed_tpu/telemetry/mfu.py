@@ -3,9 +3,11 @@
 One home for the per-chip peak numbers every surface reads (bench.py,
 bench_inference.py, the per-step telemetry records): public
 cloud.google.com/tpu specs, bf16 peak TFLOPS per chip (v2/v3 per-chip =
-2 cores). The CPU entry is a nominal 0.1 TFLOPS so CPU-rung MFU numbers
-stay nonzero and comparable across runs of the same box, never
-meaningful in absolute terms.
+2 cores), keyed by the EXACT ``device_kind`` jax reports. A kind that is
+not in the table is an error, not a default. The ``cpu`` row is a
+nominal 0.1 TFLOPS that exists only because tier-1 StepRecords are
+priced against it (tests/unit/test_telemetry.py); it is never a device
+metric and leaves with the benchmark PR (ROADMAP Design 1).
 """
 
 PEAK_TFLOPS = {
@@ -16,15 +18,22 @@ PEAK_TFLOPS = {
 }
 
 
+def lookup_device_kind(table, device, what):
+    """``table[device_kind]`` for a jax Device or a device-kind string,
+    exact match; an unknown kind raises — shared by the peak-flops and
+    ICI-bandwidth tables."""
+    kind = device if isinstance(device, str) else device.device_kind
+    if kind not in table:
+        raise KeyError(
+            "no {} for device kind {!r}: known kinds are {}".format(
+                what, kind, sorted(table)))
+    return table[kind]
+
+
 def peak_flops_for(device):
     """Peak flops/s for one chip of ``device`` (a jax Device or a
-    device-kind string); unknown kinds get the CPU nominal."""
-    kind = device if isinstance(device, str) \
-        else getattr(device, "device_kind", "cpu")
-    for name, tf in PEAK_TFLOPS.items():
-        if kind.lower().startswith(name.lower()):
-            return tf * 1e12
-    return 0.1e12
+    device-kind string)."""
+    return lookup_device_kind(PEAK_TFLOPS, device, "peak flops") * 1e12
 
 
 def mfu_of(flops_per_step, step_time_s, n_devices, peak_flops_per_chip):

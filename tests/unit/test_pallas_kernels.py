@@ -78,9 +78,9 @@ def _paged_setup(seed=0, b=3, s=2, h=2, dh=8, ps=4, max_pages=8,
     tail pages, random live content, slots at mixed lengths whose live
     windows CROSS page boundaries."""
     rng = np.random.RandomState(seed)
-    k_pool = rng.randn(usable_pages + 1, layers, h, ps, dh) \
+    k_pool = rng.randn(usable_pages + 1, layers, ps, h * dh) \
         .astype(np.float32)
-    v_pool = rng.randn(usable_pages + 1, layers, h, ps, dh) \
+    v_pool = rng.randn(usable_pages + 1, layers, ps, h * dh) \
         .astype(np.float32)
     if poison:
         k_pool[0] = np.nan
@@ -108,7 +108,7 @@ def _gather_oracle(q, k_pool, v_pool, page_tables, positions, valid_lens,
 
     def rows_of(cache):
         g = jnp.take(cache[:, layer], page_tables, axis=0)
-        return g.transpose(0, 2, 1, 3, 4).reshape(b, h, max_pages * ps, dh)
+        return g.reshape(b, max_pages * ps, h, dh).transpose(0, 2, 1, 3)
 
     return _attend_cache_rows(q, rows_of(k_pool), rows_of(v_pool),
                               positions, dh, valid_lens=valid_lens)
@@ -160,8 +160,8 @@ def test_paged_attn_ctx_dispatch_parity_and_shared_writes():
             "proj_bias": rng.randn(16).astype(np.float32),
         })
     x = jnp.asarray(rng.randn(b, s, 16).astype(np.float32))
-    k_pool = jnp.asarray(rng.randn(9, 2, 2, ps, 8).astype(np.float32))
-    v_pool = jnp.asarray(rng.randn(9, 2, 2, ps, 8).astype(np.float32))
+    k_pool = jnp.asarray(rng.randn(9, 2, ps, 2 * 8).astype(np.float32))
+    v_pool = jnp.asarray(rng.randn(9, 2, ps, 2 * 8).astype(np.float32))
     pt = np.zeros((b, mp), np.int32)
     pt[0, :2] = [1, 2]
     pt[1, :3] = [3, 4, 5]

@@ -464,8 +464,8 @@ def test_stale_kv_beyond_length_never_leaks(model, oracle, layout):
         k = k.at[jnp.asarray(dead)].set(jnp.nan)
         v = v.at[jnp.asarray(dead)].set(jnp.nan)
         off = len(prompt) % PS
-        k = k.at[live[-1], :, :, off:, :].set(jnp.nan)
-        v = v.at[live[-1], :, :, off:, :].set(jnp.nan)
+        k = k.at[live[-1], :, off:, :].set(jnp.nan)
+        v = v.at[live[-1], :, off:, :].set(jnp.nan)
     eng.kv.update((k, v))
     tokens = np.zeros(eng.num_slots, np.int32)
     tokens[0] = first
@@ -531,21 +531,27 @@ def test_pool_exhaustion_preempts_and_recovers(model, oracle):
 # ----------------------------------------------------------- sharding
 
 
-def test_paged_cache_sharded_over_heads_decode_parity(model, oracle):
-    """TP mesh: the paged pool shards its heads axis like the slot
-    cache (one KV_CACHE_SPEC serves both) and paged+spec decode on the
-    mesh still matches the unsharded slot oracle."""
+@pytest.mark.parametrize("kernel", ["auto", "pallas"])
+def test_paged_cache_sharded_over_heads_decode_parity(model, oracle, kernel):
+    """TP mesh: the paged pool shards its packed heads axis over the
+    model axis like the slot cache shards its heads axis, and paged+spec
+    decode on the mesh still matches the unsharded slot oracle — on the
+    XLA gather path (``auto`` off a TPU) and with the Pallas kernel
+    shard_mapped over the mesh, heads split over ``model``."""
     from deepspeed_tpu.parallel.topology import build_mesh
-    from deepspeed_tpu.inference.kv_cache import KV_CACHE_SPEC
+    from deepspeed_tpu.inference.kv_cache import PAGED_KV_CACHE_SPEC
     mesh = build_mesh(data=4, model=2)
     eng = deepspeed.init_inference(model=model, mesh=mesh, config={
         "inference": {"max_batch_size": 2, "prefill_buckets": [16, 32],
                       "dtype": "fp32", "greedy": True,
                       "kv_layout": "paged", "kv_block_size": PS,
+                      "paged_attention_kernel": kernel,
                       "prefix_caching": True,
                       "speculative": {"enabled": True, "method": "ngram",
                                       "num_draft_tokens": 3}}})
-    assert eng.kv.k.sharding.spec == KV_CACHE_SPEC
+    assert eng.paged_attention_kernel == \
+        ("pallas" if kernel == "pallas" else "xla")
+    assert eng.kv.k.sharding.spec == PAGED_KV_CACHE_SPEC
     rs = np.random.RandomState(8)
     prompts = [rs.randint(0, 128, size=n).tolist() for n in (7, 12)]
     assert eng.generate(prompts, max_new_tokens=5) == \

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from ..runtime.executor.jit import jit_program
+from ..utils.annotate import annotate
 from ..utils.logging import logger
 from .config import DeepSpeedInferenceConfig
 from .kv_cache import KVCache, PagedKVCache
@@ -517,6 +518,8 @@ class InferenceEngine:
                 token = sampler(logits, rng, temperature, top_p)[0]
                 return k_cache, v_cache, token, logits[0]
 
+        # the function's name is the program's in a profiler trace
+        # (module `jit_prefill`): a contract, pinned by a test
         fn = jit_program(prefill, donate=(1, 2))
         self._prefill_fns[key] = fn
         self.compile_stats["prefill_traces"] += 1
@@ -588,6 +591,9 @@ class InferenceEngine:
                                  top_p).reshape(tokens.shape)
                 return k_cache, v_cache, chosen, logits
 
+        # the function's name is the program's in a profiler trace:
+        # module `jit_decode`, and its Mosaic call `%decode.N`, by which
+        # the benchmark finds the paged kernel. Pinned by a test
         fn = jit_program(decode, donate=(1, 2))
         self._decode_fns[key] = fn
         self.compile_stats["decode_traces"] += 1
@@ -780,39 +786,39 @@ class InferenceEngine:
         assert start + n < self.max_seq_len, \
             "chunk end {} leaves no room to decode (max_seq_len " \
             "{})".format(start + n, self.max_seq_len)
-        bucket = self.bucket_for(n)
-        greedy, top_k, temperature, top_p = self._sampling_key(sampling)
-        fn = self._get_prefill_fn(bucket, greedy, top_k)
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :n] = np.asarray(tokens, np.int32)
-        extra = ()
-        if self.adapters is not None:
-            a_stack, b_stack = self._adapter_stack
-            extra = (a_stack, b_stack,
-                     jnp.int32(int(self.slot_adapters[slot])))
-        if self.kv_layout == "paged":
-            self._cow_writes(slot, start, start + n - 1)
-            k, v, token, _ = fn(
-                self.params, self.kv.k, self.kv.v, jnp.asarray(ids),
-                jnp.asarray(self.page_tables[slot]), jnp.int32(start),
-                jnp.int32(n), self._next_rng(),
-                jnp.float32(temperature), jnp.float32(top_p), *extra)
-        else:
-            # the slot layout writes the padded bucket with one
-            # dynamic_update_slice — paging.plan_chunks guarantees
-            # start + bucket <= max_seq so XLA's start clamping can
-            # never shift the write over live positions
-            assert start + bucket <= self.max_seq_len, \
-                "chunk bucket {}@{} overruns max_seq_len {}".format(
-                    bucket, start, self.max_seq_len)
-            k, v, token, _ = fn(
-                self.params, self.kv.k, self.kv.v, jnp.asarray(ids),
-                jnp.int32(slot), jnp.int32(start), jnp.int32(n),
-                self._next_rng(), jnp.float32(temperature),
-                jnp.float32(top_p), *extra)
-        self.kv.update((k, v))
-        self.lengths[slot] = start + n
-        return int(token)
+        with annotate("engine.prefill.prepare"):
+            bucket = self.bucket_for(n)
+            greedy, top_k, temperature, top_p = \
+                self._sampling_key(sampling)
+            fn = self._get_prefill_fn(bucket, greedy, top_k)
+            ids = np.zeros((1, bucket), np.int32)
+            ids[0, :n] = np.asarray(tokens, np.int32)
+            extra = ()
+            if self.adapters is not None:
+                a_stack, b_stack = self._adapter_stack
+                extra = (a_stack, b_stack,
+                         jnp.int32(int(self.slot_adapters[slot])))
+            if self.kv_layout == "paged":
+                self._cow_writes(slot, start, start + n - 1)
+                where = jnp.asarray(self.page_tables[slot])
+            else:
+                # the slot layout writes the padded bucket with one
+                # dynamic_update_slice — paging.plan_chunks guarantees
+                # start + bucket <= max_seq so XLA's start clamping can
+                # never shift the write over live positions
+                assert start + bucket <= self.max_seq_len, \
+                    "chunk bucket {}@{} overruns max_seq_len {}".format(
+                        bucket, start, self.max_seq_len)
+                where = jnp.int32(slot)
+            args = (jnp.asarray(ids), where, jnp.int32(start),
+                    jnp.int32(n), self._next_rng(),
+                    jnp.float32(temperature), jnp.float32(top_p)) + extra
+        with annotate("engine.prefill.dispatch"):
+            k, v, token, _ = fn(self.params, self.kv.k, self.kv.v, *args)
+            self.kv.update((k, v))
+            self.lengths[slot] = start + n
+        with annotate("engine.prefill.fetch"):
+            return int(token)
 
     def prefill(self, slot, prompt, sampling=None):
         """Single-shot prefill of a whole prompt (the unchunked path:
@@ -841,29 +847,29 @@ class InferenceEngine:
         assert tokens.shape[0] == self.num_slots
         width = tokens.shape[1]
         greedy, top_k, temperature, top_p = self._sampling_key(sampling)
-        fn = self._get_decode_fn(greedy, top_k, width=width)
-        extra = ()
-        if self.adapters is not None:
-            a_stack, b_stack = self._adapter_stack
-            extra = (a_stack, b_stack,
-                     jnp.asarray(self.slot_adapters, jnp.int32))
-        if self.kv_layout == "paged":
-            for slot in range(self.num_slots):
-                if self.lengths[slot] > 0:
-                    self._cow_writes(slot, int(self.lengths[slot]),
-                                     int(self.lengths[slot]) + width - 1)
-            k, v, chosen, _ = fn(
-                self.params, self.kv.k, self.kv.v, jnp.asarray(tokens),
-                jnp.asarray(self.lengths), jnp.asarray(self.page_tables),
+        with annotate("engine.decode.prepare"):
+            fn = self._get_decode_fn(greedy, top_k, width=width)
+            extra = ()
+            if self.adapters is not None:
+                a_stack, b_stack = self._adapter_stack
+                extra = (a_stack, b_stack,
+                         jnp.asarray(self.slot_adapters, jnp.int32))
+            paged = self.kv_layout == "paged"
+            if paged:
+                for slot in range(self.num_slots):
+                    if self.lengths[slot] > 0:
+                        self._cow_writes(
+                            slot, int(self.lengths[slot]),
+                            int(self.lengths[slot]) + width - 1)
+            args = (jnp.asarray(tokens), jnp.asarray(self.lengths)) + (
+                (jnp.asarray(self.page_tables),) if paged else ()) + (
                 self._next_rng(), jnp.float32(temperature),
-                jnp.float32(top_p), *extra)
-        else:
-            k, v, chosen, _ = fn(
-                self.params, self.kv.k, self.kv.v, jnp.asarray(tokens),
-                jnp.asarray(self.lengths), self._next_rng(),
-                jnp.float32(temperature), jnp.float32(top_p), *extra)
-        self.kv.update((k, v))
-        chosen = np.asarray(chosen)
+                jnp.float32(top_p)) + extra
+        with annotate("engine.decode.dispatch"):
+            k, v, chosen, _ = fn(self.params, self.kv.k, self.kv.v, *args)
+            self.kv.update((k, v))
+        with annotate("engine.decode.fetch"):
+            chosen = np.asarray(chosen)
         return chosen[:, 0] if squeeze else chosen
 
     def verify_step(self, tokens, sampling=None):

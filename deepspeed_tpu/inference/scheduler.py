@@ -31,6 +31,7 @@ stats into one ``serving_step`` record per scheduler step.
 import time
 from collections import deque
 
+from ..utils.annotate import annotate
 from ..utils.monitor import ServingMetrics
 from ..utils.timer import SynchronizedWallClockTimer
 from .paging import plan_chunks
@@ -234,38 +235,45 @@ class ContinuousBatchingScheduler:
                 if self._watchdog is not None:
                     self._watchdog.observe_pool_event("admission_blocked")
                 break                      # pool full: stay queued
-            self.queue.popleft()
-            req.slot = slot
-            req.state = "prefill"
-            req.admit_order = self._admitted
-            self._admitted += 1
-            self.slots[slot] = req
-            if self._spans is not None:
-                if req.span is None:
-                    # one span tree per REQUEST — it survives preemption
-                    # (the re-admit lands as a second admit event on the
-                    # same trace)
-                    req.span = self._spans.begin(
-                        "serving_request", uid=req.uid,
-                        prompt_tokens=len(req.prompt))
-                req.span.event(
-                    "admit", slot=slot, resumed=req.resumed,
-                    queue_wait_s=round(
-                        time.perf_counter() - req.arrival_t, 6))
-                if self.engine.kv_layout == "paged":
-                    matched = int(
-                        self.engine._admit_matched.get(slot, 0))
-                    req.span.event(
-                        "page_alloc",
-                        pages=int(self.engine.page_counts[slot]),
-                        prefix_pages=matched)
-                    if matched:
-                        req.span.event("prefix_hit", pages=matched)
-            # the chunk plan is built at FIRST-chunk time (below): the
-            # prefix match runs there, after same-step siblings have
-            # registered their pages, so bursts of one system prompt
-            # share within a single scheduler step
-            req.chunks, req.chunk_idx = None, 0
+            # the wait ends here: arrival -> a slot and its pages
+            wait = time.perf_counter() - req.arrival_t
+            with annotate("sched.admit.request", uid=req.uid,
+                          queue_wait_us=int(wait * 1e6),
+                          resumed=req.resumed):
+                self.queue.popleft()
+                req.slot = slot
+                req.state = "prefill"
+                req.admit_order = self._admitted
+                self._admitted += 1
+                self.slots[slot] = req
+                if not req.resumed:
+                    # a re-admission after preemption waited since it
+                    # was preempted, not since it arrived: no queue wait
+                    self._account("record_queue_wait", wait)
+                if self._spans is not None:
+                    if req.span is None:
+                        # one span tree per REQUEST — it survives preemption
+                        # (the re-admit lands as a second admit event on the
+                        # same trace)
+                        req.span = self._spans.begin(
+                            "serving_request", uid=req.uid,
+                            prompt_tokens=len(req.prompt))
+                    req.span.event("admit", slot=slot, resumed=req.resumed,
+                                   queue_wait_s=round(wait, 6))
+                    if self.engine.kv_layout == "paged":
+                        matched = int(
+                            self.engine._admit_matched.get(slot, 0))
+                        req.span.event(
+                            "page_alloc",
+                            pages=int(self.engine.page_counts[slot]),
+                            prefix_pages=matched)
+                        if matched:
+                            req.span.event("prefix_hit", pages=matched)
+                # the chunk plan is built at FIRST-chunk time (below): the
+                # prefix match runs there, after same-step siblings have
+                # registered their pages, so bursts of one system prompt
+                # share within a single scheduler step
+                req.chunks, req.chunk_idx = None, 0
 
     def _prefill_chunks(self, retired):
         ic = self.engine.inference_config
@@ -286,43 +294,46 @@ class ContinuousBatchingScheduler:
                     if req.span is not None:
                         req.span.event("prefix_hit", tokens=start)
             start, ln = req.chunks[req.chunk_idx]
-            chunk = req.context[start:start + ln]
-            # no page check here: try_admit reserved the WHOLE context's
-            # pages at admission, so every chunk's range is covered —
-            # only decode growth (ensure_pages in _decode) can starve
-            t = self.timers("prefill")
-            t.start()
-            token = self.engine.prefill_chunk(req.slot, chunk, start,
-                                              sampling=self.sampling)
-            t.stop()
-            dt = t.elapsed(reset=True)
-            self._account("record_prefill", ln, dt)
-            if req.span is not None:
-                now = time.time()
-                req.span.timed_child("prefill_chunk", now - dt, now,
-                                     start=start, tokens=ln)
-            req.chunk_idx += 1
-            # register the pages filled SO FAR (full pages only): a
-            # same-burst sibling admitted this very step can match them
-            self.engine.register_prefix(req.slot,
-                                        req.context[:start + ln])
-            if req.chunk_idx < len(req.chunks):
-                continue
-            # final chunk: the request becomes a decoder
-            req.state = "decode"
-            if self.engine.drafter is not None:
-                self.engine.drafter.prefill(req.slot, req.context)
-            if req.resumed:
-                # the pending token survived preemption; nothing sampled
-                continue
-            now = time.perf_counter()
-            req.first_token_t = now
-            ttft = now - req.arrival_t
-            self._account("record_ttft", ttft)
-            if self._watchdog is not None:
-                self._watchdog.observe_ttft(ttft)
-            if self._append_tokens(req, [token])[1]:
-                retired.append(req.uid)
+            with annotate("sched.prefill.chunk", uid=req.uid, tokens=ln):
+                chunk = req.context[start:start + ln]
+                # no page check here: try_admit reserved the WHOLE context's
+                # pages at admission, so every chunk's range is covered —
+                # only decode growth (ensure_pages in _decode) can starve
+                t = self.timers("prefill")
+                t.start()
+                token = self.engine.prefill_chunk(req.slot, chunk, start,
+                                                  sampling=self.sampling)
+                t.stop()
+                dt = t.elapsed(reset=True)
+                with annotate("sched.prefill.commit"):
+                    self._account("record_prefill", ln, dt)
+                    if req.span is not None:
+                        now = time.time()
+                        req.span.timed_child("prefill_chunk", now - dt, now,
+                                             start=start, tokens=ln)
+                    req.chunk_idx += 1
+                    # register the pages filled SO FAR (full pages only): a
+                    # same-burst sibling admitted this very step can match them
+                    self.engine.register_prefix(req.slot,
+                                                req.context[:start + ln])
+                    if req.chunk_idx < len(req.chunks):
+                        continue
+                    # final chunk: the request becomes a decoder
+                    req.state = "decode"
+                    if self.engine.drafter is not None:
+                        self.engine.drafter.prefill(req.slot, req.context)
+                    if req.resumed:
+                        # the pending token survived preemption;
+                        # nothing sampled
+                        continue
+                    now = time.perf_counter()
+                    req.first_token_t = now
+                    ttft = now - req.arrival_t
+                    self._account("record_ttft", ttft)
+                    if self._watchdog is not None:
+                        self._watchdog.observe_ttft(ttft)
+                    if self._append_tokens(req, [token])[1]:
+                        retired.append(req.uid)
 
     def _spec_k_eff(self):
         """Draft length this step: the configured k, or 0 (plain
@@ -343,40 +354,41 @@ class ContinuousBatchingScheduler:
         return k
 
     def _decode(self, retired):
-        active = [r for r in self.slots
-                  if r is not None and r.state == "decode"]
-        if not active:
-            return
-        # paged capacity for this step's writes (plain decode: 1 token;
-        # verify: k+1) — exhaustion preempts the youngest decoder
-        drafter = self.engine.drafter
-        k_eff = self._spec_k_eff() if drafter is not None else 0
-        width = 1 + k_eff
-        for req in list(active):
-            if req.state != "decode":
-                # preempted by an earlier slot's capacity fight
-                active.remove(req)
-                continue
-            ok = self.engine.ensure_pages(
-                req.slot, int(self.engine.lengths[req.slot]) + width)
-            while not ok and self._preempt_youngest(exclude=(req,)):
+        with annotate("sched.decode.pages"):
+            active = [r for r in self.slots
+                      if r is not None and r.state == "decode"]
+            if not active:
+                return
+            # paged capacity for this step's writes (plain decode: 1 token;
+            # verify: k+1) — exhaustion preempts the youngest decoder
+            drafter = self.engine.drafter
+            k_eff = self._spec_k_eff() if drafter is not None else 0
+            width = 1 + k_eff
+            for req in list(active):
+                if req.state != "decode":
+                    # preempted by an earlier slot's capacity fight
+                    active.remove(req)
+                    continue
                 ok = self.engine.ensure_pages(
                     req.slot, int(self.engine.lengths[req.slot]) + width)
-            if not ok:
-                # starved even after preemption: sit this step out (its
-                # write would land in the garbage page and the token's
-                # K/V would be lost)
-                active.remove(req)
-        # a later slot's capacity fight may have preempted an EARLIER
-        # already-validated one — keep only the still-decoding survivors
-        active = [r for r in active if r.state == "decode"]
-        if not active:
-            return
+                while not ok and self._preempt_youngest(exclude=(req,)):
+                    ok = self.engine.ensure_pages(
+                        req.slot, int(self.engine.lengths[req.slot]) + width)
+                if not ok:
+                    # starved even after preemption: sit this step out (its
+                    # write would land in the garbage page and the token's
+                    # K/V would be lost)
+                    active.remove(req)
+            # a later slot's capacity fight may have preempted an EARLIER
+            # already-validated one — keep only the still-decoding survivors
+            active = [r for r in active if r.state == "decode"]
+            if not active:
+                return
 
-        slots = self.engine.num_slots
-        pending = [0] * slots
-        for req in active:
-            pending[req.slot] = req.generated[-1]
+            slots = self.engine.num_slots
+            pending = [0] * slots
+            for req in active:
+                pending[req.slot] = req.generated[-1]
 
         if k_eff >= 1:
             # ---- speculative: draft k, verify all slots in one pass
@@ -399,33 +411,34 @@ class ContinuousBatchingScheduler:
                                              sampling=self.sampling)
             t.stop()
             dt = t.elapsed(reset=True)
-            emitted = 0
-            span_end = time.time()
-            for req in active:
-                row, s = chosen[req.slot], req.slot
-                accepted = 0
-                while accepted < k_eff and \
-                        int(tokens[s][accepted + 1]) == int(row[accepted]):
-                    accepted += 1
-                new = [int(row[j]) for j in range(accepted + 1)]
-                self.engine.advance(s, accepted + 1)
-                if drafter.needs_model:
-                    drafter.advance(s, accepted + 1)
-                self._account("record_spec", k_eff, accepted)
-                if req.span is not None:
-                    # the fused verify pass scored every slot at once:
-                    # each participant's child span shares its wall.
-                    # Added BEFORE _append_tokens — retiring exports the
-                    # tree, and a child added after export is lost
-                    req.span.timed_child(
-                        "spec_verify", span_end - dt, span_end,
-                        step=self.steps, drafted=k_eff,
-                        accepted=accepted, tokens=len(new))
-                appended, done = self._append_tokens(req, new)
-                emitted += appended
-                if done:
-                    retired.append(req.uid)
-            self._account("record_decode", emitted, dt)
+            with annotate("sched.decode.commit"):
+                emitted = 0
+                span_end = time.time()
+                for req in active:
+                    row, s = chosen[req.slot], req.slot
+                    accepted = 0
+                    while accepted < k_eff and \
+                            int(tokens[s][accepted + 1]) == int(row[accepted]):
+                        accepted += 1
+                    new = [int(row[j]) for j in range(accepted + 1)]
+                    self.engine.advance(s, accepted + 1)
+                    if drafter.needs_model:
+                        drafter.advance(s, accepted + 1)
+                    self._account("record_spec", k_eff, accepted)
+                    if req.span is not None:
+                        # the fused verify pass scored every slot at once:
+                        # each participant's child span shares its wall.
+                        # Added BEFORE _append_tokens — retiring exports the
+                        # tree, and a child added after export is lost
+                        req.span.timed_child(
+                            "spec_verify", span_end - dt, span_end,
+                            step=self.steps, drafted=k_eff,
+                            accepted=accepted, tokens=len(new))
+                    appended, done = self._append_tokens(req, new)
+                    emitted += appended
+                    if done:
+                        retired.append(req.uid)
+                self._account("record_decode", emitted, dt)
         else:
             if drafter is not None and drafter.needs_model:
                 # a k=0 propose embeds exactly the pending token into
@@ -441,18 +454,19 @@ class ContinuousBatchingScheduler:
                                                   sampling=self.sampling)
             t.stop()
             dt = t.elapsed(reset=True)
-            self._account("record_decode", len(active), dt)
-            span_end = time.time()
-            for req in active:
-                self.engine.advance(req.slot)
-                if drafter is not None and drafter.needs_model:
-                    drafter.advance(req.slot, 1)
-                if req.span is not None:
-                    req.span.timed_child("decode", span_end - dt,
-                                         span_end, step=self.steps)
-                if self._append_tokens(req,
-                                       [int(next_tokens[req.slot])])[1]:
-                    retired.append(req.uid)
+            with annotate("sched.decode.commit"):
+                self._account("record_decode", len(active), dt)
+                span_end = time.time()
+                for req in active:
+                    self.engine.advance(req.slot)
+                    if drafter is not None and drafter.needs_model:
+                        drafter.advance(req.slot, 1)
+                    if req.span is not None:
+                        req.span.timed_child("decode", span_end - dt,
+                                             span_end, step=self.steps)
+                    if self._append_tokens(req,
+                                           [int(next_tokens[req.slot])])[1]:
+                        retired.append(req.uid)
 
     def step(self):
         """Admit -> prefill chunks -> one decode/verify step -> retire.
@@ -477,33 +491,34 @@ class ContinuousBatchingScheduler:
             # telemetry.jsonl without bound and drag the snapshot's
             # occupancy/queue p50/p95 down to the idle value)
             return []
-        tel = getattr(self.engine, "telemetry", None)
-        # 0-based like the training engine's records (global_steps at
-        # window open) and ENGINE-lifetime (not per-generate-call), so
-        # joining the JSONLs on `step` and setting trace.start_step mean
-        # the same thing on both engines
-        record_step = getattr(self.engine, "serving_record_steps", 0)
-        if tel is not None:
-            # BEFORE the step's prefill/decode work so an armed xprof
-            # window opens around it, not after it (docs/telemetry.md)
-            tel.on_step_begin(record_step)
-        # the step body is a segment plan on the PlanExecutor
-        # (runtime/executor/serving.py): admit -> prefill -> decode ->
-        # retire, each phase one audited segment
-        from ..runtime.executor.serving import run_serving_step
-        ctrl = getattr(self.engine, "controller", None)
-        if ctrl is None:
-            return run_serving_step(self, record_step)
-        # closed-loop tick (docs/controller.md): the scheduler step
-        # wall is the serving objective; signals (acceptance rate,
-        # TTFT SLO burn, storm flags) come off the same telemetry
-        # seams the record just fed
-        t0 = time.time()
-        retired = run_serving_step(self, record_step)
-        from ..runtime.controller.adapters import serving_signals
-        ctrl.on_step(record_step, time.time() - t0,
-                     serving_signals(self))
-        return retired
+        with annotate("sched.step", step=self.steps):
+            tel = getattr(self.engine, "telemetry", None)
+            # 0-based like the training engine's records (global_steps at
+            # window open) and ENGINE-lifetime (not per-generate-call), so
+            # joining the JSONLs on `step` and setting trace.start_step mean
+            # the same thing on both engines
+            record_step = getattr(self.engine, "serving_record_steps", 0)
+            if tel is not None:
+                # BEFORE the step's prefill/decode work so an armed xprof
+                # window opens around it, not after it (docs/telemetry.md)
+                tel.on_step_begin(record_step)
+            # the step body is a segment plan on the PlanExecutor
+            # (runtime/executor/serving.py): admit -> prefill -> decode ->
+            # retire, each phase one audited segment
+            from ..runtime.executor.serving import run_serving_step
+            ctrl = getattr(self.engine, "controller", None)
+            if ctrl is None:
+                return run_serving_step(self, record_step)
+            # closed-loop tick (docs/controller.md): the scheduler step
+            # wall is the serving objective; signals (acceptance rate,
+            # TTFT SLO burn, storm flags) come off the same telemetry
+            # seams the record just fed
+            t0 = time.time()
+            retired = run_serving_step(self, record_step)
+            from ..runtime.controller.adapters import serving_signals
+            ctrl.on_step(record_step, time.time() - t0,
+                         serving_signals(self))
+            return retired
 
     def run(self):
         """Drive step() until every submitted request has retired; returns

@@ -27,7 +27,16 @@ passes over multi-plan programs), not intra-step overlap.
 ``_serving_step_topology`` is the ONE place the plan shape is written
 down: ``build_serving_plan(engine_or_scheduler)`` with no payloads is
 the ABSTRACT twin for ``analysis.ir.plan_of`` / the auditor.
+
+In the profiler's trace (utils.annotate) each segment is one
+``sched.<name>`` span, and ``sched.plan`` is what comes before the
+first: this module's plan build and the executor's validation and
+set-up. What lies between the segments' spans is the executor's own
+bookkeeping, the self time of the enclosing ``sched.step``.
 """
+import contextlib
+
+from ...utils.annotate import annotate
 from .plan import Segment, SegmentPlan
 
 
@@ -65,44 +74,52 @@ def run_serving_step(sched, record_step):
     accounting and the audit/rewrite surface)."""
     retired = []
     state = {}
+    setup = contextlib.ExitStack()
 
     def admit(env):
-        sched._admit()
+        setup.close()           # the first segment runs: set-up is over
+        with annotate("sched.admit"):
+            sched._admit()
 
     def prefill(env):
-        sched._prefill_chunks(retired)
+        with annotate("sched.prefill"):
+            sched._prefill_chunks(retired)
         # occupancy counts slots that did work THIS step — retire-at-
         # prefill already freed some, so measure before the decode
         # retire pass too
         state["busy"] = sched.num_active + len(retired)
 
     def decode(env):
-        sched._decode(retired)
+        with annotate("sched.decode"):
+            sched._decode(retired)
 
     def retire(env):
-        engine = sched.engine
-        sched.steps += 1
-        engine.serving_record_steps = record_step + 1
-        occupancy = min(state["busy"], engine.num_slots) \
-            / engine.num_slots
-        sched._account("record_schedule",
-                       occupancy=occupancy,
-                       queue_depth=len(sched.queue), step=sched.steps)
-        tel = getattr(engine, "telemetry", None)
-        if tel is not None:
-            # one serving_step record per scheduler step through the
-            # same sink layer the training engine writes
-            tel.emit_serving_step(
-                step=record_step, metrics=sched._record_metrics,
-                active_slots=sched.num_active,
-                queue_depth=len(sched.queue), occupancy=occupancy,
-                page_pool=engine.page_pool_stats(),
-                prefix=engine.prefix_stats(),
-                role=getattr(engine, "serving_role", None))
-        return retired
+        with annotate("sched.retire"):
+            engine = sched.engine
+            sched.steps += 1
+            engine.serving_record_steps = record_step + 1
+            occupancy = min(state["busy"], engine.num_slots) \
+                / engine.num_slots
+            sched._account("record_schedule",
+                           occupancy=occupancy,
+                           queue_depth=len(sched.queue), step=sched.steps)
+            tel = getattr(engine, "telemetry", None)
+            if tel is not None:
+                # one serving_step record per scheduler step through the
+                # same sink layer the training engine writes
+                tel.emit_serving_step(
+                    step=record_step, metrics=sched._record_metrics,
+                    active_slots=sched.num_active,
+                    queue_depth=len(sched.queue), occupancy=occupancy,
+                    page_pool=engine.page_pool_stats(),
+                    prefix=engine.prefix_stats(),
+                    role=getattr(engine, "serving_role", None))
+            return retired
 
     payloads = {"admit": admit, "prefill": prefill, "decode": decode,
                 "retire": retire}
-    plan = build_serving_plan(sched.engine, payloads=payloads)
-    env = sched.engine.plan_executor().execute(plan)
+    with setup:
+        setup.enter_context(annotate("sched.plan"))
+        plan = build_serving_plan(sched.engine, payloads=payloads)
+        env = sched.engine.plan_executor().execute(plan)
     return env["retire"]

@@ -18,11 +18,16 @@ alongside the xprof windows from telemetry.trace.
 Off (no ``telemetry.spans`` section) the engines hold ``spans = None``
 and the hot paths pay one ``is not None`` check — the same
 zero-overhead-off contract as the rest of telemetry.
+
+:func:`annotate` (re-exported here from the leaf ``utils.annotate``)
+is the other, smaller thing: the program's own boundaries written into
+the PROFILER's trace (docs/telemetry.md, "Program spans").
 """
 import itertools
 import os
 import time
 
+from ..utils.annotate import annotate  # noqa: F401 - re-export
 from ..utils.logging import logger
 
 KIND_SPAN = "span"

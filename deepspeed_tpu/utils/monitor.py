@@ -108,6 +108,8 @@ class ServingMetrics:
         # request latency: time-to-first-token and per-output-token
         self.ttfts = deque(maxlen=self.LATENCY_WINDOW)
         self.tpots = deque(maxlen=self.LATENCY_WINDOW)
+        # arrival -> first admission into a slot
+        self.queue_waits = deque(maxlen=self.LATENCY_WINDOW)
         self.completed_requests = 0
         self.completed_tokens = 0       # the goodput numerator
         # speculative decoding
@@ -130,6 +132,11 @@ class ServingMetrics:
 
     def record_ttft(self, seconds):
         self.ttfts.append(float(seconds))
+
+    def record_queue_wait(self, seconds):
+        """One request admitted into a slot ``seconds`` after it
+        arrived (the part of its TTFT spent queued, before prefill)."""
+        self.queue_waits.append(float(seconds))
 
     def record_completion(self, n_tokens, tpot_seconds):
         """One retired request: ``tpot_seconds`` is its mean
@@ -199,6 +206,9 @@ class ServingMetrics:
     def tpot_dist(self):
         return self._latency_dist(self.tpots)
 
+    def queue_wait_dist(self):
+        return self._latency_dist(self.queue_waits)
+
     def spec_dist(self):
         """{proposed, accepted, acceptance_rate} — None before the
         first verify step (spec off, or still prefill-only)."""
@@ -222,6 +232,7 @@ class ServingMetrics:
         }
         for name, dist in (("ttft", self.ttft_dist()),
                            ("tpot", self.tpot_dist()),
+                           ("queue_wait", self.queue_wait_dist()),
                            ("speculative", self.spec_dist())):
             if dist is not None:
                 out[name] = dist

@@ -7,6 +7,7 @@ device sync instead of ``torch.cuda.synchronize``.
 """
 import time
 
+from .annotate import annotate
 from .logging import logger
 
 
@@ -29,19 +30,21 @@ def _device_synchronize():
         import jax
     except Exception:  # noqa: BLE001 - timers must work without jax
         return
-    for _ in range(2):
-        try:
-            if _sync_scratch is None:
-                _sync_scratch = jax.device_put(0.0)
-            # (x + 0) enqueues one op; block_until_ready on the bare
-            # cached array would return immediately without fencing
-            (_sync_scratch + 0).block_until_ready()
-            return
-        except Exception:  # noqa: BLE001
-            # the cached buffer can go stale (backend reset between
-            # tests) — rebuild and retry ONCE so this interval still
-            # fences; a second failure means no live backend to fence
-            _sync_scratch = None
+    # "timer.sync" in the profiler's trace: what each fence costs
+    with annotate("timer.sync"):
+        for _ in range(2):
+            try:
+                if _sync_scratch is None:
+                    _sync_scratch = jax.device_put(0.0)
+                # (x + 0) enqueues one op; block_until_ready on the bare
+                # cached array would return immediately without fencing
+                (_sync_scratch + 0).block_until_ready()
+                return
+            except Exception:  # noqa: BLE001
+                # the cached buffer can go stale (backend reset between
+                # tests) — rebuild and retry ONCE so this interval still
+                # fences; a second failure means no live backend to fence
+                _sync_scratch = None
 
 
 class SynchronizedWallClockTimer:

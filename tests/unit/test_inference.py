@@ -97,6 +97,10 @@ def test_decode_logits_match_full_forward(shared):
             jnp.asarray(eng.lengths), jax.random.PRNGKey(0),
             jnp.float32(1.0), jnp.float32(1.0))
         eng.kv.update((k, v))
+        # the program reads eng.lengths (on the CPU jnp.asarray may
+        # alias the host buffer): it must have run before advance()
+        # writes into it
+        jax.block_until_ready(d_logits)
         eng.advance(0)
         step_logits.append(np.asarray(d_logits[0, 0]))
         seq.append(int(nxt[0, 0]))

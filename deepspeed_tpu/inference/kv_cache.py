@@ -110,7 +110,13 @@ class PagedKVCache:
     heads * d_head)`` — physical page 0 is the reserved garbage page
     (inference/paging.py), so ``num_pages`` counts USABLE pages. Buffers
     are jax arrays updated functionally; the engine's jitted programs
-    donate them, so steady-state serving writes in place."""
+    donate them, so steady-state serving writes in place (the compiled
+    programs alias both pools input to output, and their scatters
+    update the operand). Reads index it by (page, layer) together
+    (models/gpt2.py ``_gather_pages``, the paged kernel's DMAs): a
+    program that slices a layer out first, ``pool[:, layer]``, copies
+    that layer's whole slab — the cost then grows with ``num_pages``,
+    not with the tokens read."""
 
     k: object
     v: object

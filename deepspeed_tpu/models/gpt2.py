@@ -442,6 +442,19 @@ def _cached_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
     return ctx.astype(x.dtype).reshape(b, s, d), k_cache, v_cache
 
 
+def _gather_pages(cache, page_tables, layer_idx):
+    """Layer ``layer_idx`` of the rows' physical pages, straight from
+    the 4-D pool: ``(pages, layers, page_size, heads * d_head)``
+    indexed by ``page_tables`` (b, max_pages) -> ``(b, max_pages,
+    page_size, heads * d_head)``. ONE gather on (page, layer), so only
+    the rows' own pages are read. Slicing the layer out first
+    (``cache[:, layer_idx]``, a strided slice) makes XLA copy that
+    layer's whole slab before every gather: 279 MB a layer and cache
+    at 8,500 pages of GPT-2 medium, for 2 MB of pages
+    (tests/unit/test_tpu_compile.py pins the compiled programs)."""
+    return cache[page_tables, layer_idx]
+
+
 def _paged_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
                     positions, page_tables, valid_lens, page_size):
     """Incremental attention against the PAGED KV cache.
@@ -459,7 +472,9 @@ def _paged_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
     gathers the slot's full logical window back into contiguous (b, h,
     max_pages*page_size, d_head) rows and runs the same masked
     attention as the slot layout — identical values in identical order,
-    so paged decode is bit-compatible with the slot-cache oracle; with
+    so paged decode is bit-compatible with the slot-cache oracle. That
+    gather touches the rows' own pages of this layer and nothing else
+    of the pool (:func:`_gather_pages`); every prefill runs it. With
     ``config.paged_attention_kernel == "pallas"`` the read side runs
     the ops/pallas/paged_attention kernel instead (in-kernel page walk,
     double-buffered page fetches, online softmax — same masking
@@ -498,9 +513,9 @@ def _paged_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
                               mesh=config.kernel_mesh)
     else:
         def rows_of(cache):
-            # (P, ps, h*dh) --gather--> (b, max_pages, ps, h*dh)
+            # (P, L, ps, h*dh) --gather--> (b, max_pages, ps, h*dh)
             # -> contiguous logical rows (b, h, max_pages*ps, dh)
-            gathered = jnp.take(cache[:, layer_idx], page_tables, axis=0)
+            gathered = _gather_pages(cache, page_tables, layer_idx)
             return gathered.reshape(
                 b, max_pages * page_size, -1, dh).transpose(0, 2, 1, 3)
 

@@ -131,6 +131,48 @@ def test_paged_generate_matches_slot_streams(model, oracle):
     assert eng.allocator.pages_in_use == 0
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_paged_read_gathers_the_rows_own_pages(dtype):
+    """The XLA read of the pool, alone: one gather on (page, layer)
+    returns the very bits that slicing the layer's whole slab out and
+    taking the rows' pages from it did (the read before PR 27) — three
+    rows with different tables, garbage-page entries past each live
+    window, a page two rows share, and a page that is rewritten and
+    handed to another row between two reads."""
+    pages, layers, hd = 12, 3, 32
+    bits = {2: np.uint16, 4: np.uint32}[jnp.dtype(dtype).itemsize]
+    rng = np.random.default_rng(0)
+    pool = jnp.asarray(
+        rng.standard_normal((pages + 1, layers, PS, hd)), dtype)
+    pool = pool.at[GARBAGE_PAGE].set(jnp.nan)        # never attended
+    tables = np.array([[3, 7, 1, GARBAGE_PAGE, GARBAGE_PAGE],
+                       [5, GARBAGE_PAGE, GARBAGE_PAGE, GARBAGE_PAGE,
+                        GARBAGE_PAGE],
+                       [9, 3, 11, 2, 4]], np.int32)  # page 3: shared
+    read = jax.jit(gpt2._gather_pages, static_argnums=2)
+
+    def same_bits(pool, tables):
+        for layer in range(layers):
+            old = jnp.take(pool[:, layer], jnp.asarray(tables), axis=0)
+            for got in (read(pool, jnp.asarray(tables), layer),
+                        gpt2._gather_pages(pool, jnp.asarray(tables),
+                                           layer)):
+                assert got.shape == (3, 5, PS, hd) and got.dtype == dtype
+                np.testing.assert_array_equal(
+                    np.asarray(got).view(bits), np.asarray(old).view(bits))
+
+    same_bits(pool, tables)
+    # row 1 retires; its page 5 is rewritten and becomes row 0's fourth
+    pool = pool.at[5].set(
+        jnp.asarray(rng.standard_normal((layers, PS, hd)), dtype))
+    tables[1, 0], tables[0, 3] = GARBAGE_PAGE, 5
+    same_bits(pool, tables)
+    got = gpt2._gather_pages(pool, jnp.asarray(tables), 1)
+    np.testing.assert_array_equal(
+        np.asarray(got[0, 3]).view(bits), np.asarray(pool[5, 1]).view(bits))
+
+
 # ------------------------------------------------- allocator invariants
 
 

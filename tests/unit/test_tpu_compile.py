@@ -15,6 +15,7 @@ in a ``skipif`` or in a ``parametrize`` argument touches the topology or
 the backend.
 """
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -218,6 +219,83 @@ def test_paged_attention_compiles_on_a_tensor_parallel_mesh(
         fn, four_chips, ((b, 1, h, dh), BF16, P(None, None, "model")),
         pool, pool, ((b, 64), I32, P()), ((b,), I32, P()),
         ((b,), I32, P())) == 1
+
+
+# ------------------------------------- the serving engine's own programs
+@pytest.fixture(scope="module")
+def serving_engine():
+    """Two layers at gpt2_medium widths behind ``init_inference`` on the
+    CPU; the pool is tiny here — the programs are lowered at the
+    benchmark cells' pool shapes, which are arguments."""
+    import deepspeed_tpu as deepspeed
+    from deepspeed_tpu.models import gpt2
+    cfg = gpt2.GPT2Config(vocab_size=50257, max_seq_len=1024, n_layers=2,
+                          n_heads=16, d_model=1024,
+                          use_flash_attention=False, remat=False)
+    return deepspeed.init_inference(
+        model=gpt2.make_gpt2_model(config=cfg, seed=0),
+        config={"inference": {
+            "max_batch_size": 2, "dtype": "bf16", "kv_layout": "paged",
+            "kv_block_size": 16, "num_pages": 64, "greedy": True,
+            "paged_attention_kernel": "pallas",
+            "prefill_buckets": [128, 1024]}})
+
+
+# (pages, a prefill bucket, slots) of the two serving cells (benchmark/
+# configs/gpt2-350m-serve.json, -serve-batch.json); a row of either has
+# the model's 1024 positions / 16 = 64 pages, as the fixture's engine
+_SERVING_CELLS = {"chat": (3072, 128, 64), "docs": (8500, 1024, 128)}
+
+
+@pytest.mark.parametrize("cell", sorted(_SERVING_CELLS))
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_serving_programs_copy_no_layer_slab(
+        one_chip, no_persistent_cache, serving_engine, monkeypatch,
+        program, cell):
+    """``jit_prefill`` (the XLA read, one row) and ``jit_decode`` (the
+    Pallas kernel, every slot) at the cells' pool shapes: no
+    instruction makes a ``[pages + 1, page, heads * d_head]`` array — a
+    layer's whole slab of the pool, 101 MB in chat and 279 MB in docs,
+    which prefill copied twice a layer while its read sliced the layer
+    out before gathering (a row's 64 pages, 2 MB, were then taken from
+    the copy; 42-44% of docs' busy device time, ledger PR 26) — and
+    both donated pools come back in place."""
+    eng = serving_engine
+    (pages, bucket, slots), row = _SERVING_CELLS[cell], eng.max_pages
+    cfg = eng.model_config
+    layers, ps, hd = cfg.n_layers, eng.page_size, cfg.n_heads * cfg.d_head
+    # the kernel dispatch asks the backend whether to interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype),
+                                    eng.params)
+    pool = sds((pages + 1, layers, ps, hd), BF16)
+    rng = jax.random.PRNGKey(0)
+    tail = (sds(rng.shape, rng.dtype), sds((), F32), sds((), F32))
+    if program == "prefill":
+        fn = eng._get_prefill_fn(bucket, True, 0)
+        args = (sds((1, bucket), I32), sds((row,), I32), sds((), I32),
+                sds((), I32))
+    else:
+        fn = eng._get_decode_fn(True, 0)
+        args = (sds((slots, 1), I32), sds((slots,), I32),
+                sds((slots, row), I32))
+    text = fn.lower(params, pool, pool, *args, *tail).compile().as_text()
+
+    assert text.startswith("HloModule jit_" + program)
+    slab = "bf16[{},{},{}]".format(pages + 1, ps, hd)
+    assert not [line.strip()[:160] for line in text.splitlines()
+                if slab in line][:3]
+    assert text.count("tpu_custom_call") == \
+        (layers if program == "decode" else 0)
+    aliased = {int(out): int(arg) for out, arg in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+        text.split("\n", 1)[0])}
+    n_params = len(jax.tree_util.tree_leaves(params))
+    assert aliased == {0: n_params, 1: n_params + 1}
 
 
 # ------------------------------------------------------- compiler params

@@ -364,14 +364,21 @@ def collect_inference_programs(engine):
     top_p = np.float32(1.0)
     paged = engine.kv_layout == "paged"
     n_buckets = len(engine.prefill_buckets)
+    # a model with recurrent layers: its state arrays ride behind the
+    # page pool, donated like it, then the slot (prefill) or the
+    # advance mask (decode)
+    pool = getattr(engine, "state", None)
+    state = tuple(_sds(a) for a in pool.buffers()) if pool else ()
+    donate = tuple(range(1, 3 + len(state)))
     specs = []
     greedy, top_k = True, 0
     for bucket in engine.prefill_buckets:
         ids = jax.ShapeDtypeStruct((1, bucket), np.int32)
         if paged:
-            args = (params, k_sds, v_sds, ids,
-                    jax.ShapeDtypeStruct((engine.max_pages,), np.int32),
-                    np.int32(0), np.int32(1), rng, temp, top_p)
+            args = (params, k_sds, v_sds) + state + (
+                (np.int32(0),) if state else ()) + (
+                ids, jax.ShapeDtypeStruct((engine.max_pages,), np.int32),
+                np.int32(0), np.int32(1), rng, temp, top_p)
         else:
             args = (params, k_sds, v_sds, ids, np.int32(0), np.int32(0),
                     np.int32(1), rng, temp, top_p)
@@ -379,7 +386,7 @@ def collect_inference_programs(engine):
             name="prefill/b{}".format(bucket), family="inference",
             build=lambda b=bucket: _unjitted_prefill(engine, b, greedy,
                                                      top_k),
-            args=args, donate=(1, 2), mesh=engine.mesh,
+            args=args, donate=donate, mesh=engine.mesh,
             # no allow_weak needed: every scalar operand is an explicit
             # np.int32/np.float32 (strong-typed)
             taint_paths=("0",), trace_bound=n_buckets))
@@ -392,8 +399,10 @@ def collect_inference_programs(engine):
         if paged:
             tables = jax.ShapeDtypeStruct(
                 (engine.num_slots, engine.max_pages), np.int32)
-            args = (params, k_sds, v_sds, tokens, lengths, tables, rng,
-                    temp, top_p)
+            args = (params, k_sds, v_sds) + state + (
+                (jax.ShapeDtypeStruct((engine.num_slots,), np.bool_),)
+                if state else ()) + (tokens, lengths, tables, rng, temp,
+                                     top_p)
         else:
             args = (params, k_sds, v_sds, tokens, lengths, rng, temp,
                     top_p)
@@ -401,7 +410,7 @@ def collect_inference_programs(engine):
             name=name, family="inference",
             build=lambda w=width: _unjitted_decode(engine, greedy, top_k,
                                                    w),
-            args=args, donate=(1, 2), mesh=engine.mesh,
+            args=args, donate=donate, mesh=engine.mesh,
             taint_paths=("0",), trace_bound=len(widths)))
     return specs
 

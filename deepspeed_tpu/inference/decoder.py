@@ -1,0 +1,72 @@
+"""What ``init_inference()`` asks of a model: the decoder protocol.
+
+The serving engine imports no model module. The model handed to it
+carries a ``decoder`` (``make_gpt2_model`` and ``make_jamba_model``
+attach one) with:
+
+* ``config``: ``vocab_size``, ``max_seq_len``, ``d_model``;
+* ``cache_spec()`` -> :class:`CacheSpec`: the keys and values it keeps
+  (``kv_layers`` layers of ``kv_heads x d_head``, in pages or slots)
+  and, optionally, per-slot recurrent state arrays;
+* ``serving_config(mesh)`` -> the model config the serving programs
+  close over (deterministic, dense; raises for a mesh it cannot span);
+  ``decode_config(config, paged_attention_kernel)`` -> the decode
+  program family's variant of it;
+* ``serving_params(params, dtype)`` -> the weights as served;
+* ``forward_hidden(params, ids, config, cache=, positions=,
+  page_tables=, valid_lens=, page_size=[, state_slot= |
+  state_advance=])`` -> ``(hidden, cache)`` over the cache pytree
+  ``(k, v, *state arrays)``; the two ``state_*`` arguments are passed
+  to a ``recurrent`` decoder only: ``state_slot`` with a prefill chunk
+  (one slot; ``positions == 0`` marks a request's first chunk, which
+  must start from a zero state whatever the slot held),
+  ``state_advance`` (slots,) bool with a decode step (the slots whose
+  state this step may advance);
+* ``logits(params, hidden)``: the head.
+
+``recurrent`` is true where the pages are NOT the whole of a request's
+state: prefix sharing, drafting and the fleet's page hand-off refuse
+such a model at construction.
+"""
+from dataclasses import dataclass
+
+
+@dataclass(frozen=True)
+class StateSpec:
+    """One per-slot recurrent state array of shape ``lead + (slots,) +
+    tail``: layer-major, so that a program reads and writes one layer's
+    region of it in place."""
+    name: str
+    lead: tuple
+    tail: tuple
+    dtype: object
+
+
+@dataclass(frozen=True)
+class CacheSpec:
+    kv_layers: int
+    kv_heads: int
+    d_head: int
+    state: tuple = ()
+
+
+def decoder_of(model, module=None):
+    """The decoder ``model`` (or the ``Model`` made of it) carries."""
+    for holder in (module, model):
+        decoder = getattr(holder, "decoder", None)
+        if decoder is not None:
+            return decoder
+    raise AssertionError(
+        "init_inference needs a model with a decoder at .decoder "
+        "(inference/decoder.py; e.g. models.gpt2.make_gpt2_model, "
+        "models.jamba.make_jamba_model)")
+
+
+def refuse_recurrent(engine_or_decoder, what):
+    """One sentence for every feature that takes the pages for the
+    whole of a request's state."""
+    decoder = getattr(engine_or_decoder, "decoder", engine_or_decoder)
+    if getattr(decoder, "recurrent", False):
+        raise ValueError(
+            "{} cannot serve a model with recurrent layers: its state "
+            "is not in the pages".format(what))

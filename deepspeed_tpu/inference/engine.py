@@ -1,4 +1,8 @@
-"""InferenceEngine: KV-cache serving for GPT-2-family models.
+"""InferenceEngine: cached serving of any model that carries a decoder
+(inference/decoder.py): GPT-2, whose layers all keep keys and values,
+and Jamba, whose state-space layers keep a per-slot recurrent state
+beside the two attention layers' pages. No model module is imported
+here.
 
 The serving counterpart of ``runtime/engine.py``'s training engine,
 returned by ``deepspeed_tpu.init_inference()``. Jitted hot paths:
@@ -31,8 +35,6 @@ layouts shard their heads (kv_cache.KV_CACHE_SPEC /
 PAGED_KV_CACHE_SPEC), so XLA runs
 decode with each model shard attending over exactly the heads it owns.
 """
-import dataclasses
-
 import numpy as np
 
 import jax
@@ -42,7 +44,8 @@ from ..runtime.executor.jit import jit_program
 from ..utils.annotate import annotate
 from ..utils.logging import logger
 from .config import DeepSpeedInferenceConfig
-from .kv_cache import KVCache, PagedKVCache
+from .decoder import decoder_of, refuse_recurrent
+from .kv_cache import KVCache, PagedKVCache, StatePool
 from .paging import GARBAGE_PAGE, PageAllocator, PrefixCache
 from .sampling import make_sampler
 
@@ -86,20 +89,19 @@ def _parse_configs(config, mesh=None):
 
 
 class InferenceEngine:
-    """Incremental-decode engine over a ``runtime.model.Model`` whose
-    ``.config`` is a :class:`models.gpt2.GPT2Config` (``make_gpt2_model``
-    attaches it). Prompt/token values are plain ints; all device state
-    (params, KV cache) lives on ``mesh`` when one is given."""
+    """Incremental-decode engine over a ``runtime.model.Model`` that
+    carries a decoder (``make_gpt2_model`` and ``make_jamba_model``
+    attach one; inference/decoder.py says what it gives). Prompt/token
+    values are plain ints; all device state (params, KV cache,
+    recurrent state) lives on ``mesh`` when one is given."""
 
     def __init__(self, model, config=None, mesh=None, dtype=None, seed=0,
                  draft_model=None):
         from ..runtime.model import as_model
         self.module = as_model(model)
-        model_config = getattr(self.module, "config", None) or \
-            getattr(model, "config", None)
-        assert model_config is not None and hasattr(model_config, "n_heads"), \
-            "init_inference needs a model with a GPT2Config at .config " \
-            "(e.g. models.gpt2.make_gpt2_model)"
+        self.decoder = decoder_of(model, self.module)
+        model_config = self.decoder.config
+        self.recurrent = bool(getattr(self.decoder, "recurrent", False))
         self.inference_config, telemetry_config, analysis_config, \
             runtime_cfg = _parse_configs(config, mesh=mesh)
         # segment-plan executor (runtime/executor/, docs/executor.md):
@@ -133,13 +135,9 @@ class InferenceEngine:
             self.dtype_name = self.inference_config.dtype_name
         self.mesh = mesh
 
-        # serving model config: deterministic, dense path (the cached
-        # attention owns masking; flash/scan/SP are training-path levers)
-        self.model_config = dataclasses.replace(
-            model_config, dropout=0.0, scan_blocks=False,
-            sequence_parallel=None, sp_mesh=None, sparse_attention=None,
-            sparse_embedding_grads=False, embedding_grad_mesh=None,
-            paged_attention_kernel="xla", kernel_mesh=mesh)
+        # the config the serving programs close over (the decoder's
+        # deterministic, dense variant; it refuses a mesh it cannot span)
+        self.model_config = self.decoder.serving_config(mesh)
 
         ic = self.inference_config
         self.max_seq_len = ic.max_seq_len or model_config.max_seq_len
@@ -149,28 +147,38 @@ class InferenceEngine:
         self.num_slots = ic.max_batch_size
         self.prefill_buckets = ic.resolve_buckets(self.max_seq_len)
 
-        params = self.module.params
-        if getattr(model_config, "scan_blocks", False):
-            # serving iterates blocks as a python list; unstack the
-            # scan-trained (L, ...) layout once at engine build
-            blocks = params["blocks"]
-            params = dict(params)
-            params["blocks"] = [
-                jax.tree_util.tree_map(lambda t, i=i: t[i], blocks)
-                for i in range(model_config.n_layers)]
-        self.params = self._place_params(params, self.dtype)
+        self.params = self._place_params(
+            self.decoder.serving_params(self.module.params, self.dtype))
 
         # ------------------------------------------------- KV cache layout
+        spec = self.decoder.cache_spec()
         self.kv_layout = ic.kv_layout
         self.page_size = ic.kv_block_size
+        if self.recurrent:
+            if self.kv_layout != "paged":
+                raise ValueError(
+                    "a model with recurrent layers is served from the "
+                    "paged layout only (inference.kv_layout: \"paged\")")
+            if ic.prefix_caching:
+                refuse_recurrent(self.decoder,
+                                 "prefix caching (inference.prefix_caching)")
+            if ic.spec_enabled:
+                refuse_recurrent(self.decoder, "speculative decoding "
+                                 "(inference.speculative)")
+            if ic.fleet_role is not None:
+                refuse_recurrent(self.decoder, "the fleet's page hand-off "
+                                 "(inference.fleet)")
+        # per-slot recurrent state, a pool of its own beside the pages
+        # (None for a model whose pages are its whole state)
+        self.state = StatePool.allocate(spec.state, self.num_slots) \
+            if spec.state else None
         if self.kv_layout == "paged":
             self.max_pages = -(-self.max_seq_len // self.page_size)
             num_pages = ic.resolve_num_pages(self.num_slots,
                                              self.max_seq_len)
             self.kv = PagedKVCache.allocate(
-                num_pages, self.model_config.n_layers,
-                self.model_config.n_heads, self.page_size,
-                self.model_config.d_head, self.dtype, mesh=mesh)
+                num_pages, spec.kv_layers, spec.kv_heads, self.page_size,
+                spec.d_head, self.dtype, mesh=mesh)
             self.allocator = PageAllocator(num_pages)
             # per-slot logical->physical map; GARBAGE_PAGE everywhere a
             # slot has no allocation (jit writes there are redirected
@@ -187,9 +195,8 @@ class InferenceEngine:
         else:
             self.max_pages = 0
             self.kv = KVCache.allocate(
-                self.num_slots, self.model_config.n_layers,
-                self.model_config.n_heads, self.max_seq_len,
-                self.model_config.d_head, self.dtype, mesh=mesh)
+                self.num_slots, spec.kv_layers, spec.kv_heads,
+                self.max_seq_len, spec.d_head, self.dtype, mesh=mesh)
             self.allocator = None
             self.page_tables = None
             self.page_counts = None
@@ -289,10 +296,11 @@ class InferenceEngine:
                     self, controller_cfg)
         logger.info(
             "InferenceEngine: slots={} max_seq={} buckets={} dtype={} "
-            "layout={} kv_cache={:.1f} MB{}{}".format(
+            "layout={} kv_cache={:.1f} MB state_pool={:.1f} MB{}{}".format(
                 self.num_slots, self.max_seq_len, self.prefill_buckets,
                 self.dtype_name, self.kv_layout,
                 self.kv.nbytes / 2 ** 20,
+                self.state.nbytes / 2 ** 20 if self.state else 0.0,
                 " pages={}x{} paged_attn={}".format(
                     self.allocator.num_pages, self.page_size,
                     self.paged_attention_kernel)
@@ -379,12 +387,7 @@ class InferenceEngine:
 
     # ---------------------------------------------------------- placement
 
-    def _place_params(self, params, dtype):
-        def cast(x):
-            x = jnp.asarray(x)
-            return x.astype(dtype) if jnp.issubdtype(x.dtype,
-                                                     jnp.floating) else x
-        params = jax.tree_util.tree_map(cast, params)
+    def _place_params(self, params):
         if self.mesh is not None and \
                 self.module.partition_spec_fn is not None:
             from ..runtime.zero.partition import ZeroShardingPlan
@@ -452,10 +455,15 @@ class InferenceEngine:
         top_p = float(s.get("top_p", ic.top_p))
         return greedy, top_k, temperature, top_p
 
-    @staticmethod
-    def _last_logits(params, hidden):
-        # tied-embedding LM head (models/gpt2.py lm_loss convention)
-        return hidden @ params["wte"].astype(hidden.dtype).T
+    def _state_buffers(self):
+        return self.state.buffers() if self.state is not None else ()
+
+    def _update_cache(self, buffers):
+        """What a program returned in place of its donated buffers: the
+        page (or slot) pool pair, then the recurrent state arrays."""
+        self.kv.update(tuple(buffers[:2]))
+        if self.state is not None:
+            self.state.update(tuple(buffers[2:]))
 
     def _get_prefill_fn(self, bucket, greedy, top_k):
         # attached adapters switch to an extended program family (extra
@@ -465,33 +473,42 @@ class InferenceEngine:
         fn = self._prefill_fns.get(key)
         if fn is not None:
             return fn
-        from ..models import gpt2
         cfg = self.model_config
+        forward, head = self.decoder.forward_hidden, self.decoder.logits
         sampler = make_sampler(greedy, top_k)
         paged, ps = self.kv_layout == "paged", self.page_size
+        n_state = len(self._state_buffers())
 
         if paged:
-            def prefill(params, k_cache, v_cache, ids, page_row, start,
-                        length, rng, temperature, top_p, *adapter_args):
-                # ids (1, bucket); page_row (max_pages,); start/length
-                # scalar int32 — the chunk covers positions
+            def prefill(params, k_cache, v_cache, *rest):
+                # rest: the recurrent state arrays (none for a model
+                # without) and, with them, slot (scalar int32: whose
+                # state); then ids (1, bucket); page_row (max_pages,);
+                # start/length scalar int32 — the chunk covers positions
                 # [start, start+length); padded tokens redirect to the
-                # garbage page via the masked scatter. adapter_args
-                # (when attached): (a_stack (n,r,d), b_stack (n,V,r),
-                # adapter_id scalar) — a per-tenant logits delta; the
-                # KV write path is adapter-independent.
-                hidden, (k_cache, v_cache) = gpt2.forward_hidden(
-                    params, ids, cfg, cache=(k_cache, v_cache),
+                # garbage page via the masked scatter and leave a
+                # recurrent state as it was; rng, temperature, top_p;
+                # adapter args (when attached): (a_stack (n,r,d),
+                # b_stack (n,V,r), adapter_id scalar) — a per-tenant
+                # logits delta; the cache writes are adapter-independent.
+                state, rest = rest[:n_state], rest[n_state:]
+                kwargs = {}
+                if n_state:
+                    kwargs["state_slot"], rest = rest[0], rest[1:]
+                ids, page_row, start, length, rng, temperature, top_p, \
+                    *adapter_args = rest
+                hidden, cache = forward(
+                    params, ids, cfg, cache=(k_cache, v_cache) + state,
                     positions=start[None], page_tables=page_row[None],
-                    valid_lens=length[None], page_size=ps)
+                    valid_lens=length[None], page_size=ps, **kwargs)
                 last = jnp.take(hidden[0], length - 1, axis=0)     # (d,)
-                logits = self._last_logits(params, last[None])     # (1, V)
+                logits = head(params, last[None])                  # (1, V)
                 if adapter_args:
                     a_stack, b_stack, aid = adapter_args
                     logits = logits + \
                         (b_stack[aid] @ (a_stack[aid] @ last))[None]
                 token = sampler(logits, rng, temperature, top_p)[0]
-                return k_cache, v_cache, token, logits[0]
+                return (*cache, token, logits[0])
         else:
             def prefill(params, k_cache, v_cache, ids, slot, start,
                         length, rng, temperature, top_p, *adapter_args):
@@ -502,7 +519,7 @@ class InferenceEngine:
                                                      axis=0)
                 v_row = jax.lax.dynamic_slice_in_dim(v_cache, slot, 1,
                                                      axis=0)
-                hidden, (k_row, v_row) = gpt2.forward_hidden(
+                hidden, (k_row, v_row) = forward(
                     params, ids, cfg, cache=(k_row, v_row),
                     positions=start[None])
                 k_cache = jax.lax.dynamic_update_slice_in_dim(
@@ -510,7 +527,7 @@ class InferenceEngine:
                 v_cache = jax.lax.dynamic_update_slice_in_dim(
                     v_cache, v_row, slot, axis=0)
                 last = jnp.take(hidden[0], length - 1, axis=0)     # (d,)
-                logits = self._last_logits(params, last[None])     # (1, V)
+                logits = head(params, last[None])                  # (1, V)
                 if adapter_args:
                     a_stack, b_stack, aid = adapter_args
                     logits = logits + \
@@ -519,8 +536,9 @@ class InferenceEngine:
                 return k_cache, v_cache, token, logits[0]
 
         # the function's name is the program's in a profiler trace
-        # (module `jit_prefill`): a contract, pinned by a test
-        fn = jit_program(prefill, donate=(1, 2))
+        # (module `jit_prefill`): a contract, pinned by a test. Every
+        # cache buffer is donated and comes back in place
+        fn = jit_program(prefill, donate=tuple(range(1, 3 + n_state)))
         self._prefill_fns[key] = fn
         self.compile_stats["prefill_traces"] += 1
         if self.telemetry is not None:
@@ -538,16 +556,16 @@ class InferenceEngine:
         fn = self._decode_fns.get(key)
         if fn is not None:
             return fn
-        from ..models import gpt2
         # decode is the ONE family that may run the Pallas paged-
         # attention kernel (docs/pallas_kernels.md dispatch rules);
-        # self.model_config keeps "xla" so prefill and every oracle
-        # comparison stay on the gather path
-        cfg = dataclasses.replace(
-            self.model_config,
-            paged_attention_kernel=self.paged_attention_kernel)
+        # self.model_config keeps the gather path for prefill and every
+        # oracle comparison
+        cfg = self.decoder.decode_config(self.model_config,
+                                         self.paged_attention_kernel)
+        forward, head = self.decoder.forward_hidden, self.decoder.logits
         sampler = make_sampler(greedy, top_k)
         paged, ps = self.kv_layout == "paged", self.page_size
+        n_state = len(self._state_buffers())
 
         def _adapter_delta(hidden, a_stack, b_stack, adapter_ids):
             # per-slot LoRA readout: gather each slot's (A, B) pair and
@@ -559,30 +577,38 @@ class InferenceEngine:
                               b_stack[adapter_ids])    # (slots, width, V)
 
         if paged:
-            def decode(params, k_cache, v_cache, tokens, lengths,
-                       page_tables, rng, temperature, top_p,
-                       *adapter_args):
-                # tokens (slots, width); lengths (slots,) int32
-                hidden, (k_cache, v_cache) = gpt2.forward_hidden(
-                    params, tokens, cfg, cache=(k_cache, v_cache),
+            def decode(params, k_cache, v_cache, *rest):
+                # rest: the recurrent state arrays and, with them,
+                # advance (slots,) bool (the slots whose state this
+                # step advances); then tokens (slots, width); lengths
+                # (slots,) int32; page_tables; rng, temperature, top_p;
+                # adapter args
+                state, rest = rest[:n_state], rest[n_state:]
+                kwargs = {}
+                if n_state:
+                    kwargs["state_advance"], rest = rest[0], rest[1:]
+                tokens, lengths, page_tables, rng, temperature, top_p, \
+                    *adapter_args = rest
+                hidden, cache = forward(
+                    params, tokens, cfg, cache=(k_cache, v_cache) + state,
                     positions=lengths, page_tables=page_tables,
                     valid_lens=jnp.full_like(lengths, tokens.shape[1]),
-                    page_size=ps)
-                logits = self._last_logits(params, hidden)
+                    page_size=ps, **kwargs)
+                logits = head(params, hidden)
                 if adapter_args:
                     logits = logits + _adapter_delta(hidden,
                                                      *adapter_args)
                 flat = logits.reshape(-1, logits.shape[-1])
                 chosen = sampler(flat, rng, temperature,
                                  top_p).reshape(tokens.shape)
-                return k_cache, v_cache, chosen, logits
+                return (*cache, chosen, logits)
         else:
             def decode(params, k_cache, v_cache, tokens, lengths, rng,
                        temperature, top_p, *adapter_args):
-                hidden, (k_cache, v_cache) = gpt2.forward_hidden(
+                hidden, (k_cache, v_cache) = forward(
                     params, tokens, cfg, cache=(k_cache, v_cache),
                     positions=lengths)
-                logits = self._last_logits(params, hidden)
+                logits = head(params, hidden)
                 if adapter_args:
                     logits = logits + _adapter_delta(hidden,
                                                      *adapter_args)
@@ -594,7 +620,7 @@ class InferenceEngine:
         # the function's name is the program's in a profiler trace:
         # module `jit_decode`, and its Mosaic call `%decode.N`, by which
         # the benchmark finds the paged kernel. Pinned by a test
-        fn = jit_program(decode, donate=(1, 2))
+        fn = jit_program(decode, donate=tuple(range(1, 3 + n_state)))
         self._decode_fns[key] = fn
         self.compile_stats["decode_traces"] += 1
         if self.telemetry is not None:
@@ -750,7 +776,10 @@ class InferenceEngine:
         sharing never appends into a shared page, so this is the safety
         net that makes sharing granularity a policy choice rather than
         a correctness constraint."""
-        if self.kv_layout != "paged":
+        if self.kv_layout != "paged" or not self.allocator.shared_pages:
+            # no page is held twice (only prefix sharing does that):
+            # nothing to fork, and the walk over every slot's pages was
+            # 1-2 ms of each decode step at 384 slots
             return
         lo = first_pos // self.page_size
         hi = min(last_pos // self.page_size,
@@ -791,16 +820,21 @@ class InferenceEngine:
             greedy, top_k, temperature, top_p = \
                 self._sampling_key(sampling)
             fn = self._get_prefill_fn(bucket, greedy, top_k)
+            # host values go to the program as numpy (copies, so that
+            # the engine's own tables can change while a launch is in
+            # flight): the call uploads them together, where a
+            # jnp.asarray each was a transfer and a dispatch of its own
+            # (3 to 6 ms of a step at 384 slots, PERF.md section 6)
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :n] = np.asarray(tokens, np.int32)
             extra = ()
             if self.adapters is not None:
                 a_stack, b_stack = self._adapter_stack
                 extra = (a_stack, b_stack,
-                         jnp.int32(int(self.slot_adapters[slot])))
+                         np.int32(self.slot_adapters[slot]))
             if self.kv_layout == "paged":
                 self._cow_writes(slot, start, start + n - 1)
-                where = jnp.asarray(self.page_tables[slot])
+                where = self.page_tables[slot].copy()
             else:
                 # the slot layout writes the padded bucket with one
                 # dynamic_update_slice — paging.plan_chunks guarantees
@@ -809,13 +843,19 @@ class InferenceEngine:
                 assert start + bucket <= self.max_seq_len, \
                     "chunk bucket {}@{} overruns max_seq_len {}".format(
                         bucket, start, self.max_seq_len)
-                where = jnp.int32(slot)
-            args = (jnp.asarray(ids), where, jnp.int32(start),
-                    jnp.int32(n), self._next_rng(),
-                    jnp.float32(temperature), jnp.float32(top_p)) + extra
+                where = np.int32(slot)
+            state = self._state_buffers()
+            if state:
+                # the program of a request's first chunk (start 0)
+                # zeroes the slot's state itself: no launch of its own
+                state += (np.int32(slot),)
+            args = state + (
+                ids, where, np.int32(start), np.int32(n),
+                self._next_rng(), np.float32(temperature),
+                np.float32(top_p)) + extra
         with annotate("engine.prefill.dispatch"):
-            k, v, token, _ = fn(self.params, self.kv.k, self.kv.v, *args)
-            self.kv.update((k, v))
+            *cache, token, _ = fn(self.params, self.kv.k, self.kv.v, *args)
+            self._update_cache(cache)
             self.lengths[slot] = start + n
         with annotate("engine.prefill.fetch"):
             return int(token)
@@ -833,14 +873,19 @@ class InferenceEngine:
             assert self.ensure_pages(slot, n), "KV page pool exhausted"
         return self.prefill_chunk(slot, prompt, 0, sampling=sampling)
 
-    def decode_step(self, tokens, sampling=None):
+    def decode_step(self, tokens, sampling=None, active=None):
         """One decode step for ALL slots: ``tokens`` (slots,) or
         (slots, width) are each slot's pending token (+ drafted tokens
         for the speculative verify pass; anything for inactive slots).
         Returns the same-shaped int array of chosen tokens — for
         width=1 the sampled next token per slot; the caller decides
-        which slots' results are live and calls :meth:`advance`."""
-        tokens = np.asarray(tokens, np.int32)
+        which slots' results are live and calls :meth:`advance`.
+        ``active``: the slots that are decoding (default: all). Keys
+        and values written for any other slot are masked or land in
+        the garbage page, but a recurrent state has no mask: only the
+        active slots' state advances (a slot between two chunks of its
+        prompt keeps what the first chunk left)."""
+        tokens = np.array(tokens, np.int32)      # a copy: see prefill_chunk
         squeeze = tokens.ndim == 1
         if squeeze:
             tokens = tokens[:, None]
@@ -853,7 +898,7 @@ class InferenceEngine:
             if self.adapters is not None:
                 a_stack, b_stack = self._adapter_stack
                 extra = (a_stack, b_stack,
-                         jnp.asarray(self.slot_adapters, jnp.int32))
+                         self.slot_adapters.astype(np.int32))
             paged = self.kv_layout == "paged"
             if paged:
                 for slot in range(self.num_slots):
@@ -861,13 +906,20 @@ class InferenceEngine:
                         self._cow_writes(
                             slot, int(self.lengths[slot]),
                             int(self.lengths[slot]) + width - 1)
-            args = (jnp.asarray(tokens), jnp.asarray(self.lengths)) + (
-                (jnp.asarray(self.page_tables),) if paged else ()) + (
-                self._next_rng(), jnp.float32(temperature),
-                jnp.float32(top_p)) + extra
+            state = self._state_buffers()
+            if state:
+                advance = np.ones((self.num_slots,), bool)
+                if active is not None:
+                    advance[:] = False
+                    advance[list(active)] = True
+                state += (advance,)
+            args = state + (tokens, self.lengths.copy()) + (
+                (self.page_tables.copy(),) if paged else ()) + (
+                self._next_rng(), np.float32(temperature),
+                np.float32(top_p)) + extra
         with annotate("engine.decode.dispatch"):
-            k, v, chosen, _ = fn(self.params, self.kv.k, self.kv.v, *args)
-            self.kv.update((k, v))
+            *cache, chosen, _ = fn(self.params, self.kv.k, self.kv.v, *args)
+            self._update_cache(cache)
         with annotate("engine.decode.fetch"):
             chosen = np.asarray(chosen)
         return chosen[:, 0] if squeeze else chosen

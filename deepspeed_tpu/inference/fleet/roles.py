@@ -17,6 +17,7 @@ imported page table at identical positions.
 """
 import time
 
+from ..decoder import refuse_recurrent
 from ..scheduler import ContinuousBatchingScheduler, InferenceRequest
 from ..paging import plan_chunks
 from .handoff import (DEFAULT_HANDOFF_BLOCK, can_import, export_slice,
@@ -33,6 +34,7 @@ class PrefillRole:
         assert engine.kv_layout == "paged", \
             "the prefill role needs kv_layout 'paged' (page-table " \
             "slices are its export format)"
+        refuse_recurrent(engine, "the fleet's page hand-off (prefill role)")
         self.engine = engine
         self.sampling = sampling
         self.quantize = bool(quantize)
@@ -124,6 +126,7 @@ class DecodeRole:
         assert engine.kv_layout == "paged", \
             "the decode role needs kv_layout 'paged' (it imports " \
             "page-table slices)"
+        refuse_recurrent(engine, "the fleet's page hand-off (decode role)")
         self.engine = engine
         engine.serving_role = "decode"
         self.sched = ContinuousBatchingScheduler(engine, metrics=metrics,

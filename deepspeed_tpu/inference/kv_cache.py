@@ -1,4 +1,7 @@
-"""Preallocated KV caches for incremental decode: slot and paged layouts.
+"""Preallocated serving caches: keys and values in a slot or a paged
+layout, and, for a model with recurrent layers, a pool of per-slot
+state beside them (:class:`StatePool`). What a model keeps it declares
+itself (inference/decoder.py ``CacheSpec``); no model is imported here.
 
 **Slot layout** (:class:`KVCache`, the numerics oracle and default): one
 buffer pair ``(k, v)`` of shape ``(slots, layers, heads, max_seq,
@@ -21,11 +24,17 @@ tokens, not with
 into many tables (prefix sharing). Physical page 0 is the reserved
 garbage page: never allocated, the target of every masked/padded write.
 
-Freed slots and recycled pages are reused WITHOUT clearing — the
-absolute-position causal mask in the model's cached attention
-(models/gpt2.py ``_attend_cache_rows``: ``k_pos <= q_pos``) makes stale
-entries unreachable in both layouts, for any garbage content including
-NaN (pinned by tests/unit/test_serving.py poison tests).
+Reuse, per cache kind. Keys and values: freed slots and recycled pages
+are reused WITHOUT clearing — the absolute-position causal mask in the
+model's cached attention (models/gpt2.py ``_attend_cache_rows``,
+models/jamba.py ``_attend``: ``k_pos <= q_pos``) makes stale entries
+unreachable in both layouts, for any garbage content including NaN
+(pinned by tests/unit/test_serving.py poison tests). Recurrent state:
+NO mask hides what a slot held, so it is not reused as it is: the
+prefill program that runs a request's first chunk starts from zeros
+whatever the slot holds (no clearing launch of its own), and a decode
+step advances only the slots that are decoding (pinned by
+tests/unit/test_jamba.py's NaN-poisoned state pool).
 
 Sharding: the heads carry the tensor-parallel partition in both layouts
 (the slot cache's ``heads`` axis, the paged pool's packed ``heads *
@@ -102,6 +111,36 @@ def _shard_heads(k, v, heads, mesh, spec):
         k = jax.device_put(k, sharding)
         v = jax.device_put(v, sharding)
     return k, v
+
+
+@dataclass
+class StatePool:
+    """Per-slot recurrent state: one array per ``StateSpec`` of the
+    model's ``CacheSpec``, each ``lead + (slots,) + tail`` — layer-major,
+    so a program reads and writes one layer's region (a static index on
+    the leading dimension) and never copies a slab to reach a slot.
+    Like the page pool the arrays are donated to, and aliased input to
+    output by, every serving program. Replicated on a mesh (a model
+    that keeps one refuses a ``model`` axis)."""
+
+    arrays: tuple
+    num_slots: int
+
+    @classmethod
+    def allocate(cls, specs, slots):
+        return cls(tuple(
+            jnp.zeros(tuple(s.lead) + (slots,) + tuple(s.tail), s.dtype)
+            for s in specs), int(slots))
+
+    @property
+    def nbytes(self):
+        return sum(a.size * a.dtype.itemsize for a in self.arrays)
+
+    def buffers(self):
+        return self.arrays
+
+    def update(self, buffers):
+        self.arrays = tuple(buffers)
 
 
 @dataclass

@@ -51,6 +51,9 @@ class PageAllocator:
         # cache lines / HBM pages are warm)
         self._free = list(range(self.num_pages, 0, -1))
         self._refs = [0] * (self.num_pages + 1)
+        # pages held more than once: while there are none, no write can
+        # land in a shared page and nobody need look (engine._cow_writes)
+        self.shared_pages = 0
 
     @property
     def free_pages(self):
@@ -82,6 +85,7 @@ class PageAllocator:
         assert self._refs[page] >= 1, \
             "ref of unheld page {}".format(page)
         self._refs[page] += 1
+        self.shared_pages += self._refs[page] == 2
 
     def free(self, page):
         """Drop one reference; the page returns to the pool at zero."""
@@ -90,6 +94,7 @@ class PageAllocator:
         assert self._refs[page] >= 1, \
             "double free of page {}".format(page)
         self._refs[page] -= 1
+        self.shared_pages -= self._refs[page] == 1
         if self._refs[page] == 0:
             self._free.append(page)
 
@@ -102,6 +107,7 @@ class PageAllocator:
             return page, False
         new = self.alloc()
         self._refs[page] -= 1
+        self.shared_pages -= self._refs[page] == 1
         return new, True
 
     def stats(self):

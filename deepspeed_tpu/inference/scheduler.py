@@ -111,8 +111,9 @@ class ContinuousBatchingScheduler:
         prompt = list(prompt)
         assert len(prompt) >= 1, "empty prompt"
         # admission-time validation so a bad request fails its caller,
-        # not a later step() on someone else's request
-        self.engine.bucket_for(len(prompt))
+        # not a later step() on someone else's request (a prompt longer
+        # than the largest prefill bucket is no bad request: it goes in
+        # chunks of that bucket, paging.plan_chunks)
         assert len(prompt) < self.engine.max_seq_len, \
             "prompt length {} leaves no room to decode (max_seq_len " \
             "{})".format(len(prompt), self.engine.max_seq_len)
@@ -294,7 +295,13 @@ class ContinuousBatchingScheduler:
                     if req.span is not None:
                         req.span.event("prefix_hit", tokens=start)
             start, ln = req.chunks[req.chunk_idx]
-            with annotate("sched.prefill.chunk", uid=req.uid, tokens=ln):
+            # first: the chunk's program starts the slot's recurrent
+            # state (where the model keeps one) from zeros
+            first = start == 0
+            with annotate("sched.prefill.chunk", uid=req.uid, tokens=ln,
+                          padded=self.engine.bucket_for(ln), first=first):
+                if first and self.engine.state is not None:
+                    self._account("record_state_reset")
                 chunk = req.context[start:start + ln]
                 # no page check here: try_admit reserved the WHOLE context's
                 # pages at admission, so every chunk's range is covered —
@@ -450,8 +457,9 @@ class ContinuousBatchingScheduler:
                 drafter.propose_batch(pending, 0)
             t = self.timers("decode")
             t.start()
-            next_tokens = self.engine.decode_step(pending,
-                                                  sampling=self.sampling)
+            next_tokens = self.engine.decode_step(
+                pending, sampling=self.sampling,
+                active=[req.slot for req in active])
             t.stop()
             dt = t.elapsed(reset=True)
             with annotate("sched.decode.commit"):

@@ -81,8 +81,10 @@ class ModelDrafter:
 
     def __init__(self, model, num_slots, max_seq_len, dtype, mesh=None):
         from ..runtime.model import as_model
+        from .decoder import refuse_recurrent
         from .kv_cache import KVCache
         self.module = as_model(model)
+        refuse_recurrent(self.module, "a draft model's slot cache")
         cfg = getattr(self.module, "config", None) or \
             getattr(model, "config", None)
         assert cfg is not None and hasattr(cfg, "n_heads"), \

@@ -853,6 +853,7 @@ def make_gpt2_model(config=None, size="gpt2_small", seed=0, **overrides):
     model = Model(apply_fn, params, partition_spec_fn=partition_spec_fn,
                   name="gpt2")
     model.config = config
+    model.decoder = GPT2Decoder(config)
     model.bind_mesh = partial(setattr, config, "kernel_mesh")
     model.profile_spec_fn = lambda batch_size, seq=None: profile_spec(
         config, batch_size, seq=seq)
@@ -862,6 +863,63 @@ def make_gpt2_model(config=None, size="gpt2_small", seed=0, **overrides):
         # rejects the combination loudly
         model.stream_spec = stream_spec_for(config)
     return model
+
+
+class GPT2Decoder:
+    """GPT-2 as ``init_inference()`` sees it (inference/decoder.py):
+    every layer keeps keys and values, nothing else: the pages (or the
+    slot rows) are the whole of a request's state."""
+
+    recurrent = False
+
+    def __init__(self, config):
+        self.config = config
+
+    def cache_spec(self):
+        from ..inference.decoder import CacheSpec
+        cfg = self.config
+        return CacheSpec(kv_layers=cfg.n_layers, kv_heads=cfg.n_heads,
+                         d_head=cfg.d_head)
+
+    def serving_config(self, mesh):
+        # deterministic, dense path: the cached attention owns masking;
+        # flash / scan / SP are training-path levers
+        return dataclasses.replace(
+            self.config, dropout=0.0, scan_blocks=False,
+            sequence_parallel=None, sp_mesh=None, sparse_attention=None,
+            sparse_embedding_grads=False, embedding_grad_mesh=None,
+            paged_attention_kernel="xla", kernel_mesh=mesh)
+
+    def decode_config(self, config, paged_attention_kernel):
+        # decode is the ONE family that may run the Pallas paged-
+        # attention kernel (docs/pallas_kernels.md dispatch rules); the
+        # serving config keeps "xla" so prefill and every oracle
+        # comparison stay on the gather path
+        return dataclasses.replace(
+            config, paged_attention_kernel=paged_attention_kernel)
+
+    def serving_params(self, params, dtype):
+        if self.config.scan_blocks:
+            # serving iterates blocks as a python list; unstack the
+            # scan-trained (L, ...) layout once at engine build
+            blocks = params["blocks"]
+            params = dict(params)
+            params["blocks"] = [
+                jax.tree_util.tree_map(lambda t, i=i: t[i], blocks)
+                for i in range(self.config.n_layers)]
+
+        def cast(x):
+            x = jnp.asarray(x)
+            return x.astype(dtype) if jnp.issubdtype(x.dtype,
+                                                     jnp.floating) else x
+        return jax.tree_util.tree_map(cast, params)
+
+    forward_hidden = staticmethod(forward_hidden)
+
+    @staticmethod
+    def logits(params, hidden):
+        # tied-embedding LM head (lm_loss's convention)
+        return hidden @ params["wte"].astype(hidden.dtype).T
 
 
 def num_params(config):

@@ -152,6 +152,145 @@ def _kernel(pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref, v_pool_ref,
          for hi in range(num_heads)], axis=1)
 
 
+def _grouped_kernel(pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref,
+                    v_pool_ref, o_ref, k_buf, v_buf, k_sem, v_sem, *,
+                    layer_idx, page_size, kv_heads, group, d_head,
+                    sm_scale, seq, chunk):
+    """One slot's page-table walk where ``group`` query heads share
+    each key-value head (grouped-query attention; ``kv_heads = 1`` is
+    multi-query). The masking contract is :func:`_kernel`'s. What
+    differs: a page of ``kv_heads * d_head`` lanes is small (4 KB at
+    one head of 128), so pages are fetched ``chunk`` at a time into one
+    buffer of ``chunk * page_size`` tokens (the next chunk's copies in
+    flight while this one is on the MXU), and a key-value head's
+    ``seq * group`` queries are the rows of ONE matmul per chunk.
+
+    q_ref / o_ref (1, kv_heads, seq * group, d_head), rows ordered
+    (query, head of the group); k/v_buf (2, chunk * page_size,
+    kv_heads * d_head)."""
+    i = pl.program_id(0)
+    pos = pos_ref[i]
+    vlen = vlen_ref[i]
+    live = pos + vlen - 1                  # last live absolute position
+    n_pages = jnp.maximum(live, 0) // page_size + 1
+    n_chunks = (n_pages + chunk - 1) // chunk
+    rows, tokens = seq * group, chunk * page_size
+
+    def transfer(slot, c, start):
+        # a chunk's last pages may lie past the live window: no copy,
+        # and what the buffer holds there is masked below
+        for j in range(chunk):
+            p = c * chunk + j
+
+            @pl.when(p < n_pages)
+            def _copy():
+                phys = pt_ref[i, p]
+                dst = pl.ds(j * page_size, page_size)
+                for pool, buf, sem in ((k_pool_ref, k_buf, k_sem),
+                                       (v_pool_ref, v_buf, v_sem)):
+                    copy = pltpu.make_async_copy(
+                        pool.at[phys, layer_idx], buf.at[slot, dst],
+                        sem.at[slot])
+                    copy.start() if start else copy.wait()
+
+    transfer(0, 0, True)
+    q_pos = pos + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, tokens), 0) // group
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 1)
+    vcol = jax.lax.broadcasted_iota(jnp.int32, (tokens, 1), 0)
+    qs = [q_ref[0, h].astype(jnp.float32) * sm_scale
+          for h in range(kv_heads)]                       # (rows, dh)
+
+    def body(c, carry):
+        slot = jax.lax.rem(c, 2)
+
+        @pl.when(c + 1 < n_chunks)
+        def _prefetch():
+            transfer(jax.lax.rem(c + 1, 2), c + 1, True)
+
+        transfer(slot, c, False)
+        k_pos = c * tokens + col
+        mask = jnp.logical_and(k_pos <= q_pos, k_pos <= live)
+        vmask = (c * tokens + vcol) <= live
+        k_all, v_all = k_buf[slot], v_buf[slot]
+        out = []
+        for h in range(kv_heads):
+            acc, m, l = carry[h]
+            sl = slice(h * d_head, (h + 1) * d_head)
+            k_h = k_all[:, sl].astype(jnp.float32)
+            v_h = jnp.where(vmask, v_all[:, sl].astype(jnp.float32), 0.0)
+            scores = jax.lax.dot_general(
+                qs[h], k_h, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)       # (rows, tokens)
+            scores = jnp.where(mask, scores, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+            pexp = jnp.exp(scores - m_new)
+            corr = jnp.exp(m - m_new)
+            out.append((acc * corr + jax.lax.dot_general(
+                pexp, v_h, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32),
+                m_new, l * corr + jnp.sum(pexp, axis=-1, keepdims=True)))
+        return tuple(out)
+
+    init = tuple((jnp.zeros((rows, d_head), jnp.float32),
+                  jnp.full((rows, 1), NEG_INF, jnp.float32),
+                  jnp.zeros((rows, 1), jnp.float32))
+                 for _ in range(kv_heads))
+    final = jax.lax.fori_loop(0, n_chunks, body, init)
+    for h, (acc, _, l) in enumerate(final):
+        o_ref[0, h] = acc / jnp.where(l == 0.0, 1.0, l)
+
+
+def _grouped_paged_attention(q, k_pool, v_pool, page_tables, positions,
+                             valid_lens, *, layer_idx, page_size,
+                             interpret, chunk=8):
+    """:func:`paged_attention` for pools of fewer key-value heads than
+    query heads. q (b, s, h, dh); pools (pages+1, layers, page_size,
+    kvh * dh) with ``h % kvh == 0``."""
+    b, s, h, dh = q.shape
+    kvh = k_pool.shape[3] // dh
+    group = h // kvh
+    rows = s * group
+    max_pages = page_tables.shape[1]
+    chunk = min(chunk, max_pages)
+    # rows of one key-value head: (query, head of its group)
+    q = q.reshape(b, s, kvh, group, dh).transpose(0, 2, 1, 3, 4) \
+        .reshape(b, kvh, rows, dh)
+    block = pl.BlockSpec((1, kvh, rows, dh), lambda i, *_: (i, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[block, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=block,
+        scratch_shapes=[
+            pltpu.VMEM((2, chunk * page_size, kvh * dh), k_pool.dtype),
+            pltpu.VMEM((2, chunk * page_size, kvh * dh), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+        ])
+    kernel = functools.partial(
+        _grouped_kernel, layer_idx=layer_idx, page_size=page_size,
+        kv_heads=kvh, group=group, d_head=dh,
+        sm_scale=1.0 / math.sqrt(dh), seq=s, chunk=chunk)
+    window = max_pages * page_size
+    cost = pl.CostEstimate(
+        flops=4 * b * s * window * h * dh,
+        bytes_accessed=(q.size * q.dtype.itemsize
+                        + 2 * b * window * kvh * dh
+                        * k_pool.dtype.itemsize + b * s * h * dh * 4),
+        transcendentals=b * s * window * h)
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kvh, rows, dh), jnp.float32),
+        cost_estimate=cost, interpret=interpret,
+        name="paged_attention_grouped",
+    )(page_tables.astype(jnp.int32), positions.astype(jnp.int32),
+      valid_lens.astype(jnp.int32), q, k_pool, v_pool)
+    return out.reshape(b, kvh, s, group, dh).transpose(0, 2, 1, 3, 4) \
+        .reshape(b, s, h, dh)
+
+
 def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
                     *, layer_idx, page_size, interpret=None, mesh=None):
     """Paged attention for ``s`` new queries per slot against the pool.
@@ -163,7 +302,9 @@ def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
     ``q``: (b, s, h, dh) — the new tokens' queries (cache writes for the
     SAME tokens must already have landed via the masked scatter, exactly
     as on the XLA gather path; this kernel replaces only the read side).
-    ``k_pool``/``v_pool``: (pages+1, layers, page_size, h*dh);
+    ``k_pool``/``v_pool``: (pages+1, layers, page_size, h*dh), or
+    (..., kvh*dh) with ``kvh`` key-value heads each shared by ``h / kvh``
+    query heads (grouped-query attention: :func:`_grouped_kernel`);
     ``page_tables``: (b, max_pages) int32; ``positions``/``valid_lens``:
     (b,) int32. ``layer_idx`` is trace-static (the model's python layer
     loop). Returns fp32 ctx (b, s, h, dh) — within 1e-5 of the slot
@@ -185,6 +326,13 @@ def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
             q_spec)(q, k_pool, v_pool, page_tables, positions, valid_lens)
     b, s, h, dh = q.shape
     hd = h * dh
+    packed = k_pool.shape[3]
+    if packed != hd and packed % dh == 0 and h % (packed // dh) == 0 \
+            and k_pool.shape[2] == page_size:
+        # fewer key-value heads than query heads: the grouped kernel
+        return _grouped_paged_attention(
+            q, k_pool, v_pool, page_tables, positions, valid_lens,
+            layer_idx=layer_idx, page_size=page_size, interpret=interpret)
     if k_pool.shape[2:] != (page_size, hd):
         raise ValueError(
             "paged_attention wants pools (pages+1, layers, page_size {}, "

@@ -103,6 +103,10 @@ def run_serving_step(sched, record_step):
             sched._account("record_schedule",
                            occupancy=occupancy,
                            queue_depth=len(sched.queue), step=sched.steps)
+            pool = getattr(engine, "state", None)
+            if pool is not None:
+                sched._account("record_state_pool", pool.num_slots,
+                               pool.nbytes, sched.num_active)
             tel = getattr(engine, "telemetry", None)
             if tel is not None:
                 # one serving_step record per scheduler step through the

@@ -116,6 +116,22 @@ class ServingMetrics:
         self.spec_proposed = 0
         self.spec_accepted = 0
         self.spec_steps = 0
+        # the recurrent-state pool of a model that keeps one
+        self.state_slots = 0
+        self.state_bytes = 0
+        self.state_live_slots = 0
+        self.state_resets = 0
+
+    def record_state_pool(self, slots, nbytes, live_slots):
+        """The recurrent-state pool as the step leaves it: its slots and
+        bytes, and the slots that hold a live request's state."""
+        self.state_slots, self.state_bytes = int(slots), int(nbytes)
+        self.state_live_slots = int(live_slots)
+
+    def record_state_reset(self):
+        """One request's first prefill chunk started a slot's recurrent
+        state from zeros (inside the chunk's program)."""
+        self.state_resets += 1
 
     def record_prefill(self, tokens, seconds):
         self.prefill_tokens += int(tokens)
@@ -236,4 +252,9 @@ class ServingMetrics:
                            ("speculative", self.spec_dist())):
             if dist is not None:
                 out[name] = dist
+        if self.state_slots:
+            out["state_pool"] = {"slots": self.state_slots,
+                                 "bytes": self.state_bytes,
+                                 "live_slots": self.state_live_slots,
+                                 "resets": self.state_resets}
         return out

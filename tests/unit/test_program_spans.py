@@ -151,7 +151,15 @@ def test_step_and_request_attributes(traced):
         [(0, 7), (1, 4), (1, 16), (2, 3)]
     # and nothing is written that nothing reads
     assert {k for ev in lines[0] for k in ev[3]} == {
-        "step", "uid", "queue_wait_us", "resumed", "tokens"}
+        "step", "uid", "queue_wait_us", "resumed", "tokens", "padded",
+        "first"}
+    # the bucket each chunk was padded to, and whose first chunk it was
+    # (the one whose program starts a recurrent state from zeros)
+    buckets = traced[5].engine.prefill_buckets
+    assert all(c["padded"] == min(b for b in buckets if b >= c["tokens"])
+               for c in chunks)
+    assert sorted((c["uid"], c["first"]) for c in chunks) == \
+        [(0, 1), (1, 0), (1, 1), (2, 1)]
 
 
 def test_serving_program_names_are_pinned(traced):

@@ -164,6 +164,24 @@ def test_paged_attention_compiles_at_d_head_64(one_chip,
 
 
 # ------------------------------------------------- kernels on a mesh
+def test_paged_attention_compiles_at_one_kv_head_of_128(
+        one_chip, no_persistent_cache):
+    """The grouped kernel at AI21-Jamba2-3B's attention layers: 20
+    query heads of 128 on one key-value head, 256 slots, a row of 192
+    pages of 16 tokens; a page is a (16, 128) bf16 tile."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+
+    def fn(q, k_pool, v_pool, page_tables, positions, valid_lens):
+        return paged_attention(q, k_pool, v_pool, page_tables, positions,
+                               valid_lens, layer_idx=1, page_size=16,
+                               interpret=False)
+
+    b = 256
+    pool = ((18001, 2, 16, 128), BF16)
+    assert _compile(fn, one_chip, ((b, 1, 20, 128), BF16), pool, pool,
+                    ((b, 192), I32), ((b,), I32), ((b,), I32)) == 1
+
+
 def _mesh_compile(fn, mesh, *args):
     """``args``: (shape, dtype, PartitionSpec) placed on ``mesh``."""
     sds = [jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(mesh, spec))
@@ -299,6 +317,158 @@ def test_serving_programs_copy_no_layer_slab(
 
 
 # ------------------------------------------------------- compiler params
+# ------------------------------------------------- Jamba: scan, step, pools
+JAMBA = dict(d_inner=5120, d_state=16, mamba_layers=26)
+
+
+@pytest.mark.parametrize("chunk", [128, 256, 512])
+def test_mamba_scan_compiles_at_the_published_widths(
+        one_chip, no_persistent_cache, chunk):
+    from deepspeed_tpu.ops.pallas.mamba import mamba_scan
+    di, n = JAMBA["d_inner"], JAMBA["d_state"]
+
+    def fn(x, dt, B, C, A, h0, valid):
+        return mamba_scan(x, dt, B, C, A, h0, valid, interpret=False)
+
+    assert _compile(fn, one_chip, ((chunk, di), F32), ((chunk, di), F32),
+                    ((chunk, n), F32), ((chunk, n), F32), ((n, di), F32),
+                    ((n, di), F32), ((), I32)) == 1
+
+
+@pytest.mark.parametrize("state", [F32, BF16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("slots", [256, 512])
+def test_mamba_step_compiles_in_place_on_the_pool(
+        one_chip, no_persistent_cache, slots, state):
+    from deepspeed_tpu.ops.pallas.mamba import mamba_step
+    di, n, layers = (JAMBA["d_inner"], JAMBA["d_state"],
+                     JAMBA["mamba_layers"])
+
+    def fn(pool, x, dt, B, C, A):
+        return mamba_step(pool, 3, x, dt, B, C, A, interpret=False)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((layers, slots, n, di), state), ((slots, di), F32),
+        ((slots, di), F32), ((slots, n), F32), ((slots, n), F32),
+        ((n, di), F32))]
+    compiled = jax.jit(fn, donate_argnums=0).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    # the pool comes back in place, and no layer's slab is made of it
+    assert re.search(r"\{1\}: \(0, \{\}, (?:may|must)-alias\)",
+                     text.split("\n", 1)[0])
+    slab = "{}[{},{},{}]".format("f32" if state == F32 else "bf16",
+                                 slots, n, di)
+    assert slab not in text
+
+
+@pytest.fixture(scope="module")
+def jamba_engine():
+    """A tiny Jamba engine on the CPU whose programs are lowered at the
+    published widths: the model config, the weights and both pools are
+    what the programs close over or take as arguments."""
+    import json
+    import os
+    import deepspeed_tpu as deepspeed
+    from deepspeed_tpu.models import jamba
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "jamba2-3b-serve.json")) as f:
+        cell = json.load(f)
+    tiny = dict(cell["model"], hidden_size=64, intermediate_size=128,
+                num_attention_heads=4, vocab_size=128, mamba_dt_rank=8,
+                num_hidden_layers=8)
+    eng = deepspeed.init_inference(
+        model=jamba.make_jamba_model(jamba.config_from_hf(tiny), seed=0),
+        config={"inference": dict(cell["inference"], max_batch_size=2,
+                                  num_pages=256,
+                                  paged_attention_kernel="pallas")})
+    eng.model_config = jamba.config_from_hf(
+        cell["model"], scan_kernel="pallas",
+        state_dtype=jnp.dtype(cell["precision_state"]))
+    return eng, cell
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_jamba_programs_copy_no_state_slab_and_alias_both_pools(
+        one_chip, no_persistent_cache, jamba_engine, monkeypatch, program):
+    """``jit_prefill`` (the largest bucket, one slot) and ``jit_decode``
+    (every slot) of AI21-Jamba2-3B at the cell's pool shapes: no
+    instruction makes an array of a layer's whole SSM-state slab
+    (``[slots, 16, 5120]``, 84 MB of float32 at 256 slots) nor, in
+    prefill, of its convolution-tail slab or of a layer's pages; the
+    page pool AND the state pool, four donated buffers, come back in
+    place; and each Mamba layer runs one kernel."""
+    from deepspeed_tpu.models import jamba
+    eng, cell = jamba_engine
+    cfg = eng.model_config
+    inference = cell["inference"]
+    slots, pages = inference["max_batch_size"], inference["num_pages"]
+    bucket, ps = inference["prefill_buckets"][-1], eng.page_size
+    row = inference["max_seq_len"] // ps
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(lambda: jamba.JambaDecoder(cfg).serving_params(
+        jamba.init_params(cfg, 0), BF16))
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), params)
+    n_mamba, n_attn = len(cfg.mamba_layers), len(cfg.attention_layers)
+    pool = sds((pages + 1, n_attn, ps, cfg.n_kv_heads * cfg.d_head), BF16)
+    conv = sds((n_mamba, slots, (cfg.d_conv - 1) * cfg.d_inner), BF16)
+    ssm = sds((n_mamba, slots, cfg.d_state, cfg.d_inner), cfg.state_dtype)
+    rng = jax.random.PRNGKey(0)
+    tail = (sds(rng.shape, rng.dtype), sds((), F32), sds((), F32))
+    if program == "prefill":
+        fn = eng._get_prefill_fn(bucket, True, 0)
+        args = (sds((), I32), sds((1, bucket), I32), sds((row,), I32),
+                sds((), I32), sds((), I32))
+    else:
+        fn = eng._get_decode_fn(True, 0)
+        args = (sds((slots,), jnp.bool_), sds((slots, 1), I32),
+                sds((slots,), I32), sds((slots, row), I32))
+    compiled = fn.lower(params, pool, pool, conv, ssm, *args,
+                        *tail).compile()
+    text = compiled.as_text()
+
+    assert text.startswith("HloModule jit_" + program)
+    state_name = {"float32": "f32", "bfloat16": "bf16"}[
+        jnp.dtype(cfg.state_dtype).name]
+    slabs = ["{}[{},{},{}]".format(state_name, slots, cfg.d_state,
+                                   cfg.d_inner)]
+    if program == "prefill":
+        # decode reads and writes every slot's tail: there the layer's
+        # region IS the operand, 7.9 MB, and no copy made to slice it
+        slabs += ["bf16[{},{}]".format(slots,
+                                       (cfg.d_conv - 1) * cfg.d_inner)]
+    # and the whole pools are never copied or re-tiled
+    slabs += ["bf16[{},{},{}]".format(n_mamba, slots,
+                                      (cfg.d_conv - 1) * cfg.d_inner) +
+              "{2,1,0:T(8,128)(2,1)} copy("]
+    # nor of a layer's pages, nor (decode) of every slot's whole window
+    slabs += ["bf16[{},{},{}]".format(pages + 1, ps,
+                                      cfg.n_kv_heads * cfg.d_head),
+              "bf16[{},{},{}]".format(slots * row, ps,
+                                      cfg.n_kv_heads * cfg.d_head)]
+    for slab in slabs:
+        assert not [line.strip()[:160] for line in text.splitlines()
+                    if slab in line][:3], slab
+    # a kernel a Mamba layer; in decode the two attention layers walk
+    # their pages in the grouped paged kernel, prefill gathers its row
+    assert text.count("tpu_custom_call") == \
+        n_mamba + (n_attn if program == "decode" else 0)
+    aliased = {int(out): int(arg) for out, arg in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+        text.split("\n", 1)[0])}
+    n_params = len(jax.tree_util.tree_leaves(params))
+    assert aliased == {i: n_params + i for i in range(4)}
+    # the whole of it fits the chip with room to spare
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < \
+        12 * 2 ** 30
+
+
 def test_pallas_compiler_params_construct():
     """Every ``compiler_params`` a pallas_call site passes must construct
     under the installed jax — the sites are only reached with

@@ -77,143 +77,6 @@ ROUTER_EVENT_KEYS = (
 ROUTER_DECISIONS = ("admit", "deny", "route_away", "preempt_migrate",
                     "enroll", "enroll_refusal")
 
-# Local copies of runtime/controller/ledger.py DECISION_KEYS /
-# CONTROLLER_EVENT_TYPES / CONTROLLER_KNOBS and telemetry/record.py
-# CONTROLLER_SNAPSHOT_KEYS (same stdlib-only constraint; pinned equal
-# by tests/unit/test_controller.py). Every closed-loop controller
-# decision is replayable from these records alone (docs/controller.md).
-CONTROLLER_EVENTS_JSONL = "controller_events.jsonl"
-KIND_CONTROLLER_EVENT = "controller_event"
-DECISION_KEYS = (
-    "kind", "wall", "seq", "event", "decision_id", "policy", "knob",
-    "target", "old", "new", "signal", "predicted_win_s",
-    "measured_win_s", "reason",
-)
-CONTROLLER_EVENT_TYPES = ("decision", "outcome", "revert")
-CONTROLLER_KNOBS = (
-    "launch_ahead_window", "h2d_bucket_elems", "spec_k",
-    "prefill_chunk_tokens", "quantized_collectives", "prefill_buckets",
-)
-CONTROLLER_SNAPSHOT_KEYS = (
-    "enabled", "role", "policies", "decisions", "outcomes", "reverts",
-    "pending", "overrides", "drift", "ledger_path",
-)
-
-
-def check_controller_event(ev, where):
-    """-> list of problems with one controller ledger event (a stdlib
-    re-statement of runtime/controller/ledger.py
-    ``validate_controller_event`` — the ledger's own checker is the
-    source of truth)."""
-    problems = []
-    if not isinstance(ev, dict):
-        return ["{} is not a dict".format(where)]
-    for key in DECISION_KEYS:
-        if key not in ev:
-            problems.append("{} missing key {!r}".format(where, key))
-    extra = sorted(set(ev) - set(DECISION_KEYS))
-    if extra:
-        # the fleet merger stamps the originating host
-        extra = [k for k in extra if k != "source"]
-    if extra:
-        problems.append("{} has unexpected key(s) {}".format(
-            where, extra))
-    if problems:
-        return problems
-    if ev["kind"] != KIND_CONTROLLER_EVENT:
-        problems.append("{} has kind {!r}".format(where, ev["kind"]))
-    if ev["event"] not in CONTROLLER_EVENT_TYPES:
-        problems.append("{} has unknown event {!r}".format(
-            where, ev["event"]))
-    if ev["knob"] not in CONTROLLER_KNOBS:
-        problems.append("{} has unknown knob {!r}".format(
-            where, ev["knob"]))
-    if not _is_num(ev["wall"]):
-        problems.append("{}.wall is not a number".format(where))
-    if not isinstance(ev["seq"], int) or isinstance(ev["seq"], bool) \
-            or ev["seq"] < 0:
-        problems.append("{}.seq is not an int >= 0".format(where))
-    for key in ("decision_id", "policy"):
-        if not isinstance(ev[key], str) or not ev[key]:
-            problems.append(
-                "{}.{} is not a non-empty string".format(where, key))
-    if not isinstance(ev["reason"], str):
-        problems.append("{}.reason is not a string".format(where))
-    if ev["signal"] is not None and not isinstance(ev["signal"], dict):
-        problems.append(
-            "{}.signal is neither null nor a dict".format(where))
-    for key in ("predicted_win_s", "measured_win_s"):
-        if ev[key] is not None and not _is_num(ev[key]):
-            problems.append(
-                "{}.{} is neither null nor a number".format(where, key))
-    if ev["event"] == "decision" and not isinstance(ev["signal"], dict):
-        problems.append("{} is a decision without its signal citation"
-                        .format(where))
-    if ev["event"] in ("outcome", "revert") and \
-            not _is_num(ev["measured_win_s"]):
-        problems.append("{} is an {} without a measured_win_s".format(
-            where, ev["event"]))
-    return problems
-
-
-def check_controller_snapshot(snap, where):
-    """-> list of problems with one controller snapshot (the
-    ``extra.controller`` bench block / telemetry-snapshot section; a
-    stdlib re-statement of telemetry/record.py
-    ``validate_controller_snapshot``)."""
-    problems = []
-    if not isinstance(snap, dict):
-        return ["{} is not a dict".format(where)]
-    for key in CONTROLLER_SNAPSHOT_KEYS:
-        if key not in snap:
-            problems.append("{} missing key {!r}".format(where, key))
-    extra = sorted(set(snap) - set(CONTROLLER_SNAPSHOT_KEYS))
-    if extra:
-        problems.append("{} has unexpected key(s) {}".format(
-            where, extra))
-    if problems:
-        return problems
-    if not isinstance(snap["enabled"], bool):
-        problems.append("{}.enabled is not a bool".format(where))
-    if not isinstance(snap["role"], str):
-        problems.append("{}.role is not a string".format(where))
-    for key in ("decisions", "outcomes", "reverts", "pending"):
-        val = snap[key]
-        if not isinstance(val, int) or isinstance(val, bool) or val < 0:
-            problems.append(
-                "{}.{} is not an int >= 0".format(where, key))
-    for key in ("policies", "overrides"):
-        if not isinstance(snap[key], list):
-            problems.append("{}.{} is not a list".format(where, key))
-    if snap["drift"] is not None and not _is_num(snap["drift"]):
-        problems.append(
-            "{}.drift is neither null nor a number".format(where))
-    if snap["ledger_path"] is not None and \
-            not isinstance(snap["ledger_path"], str):
-        problems.append(
-            "{}.ledger_path is neither null nor a string".format(where))
-    return problems
-
-
-def check_controller_events_text(text):
-    """-> list of problems with one ``controller_events.jsonl`` file's
-    text (one schema-pinned event per line)."""
-    problems = []
-    lines = [ln for ln in text.splitlines() if ln.strip()]
-    if not lines:
-        return ["controller ledger holds no events"]
-    for i, line in enumerate(lines):
-        try:
-            ev = json.loads(line)
-        except ValueError as err:
-            problems.append("line {}: unparseable: {}".format(i, err))
-            break
-        problems.extend(check_controller_event(
-            ev, "line {}".format(i)))
-        if problems:
-            break                       # first bad event names the file
-    return problems
-
 # Local copy of telemetry/record.py SEGMENT_KEYS /
 # SEGMENT_KIND_KEYS / SEGMENT_OPTIONAL_KEYS (same stdlib-only
 # constraint; pinned equal by tests/unit/test_executor.py): the
@@ -365,9 +228,6 @@ def check_telemetry_snapshot(snap):
             return problems
     if steps == 0 and serving == 0:
         problems.append("telemetry carries neither train nor serving steps")
-    if "controller" in snap:
-        problems.extend(check_controller_snapshot(
-            snap["controller"], "telemetry.controller"))
     if steps > 0:
         for name in ("step_time_s", "mfu", "tokens_per_sec_per_chip"):
             _check_dist(snap.get(name), name, problems)
@@ -713,9 +573,6 @@ def check_bench_payload(payload):
                     extra["executor"], "extra.executor"))
             if "metrics" in extra:
                 problems.extend(check_metrics_payload(extra["metrics"]))
-            if "controller" in extra:
-                problems.extend(check_controller_snapshot(
-                    extra["controller"], "extra.controller"))
     return problems
 
 
@@ -829,7 +686,7 @@ def check_analysis_report(payload):
 FLEET_REPORT_KEYS = (
     "kind", "run_dir", "n_hosts", "hosts", "offsets", "records", "gaps",
     "straggler", "ici_health", "trace", "divergence", "rescale",
-    "router", "controller",
+    "router",
 )
 # Local copy of runtime/elastic/events.py RESCALE_EVENT_KEYS (same
 # stdlib-only constraint; pinned equal by
@@ -986,34 +843,6 @@ def check_fleet_report(payload):
                         "router.events[{}] has unknown decision "
                         "{!r}".format(i, ev.get("decision")))
                     break
-    controller = payload.get("controller")
-    if not isinstance(controller, dict):
-        problems.append("controller is not a dict")
-    else:
-        if not isinstance(controller.get("count"), int) or \
-                isinstance(controller.get("count"), bool):
-            problems.append("controller.count is not an int")
-        tally = controller.get("tally")
-        if not isinstance(tally, dict):
-            problems.append("controller.tally is not a dict")
-        else:
-            unknown = sorted(set(tally) - set(CONTROLLER_EVENT_TYPES))
-            if unknown:
-                problems.append(
-                    "controller.tally has unknown event type(s) "
-                    "{}".format(unknown))
-        if not isinstance(controller.get("unreverted"), list):
-            problems.append("controller.unreverted is not a list")
-        events = controller.get("events")
-        if not isinstance(events, list):
-            problems.append("controller.events is not a list")
-        else:
-            for i, ev in enumerate(events):
-                sub = check_controller_event(
-                    ev, "controller.events[{}]".format(i))
-                if sub:
-                    problems.extend(sub)
-                    break
     return problems
 
 
@@ -1076,8 +905,6 @@ def check_file(path):
             text = fh.read()
     except OSError as err:
         return ["unreadable: {}".format(err)]
-    if os.path.basename(path) == CONTROLLER_EVENTS_JSONL:
-        return check_controller_events_text(text)
     if text.lstrip().startswith("["):
         # only the span tracer's Chrome trace files are arrays
         return check_trace_events(text)

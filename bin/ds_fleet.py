@@ -7,10 +7,8 @@ report, and emit a merged multi-process Perfetto trace.
     python bin/ds_fleet.py RUN_DIR --json report.json  # fleet_report artifact
     python bin/ds_fleet.py RUN_DIR --trace merged.json # merged Chrome trace
     python bin/ds_fleet.py RUN_DIR --factor 2 --k 5    # detector thresholds
-    python bin/ds_fleet.py RUN_DIR --strict            # exit 2 on flags,
-                                                       #   divergence, or
-                                                       #   unreverted
-                                                       #   regressions
+    python bin/ds_fleet.py RUN_DIR --strict            # exit 2 on flags
+                                                       #   or divergence
 
 ``RUN_DIR`` is a ``telemetry.output_path`` whose per-job subdirectories
 each hold one host's ``host_manifest.json`` + ``telemetry.jsonl`` (the
@@ -174,43 +172,6 @@ def print_report(report):
     else:
         print("no router decisions (the run served without a fleet "
               "front-end)")
-    controller = report.get("controller") or {}
-    print()
-    if controller.get("events"):
-        tally = controller.get("tally") or {}
-        print("CONTROLLER DECISIONS ({} event(s): {}; "
-              "docs/controller.md):".format(
-                  controller.get("count", 0),
-                  ", ".join("{} {}".format(n, e)
-                            for e, n in sorted(tally.items()))))
-        for ev in controller["events"]:
-            extras = []
-            if ev.get("target") is not None:
-                extras.append("target {}".format(ev["target"]))
-            if ev.get("old") is not None or ev.get("new") is not None:
-                extras.append("{} -> {}".format(ev.get("old"),
-                                                ev.get("new")))
-            if ev.get("predicted_win_s") is not None:
-                extras.append("predicted {:+.4f}s".format(
-                    ev["predicted_win_s"]))
-            if ev.get("measured_win_s") is not None:
-                extras.append("measured {:+.4f}s".format(
-                    ev["measured_win_s"]))
-            print("  - [{}] {:<8} {:<22} {}{}".format(
-                ev.get("source") or "-", ev.get("event", "?"),
-                "{}/{}".format(ev.get("policy", "?"),
-                               ev.get("knob", "?")),
-                ev.get("reason", ""),
-                " ({})".format(", ".join(extras)) if extras else ""))
-        unreverted = controller.get("unreverted") or []
-        if unreverted:
-            print("  UNREVERTED REGRESSIONS: {} (the controller "
-                  "measured these decisions making the objective worse "
-                  "and did NOT undo them)".format(
-                      ", ".join(unreverted)))
-    else:
-        print("no controller decisions (the run had no closed-loop "
-              "controller, or it never moved a knob)")
 
 
 def main(argv=None):
@@ -232,10 +193,8 @@ def main(argv=None):
                         help="minimum hosts for median attribution "
                              "(default 2)")
     parser.add_argument("--strict", action="store_true",
-                        help="exit 2 when any straggler/ICI flag fired, "
-                             "the host program fingerprints diverge, or "
-                             "the controller left a measured regression "
-                             "unreverted")
+                        help="exit 2 when any straggler/ICI flag fired "
+                             "or the host program fingerprints diverge")
     args = parser.parse_args(argv)
     aggregate, _straggler = _load_fleet_modules()
     if not os.path.isdir(args.run_dir):
@@ -260,8 +219,7 @@ def main(argv=None):
               "ui.perfetto.dev)".format(trace["path"], trace["events"],
                                         trace["hosts_merged"]))
     if args.strict and (report["straggler"]["flags"] or
-                        (report.get("divergence") or {}).get("mismatch") or
-                        (report.get("controller") or {}).get("unreverted")):
+                        (report.get("divergence") or {}).get("mismatch")):
         return 2
     return 0
 

@@ -69,15 +69,6 @@ AFTER the cost is paid:
     (record.py itself is exempt — it IS the schema; the rule is inert
     when the schema file is absent, so partial checkouts never
     false-fail).
-  * **DSL012 knob-write-outside-controller** — an assignment to one of
-    the closed-loop controller's managed tunables (``spec_k``,
-    ``prefill_chunk_tokens``, ``prefill_buckets``, ``windows``,
-    ``_h2d_bucket_elems``, ``_qwz_enabled``, ``_qgz_enabled``) outside
-    ``deepspeed_tpu/runtime/controller/`` and the config parsers: a
-    live retune that bypasses ``RuntimeController.apply_override``
-    never lands in the decision ledger, so the run's behavior stops
-    being replayable from ``controller_events.jsonl``
-    (docs/controller.md). Construction-time sites are baselined.
 
 Violations key as ``DSL###:<relpath>::<qualname>`` and count per key —
 the committed baseline file maps keys to accepted counts, so existing
@@ -101,7 +92,6 @@ LINT_RULES = {
     "DSL009": "thread-without-daemon-story",
     "DSL010": "serving-field-outside-schema",
     "DSL011": "pallas-call-without-cost-estimate",
-    "DSL012": "knob-write-outside-controller",
 }
 
 # DSL008: mutating container methods (the static twin of the dynamic
@@ -180,21 +170,6 @@ def load_serving_schema(base):
 _OPS_PREFIX = "deepspeed_tpu/ops/"
 # DSL006: the one directory step-scheduling machinery may live in
 _EXECUTOR_PREFIX = "deepspeed_tpu/runtime/executor/"
-# DSL012: the one directory live knob mutations may live in (the
-# audited apply_override seam), plus the config parsers that SET the
-# tunables at construction time
-_CONTROLLER_PREFIX = "deepspeed_tpu/runtime/controller/"
-_DSL012_CONFIG_MODULES = frozenset({
-    "deepspeed_tpu/runtime/config.py",
-    "deepspeed_tpu/inference/config.py",
-})
-# the controller-managed tunables' attribute names (the static twin of
-# runtime/controller/ledger.py CONTROLLER_KNOBS — attribute spelling,
-# not knob spelling; pinned by tests/unit/test_controller.py)
-_DSL012_KNOB_ATTRS = frozenset({
-    "spec_k", "prefill_chunk_tokens", "prefill_buckets", "windows",
-    "_h2d_bucket_elems", "_qwz_enabled", "_qgz_enabled",
-})
 
 _TIME_FNS = {"time", "monotonic", "perf_counter"}
 
@@ -285,29 +260,7 @@ class _FunctionLint(ast.NodeVisitor):
             self._check_guarded_mutation(
                 self._guarded_attr_of(tgt.value), node.lineno,
                 "augmented subscript assign")
-        self._check_knob_write(tgt, node.lineno)
         self.generic_visit(node)
-
-    # ------------------------------------------------------------ DSL012
-    def _check_knob_write(self, tgt, lineno):
-        if self.linter.knob_exempt:
-            return
-        if isinstance(tgt, (ast.Tuple, ast.List)):
-            for elt in tgt.elts:
-                self._check_knob_write(elt, lineno)
-            return
-        node = tgt
-        if isinstance(node, ast.Subscript):
-            node = node.value      # windows["h2d"] = 4 writes `windows`
-        if isinstance(node, ast.Attribute) and \
-                node.attr in _DSL012_KNOB_ATTRS:
-            self.linter.report(
-                "DSL012", self.qualname, lineno,
-                "controller-managed tunable .{} written outside "
-                "runtime/controller/ — a live retune must go through "
-                "RuntimeController.apply_override so the move lands in "
-                "the decision ledger (docs/controller.md)".format(
-                    node.attr))
 
     def visit_For(self, node):
         self.loop_depth += 1
@@ -329,7 +282,6 @@ class _FunctionLint(ast.NodeVisitor):
                 self._check_guarded_mutation(
                     self._guarded_attr_of(tgt.value), node.lineno,
                     "subscript assign")
-            self._check_knob_write(tgt, node.lineno)
         self.generic_visit(node)
 
     def _guards_telemetry(self, expr):
@@ -506,8 +458,6 @@ class FileLinter:
         self.metric_catalog = metric_catalog
         self.serving_schema = serving_schema
         self.is_serving_schema = norm == _SERVING_SCHEMA_MODULE
-        self.knob_exempt = norm.startswith(_CONTROLLER_PREFIX) or \
-            norm in _DSL012_CONFIG_MODULES
         self.violations = []       # [(rule, qualname, lineno, message)]
 
     def report(self, rule, qualname, lineno, message):

@@ -63,8 +63,7 @@ def _parse_configs(config, mesh=None):
                                   get_runtime_executor_rewrites)
     default_runtime = {"executor": RUNTIME_EXECUTOR_DEFAULT,
                        "executor_rewrites":
-                       get_runtime_executor_rewrites({}),
-                       "controller": None}
+                       get_runtime_executor_rewrites({})}
     if isinstance(config, DeepSpeedInferenceConfig):
         return config, None, None, default_runtime
     from ..runtime.config import DeepSpeedConfig
@@ -72,8 +71,7 @@ def _parse_configs(config, mesh=None):
         return (config.inference_config, config.telemetry_config,
                 config.analysis_config,
                 {"executor": config.runtime_executor,
-                 "executor_rewrites": config.runtime_executor_rewrites,
-                 "controller": config.controller_config})
+                 "executor_rewrites": config.runtime_executor_rewrites})
     if config is None:
         return DeepSpeedInferenceConfig({}), None, None, default_runtime
     if isinstance(config, dict):
@@ -84,8 +82,7 @@ def _parse_configs(config, mesh=None):
     return (full.inference_config, full.telemetry_config,
             full.analysis_config,
             {"executor": full.runtime_executor,
-             "executor_rewrites": full.runtime_executor_rewrites,
-             "controller": full.controller_config})
+             "executor_rewrites": full.runtime_executor_rewrites})
 
 
 class InferenceEngine:
@@ -273,27 +270,6 @@ class InferenceEngine:
                 "ds_config", lambda: vars(self.inference_config))
             self.telemetry.recorder.set_context(
                 "engine", self._flight_state)
-        # closed-loop controller (runtime/controller/, docs/
-        # controller.md): None unless the "controller" section enables
-        # it — off is structurally absent; requires telemetry (the
-        # controller observes/actuates through its seams)
-        self.controller = None
-        controller_cfg = runtime_cfg.get("controller")
-        if controller_cfg is not None:
-            if self.telemetry is None:
-                from ..telemetry.config import warn_or_raise_noop
-                warn_or_raise_noop(
-                    "controller is enabled but telemetry is not — the "
-                    "controller observes/actuates through telemetry "
-                    "seams, so it cannot run (enable the telemetry "
-                    "section)",
-                    telemetry_config.strict
-                    if telemetry_config is not None else False)
-            else:
-                from ..runtime.controller.adapters import \
-                    attach_serving_controller
-                self.controller = attach_serving_controller(
-                    self, controller_cfg)
         logger.info(
             "InferenceEngine: slots={} max_seq={} buckets={} dtype={} "
             "layout={} kv_cache={:.1f} MB state_pool={:.1f} MB{}{}".format(

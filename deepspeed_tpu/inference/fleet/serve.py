@@ -12,9 +12,8 @@ every decode host. Degraded hosts with live streams get their
 youngest slot preempt-and-migrated instead of a warning.
 
 Metrics land in ONE shared ServingMetrics (TTFT at first-token from
-the prefill half, decode/goodput from the decode halves), so
-bench_inference.py's trace harness reads the same snapshot keys it
-reads from a monolith.
+the prefill half, decode/goodput from the decode halves), so a
+caller reads the same snapshot keys it reads from a monolith.
 """
 import time
 from collections import deque

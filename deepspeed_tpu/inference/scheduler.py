@@ -514,19 +514,7 @@ class ContinuousBatchingScheduler:
             # (runtime/executor/serving.py): admit -> prefill -> decode ->
             # retire, each phase one audited segment
             from ..runtime.executor.serving import run_serving_step
-            ctrl = getattr(self.engine, "controller", None)
-            if ctrl is None:
-                return run_serving_step(self, record_step)
-            # closed-loop tick (docs/controller.md): the scheduler step
-            # wall is the serving objective; signals (acceptance rate,
-            # TTFT SLO burn, storm flags) come off the same telemetry
-            # seams the record just fed
-            t0 = time.time()
-            retired = run_serving_step(self, record_step)
-            from ..runtime.controller.adapters import serving_signals
-            ctrl.on_step(record_step, time.time() - t0,
-                         serving_signals(self))
-            return retired
+            return run_serving_step(self, record_step)
 
     def run(self):
         """Drive step() until every submitted request has retired; returns

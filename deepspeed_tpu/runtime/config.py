@@ -540,81 +540,19 @@ def get_runtime_executor_rewrites(param_dict):
 
 
 CONTROLLER = "controller"
-CONTROLLER_KEYS = ("enabled", "interval_steps", "eval_steps",
-                   "cooldown_steps", "guardrail_pct",
-                   "max_moves_per_tick", "policies")
-CONTROLLER_INTERVAL_STEPS_DEFAULT = 20
-CONTROLLER_EVAL_STEPS_DEFAULT = 20
-CONTROLLER_COOLDOWN_STEPS_DEFAULT = 40
-CONTROLLER_GUARDRAIL_PCT_DEFAULT = 0.2
-CONTROLLER_MAX_MOVES_DEFAULT = 1
 
 
-def get_controller(param_dict):
-    """Top-level ``controller`` section: the closed-loop runtime
-    controller (``runtime/controller/``, docs/controller.md) that
-    retunes launch-ahead windows, transfer chunks, speculative k,
-    chunked-prefill size, quantized collectives and prefill buckets
-    from live telemetry. Default OFF and structurally absent — the
-    parser returns ``None`` so engines never construct a controller,
-    ledger file or policy object. ``true`` enables every policy with
-    defaults; a dict selects policies and bounds. Strict-validated
-    like ``runtime.executor``: unknown keys or policy names raise — a
-    typo'd policy silently not steering would fake a recovery."""
-    from .controller.policies import CONTROLLER_POLICIES
-    val = param_dict.get(CONTROLLER, False)
-    if isinstance(val, bool):
-        val = {"enabled": val}
-    if not isinstance(val, dict):
+def refuse_removed_controller(value, key=CONTROLLER):
+    """An old ds_config may still carry the run-time controller's keys
+    (``controller``, ``telemetry.watchdog.controller``): ``false`` (the
+    controller off) is accepted and means nothing; a config that asks
+    for the controller is refused, not ignored."""
+    if value is not False:
         raise DeepSpeedConfigError(
-            "{} must be a bool or a dict, got {!r}".format(
-                CONTROLLER, val))
-    for key in val:
-        if key not in CONTROLLER_KEYS:
-            raise DeepSpeedConfigError(
-                "unknown key {!r} in {} (accepted: {})".format(
-                    key, CONTROLLER, ", ".join(CONTROLLER_KEYS)))
-    enabled = val.get("enabled", True)
-    if not isinstance(enabled, bool):
-        raise DeepSpeedConfigError(
-            "{}.enabled must be a bool, got {!r}".format(
-                CONTROLLER, enabled))
-    if not enabled:
-        return None
-    out = {}
-    for key, default in (
-            ("interval_steps", CONTROLLER_INTERVAL_STEPS_DEFAULT),
-            ("eval_steps", CONTROLLER_EVAL_STEPS_DEFAULT),
-            ("cooldown_steps", CONTROLLER_COOLDOWN_STEPS_DEFAULT),
-            ("max_moves_per_tick", CONTROLLER_MAX_MOVES_DEFAULT)):
-        n = val.get(key, default)
-        if isinstance(n, bool) or not isinstance(n, int) or n < 1:
-            raise DeepSpeedConfigError(
-                "{}.{} must be an int >= 1, got {!r}".format(
-                    CONTROLLER, key, n))
-        out[key] = n
-    pct = val.get("guardrail_pct", CONTROLLER_GUARDRAIL_PCT_DEFAULT)
-    if isinstance(pct, bool) or not isinstance(pct, (int, float)) \
-            or pct <= 0:
-        raise DeepSpeedConfigError(
-            "{}.guardrail_pct must be a positive number, got "
-            "{!r}".format(CONTROLLER, pct))
-    out["guardrail_pct"] = float(pct)
-    policies = val.get("policies", list(CONTROLLER_POLICIES))
-    if not isinstance(policies, (list, tuple)) or not policies or \
-            not all(isinstance(p, str) for p in policies):
-        raise DeepSpeedConfigError(
-            "{}.policies must be a non-empty list of policy names, "
-            "got {!r}".format(CONTROLLER, policies))
-    for p in policies:
-        if p not in CONTROLLER_POLICIES:
-            raise DeepSpeedConfigError(
-                "unknown policy {!r} in {}.policies (accepted: "
-                "{})".format(p, CONTROLLER,
-                             "|".join(CONTROLLER_POLICIES)))
-    out["policies"] = list(policies)
-    out["enabled"] = True
-    return out
+            "{} is set, but the run-time controller was removed in PR 31: "
+            "the knobs it moved (executor windows, spec_k, "
+            "prefill_chunk_tokens, prefill_buckets, quantized "
+            "collectives) are set in the config".format(key))
 
 
 TRANSFORMER_FLASH_ATTENTION_MODES = ("auto", "pallas", "xla")
@@ -834,9 +772,7 @@ class DeepSpeedConfig(object):
         self.runtime_executor = get_runtime_executor(param_dict)
         self.runtime_executor_rewrites = \
             get_runtime_executor_rewrites(param_dict)
-        # closed-loop controller (runtime/controller/): None = off =
-        # structurally absent on both engines
-        self.controller_config = get_controller(param_dict)
+        refuse_removed_controller(param_dict.get(CONTROLLER, False))
 
         self.gradient_clipping = get_gradient_clipping(param_dict)
         self.grad_accum_dtype = get_grad_accum_dtype(param_dict)

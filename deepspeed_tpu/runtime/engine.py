@@ -256,27 +256,6 @@ class DeepSpeedEngine:
                 "GPT2Config.sparse_embedding_grads=True with "
                 "embedding_grad_mesh); gradients stay dense")
 
-        # closed-loop controller (runtime/controller/, docs/
-        # controller.md): None unless the strict-validated "controller"
-        # section enables it — off is structurally absent (no ledger
-        # file, no policies; the emit path pays one is-not-None check).
-        # Constructed LAST so its knob bindings see the resolved
-        # zero_plan / executor / quantization state.
-        self.controller = None
-        if self._config.controller_config is not None:
-            if self.telemetry is None:
-                from ..telemetry.config import warn_or_raise_noop
-                warn_or_raise_noop(
-                    "controller is enabled but telemetry is not — the "
-                    "controller observes/actuates through telemetry "
-                    "seams, so it cannot run (enable the telemetry "
-                    "section)", self._config.telemetry_config.strict
-                    if self._config.telemetry_config else False)
-            else:
-                from .controller.adapters import attach_train_controller
-                self.controller = attach_train_controller(
-                    self, self._config.controller_config)
-
         if self._config.dump_state:
             self._config.print("DeepSpeedEngine configuration")
 
@@ -1881,15 +1860,6 @@ class DeepSpeedEngine:
             segments=exec_segments if exec_segments and (
                 self.stream_runner is not None or
                 self.host_state is not None) else None)
-        if self.controller is not None:
-            # closed-loop tick (docs/controller.md): fold this step's
-            # wall into the objective window, finalize due override
-            # evaluations, and every interval_steps let the policies
-            # propose moves from the signals assembled off the seams
-            # this record was just built from
-            from .controller.adapters import train_signals
-            self.controller.on_step(self._window_step, dt,
-                                    train_signals(self))
 
     # ----------------------------------------------------------- diagnostics
     def _resolved_step_path(self):

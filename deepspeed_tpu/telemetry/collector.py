@@ -254,7 +254,6 @@ class TelemetryCollector(AtexitCloseMixin):
         # the existing record stream: zero new hot-path instrumentation.
         self.fleet = None
         self.elastic_observer = None
-        self.controller_view = None
         self.metrics = None
         self.exporter = None
         # healthz() reads _wall_start and the exporter thread serves it
@@ -451,11 +450,6 @@ class TelemetryCollector(AtexitCloseMixin):
             # straggler flags + last ici_health + export liveness ride
             # the EXISTING telemetry_snapshot() instead of a second API
             out["fleet"] = self.fleet_snapshot()
-        if self.controller_view is not None:
-            # the controller's decision counters/overrides ride the
-            # same seam (docs/controller.md) — benches embed this as
-            # extra.controller
-            out["controller"] = self.controller_view()
         return out
 
     # ---------------------------------------------------------------- fleet
@@ -522,13 +516,6 @@ class TelemetryCollector(AtexitCloseMixin):
         ElasticRunner's ``observe_fleet``); pass None to detach."""
         self.elastic_observer = fn
 
-    def set_controller_view(self, fn):
-        """Register the RuntimeController's ``snapshot`` callable so
-        ``telemetry_snapshot()['controller']`` and ``/healthz`` show
-        the live overrides/decision counters; pass None to detach
-        (off = the key is absent, not null — structurally absent)."""
-        self.controller_view = fn
-
     def healthz(self):
         """The ``/healthz`` JSON payload: watchdog trips, rolling-window
         MFU, TTFT-SLO burn rate, overflow/skip counters, and the fleet
@@ -557,10 +544,6 @@ class TelemetryCollector(AtexitCloseMixin):
             if self.watchdog is not None else None,
             "fleet": fleet,
         }
-        if self.controller_view is not None:
-            # live overrides on /healthz: what the controller currently
-            # holds retuned away from the static ds_config
-            out["controller"] = self.controller_view()
         return out
 
     def metrics_scrape(self):

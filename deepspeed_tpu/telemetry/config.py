@@ -68,10 +68,10 @@ from .programs import (RECOMPILE_STORM_THRESHOLD_DEFAULT,
 from .recorder import (RECORDER_CAPACITY_DEFAULT,
                        RECORDER_MAX_BUNDLES_DEFAULT)
 from .spans import SPANS_MAX_EVENTS_DEFAULT
-from .watchdog import (CONTROLLER_DEFAULTS, LOSS_SPIKE_DEFAULTS,
-                       NAN_STREAK_DEFAULTS, POOL_EXHAUSTION_DEFAULTS,
-                       STEP_DEADLINE_DEFAULTS, STRAGGLER_DEFAULTS,
-                       TTFT_SLO_DEFAULTS, WATCHDOG_ACTIONS)
+from .watchdog import (LOSS_SPIKE_DEFAULTS, NAN_STREAK_DEFAULTS,
+                       POOL_EXHAUSTION_DEFAULTS, STEP_DEADLINE_DEFAULTS,
+                       STRAGGLER_DEFAULTS, TTFT_SLO_DEFAULTS,
+                       WATCHDOG_ACTIONS)
 
 
 def warn_or_raise_noop(msg, strict, flag="telemetry.strict"):
@@ -277,6 +277,10 @@ class DeepSpeedTelemetryConfig(object):
         section = self._section_dict(section, TELEMETRY_WATCHDOG)
         self._reject_unknown(section, KNOWN_WATCHDOG_KEYS,
                              "telemetry.watchdog")
+        # imported here: runtime.config imports this module
+        from ..runtime.config import refuse_removed_controller
+        refuse_removed_controller(section.get("controller", False),
+                                  "telemetry.watchdog.controller")
         if not section.get("enabled", True):
             return
         defaults = {
@@ -286,7 +290,6 @@ class DeepSpeedTelemetryConfig(object):
             "ttft_slo": TTFT_SLO_DEFAULTS,
             "pool_exhaustion": POOL_EXHAUSTION_DEFAULTS,
             "straggler": STRAGGLER_DEFAULTS,
-            "controller": CONTROLLER_DEFAULTS,
         }
         parsed = {}
         for name, base in defaults.items():

@@ -67,7 +67,7 @@ FINGERPRINT_KEYS = ("version", "digest", "families")
 FLEET_REPORT_KEYS = (
     "kind", "run_dir", "n_hosts", "hosts", "offsets", "records", "gaps",
     "straggler", "ici_health", "trace", "divergence", "rescale",
-    "router", "controller",
+    "router",
 )
 
 # elastic rescale events (ISSUE 16): file name + kind + schema
@@ -95,18 +95,6 @@ ROUTER_DECISIONS = ("admit", "deny", "route_away", "preempt_migrate",
 # serving-role vocabulary duplicated from telemetry/record.py
 # (SERVING_ROLES), same pin
 SERVING_ROLES = ("monolith", "prefill", "decode", "router")
-
-# runtime-controller decision ledger (ISSUE 20): file name + kind +
-# schema duplicated from runtime/controller/ledger.py (stdlib-import
-# contract); pinned equal by tests/unit/test_controller.py
-CONTROLLER_EVENTS_JSONL = "controller_events.jsonl"
-KIND_CONTROLLER_EVENT = "controller_event"
-DECISION_KEYS = (
-    "kind", "wall", "seq", "event", "decision_id", "policy", "knob",
-    "target", "old", "new", "signal", "predicted_win_s",
-    "measured_win_s", "reason",
-)
-CONTROLLER_EVENT_TYPES = ("decision", "outcome", "revert")
 
 # every merged fleet-step record carries exactly these keys
 FLEET_STEP_KEYS = (
@@ -633,48 +621,6 @@ def merge_run(run_dir, factor=None, k=None, min_hosts=None,
         "decisions": decisions,
         "events": router_events,
     }
-    # runtime-controller decision ledger (ISSUE 20): per-host
-    # controller_events.jsonl files, wall-ordered union + per-event-type
-    # tally + the unreverted-regression list (`ds_fleet --strict` exits
-    # 2 on those: the controller measured itself making things worse
-    # and did NOT undo it)
-    controller_events = []
-    for host in hosts:
-        path = os.path.join(host.path, CONTROLLER_EVENTS_JSONL)
-        if not os.path.exists(path):
-            continue
-        events, problems = read_jsonl_tolerant(path)
-        host.gaps.extend(problems)
-        gaps.extend("{}: {}".format(host.name, p) for p in problems)
-        for ev in events:
-            if isinstance(ev, dict) and \
-                    ev.get("kind") == KIND_CONTROLLER_EVENT:
-                controller_events.append(dict(ev, source=host.name))
-    controller_events.sort(
-        key=lambda ev: ev["wall"]
-        if isinstance(ev.get("wall"), _NUMERIC)
-        and not isinstance(ev.get("wall"), bool) else 0.0)
-    ctrl_tally = {}
-    regressed, reverted_ids = set(), set()
-    for ev in controller_events:
-        etype = ev.get("event")
-        if isinstance(etype, str):
-            ctrl_tally[etype] = ctrl_tally.get(etype, 0) + 1
-        if etype == "revert":
-            reverted_ids.add(ev.get("decision_id"))
-        elif etype == "outcome":
-            win = ev.get("measured_win_s")
-            if isinstance(win, _NUMERIC) and \
-                    not isinstance(win, bool) and win < 0:
-                regressed.add(ev.get("decision_id"))
-    controller = {
-        "count": len(controller_events),
-        "tally": ctrl_tally,
-        "unreverted": sorted(d for d in regressed
-                             if d not in reverted_ids and
-                             d is not None),
-        "events": controller_events,
-    }
     return {
         "kind": KIND_FLEET_REPORT,
         "run_dir": os.path.abspath(run_dir),
@@ -689,7 +635,6 @@ def merge_run(run_dir, factor=None, k=None, min_hosts=None,
         "divergence": divergence,
         "rescale": rescale,
         "router": router,
-        "controller": controller,
     }
 
 

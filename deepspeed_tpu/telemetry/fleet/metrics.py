@@ -398,27 +398,6 @@ class MetricsSink:
         # ---- doctor families
         self._trips = r.counter(
             "watchdog_trips_total", "watchdog trips per alarm")
-        # ---- controller families (runtime/controller/, the
-        # RuntimeController updates these through the methods below)
-        self._ctrl_decisions = r.counter(
-            "controller_decisions_total",
-            "controller override decisions per knob")
-        self._ctrl_reverts = r.counter(
-            "controller_reverts_total",
-            "controller guardrail auto-reverts per knob")
-        self._ctrl_drift = r.gauge(
-            "controller_drift",
-            "predicted/measured win ratio, last evaluated override")
-
-    # ------------------------------------------------- controller updates
-    def controller_decision(self, knob):
-        self._ctrl_decisions.inc(knob=str(knob))
-
-    def controller_revert(self, knob):
-        self._ctrl_reverts.inc(knob=str(knob))
-
-    def controller_drift(self, ratio):
-        self._ctrl_drift.set(float(ratio))
 
     # ------------------------------------------------------ sink protocol
     def emit(self, rec):

@@ -1,9 +1,8 @@
 """Peak-flops tables and MFU arithmetic.
 
-One home for the per-chip peak numbers every surface reads (bench.py,
-bench_inference.py, the per-step telemetry records): public
-cloud.google.com/tpu specs, bf16 peak TFLOPS per chip (v2/v3 per-chip =
-2 cores), keyed by the EXACT ``device_kind`` jax reports. A kind that is
+One home for the per-chip peak numbers the per-step telemetry records
+read: public cloud.google.com/tpu specs, bf16 peak TFLOPS per chip
+(v2/v3 per-chip = 2 cores), keyed by the EXACT ``device_kind`` jax reports. A kind that is
 not in the table is an error, not a default. The ``cpu`` row is a
 nominal 0.1 TFLOPS that exists only because tier-1 StepRecords are
 priced against it (tests/unit/test_telemetry.py); it is never a device

@@ -135,58 +135,6 @@ def validate_rewrite_stats(stats):
     return problems
 
 
-# the runtime controller's snapshot shape (runtime/controller/core.py
-# RuntimeController.snapshot): rides telemetry_snapshot()["controller"],
-# /healthz and the bench extra.controller block. check_bench_schema.py
-# carries a stdlib copy pinned equal by tests/unit/test_controller.py.
-CONTROLLER_SNAPSHOT_KEYS = ("enabled", "role", "policies", "decisions",
-                            "outcomes", "reverts", "pending",
-                            "overrides", "drift", "ledger_path")
-
-
-def validate_controller_snapshot(snap):
-    """Schema check for one CONTROLLER_SNAPSHOT_KEYS dict (a bench's
-    ``extra.controller``). Returns a list of problem strings."""
-    problems = []
-    if not isinstance(snap, dict):
-        return ["controller snapshot is not a dict: {!r}".format(
-            type(snap).__name__)]
-    for key in CONTROLLER_SNAPSHOT_KEYS:
-        if key not in snap:
-            problems.append("controller missing key {!r}".format(key))
-    extra = sorted(set(snap) - set(CONTROLLER_SNAPSHOT_KEYS))
-    if extra:
-        problems.append("controller unexpected key(s) {}".format(extra))
-    if problems:
-        return problems
-    if not isinstance(snap["enabled"], bool):
-        problems.append("controller.enabled is not a bool: {!r}".format(
-            snap["enabled"]))
-    if not isinstance(snap["role"], str):
-        problems.append("controller.role is not a string: {!r}".format(
-            snap["role"]))
-    for key in ("decisions", "outcomes", "reverts", "pending"):
-        val = snap[key]
-        if isinstance(val, bool) or not isinstance(val, int) or val < 0:
-            problems.append("controller.{} is not a nonnegative int: "
-                            "{!r}".format(key, val))
-    for key, want in (("policies", "policy names"),
-                      ("overrides", "override dicts")):
-        if not isinstance(snap[key], (list, tuple)):
-            problems.append("controller.{} is not a list of {}".format(
-                key, want))
-    if snap["drift"] is not None and (
-            isinstance(snap["drift"], bool) or
-            not isinstance(snap["drift"], _NUMERIC)):
-        problems.append("controller.drift is neither null nor a "
-                        "number: {!r}".format(snap["drift"]))
-    if snap["ledger_path"] is not None and \
-            not isinstance(snap["ledger_path"], str):
-        problems.append("controller.ledger_path is neither null nor a "
-                        "string: {!r}".format(snap["ledger_path"]))
-    return problems
-
-
 def validate_segment_stats(stats):
     """Schema check for one SEGMENT_KEYS stats dict (a StepRecord's
     ``offload`` sub-dict on the lowered paths, or a bench's

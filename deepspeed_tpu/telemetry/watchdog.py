@@ -1,6 +1,6 @@
 """Run doctor watchdogs: hang/anomaly alarms over the telemetry stream.
 
-Seven alarms, each with a configurable action (``telemetry.watchdog``):
+Six alarms, each with a configurable action (``telemetry.watchdog``):
 
 * **step_deadline** — a background thread arms a deadline at every step
   begin (``max(factor x rolling-median step time, floor_s)``, armed only
@@ -23,12 +23,6 @@ Seven alarms, each with a configurable action (``telemetry.watchdog``):
   :meth:`Watchdog.observe_fleet` by ``TelemetryCollector.ingest_fleet``
   (the ``bin/ds_fleet.py`` live seam); the detection itself lives in
   fleet/straggler.py.
-* **controller** — the closed-loop runtime controller
-  (runtime/controller/) measured one of its own overrides regressing
-  the objective past its guardrail. Default action is ``dump`` (the
-  crash bundle carries the full decision ledger via the recorder's
-  ``controller`` context), and the controller auto-reverts the
-  override after the trip — the revert is itself a ledger event.
 
 Actions: ``warn`` logs; ``dump`` logs + writes a flight-recorder crash
 bundle; ``raise`` logs + dumps + raises :class:`WatchdogError` (from the
@@ -56,8 +50,6 @@ LOSS_SPIKE_DEFAULTS = {"zscore": 8.0, "window": 50, "min_steps": 10,
                        "action": "warn"}
 TTFT_SLO_DEFAULTS = {"slo_s": None, "every": 1, "action": "warn"}
 POOL_EXHAUSTION_DEFAULTS = {"every": 100, "action": "warn"}
-# dump by default: the trip's whole point is the bundle with the ledger
-CONTROLLER_DEFAULTS = {"action": "dump"}
 
 _MAX_TRIPS = 64
 
@@ -307,16 +299,6 @@ class Watchdog:
                                         flag.get("worst_ratio", 0.0)),
                     flag.get("steps"), flag.get("first_step")),
                 cfg["action"])
-
-    def observe_controller(self, detail):
-        """Feed a controller guardrail regression (the RuntimeController
-        calls this BEFORE reverting, so a ``dump`` action's bundle
-        shows the regressing override still applied and the ledger up
-        to the moment of the trip)."""
-        cfg = self.cfg.get("controller")
-        if cfg is None:
-            return
-        self._trip("controller", detail, cfg["action"])
 
     def observe_pool_event(self, kind):
         """``kind``: 'admission_blocked' | 'preemption' — the paged KV

@@ -1,6 +1,6 @@
 """Where the persistent XLA compile cache lives.
 
-Entry scripts (chip_smoke.py, bench.py, bench_inference.py) call
+Entry scripts (chip_smoke.py, benchmark/run.py) call
 :func:`enable_compile_cache` once, before first backend use; nothing
 calls it at import. The directory is placed from OUTSIDE when
 ``JAX_COMPILATION_CACHE_DIR`` is set (jax reads that variable itself, so

@@ -33,6 +33,26 @@ def tmp_config_file(tmp_path):
     return _write
 
 
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiled = []       # fun_name of every program this process compiled
+
+
+def _on_compile(event, duration, fun_name=None, **_):
+    if event == _COMPILE_EVENT:
+        _compiled.append(fun_name)
+
+
+@pytest.fixture(scope="session")
+def compiled_programs():
+    """The names of the programs this process has compiled, in order;
+    it grows by one with every backend compile, the event the
+    benchmark's ``compiles_in_window`` counts. A test notes its length,
+    runs what must compile nothing, and reads what came after. One
+    listener a process: jax has no call that removes one."""
+    jax.monitoring.register_event_duration_secs_listener(_on_compile)
+    return _compiled
+
+
 # Two tests of tests/unit_benchmark/ were written when GPT-2 was the one
 # family and hold EVERY configuration or serving cell to GPT-2 medium's
 # sizes: test_config_file_states_source_and_cuts (the `model` section)

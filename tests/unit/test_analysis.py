@@ -600,6 +600,33 @@ def test_repo_self_lint_clean_against_committed_baseline():
         f.message for f in new)
 
 
+def test_lint_baseline_has_no_stale_entries():
+    """Every key of the committed baseline still fires at least its
+    count: an entry whose code or whose rule went would otherwise sit
+    there and excuse the next occurrence under that key."""
+    findings = astlint.lint_paths(
+        [os.path.join(REPO, "deepspeed_tpu")], base=REPO)
+    baseline = astlint.load_baseline(
+        os.path.join(REPO, "bin", "ds_lint_baseline.json"))
+    dead = {key: (allowed, len(findings.get(key, ())))
+            for key, allowed in baseline.items()
+            if len(findings.get(key, ())) < allowed}
+    assert dead == {}, "baseline allows more than fires: {}".format(dead)
+
+
+def test_repo_self_lint_is_baseline_clean():
+    """``python bin/ds_lint.py`` with its own defaults (the paths it
+    walks, the baseline it loads) is the command a PR is held to: it
+    exits 0 over this checkout."""
+    import subprocess
+    import sys
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bin", "ds_lint.py")],
+        capture_output=True, text=True, cwd=REPO)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "0 above baseline; 0 stale" in proc.stdout, proc.stdout
+
+
 # ----------------------------------------------------------- HLO layer
 def test_hlo_census_parsers():
     from deepspeed_tpu.analysis.hlo import (_parse_permute_groups,

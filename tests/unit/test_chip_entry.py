@@ -26,12 +26,11 @@ def _run(args, **kw):
 
 
 # ------------------------------------------------------- no chip, no result
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py",
-                                    "bench_inference.py"])
-def test_entry_script_fails_without_a_tpu(script):
-    """Under JAX_PLATFORMS=cpu each script exits non-zero at its device
-    check with a one-line reason — no stand-in model, no result line."""
-    res = _run([script])
+def test_entry_script_fails_without_a_tpu():
+    """Under JAX_PLATFORMS=cpu the smoke exits non-zero at its device
+    check with a one-line reason — no stand-in model, no result line
+    (the benchmark's own refusal: tests/unit_benchmark/)."""
+    res = _run(["chip_smoke.py"])
     assert res.returncode != 0
     assert '"ok": true' not in res.stdout
     assert '"value"' not in res.stdout

@@ -259,6 +259,38 @@ def test_unknown_key_ignore_mode():
     DeepSpeedConfig(None, param_dict=cfg_dict)  # no raise, no warning needed
 
 
+@pytest.mark.parametrize("section, refused", [
+    ({"controller": False}, False),
+    ({"controller": True}, True),
+    ({"controller": {"enabled": True, "policies": ["speculation"]}}, True),
+    ({"telemetry": {"enabled": False,
+                    "watchdog": {"controller": {"action": "dump"}}}}, True),
+], ids=["false", "true", "dict", "watchdog"])
+def test_removed_controller_section(section, refused):
+    """An old ds_config that still carries the run-time controller's
+    keys: off is accepted (under strict validation too) and means
+    nothing; on is refused in a sentence that names the removal, by
+    the training config and by ``init_inference`` alike — never
+    ignored."""
+    import deepspeed_tpu
+    from deepspeed_tpu.models import gpt2
+    cfg_dict = dict(section, train_batch_size=8,
+                    config_validation="strict")
+    model = gpt2.make_gpt2_model(config=gpt2.GPT2Config(
+        vocab_size=64, max_seq_len=16, n_layers=1, n_heads=1, d_model=8,
+        use_flash_attention=False, remat=False))
+    serve = dict(section, inference={"max_batch_size": 1,
+                                     "prefill_buckets": [8]})
+    if not refused:
+        DeepSpeedConfig(None, param_dict=cfg_dict)
+        deepspeed_tpu.init_inference(model=model, config=serve)
+        return
+    with pytest.raises(DeepSpeedConfigError, match="removed in PR 31"):
+        DeepSpeedConfig(None, param_dict=cfg_dict)
+    with pytest.raises(DeepSpeedConfigError, match="removed in PR 31"):
+        deepspeed_tpu.init_inference(model=model, config=serve)
+
+
 def test_doc_covers_every_known_key():
     """docs/_pages/config-json.md must mention every accepted key (and the
     parser must accept every key the doc shows) — the strict-or-warn

@@ -327,3 +327,114 @@ def test_lamb_bf16_moments():
     assert np.isfinite(np.asarray(new_p["w"])).all()
     with _pytest.raises(ValueError, match="incompatible"):
         FusedLamb(use_pallas=True, moments_dtype="bf16")
+
+
+# ------------------------------------------- the program family is closed
+_ZERO2_BF16 = dict(
+    bf16={"enabled": True}, zero_optimization={"stage": 2},
+    optimizer={"type": "Adam",
+               "params": {"lr": 1e-2, "moments_dtype": "bf16"}},
+    data_types={"grad_accum_dtype": "bf16"})
+_WARMUP = {"type": "WarmupLR", "params": {
+    "warmup_min_lr": 0.0, "warmup_max_lr": 0.01, "warmup_num_steps": 100}}
+
+# case -> (config, how a step is driven, what happens between the first
+# two steps and the four that must compile nothing)
+_TRAIN_FAMILY = {
+    # the training cell's shape of config (benchmark/configs/
+    # gpt2-350m-train.json): ZeRO-2, bf16 moments and accumulation
+    "zero2_bf16": (base_config(WORLD, **_ZERO2_BF16), "fused", None),
+    # a changing _hyper() must be an operand, not a constant
+    "zero2_bf16_lr_warmup": (
+        base_config(WORLD, scheduler=_WARMUP, **_ZERO2_BF16), "fused", None),
+    "gas4_train_batch": (
+        base_config(WORLD, gas=4, **_ZERO2_BF16), "fused", None),
+    # the `micro` and `apply` programs
+    "gas4_forward_backward_step": (
+        base_config(WORLD, gas=4, **_ZERO2_BF16), "micro", None),
+    "zero3": (
+        base_config(WORLD, bf16={"enabled": True},
+                    zero_optimization={
+                        "stage": 3,
+                        "stage3_param_persistence_threshold": 0}),
+        "fused", None),
+    "zero2_quantized_gradients": (
+        base_config(WORLD, bf16={"enabled": True},
+                    zero_optimization={"stage": 2,
+                                       "zero_quantized_gradients": True}),
+        "fused", None),
+    # scalar state leaves (scale, skip count) after a skipped step
+    "fp16_overflow_step": (
+        base_config(WORLD, fp16={"enabled": True, "initial_scale_power": 8,
+                                 "hysteresis": 1}),
+        "fused", "overflow"),
+    # loaded leaves: the dtypes and shardings the program was traced with
+    "after_checkpoint_load": (
+        base_config(WORLD, **_ZERO2_BF16), "fused", "reload"),
+}
+
+
+# a defect this test found (PR 31) and, by that PR's terms, left alone:
+# _load_checkpoint_tag casts every optimizer leaf to float32 (bf16
+# moments come back float32 and stay so) and leaves opt.step, the
+# scaler's leaves and skip_count uncommitted on one device
+_RELOAD_DEFECT = pytest.mark.xfail(
+    strict=True,
+    reason="jit(fused) compiles twice more after load_checkpoint: the "
+           "loaded optimizer leaves are float32 whatever moments_dtype "
+           "says, and the loaded scalars are not committed replicated")
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param(case, marks=_RELOAD_DEFECT)
+    if case == "after_checkpoint_load" else case
+    for case in sorted(_TRAIN_FAMILY)])
+def test_train_program_family_is_closed(case, compiled_programs, tmp_path):
+    """After the first two steps, four more compile nothing: the step's
+    programs are fixed by the config, and nothing a step leaves behind
+    (a learning rate, a loss scale, a skip count, leaves read back from
+    a checkpoint) is a new program to the next one."""
+    config, path, between = _TRAIN_FAMILY[case]
+    gas = config["gradient_accumulation_steps"]
+    dataset = SimpleDataset(1024, HIDDEN)
+    micro = config["train_micro_batch_size_per_gpu"] * WORLD
+    cursor = iter(range(0, 1 << 30, micro))
+
+    def micro_batch(poison=False):
+        lo = next(cursor)
+        rows = [dataset[i % len(dataset)] for i in range(lo, lo + micro)]
+        x = np.stack([r[0] for r in rows])
+        if poison:
+            x[0, 0] = 1e30
+        return x, np.stack([r[1] for r in rows])
+
+    def step(engine, poison=False):
+        batches = [micro_batch(poison) for _ in range(gas)]
+        if path == "fused":
+            engine.train_batch(batch=tuple(
+                np.stack(leaf) for leaf in zip(*batches)))
+            return
+        for x, y in batches:
+            engine.backward(engine(x, y))
+            engine.step()
+
+    def warm_engine(seed):
+        engine = make_engine(config, seed=seed)
+        for _ in range(2):
+            step(engine)
+        return engine
+
+    engine = warm_engine(0)
+    if between == "reload":
+        engine.save_checkpoint(str(tmp_path), tag="two")
+        # a fresh engine compiles its programs in its first two steps;
+        # what it then loads must fit them
+        engine = warm_engine(1)
+        engine.load_checkpoint(str(tmp_path), tag="two")
+    before = len(compiled_programs)
+    for i in range(4):
+        step(engine, poison=between == "overflow" and i == 1)
+    assert compiled_programs[before:] == []
+    if between == "overflow":
+        assert engine.skipped_steps == 1
+        assert engine.loss_scale() == 2 ** 7

@@ -570,6 +570,30 @@ def test_merge_run_end_to_end_torn_missing_skewed(tmp_path):
     assert report["straggler"]["flags"] == []
 
 
+def test_merge_run_passes_over_an_old_controller_ledger(tmp_path):
+    """A run directory written before PR 31 may hold the removed
+    run-time controller's ``controller_events.jsonl``: the merge reads
+    the host as if the file were not there, and the report has no
+    ``controller`` section and passes the stdlib checker."""
+    for name in ("h0", "h1"):
+        d = _write_host(tmp_path, name, steps=4)
+        with open(os.path.join(d, "controller_events.jsonl"), "w") as fh:
+            fh.write(json.dumps({
+                "kind": "controller_event", "wall": 1001.0, "seq": 0,
+                "event": "decision", "decision_id": name + "-0000",
+                "policy": "speculation", "knob": "spec_k", "target": None,
+                "old": 3, "new": 8, "signal": {"step": 3},
+                "predicted_win_s": 0.01, "measured_win_s": None,
+                "reason": "acceptance rate 0.9"}) + "\n")
+    report = merge_run(str(tmp_path))
+    assert "controller" not in report
+    assert report["n_hosts"] == 2 and report["gaps"] == []
+    assert len(report["records"]) == 4
+    path = tmp_path / "fleet_report.json"
+    path.write_text(json.dumps(report))
+    assert _load_bin("check_bench_schema").check_file(str(path)) == []
+
+
 def test_merge_chrome_traces_lanes_and_offsets(tmp_path):
     d0 = _write_host(tmp_path, "h0", steps=2)
     d1 = _write_host(tmp_path, "h1", steps=2, skew=2.0)

@@ -695,3 +695,110 @@ def test_bench_schema_checker_table_matches_record_schema():
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     assert mod.SERVING_SUBDICT_KEYS == SERVING_SUBDICT_KEYS
+
+
+# ------------------------------------------- the program family is closed
+
+
+def _jamba_engine():
+    from deepspeed_tpu.models import jamba
+    hf = {"attn_layer_offset": 1, "attn_layer_period": 2,
+          "hidden_size": 32, "intermediate_size": 64,
+          "mamba_conv_bias": True, "mamba_d_conv": 4, "mamba_d_state": 8,
+          "mamba_dt_rank": 4, "mamba_expand": 2, "mamba_proj_bias": False,
+          "max_position_embeddings": 64, "num_attention_heads": 2,
+          "num_experts": 1, "num_hidden_layers": 2,
+          "num_key_value_heads": 1, "rms_norm_eps": 1e-6,
+          "vocab_size": 128, "initializer_range": 0.1}
+    cfg = jamba.config_from_hf(hf, dtype=jnp.float32)
+    return paged_engine(jamba.make_jamba_model(cfg, seed=0),
+                        paged_attention_kernel="xla", max_seq_len=64,
+                        num_pages=24, prefill_buckets=[8, 16])
+
+
+_NGRAM = {"enabled": True, "method": "ngram", "num_draft_tokens": 3}
+_MODEL_DRAFT = {"enabled": True, "method": "model", "num_draft_tokens": 3}
+
+# case -> (engine, new tokens, prompt lengths of the first pass, of the
+# second); what a case adds to plain greedy serving is in its engine
+_FAMILY = {
+    "gpt2_greedy": (
+        lambda: paged_engine(tiny_model()), 6, (5, 11, 26), (7, 14, 30, 3)),
+    "gpt2_sampled": (
+        lambda: paged_engine(tiny_model(), greedy=False, top_k=8,
+                             temperature=0.9),
+        6, (5, 11, 26), (7, 14, 30, 3)),
+    "gpt2_chunked_prefill": (
+        lambda: paged_engine(tiny_model(), prefill_chunk_tokens=8),
+        6, (29, 5, 18), (27, 7, 12)),
+    "gpt2_prefix_cache": (
+        lambda: paged_engine(tiny_model(), prefix_caching=True),
+        5, (20, 23, 9, 30), (21, 25, 11, 28)),
+    "gpt2_ngram_drafter": (
+        lambda: paged_engine(tiny_model(), speculative=_NGRAM),
+        9, (14, 6, 17, 30), (12, 7, 20, 27)),
+    "gpt2_model_drafter": (
+        lambda: deepspeed.init_inference(
+            model=tiny_model(),
+            draft_model=tiny_model(seed=123, n_layers=1),
+            config={"inference": {
+                "max_batch_size": 2, "prefill_buckets": [8, 16, 32],
+                "dtype": "fp32", "greedy": True, "kv_layout": "paged",
+                "kv_block_size": PS, "speculative": _MODEL_DRAFT}}),
+        9, (6, 13, 29), (4, 15, 25)),
+    # 58 of 64 positions: while that slot lives, every step is plain
+    # decode; once it retires the short request speculates again
+    "gpt2_drafter_near_ceiling": (
+        lambda: paged_engine(tiny_model(), speculative=_NGRAM,
+                             prefill_buckets=[8, 16, 32, 64],
+                             max_batch_size=2),
+        25, (58, 4, 12, 30), (57, 6, 10, 20)),
+    # 9 pages for three answers of up to 38 tokens: the youngest is
+    # preempted and prefills its prompt and its tokens so far again
+    "gpt2_preemption": (
+        lambda: paged_engine(tiny_model(), num_pages=9),
+        24, (12, 14, 10, 30, 5), (11, 13, 9, 26, 6)),
+    "jamba_greedy": (
+        _jamba_engine, 6, (5, 11, 16), (7, 14, 3)),
+    # 23 tokens over a largest bucket of 16: two chunks, the second on
+    # the state the first left
+    "jamba_two_chunks": (
+        _jamba_engine, 6, (23, 5, 11), (21, 7, 19)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FAMILY))
+def test_serving_program_family_is_closed(case, compiled_programs):
+    """What a configuration fixes at construction fixes the programs:
+    after a first pass over every bucket and both decode widths, other
+    prompts in the same buckets compile nothing — what the chip's check
+    calls ``compiles_in_window == 0``, here for every path of the
+    scheduler that picks a program."""
+    from deepspeed_tpu.inference.scheduler import ContinuousBatchingScheduler
+    build, new_tokens, first, second = _FAMILY[case]
+    engine = build()
+    shared = [9, 4, 7, 1, 8, 2, 6, 3] * 2       # two whole pages
+
+    def serve(lengths, salt):
+        rs = np.random.RandomState(salt)
+        sched = ContinuousBatchingScheduler(engine)
+        for n in lengths:
+            prompt = rs.randint(0, 128, size=n).tolist()
+            if engine.prefix_cache is not None and n > len(shared):
+                prompt[:len(shared)] = shared
+            sched.submit(prompt, max_new_tokens=new_tokens,
+                         eos_token_id=None)
+        sched.run()
+        return sched
+
+    serve(first, 1)
+    stats, before = dict(engine.compile_stats), len(compiled_programs)
+    sched = serve(second, 2)
+    assert compiled_programs[before:] == []
+    assert engine.compile_stats == stats
+    if case == "gpt2_prefix_cache":
+        assert engine.prefix_stats()["hits"] >= 2
+    if case == "gpt2_drafter_near_ceiling":
+        assert stats["decode_traces"] == 2       # "two widths", no third
+    if case == "gpt2_preemption":
+        assert sched.preemptions >= 1

@@ -578,3 +578,42 @@ def test_dsl010_accepts_schema_conformant_literal(tmp_path):
     assert not [v for v in astlint.lint_file(path, relpath="ok.py",
                                              serving_schema=schema)
                 if v[0] == "DSL010"]
+
+
+# ------------------------- one request, one trace id across a hand-off
+def test_page_slice_carries_trace_id_across_the_wire():
+    k = np.arange(2 * 1 * 2 * 4 * 3, dtype=np.float32).reshape(
+        2, 1, 2, 4, 3)
+    sl = PageSlice(k, k + 1, page_size=4, length=5, pending_token=7,
+                   context=[1, 2, 3, 4, 5], trace_id="serve-9-12")
+    back = deserialize_slice(serialize_slice(sl))
+    assert back.trace_id == "serve-9-12"
+    # absence stays None (older slices, spans off)
+    sl2 = PageSlice(k, k, page_size=4, length=5, pending_token=7,
+                    context=[1])
+    assert deserialize_slice(serialize_slice(sl2)).trace_id is None
+
+
+def test_span_tracer_continues_a_carried_trace_id():
+    from deepspeed_tpu.telemetry.spans import SpanTracer
+    tracer = SpanTracer([])
+    cont = tracer.begin("serving_request", trace_id="prefill-1-0")
+    assert cont.trace_id == "prefill-1-0"
+    minted = tracer.begin("serving_request")
+    assert minted.trace_id != "prefill-1-0"
+
+
+def test_merged_trace_rehomes_cross_host_requests():
+    ev = lambda pid, tid_arg: {"name": "s", "ph": "X", "ts": 1.0,
+                               "dur": 1.0, "pid": pid, "tid": 0,
+                               "args": {"trace_id": tid_arg}}
+    merged = [ev(0, "req-a"), ev(1, "req-a"),    # crosses hosts
+              ev(0, "req-b"),                    # single-host: stays
+              {"name": "x", "ph": "X", "ts": 0.0, "dur": 1.0,
+               "pid": 1, "tid": 3}]              # no trace_id: stays
+    aggregate._rehome_cross_host_requests(merged, req_pid=2)
+    assert [e["pid"] for e in merged[:4]] == [2, 2, 0, 1]
+    assert merged[0]["tid"] == merged[1]["tid"]
+    names = [e for e in merged if e.get("ph") == "M"]
+    assert {(m["name"], m["args"]["name"]) for m in names} == \
+        {("process_name", "requests"), ("thread_name", "req-a")}

@@ -1,18 +1,23 @@
 """The prefill selective-scan kernel's share of its HBM roofline: the
 least time the chip's memory could take to move the scan's operands
-and results for the window's padded chunk tokens (bytes from shapes
-over the HBM peak) over the device time of the kernel's events.
+and results for the padded tokens of the prefill chunks that the trace
+holds whole (bytes from shapes over the HBM peak; a chunk's padded
+tokens are the mean over the window's chunk spans) over the device
+time of the kernel's events in those chunks (``kernel_launches``).
 Parameters: ``patterns``, ``span`` (the chunk span)."""
 from .. import manifest
-from . import span_chunks
+from . import kernel_launches, span_chunks
 
 
 def read(run, params):
-    count, seconds = run.reduction.matching(params["patterns"])
     found = span_chunks.chunks(run, params["span"])
-    if not count or not found:
+    if not found:
+        return None
+    launches, seconds = kernel_launches.held(run, params, len(found),
+                                             "chunks")
+    if not launches:
         return None
     family = manifest.plugin("models", run.config["family"])
-    nbytes = family.mamba_scan_bytes(
-        run.config["model"], sum(p for _, p in found), len(found))
+    padded = sum(p for _, p in found) * launches / len(found)
+    nbytes = family.mamba_scan_bytes(run.config["model"], padded, launches)
     return 100.0 * (nbytes / run.peaks["hbm_bytes_per_s"]) / seconds

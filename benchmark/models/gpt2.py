@@ -72,6 +72,15 @@ def train_flops_per_token(model, seq):
     return 6.0 * matmul_params + 6.0 * L * seq * d
 
 
+def serve_flops_per_token(model):
+    """Operations every served token needs, prompt or generated: 2 for
+    each weight of the layers' matmuls (12 d^2 a layer). A floor: the
+    head, which only a sampled position needs, and attention's scores
+    and values, which grow with the context, are left out."""
+    d, L = model["n_embd"], model["n_layer"]
+    return 2.0 * L * 12 * d * d
+
+
 def flash_attention_flops(model, rows, seq):
     """Causal attention forward and backward over ``rows`` sequences of
     ``seq`` tokens, all layers: forward is two matmuls over the lower

@@ -73,6 +73,25 @@ def release(*trees):
 
 
 # ----------------------------------------------------------------- counts
+def serve_flops_per_token(model):
+    """Operations every served token needs, prompt or generated: 2 for
+    each weight of the layers' matmuls (the gated MLP of every layer;
+    in, x, dt and out projections of a Mamba layer; q, k, v and o of an
+    attention layer). A floor: the head, which only a sampled position
+    needs, the scan and attention's scores and values are left out."""
+    d, ff = model["hidden_size"], model["intermediate_size"]
+    di, n = reference.d_inner(model), model["mamba_d_state"]
+    r = model["mamba_dt_rank"]
+    kv = (model["num_key_value_heads"] * d //
+          model["num_attention_heads"])
+    layers = model["num_hidden_layers"]
+    n_attn = sum(reference.is_attention(model, i) for i in range(layers))
+    mamba = d * 2 * di + di * (r + 2 * n) + r * di + di * d
+    attn = 2 * d * d + 2 * d * kv
+    return 2.0 * (layers * 3 * d * ff + n_attn * attn +
+                  (layers - n_attn) * mamba)
+
+
 def mamba_scan_bytes(model, padded_tokens, chunks):
     """Bytes the prefill scan's operands and results take, all Mamba
     layers: per padded token x, dt and y (float32, d_inner each) and B,

@@ -116,7 +116,13 @@ def layer_metrics(run, outcome):
     that finds nothing to read returns None and the metric is left
     out."""
     from . import trace
-    run.reduction = trace.reduce_trace(run.trace_dir, run.spans.names())
+    red = run.reduction = trace.reduce_trace(run.trace_dir,
+                                             run.spans.names())
+    run.log("trace: {} device operations and {} program runs over "
+            "{:.6f} s, {} host spans".format(
+                sum(map(len, red.device_events.values())),
+                sum(map(len, red.device_modules.values())),
+                red.window_s, len(red.host_spans)))
     run.end_to_end = outcome["end_to_end"]
     values = {}
     for metric in manifest.cell_metrics(run.manifest, run.cell["name"],

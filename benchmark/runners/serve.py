@@ -3,7 +3,9 @@ thread: submit what is due, take one ``scheduler.step()``, look at what
 each request has got, repeat. Open loop: a request is due when the
 traffic says so, whatever the server is doing, and its time to first
 token counts from when it was DUE. A backlog is the same loop with
-everything due at the start.
+everything due at the start; it has to outlast the window, and a run
+whose queue is empty when the window closes raises: its last steps ran
+with slots that nothing was waiting for.
 
 Workload parameters: ``traffic`` (generator + parameters), ``lead_s``
 (seconds of the same traffic before the window opens, so that the
@@ -61,6 +63,17 @@ def latency_tails(sample, g0, q):
             "failed": sum(r.seen < r.want for r in sample)}
 
 
+def _backlog_ran_dry(requests, served_s):
+    """The error for a backlog that did not outlast the window, with
+    the rate below which its tokens would have lasted that long."""
+    tokens = sum(r.prompt_len + r.want for r in requests)
+    return RuntimeError(
+        "the backlog ran dry: 0 of {} requests were still queued when the "
+        "window closed; {} tokens over {:g} s keep the queue full only "
+        "below {:.0f} tokens/s: raise arrivals.queued".format(
+            len(requests), tokens, served_s, tokens / served_s))
+
+
 def _warm_up(engine, scheduler_cls, vocab, rng):
     """One request in every prefill bucket, two tokens each: compiles
     (or loads) every prefill program and the decode program."""
@@ -84,6 +97,7 @@ def run(run):
     vocab = config["model"]["padded_vocab_size"]
     seconds = run.window_seconds()
     latency = workload["latency"] and not run.trace
+    backlog = workload["traffic"]["arrivals"]["process"] == "backlog"
     lead_s = workload["lead_s"]
     drain_cap_s = workload["drain_cap_s"] if latency else 0.0
 
@@ -110,6 +124,7 @@ def run(run):
     t_open_at, t_close_at = g0 + lead_s, g0 + lead_s + seconds
     sample = [r for r in requests if lead_s <= r.due < lead_s + seconds]
     prefill_s_at_open = 0.0
+    backlog_left = 0               # requests still queued at the close
 
     def observe(req, n, now):
         new = n - req.seen
@@ -132,6 +147,7 @@ def run(run):
             run.close_window()
             run.counters["prefill_seconds"] = \
                 metrics.prefill_seconds - prefill_s_at_open
+            backlog_left = len(scheduler.queue) + len(requests) - nxt
         if run.t_close is not None and (
                 not latency or now - run.t_close >= drain_cap_s or
                 all(r.seen >= r.want for r in sample)):
@@ -171,6 +187,8 @@ def run(run):
                 engine.pages_for(int(engine.lengths[r.slot]))
                 for r in scheduler.slots
                 if r is not None and r.state == "decode")
+    if backlog and backlog_left == 0:
+        raise _backlog_ran_dry(requests, lead_s + seconds)
     if run.t_close is None:
         raise RuntimeError("the traffic ended before the window closed: "
                            "{} requests for {} s".format(len(requests),
@@ -181,6 +199,10 @@ def run(run):
     run.log("loadgen: submitted={} late_p50_ms={:.3f} late_max_ms={:.3f}"
             .format(nxt, 1e3 * stats.percentile(late, 50),
                     1e3 * max(late)))
+    if backlog:
+        run.log("backlog: left={} of {}".format(backlog_left,
+                                                len(requests)))
+        run.counters["backlog_left"] = backlog_left
     run.counters.update(
         steps=steps_in_window, active_slot_steps=active_sum,
         slot_steps=steps_in_window * engine.num_slots,

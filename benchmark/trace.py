@@ -2,8 +2,13 @@
 times and labelled idle gaps (read with ``jax.profiler.ProfileData``).
 
 A device plane is one whose name starts with ``/device:TPU:``; of its
-lines only those that hold single operations are read (``XLA Ops``):
-the module and step lines cover the same time again. The benchmark's
+lines those that hold single operations are read for times (``XLA
+Ops``); the module line (``XLA Modules``: one event a run of a compiled
+program) covers the same time again and is read only to tell which
+operations belong to one run (:meth:`Reduction.whole_launches`): a
+kernel's share of a roofline prices the runs that the trace holds
+whole, not the steps the host counted, so that a trace that lost
+operations loses their work with their time. The benchmark's
 own host spans are the ``TraceAnnotation`` events of the same names on
 the host planes, on the profiler's clock like the device's events, so
 an idle gap is labelled by the span that covers most of it.
@@ -11,6 +16,7 @@ an idle gap is labelled by the span that covers most of it.
 ``python -m benchmark.trace <dir-or-file>`` prints what a trace holds:
 look at one by hand before writing a pattern against it.
 """
+import bisect
 import collections
 import glob
 import os
@@ -19,6 +25,7 @@ import sys
 
 DEVICE_PLANE_PREFIX = "/device:TPU:"
 OP_LINES = ("XLA Ops",)
+MODULE_LINES = ("XLA Modules",)
 TOP = 10
 # operations that only contain others (their bodies' operations are
 # events of their own): counted for busy time, not as operations
@@ -42,6 +49,12 @@ def short_name(event_name):
         return re.sub(r"\.\d+$", "", name.lstrip("%")) + " kernel", kind
     shape = re.sub(r"\{[^}]*\}", "", rest[:found.start()]).strip()
     return (kind + " " + shape)[:96], kind
+
+
+def program_of(module_name):
+    """``jit_decode(1234567890)`` -> ``jit_decode``: the module line
+    names a run by its program and a number."""
+    return re.sub(r"\(\d+\)$", "", module_name)
 
 
 def find_xplane(path):
@@ -95,9 +108,11 @@ def label_gap(gap, host_spans):
 class Reduction:
     """One trace, reduced. Times in seconds on the profiler's clock."""
 
-    def __init__(self, device_events, host_spans):
+    def __init__(self, device_events, host_spans, device_modules=None):
         # device_events: {plane name: [(name, text, start_s, end_s)]}
+        # device_modules: {plane name: [(program, start_s, end_s)]}
         self.device_events = device_events
+        self.device_modules = device_modules or {}
         self.host_spans = host_spans
         edges = [t for evs in device_events.values()
                  for _, _, s, e in evs for t in (s, e)]
@@ -119,18 +134,40 @@ class Reduction:
         n = max(1, len(self.device_events))
         return {name: t / n for name, t in totals.items()}
 
-    def matching(self, patterns):
-        """Events whose name or statistics match one of ``patterns``
-        (regular expressions, searched): (count, seconds), averaged
-        over the device planes."""
+    def whole_launches(self, patterns):
+        """The runs of a program (events of the module line) that hold
+        the operations matching ``patterns`` whole: as many of them as
+        most runs of that program hold. -> (whole runs, runs that hold
+        fewer, seconds of the matching operations inside the whole
+        runs), averaged over the device planes. A matching operation
+        outside every run, or inside one that lost others, is not
+        counted: its work cannot be told."""
         patterns = [re.compile(p) for p in patterns]
-        count, seconds = 0, 0.0
-        for evs in self.device_events.values():
+        whole = part = 0
+        seconds = 0.0
+        for plane, evs in self.device_events.items():
+            runs = sorted(self.device_modules.get(plane, ()),
+                          key=lambda m: m[1])
+            starts = [m[1] for m in runs]
+            held = {}                  # run index -> [operations, seconds]
             for _, text, s, e in evs:
-                if any(p.search(text) for p in patterns):
-                    count, seconds = count + 1, seconds + e - s
+                if not any(p.search(text) for p in patterns):
+                    continue
+                i = bisect.bisect_right(starts, s) - 1
+                if i >= 0 and s < runs[i][2]:
+                    row = held.setdefault(i, [0, 0.0])
+                    row[0], row[1] = row[0] + 1, row[1] + e - s
+            by_program = collections.defaultdict(collections.Counter)
+            for i, (n, _) in held.items():
+                by_program[program_of(runs[i][0])][n] += 1
+            for i, (n, t) in held.items():
+                most = by_program[program_of(runs[i][0])].most_common(1)
+                if n == most[0][0]:
+                    whole, seconds = whole + 1, seconds + t
+                else:
+                    part += 1
         n = max(1, len(self.device_events))
-        return count / n, seconds / n
+        return whole / n, part / n, seconds / n
 
     def gap_seconds(self):
         """{host span or "unattributed": idle seconds under it}, over
@@ -153,11 +190,15 @@ def reduce_trace(path, span_names):
     import jax
     data = jax.profiler.ProfileData.from_file(find_xplane(path))
     span_names = set(span_names)
-    device_events, host_spans = {}, []
+    device_events, device_modules, host_spans = {}, {}, []
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PLANE_PREFIX):
             events = []
             for line in plane.lines:
+                if line.name in MODULE_LINES:
+                    device_modules.setdefault(plane.name, []).extend(
+                        (ev.name, ev.start_ns * 1e-9, ev.end_ns * 1e-9)
+                        for ev in line.events)
                 if line.name not in OP_LINES:
                     continue
                 for ev in line.events:
@@ -173,7 +214,7 @@ def reduce_trace(path, span_names):
                     if ev.name in span_names:
                         host_spans.append((ev.name, ev.start_ns * 1e-9,
                                            ev.end_ns * 1e-9))
-    return Reduction(device_events, host_spans)
+    return Reduction(device_events, host_spans, device_modules)
 
 
 def describe(path, out=sys.stdout):

@@ -2,8 +2,11 @@
 length, from a data file of parameters.
 
 ``arrivals``: ``{"process": "poisson", "rate_per_s": r}`` (open loop:
-exponential gaps) or ``{"process": "backlog", "queued": n}`` (all due
-at time 0, before the window). ``prompt_tokens`` / ``output_tokens``:
+exponential gaps) or ``{"process": "backlog", "queued": n,
+"sized_at_tokens_per_s": r}`` (all due at time 0, before the window;
+``r`` is the cell's rate when ``n`` was chosen, which the generator
+does not read: a data test holds ``n`` to four times the tokens that
+rate drains). ``prompt_tokens`` / ``output_tokens``:
 ``{"dist": "lognormal", "median", "sigma", "min", "max"}`` or
 ``{"dist": "uniform", "min", "max"}``.
 

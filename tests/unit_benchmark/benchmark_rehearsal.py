@@ -73,4 +73,9 @@ if __name__ == "__main__":
     sys.path.insert(0, os.getcwd())
     for spec in sys.argv[1:]:
         cell, trace = spec.rsplit(":", 1)
-        print(json.dumps(rehearse(cell, 7, 2.0, int(trace))), flush=True)
+        try:
+            result = rehearse(cell, 7, 2.0, int(trace))
+        except RuntimeError as err:     # a runner that refuses its run
+            result = {"cell": cell, "trace": int(trace),
+                      "error": str(err)}
+        print(json.dumps(result), flush=True)

@@ -110,6 +110,8 @@ def test_counts_of_operations_and_bytes():
     assert family.flash_attention_flops(model, 20, 1024) == \
         20 * 24 * 6 * 1024 * 1024 * 1024
     assert family.paged_attention_bytes(model, 16, 1) == 1572864
+    # 2 for each weight of the layers' matmuls; head and attention not
+    assert family.serve_flops_per_token(model) == 2 * 24 * 12 * 1024 ** 2
 
 
 def _greedy(config, w, prompt, want, rounding):

@@ -16,6 +16,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
 RUNS = ["tiny-train.seq64:0", "tiny-train.seq64:1", "tiny-serve.chat:0",
         "tiny-serve.chat:1", "tiny-serve.docs:0"]
+DRAINED = "tiny-serve.drained:0"
 
 
 @pytest.fixture(scope="module")
@@ -30,8 +31,8 @@ def results(tmp_path_factory):
                TMPDIR=str(copy))
     proc = subprocess.run(
         [sys.executable, os.path.join(HERE, "benchmark_rehearsal.py")] +
-        RUNS, cwd=str(copy), env=env, capture_output=True, text=True,
-        timeout=900)
+        RUNS + [DRAINED], cwd=str(copy), env=env, capture_output=True,
+        text=True, timeout=900)
     assert proc.returncode == 0, proc.stderr[-4000:]
     out = {}
     for line in proc.stdout.splitlines():
@@ -61,6 +62,18 @@ def test_runner_rehearsal(results, spec):
     if spec.endswith(".chat:0"):
         assert {"ttft_p90_ms", "tpot_p90_ms"} <= set(r["end_to_end"])
         assert "loadgen.wait" in r["spans"]
+        assert "backlog_left" not in r["counters"]
+    if spec.endswith(".docs:0"):
+        # a backlog that outlasts the window says how much of it is left
+        assert 0 < r["counters"]["backlog_left"] < 6000
+
+
+def test_a_backlog_that_runs_dry_refuses_its_run(results):
+    """6 requests are gone long before the window closes: no result,
+    and the error names the rate that would have kept the queue full."""
+    error = results[DRAINED]["error"]
+    assert error.startswith("the backlog ran dry: 0 of 6 requests")
+    assert "tokens/s: raise arrivals.queued" in error
 
 
 @pytest.mark.parametrize("spec, metric", [

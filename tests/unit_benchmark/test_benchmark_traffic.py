@@ -5,9 +5,13 @@ import pytest
 from benchmark import manifest
 from benchmark.traffic import requests, token_batches
 
-MIXES = {c["name"]: manifest.load_workload(c["name"])["traffic"]
-         for c in manifest.load_manifest()["workloads"]}
+MANIFEST = manifest.load_manifest()
+WORKLOADS = {c["name"]: manifest.load_workload(c["name"])
+             for c in MANIFEST["workloads"]}
+MIXES = {n: w["traffic"] for n, w in WORKLOADS.items()}
 SERVING = [n for n, t in MIXES.items() if t["generator"] == "requests"]
+BACKLOGS = [n for n in SERVING
+            if MIXES[n]["arrivals"]["process"] == "backlog"]
 BIG_SEED = 2 ** 31 + 12345
 
 
@@ -67,6 +71,22 @@ def test_lengths_stay_inside_the_mix_and_the_model(cell):
     assert outputs.max() <= mix["output_tokens"]["max"]
     assert (lens + outputs).max() < 1024      # the model's positions
     assert all(p.min() >= 0 and p.max() < 50304 for p in prompts[:50])
+
+
+@pytest.mark.parametrize("cell", BACKLOGS)
+def test_a_backlog_outlasts_a_program_four_times_as_fast(cell):
+    """A backlog has to stay a backlog under the gains it is there to
+    measure: its tokens, served from the start of the lead-in to the
+    window's close, last at four times the rate the cell read when it
+    was sized (``sized_at_tokens_per_s``, beside ``queued``). When the
+    cell's level nears that, raise ``queued`` first: the runner refuses
+    a run whose queue is empty at the close."""
+    workload, arrivals = WORKLOADS[cell], MIXES[cell]["arrivals"]
+    _, prompt_lens, output_lens = requests.cycle(MIXES[cell], 1.0)
+    assert len(prompt_lens) == arrivals["queued"]
+    tokens = int(prompt_lens.sum() + output_lens.sum())
+    lasts_below = tokens / (workload["lead_s"] + MANIFEST["run_seconds"])
+    assert lasts_below >= 4 * arrivals["sized_at_tokens_per_s"]
 
 
 def test_poisson_rate_and_lognormal_median_are_what_the_file_says():

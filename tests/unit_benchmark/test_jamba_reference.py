@@ -72,7 +72,8 @@ def test_the_configuration_is_the_catalog_rows_nothing_cut():
              if c["config"] == entry["name"]]
     assert [c["traffic"] for c in cells] == ["rollouts"]
     traffic = manifest.load_workload(cells[0]["name"])["traffic"]
-    assert traffic["arrivals"] == {"process": "backlog", "queued": 4000}
+    assert traffic["arrivals"] == {"process": "backlog", "queued": 4000,
+                                   "sized_at_tokens_per_s": 12400}
     assert traffic["prompt_tokens"] == {
         "dist": "lognormal", "median": 256, "sigma": 0.8, "min": 32,
         "max": 1024}
@@ -198,6 +199,18 @@ def test_counts_of_bytes():
         pytest.approx(4.77, abs=0.01)
     assert jamba.mamba_step_bytes(model, 1, "bfloat16") < \
         jamba.mamba_step_bytes(model, 1, "float32")
+
+
+def test_count_of_operations_a_served_token():
+    model = manifest.load_config(MANIFEST, ENTRY[0]["name"])["model"]
+    mlp = 3 * 2560 * 8192
+    mamba = 2560 * 10240 + 5120 * (160 + 2 * 16) + 160 * 5120 + 5120 * 2560
+    attention = 2 * 2560 * 2560 + 2 * 2560 * 128    # one key-value head
+    weights = 28 * mlp + 26 * mamba + 2 * attention
+    assert jamba.serve_flops_per_token(model) == 2 * weights
+    # every parameter but the embedding, norms, biases, convolutions, A, D
+    assert 0.99 * (3029337472 - 65536 * 2560) < weights < \
+        3029337472 - 65536 * 2560
 
 
 def _run_on(trace_file):

@@ -477,9 +477,11 @@ def _paged_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
     of the pool (:func:`_gather_pages`); every prefill runs it. With
     ``config.paged_attention_kernel == "pallas"`` the read side runs
     the ops/pallas/paged_attention kernel instead (in-kernel page walk,
-    double-buffered page fetches, online softmax — same masking
-    contract, ctx within 1e-5 of the gather path, greedy streams
-    byte-identical; docs/pallas_kernels.md). The WRITE scatter is
+    a block of pages fetched and every head folded a loop turn, K, V
+    and the softmax weights on the MXU in the pool's dtype, online
+    softmax in float32 — same masking contract, ctx within 1e-5 of the
+    gather path under a float32 pool, greedy streams byte-identical;
+    docs/pallas_kernels.md). The WRITE scatter is
     shared by both paths, so the cache bits never diverge.
     """
     b, s, d = x.shape

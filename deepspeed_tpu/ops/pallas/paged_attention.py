@@ -6,13 +6,43 @@ by gathering every slot's pages back into contiguous ``(b, h,
 max_pages * page_size, d_head)`` rows — ``jnp.take`` materializes each
 slot's FULL logical KV window in HBM per layer per decode step, then the
 dense masked attention reads it again. This kernel walks each slot's
-page table inside the kernel instead: physical pages stream
-HBM -> VMEM through double-buffered ``pltpu.make_async_copy`` fetches
-(page p+1's DMA is in flight while page p's scores are on the MXU), and
-an online-softmax accumulator (flash-attention style, fp32) folds each
-page in as it lands. Bytes touched per step drop from
-``2 * max_pages * page_size`` rows per slot to ``2 * ceil(live_len /
-page_size)`` pages — and nothing is ever re-materialized contiguously.
+page table inside the kernel instead, a BLOCK of pages a loop turn:
+the block's physical pages stream HBM -> VMEM as one
+``pltpu.make_async_copy`` each into a double buffer (the next block's
+copies are in flight while this one is on the MXU, and after a slot's
+last block the next slot's first), and an online-softmax accumulator
+(flash-attention style, fp32) folds the block in, every head at once.
+Bytes touched per step drop from ``2 * max_pages * page_size`` rows per
+slot to ``2 * ceil(live_len / page_size)`` pages — and nothing is ever
+re-materialized contiguously.
+
+Pages a block (:func:`_pages_per_block`): what ``_KV_BLOCK_VMEM_BYTES``
+holds of K and V, double-buffered, at the pool's ``page_size * heads *
+d_head * itemsize`` a page, and no more than a row has — from static
+shapes only: 8 pages (128 tokens, 256 KB a buffer half) at GPT-2
+medium's 1,024 bf16 lanes, 32 where a tensor-parallel shard holds a
+quarter of the heads. A page fetched alone (32 KB) leaves the loop
+waiting on a DMA's latency every 16 tokens.
+
+One pass over the packed lanes folds every head (:func:`_kernel`): the
+slot's queries are laid out block-diagonally, row ``(query, head)``
+holding that head's ``d_head`` lanes and zeros elsewhere, so the scores
+of all heads against a block of keys are ONE matmul ``(s * h, h * dh) x
+(tokens, h * dh)^T`` and the weighted values ONE matmul ``(s * h,
+tokens) x (tokens, h * dh)``, whose block diagonal is picked out after
+the walk (h times the useful flops of the second matmul, nothing beside
+the block's fetch, to keep every operand lane-dense: no 64-lane slice of
+a page, no per-head carry to put back together).
+
+Precision: K and V enter the MXU in the pool's dtype, as stored (no
+float32 copy of a page), the queries cast to it (the bf16 the model's
+qkv matmul produced, under a bf16 pool), ``sm_scale`` multiplies the
+float32 scores, every accumulation and every softmax statistic is
+float32, and the weights ``exp(scores - m)`` enter the second matmul in
+the pool's dtype, as the flash kernels' do
+(ops/transformer/flash_attention.py) and as the chip's default matmul
+precision did to the float32 copies this kernel used to make. With a
+float32 pool nothing is rounded.
 
 Masking contract (bit-compatible with the slot oracle,
 ``_attend_cache_rows``):
@@ -39,13 +69,13 @@ PACKED in the minor dimension, so one page of one layer is a contiguous
 of the chip's 128 lanes at every GPT-2 width. (A ``(..., page_size,
 d_head)`` minor pair is refused by the chip's compiler at d_head 64:
 "Slice shape along dimension 4 must be aligned to tiling (128), but is
-64" — and padded to 128 lanes in HBM.) Heads are a static in-kernel
-loop over lane slices, the packed flash kernels' pattern
-(ops/transformer/flash_attention.py).
+64" — and padded to 128 lanes in HBM.)
 
-The kernel is grid-parallel over slots; the page-table row, position
-and valid length ride ``PrefetchScalarGridSpec`` scalar prefetch so the
-DMA source indices are known before the body runs. Off-TPU it runs
+The grid is one step a slot, in order (a slot's first block is fetched
+during the slot before it, so the axis is ``arbitrary``, not
+``parallel``); the page tables, positions and valid lengths ride
+``PrefetchScalarGridSpec`` scalar prefetch so the DMA source indices, of
+this slot and the next, are known before the body runs. Off-TPU it runs
 under the Pallas interpreter (``interpret=True``) — the numerics-pinning
 vehicle for tier-1/dryrun, not a serving configuration
 (``inference.paged_attention_kernel: "auto"`` keeps CPU on the XLA
@@ -66,90 +96,151 @@ from .common import default_interpret, shard_kernel, split_axes
 NEG_INF = -1e30
 
 
+# VMEM the page walk's K and V block buffers may take together (two
+# pools, each double-buffered): 8 pages of 16 tokens a block at GPT-2
+# medium's 1,024 bf16 lanes, 256 KB a buffer half.
+_KV_BLOCK_VMEM_BYTES = 1 << 20
+
+
+def _pages_per_block(max_pages, page_size, packed, itemsize):
+    """Pages one turn of the walk fetches and folds: as many as the
+    block buffers' VMEM budget holds, and no more than a row has."""
+    page_bytes = page_size * packed * itemsize
+    return max(1, min(max_pages, _KV_BLOCK_VMEM_BYTES // (4 * page_bytes)))
+
+
 def _kernel(pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref, v_pool_ref,
-            o_ref, k_buf, v_buf, k_sem, v_sem, *, layer_idx, page_size,
-            num_heads, d_head, sm_scale, seq):
-    """One slot's page-table walk. Refs:
+            o_ref, k_buf, v_buf, k_sem, v_sem, half_ref, *, layer_idx,
+            page_size, num_heads, d_head, sm_scale, seq, block):
+    """One slot's page-table walk, ``block`` pages and every head a
+    loop turn. Refs:
 
     pt_ref (b, max_pages) / pos_ref (b,) / vlen_ref (b,): SMEM scalar
     prefetch; q_ref (1, s, h*dh) VMEM block; k/v_pool_ref the whole
     paged pools (pages+1, L, page_size, h*dh) left in HBM; o_ref
-    (1, s, h*dh) fp32; k/v_buf (2, page_size, h*dh) double buffers.
+    (1, s, h*dh) fp32; k/v_buf (2, block * page_size, h*dh) double
+    buffers, one DMA semaphore a half; half_ref (1,) SMEM: the buffer
+    half that holds this slot's first block.
+
+    The scratch outlives a grid step and the grid is sequential, so
+    the copies of slot ``i + 1``'s first block start during slot
+    ``i``'s last (slot 0 starts its own; the last slot starts none).
     """
+    # Index arithmetic is on non-negative ints, so ``lax.div`` / ``rem``
+    # stand for ``//`` / ``%``: those lower through ``sign``, 4 s of a
+    # 24-layer decode program's lowering on every start (PERF.md, PR 33).
     i = pl.program_id(0)
+    num_slots = pl.num_programs(0)
+    max_pages = pt_ref.shape[1]
+    rows, lanes = seq * num_heads, num_heads * d_head
+    tokens = block * page_size
+
+    def pages_of(slot):
+        # ceil((positions + valid_lens) / page_size), at least the one
+        # page an empty slot's table redirects to the garbage page
+        live = pos_ref[slot] + vlen_ref[slot] - 1
+        return jnp.minimum(
+            jax.lax.div(jnp.maximum(live, 0), page_size) + 1, max_pages)
+
+    def transfer(slot, c, half, start):
+        # a block's last pages may lie past the live window: no copy,
+        # and what the buffer holds there is masked below
+        first = c * block
+
+        def page(j, carry):
+            phys = pt_ref[slot, first + j]
+            dst = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            for pool, buf, sem in ((k_pool_ref, k_buf, k_sem),
+                                   (v_pool_ref, v_buf, v_sem)):
+                copy = pltpu.make_async_copy(
+                    pool.at[phys, layer_idx], buf.at[half, dst],
+                    sem.at[half])
+                copy.start() if start else copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(pages_of(slot) - first, 0, block),
+                          page, 0)
+
+    @pl.when(i == 0)
+    def _first_slot():
+        half_ref[0] = 0
+        transfer(0, 0, 0, True)
+
     pos = pos_ref[i]
-    vlen = vlen_ref[i]
-    live = pos + vlen - 1                  # last live absolute position
-    n_pages = jnp.maximum(live, 0) // page_size + 1
+    live = pos + vlen_ref[i] - 1           # last live absolute position
+    n_blocks = jax.lax.div(pages_of(i) + block - 1, block)
+    first_half = half_ref[0]
 
-    def fetch(slot, p):
-        phys = pt_ref[i, p]
-        return (pltpu.make_async_copy(k_pool_ref.at[phys, layer_idx],
-                                      k_buf.at[slot], k_sem.at[slot]),
-                pltpu.make_async_copy(v_pool_ref.at[phys, layer_idx],
-                                      v_buf.at[slot], v_sem.at[slot]))
+    # the slot's queries block-diagonal over the packed lanes: row
+    # (query, head) holds that head's d_head lanes of the query and
+    # zeros elsewhere, so ONE matmul over all h*dh lanes scores every
+    # head against a block of keys
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
+    row_query = jax.lax.div(row, num_heads)
+    own_lanes = jax.lax.div(lane, d_head) == jax.lax.rem(row, num_heads)
+    q = q_ref[0].astype(jnp.float32)                      # (s, h*dh)
+    q_rows = q[0:1]
+    for j in range(1, seq):
+        q_rows = jnp.where(row_query == j, q[j:j + 1], q_rows)
+    q_bd = jnp.where(own_lanes, q_rows, 0.0).astype(k_buf.dtype)
 
-    kd, vd = fetch(0, 0)
-    kd.start()
-    vd.start()
+    q_pos = pos + jax.lax.div(
+        jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 0), num_heads)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 1)
+    token = jax.lax.broadcasted_iota(jnp.int32, (tokens, 1), 0)
 
-    qf = q_ref[0].astype(jnp.float32) * sm_scale          # (s, h*dh)
-    q_pos = pos + jax.lax.broadcasted_iota(jnp.int32, (seq, page_size), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (seq, page_size), 1)
-    vcol = jax.lax.broadcasted_iota(jnp.int32, (page_size, 1), 0)
+    def body(c, carry):
+        acc, m, l = carry              # (rows, h*dh), (rows, 1) x 2 fp32
+        half = jax.lax.rem(first_half + c, 2)
 
-    def body(p, carry):
-        acc, m, l = carry                  # (s,h*dh), (s,h), (s,h) fp32
-        slot = jax.lax.rem(p, 2)
+        # next in flight while this block is on the MXU: this slot's
+        # next block, or after its last the next slot's first
+        last = c + 1 == n_blocks
+        nxt_slot = jnp.where(last, i + 1, i)
 
-        @pl.when(p + 1 < n_pages)
+        @pl.when(nxt_slot < num_slots)
         def _prefetch():
-            kn, vn = fetch(jax.lax.rem(p + 1, 2), p + 1)
-            kn.start()
-            vn.start()
+            transfer(nxt_slot, jnp.where(last, 0, c + 1), 1 - half, True)
 
-        kw, vw = fetch(slot, p)
-        kw.wait()
-        vw.wait()
-        k_pg = k_buf[slot].astype(jnp.float32)            # (ps, h*dh)
-        v_pg = v_buf[slot].astype(jnp.float32)
+        transfer(i, c, half, False)
 
-        k_pos = p * page_size + col                       # (s, ps)
-        mask = jnp.logical_and(k_pos <= q_pos, k_pos <= live)
-        vmask = (p * page_size + vcol) <= live            # (ps, 1)
-        v_pg = jnp.where(vmask, v_pg, 0.0)
+        # only a slot's last block reaches past its live window: zero
+        # V there in place, before it meets a weight
+        @pl.when(last)
+        def _zero_dead_values():
+            v_blk = v_buf[half]
+            v_buf[half] = jnp.where(c * tokens + token <= live, v_blk,
+                                    jnp.zeros_like(v_blk))
 
-        new_acc, new_m, new_l = [], [], []
-        for hi in range(num_heads):
-            sl = slice(hi * d_head, (hi + 1) * d_head)
-            scores = jax.lax.dot_general(
-                qf[:, sl], k_pg[:, sl], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)       # (s, ps)
-            scores = jnp.where(mask, scores, NEG_INF)
-            m_old = m[:, hi:hi + 1]
-            m_new = jnp.maximum(m_old,
-                                jnp.max(scores, axis=-1, keepdims=True))
-            pexp = jnp.exp(scores - m_new)
-            corr = jnp.exp(m_old - m_new)
-            new_m.append(m_new)
-            new_l.append(l[:, hi:hi + 1] * corr
-                         + jnp.sum(pexp, axis=-1, keepdims=True))
-            new_acc.append(acc[:, sl] * corr + jax.lax.dot_general(
-                pexp, v_pg[:, sl], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
-        return (jnp.concatenate(new_acc, axis=1),
-                jnp.concatenate(new_m, axis=1),
-                jnp.concatenate(new_l, axis=1))
+        scores = jax.lax.dot_general(
+            q_bd, k_buf[half], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # (rows, tokens)
+        k_pos = c * tokens + col
+        scores = jnp.where(
+            jnp.logical_and(k_pos <= q_pos, k_pos <= live), scores, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+        pexp = jnp.exp(scores - m_new)
+        corr = jnp.exp(m - m_new)
+        # every head's weights over ALL h*dh value lanes (h times the
+        # useful flops, every operand lane-dense); a row's own head's
+        # lanes are picked out after the walk
+        acc = acc * corr + jax.lax.dot_general(
+            pexp.astype(v_buf.dtype), v_buf[half], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return acc, m_new, l * corr + jnp.sum(pexp, axis=-1, keepdims=True)
 
-    acc0 = jnp.zeros((seq, num_heads * d_head), jnp.float32)
-    m0 = jnp.full((seq, num_heads), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((seq, num_heads), jnp.float32)
-    acc, _, l = jax.lax.fori_loop(0, n_pages, body, (acc0, m0, l0))
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    # per-head rescale: (s, h) -> lane slices of (s, h*dh)
-    o_ref[0] = jnp.concatenate(
-        [acc[:, hi * d_head:(hi + 1) * d_head] / l_safe[:, hi:hi + 1]
-         for hi in range(num_heads)], axis=1)
+    init = (jnp.zeros((rows, lanes), jnp.float32),
+            jnp.full((rows, 1), NEG_INF, jnp.float32),
+            jnp.zeros((rows, 1), jnp.float32))
+    # a walk has a block at least, so every row's l counts a token
+    acc, _, l = jax.lax.fori_loop(0, n_blocks, body, init)
+    half_ref[0] = jax.lax.rem(first_half + n_blocks, 2)
+
+    out = jnp.where(own_lanes, acc / l, 0.0)
+    for j in range(seq):
+        mine = out if seq == 1 else jnp.where(row_query == j, out, 0.0)
+        o_ref[0, j:j + 1, :] = jnp.sum(mine, axis=0, keepdims=True)
 
 
 def _grouped_kernel(pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref,
@@ -307,9 +398,9 @@ def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
     query heads (grouped-query attention: :func:`_grouped_kernel`);
     ``page_tables``: (b, max_pages) int32; ``positions``/``valid_lens``:
     (b,) int32. ``layer_idx`` is trace-static (the model's python layer
-    loop). Returns fp32 ctx (b, s, h, dh) — within 1e-5 of the slot
-    oracle's dense masked softmax (same contributing entries, online
-    accumulation order).
+    loop). Returns fp32 ctx (b, s, h, dh) — with a float32 pool within
+    1e-5 of the slot oracle's dense masked softmax (same contributing
+    entries, online accumulation order).
     """
     if interpret is None:
         interpret = default_interpret()
@@ -339,6 +430,8 @@ def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
             "heads*d_head {}), got {}".format(page_size, hd, k_pool.shape))
     max_pages = page_tables.shape[1]
     full_window = max_pages * page_size
+    block = _pages_per_block(max_pages, page_size, hd,
+                             k_pool.dtype.itemsize)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b,),
@@ -349,14 +442,15 @@ def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
         ],
         out_specs=pl.BlockSpec((1, s, hd), lambda i, *_: (i, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, page_size, hd), k_pool.dtype),
-            pltpu.VMEM((2, page_size, hd), v_pool.dtype),
+            pltpu.VMEM((2, block * page_size, hd), k_pool.dtype),
+            pltpu.VMEM((2, block * page_size, hd), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
         ])
     kernel = functools.partial(
         _kernel, layer_idx=layer_idx, page_size=page_size, num_heads=h,
-        d_head=dh, sm_scale=1.0 / math.sqrt(dh), seq=s)
+        d_head=dh, sm_scale=1.0 / math.sqrt(dh), seq=s, block=block)
     # flops pinned to the dense math over the full logical window (qk^T
     # + p@v), the same count the XLA gather path's dots report — keeps
     # the cost-analysis pricing seam (telemetry/programs.py) honest.
@@ -373,6 +467,10 @@ def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
         out_shape=jax.ShapeDtypeStruct((b, s, hd), jnp.float32),
         cost_estimate=cost,
         interpret=interpret,
+        # a slot's first block is fetched during the slot before it
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="paged_attention",
     )(page_tables.astype(jnp.int32), positions.astype(jnp.int32),
       valid_lens.astype(jnp.int32), q.reshape(b, s, hd), k_pool, v_pool)
     return out.reshape(b, s, h, dh)

@@ -142,6 +142,97 @@ def test_paged_attention_padded_valid_lens_stay_clean():
                                    rtol=1e-5)
 
 
+# The block walk: h 8 x dh 64 = 512 packed lanes and pages of 16 tokens
+# make a page 32 KB in float32, so the kernel's VMEM budget gives 8
+# pages (128 tokens) a block and a row of 20 pages is walked as 8, 8, 4
+# (a bf16 pool's pages are half that: 16 a block, walked as 16, 4).
+_WALK_H, _WALK_DH, _WALK_PS = 8, 64, 16
+# name: ((position, valid_len) per slot, s, max_pages, pool dtype)
+_WALK_CASES = {
+    "ends_in_first_page_of_second_block":
+        ([(131, 1), (140, 1)], 1, 20, np.float32),
+    "ends_on_a_blocks_last_token":
+        ([(127, 1), (255, 1), (126, 1)], 1, 20, np.float32),
+    "ends_in_third_block":
+        ([(296, 1), (40, 1), (257, 1)], 1, 20, np.float32),
+    "row_not_a_multiple_of_the_block":
+        ([(319, 1), (300, 1)], 1, 20, np.float32),
+    "row_smaller_than_the_block":
+        ([(79, 1), (3, 1), (64, 1)], 1, 5, np.float32),
+    "one_page_slots":
+        ([(0, 1), (15, 1), (7, 1)], 1, 20, np.float32),
+    "dead_slot_between_live_slots":
+        ([(100, 1), (0, 0), (200, 1)], 1, 20, np.float32),
+    "dead_slot_last":
+        ([(130, 1), (37, 1), (0, 0)], 1, 20, np.float32),
+    "two_queries_across_a_block_edge":
+        ([(127, 2), (15, 2), (130, 1)], 2, 20, np.float32),
+    "verify_width_padded":
+        ([(126, 3), (250, 5), (9, 0), (14, 1)], 5, 20, np.float32),
+    "verify_width_dead_slot_last":
+        ([(255, 5), (60, 4), (0, 0)], 5, 20, np.float32),
+    "bf16_pool":
+        ([(296, 1), (127, 1), (0, 0), (31, 1)], 1, 20, jnp.bfloat16),
+    "bf16_pool_verify_width":
+        ([(126, 3), (250, 5), (14, 1)], 5, 20, jnp.bfloat16),
+}
+# A bf16 pool against the float32 oracle: the weights enter the second
+# matmul rounded to bf16, as the one-page float32 kernel's did on the
+# chip (its matmuls ran at the chip's default precision). The tolerance
+# is that kernel's own max abs error on these inputs on a TPU v5e
+# (8.564e-4 and 1.73086e-3; PR 33's kernel bench, CHANGES.md); this one
+# read 8.346e-4 and 1.73086e-3 there.
+_WALK_BF16_ATOL = {"bf16_pool": 8.56e-4, "bf16_pool_verify_width": 1.7308e-3}
+
+
+def _walk_setup(slots, s, max_pages, dtype, seed=0):
+    """A pool where everything a slot must not see is NaN: the garbage
+    page, every page no slot owns, and the tail of a slot's last page
+    past its live window (a recycled page's stale content). Pages are
+    dealt out of order, so a walk that trusts anything but the table
+    fails."""
+    rng = np.random.RandomState(seed)
+    b, h, dh, ps = len(slots), _WALK_H, _WALK_DH, _WALK_PS
+    owned = [-(-(pos + n) // ps) for pos, n in slots]
+    pages = sum(owned) + 3
+    k_pool = np.full((pages + 1, 2, ps, h * dh), np.nan, np.float32)
+    v_pool = np.full((pages + 1, 2, ps, h * dh), np.nan, np.float32)
+    free = list(rng.permutation(np.arange(1, pages + 1)))
+    page_tables = np.zeros((b, max_pages), np.int32)
+    for i, (pos, n) in enumerate(slots):
+        for j in range(owned[i]):
+            page = page_tables[i, j] = free.pop()
+            live = min(ps, pos + n - j * ps)
+            k_pool[page, :, :live] = rng.randn(2, live, h * dh)
+            v_pool[page, :, :live] = rng.randn(2, live, h * dh)
+    q = rng.randn(b, s, h, dh).astype(np.float32)
+    positions, valid_lens = np.array(slots, np.int32).reshape(b, 2).T
+    return (jnp.asarray(q, dtype), jnp.asarray(k_pool, dtype),
+            jnp.asarray(v_pool, dtype), jnp.asarray(page_tables),
+            jnp.asarray(positions), jnp.asarray(valid_lens))
+
+
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_paged_attention_block_walk(case):
+    from deepspeed_tpu.ops.pallas.paged_attention import _pages_per_block
+    slots, s, max_pages, dtype = _WALK_CASES[case]
+    ps, itemsize = _WALK_PS, jnp.dtype(dtype).itemsize
+    assert _pages_per_block(max_pages, ps, _WALK_H * _WALK_DH, itemsize) \
+        == min(32 // itemsize, max_pages)
+    q, kp, vp, pt, pos, vl = _walk_setup(slots, s, max_pages, dtype)
+    got = np.asarray(paged_attention(q, kp, vp, pt, pos, vl, layer_idx=1,
+                                     page_size=ps))
+    # no NaN of a dead page, a dead tail or a dead slot reaches a row
+    assert np.isfinite(got).all()
+    f32 = [a.astype(jnp.float32) for a in (q, kp, vp)]
+    want = np.asarray(_gather_oracle(*f32, pt, pos, vl, ps, max_pages, 1))
+    atol, rtol = (1e-5, 1e-5) if dtype == np.float32 else \
+        (_WALK_BF16_ATOL[case], 0.0)
+    for i, (_, n) in enumerate(slots):
+        np.testing.assert_allclose(got[i, :n], want[i, :n], atol=atol,
+                                   rtol=rtol)
+
+
 def test_paged_attn_ctx_dispatch_parity_and_shared_writes():
     # the model-level dispatch: ctx within 1e-5 AND the cache WRITES
     # bitwise identical (the scatter is shared by both read paths)

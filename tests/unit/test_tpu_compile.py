@@ -163,6 +163,29 @@ def test_paged_attention_compiles_at_d_head_64(one_chip,
                     ((b, 64), I32), ((b,), I32), ((b,), I32)) == 1
 
 
+# (slots, pages a row, pages + 1) of the two GPT-2 serving cells' decode
+# programs: 64 slots x 768 tokens, 128 slots x 1008 tokens, pages of 16
+_DECODE_CELLS = {"chat": (64, 48, 3073), "docs": (128, 63, 8501)}
+
+
+@pytest.mark.parametrize("width", [1, 5], ids=["decode", "spec_verify"])
+@pytest.mark.parametrize("cell", sorted(_DECODE_CELLS))
+def test_paged_attention_block_walk_compiles_at_the_cells_shapes(
+        one_chip, no_persistent_cache, cell, width):
+    """The block walk at the shapes the benchmark runs it at: 16 heads
+    of 64 packed in 1,024 bf16 lanes, 24 layers, 8 pages (128 tokens) a
+    block; a row of 63 pages is not a multiple of the block."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        _pages_per_block, paged_attention)
+    (b, row, pages), (h, dh, ps) = _DECODE_CELLS[cell], (16, 64, 16)
+    assert _pages_per_block(row, ps, h * dh, 2) == 8
+    pool = ((pages, 24, ps, h * dh), BF16)
+    fn = functools.partial(paged_attention, layer_idx=23, page_size=ps,
+                           interpret=False)
+    assert _compile(fn, one_chip, ((b, width, h, dh), BF16), pool, pool,
+                    ((b, row), I32), ((b,), I32), ((b,), I32)) == 1
+
+
 # ------------------------------------------------- kernels on a mesh
 def test_paged_attention_compiles_at_one_kv_head_of_128(
         one_chip, no_persistent_cache):
@@ -236,6 +259,27 @@ def test_paged_attention_compiles_on_a_tensor_parallel_mesh(
     assert _mesh_compile(
         fn, four_chips, ((b, 1, h, dh), BF16, P(None, None, "model")),
         pool, pool, ((b, 64), I32, P()), ((b,), I32, P()),
+        ((b,), I32, P())) == 1
+
+
+@pytest.mark.parametrize("width", [1, 5], ids=["decode", "spec_verify"])
+@pytest.mark.parametrize("cell", sorted(_DECODE_CELLS))
+def test_paged_attention_block_walk_compiles_on_a_tensor_parallel_mesh(
+        four_chips, no_persistent_cache, cell, width):
+    """The cells' shapes with the heads split four ways: a shard's 4
+    heads are 256 lanes, so its block is worked out anew (32 pages,
+    512 tokens) and its score rows are 4 a query."""
+    from deepspeed_tpu.inference.kv_cache import PAGED_KV_CACHE_SPEC
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        _pages_per_block, paged_attention)
+    (b, row, pages), (h, dh, ps) = _DECODE_CELLS[cell], (16, 64, 16)
+    assert _pages_per_block(row, ps, h * dh // 4, 2) == 32
+    fn = functools.partial(paged_attention, layer_idx=23, page_size=ps,
+                           interpret=False, mesh=four_chips)
+    pool = ((pages, 24, ps, h * dh), BF16, PAGED_KV_CACHE_SPEC)
+    assert _mesh_compile(
+        fn, four_chips, ((b, width, h, dh), BF16, P(None, None, "model")),
+        pool, pool, ((b, row), I32, P()), ((b,), I32, P()),
         ((b,), I32, P())) == 1
 
 

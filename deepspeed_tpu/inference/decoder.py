@@ -1,8 +1,8 @@
 """What ``init_inference()`` asks of a model: the decoder protocol.
 
 The serving engine imports no model module. The model handed to it
-carries a ``decoder`` (``make_gpt2_model`` and ``make_jamba_model``
-attach one) with:
+carries a ``decoder`` (``make_gpt2_model``, ``make_jamba_model`` and
+``make_lfm2_model`` attach one) with:
 
 * ``config``: ``vocab_size``, ``max_seq_len``, ``d_model``;
 * ``cache_spec()`` -> :class:`CacheSpec`: the keys and values it keeps
@@ -22,7 +22,16 @@ attach one) with:
   must start from a zero state whatever the slot held),
   ``state_advance`` (slots,) bool with a decode step (the slots whose
   state this step may advance);
-* ``logits(params, hidden)``: the head.
+* ``logits(params, hidden)``: the head;
+* optionally ``counters``, a tuple of names: ``forward_hidden`` is then
+  called with ``counters=True`` and returns ``(hidden, cache,
+  values)``, one small integer array a name, which the serving
+  programs return beside their tokens and the engine fetches WITH the
+  tokens. ``counter_attrs(name, value)`` makes of a fetched value the
+  attributes (a dict of ints) of one span of that name a launch in the
+  profiler's trace, which the scheduler also sums into
+  ``ServingMetrics.program_counters[name]``. Engine and scheduler know
+  the names only (LFM2's ``moe.load``: the rows each expert got).
 
 ``recurrent`` is true where the pages are NOT the whole of a request's
 state: prefix sharing, drafting and the fleet's page hand-off refuse
@@ -59,7 +68,7 @@ def decoder_of(model, module=None):
     raise AssertionError(
         "init_inference needs a model with a decoder at .decoder "
         "(inference/decoder.py; e.g. models.gpt2.make_gpt2_model, "
-        "models.jamba.make_jamba_model)")
+        "models.jamba.make_jamba_model, models.lfm2.make_lfm2_model)")
 
 
 def refuse_recurrent(engine_or_decoder, what):

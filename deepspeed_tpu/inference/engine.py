@@ -99,6 +99,11 @@ class InferenceEngine:
         self.decoder = decoder_of(model, self.module)
         model_config = self.decoder.config
         self.recurrent = bool(getattr(self.decoder, "recurrent", False))
+        # counters the decoder's serving programs return beside their
+        # tokens (inference/decoder.py), by name; what they count is
+        # the decoder's business
+        self.counter_names = tuple(getattr(self.decoder, "counters", ()))
+        self.last_counters = {}    # name -> attributes of the last launch
         self.inference_config, telemetry_config, analysis_config, \
             runtime_cfg = _parse_configs(config, mesh=mesh)
         # segment-plan executor (runtime/executor/, docs/executor.md):
@@ -441,6 +446,28 @@ class InferenceEngine:
         if self.state is not None:
             self.state.update(tuple(buffers[2:]))
 
+    def _launch(self, fn, args):
+        """Run a serving program on the cache it donates. What it
+        returns is the cache back in place, the chosen tokens, the
+        decoder's counters (``counter_names``) and the logits. ->
+        (tokens, counters), still on the device."""
+        out = fn(self.params, self.kv.k, self.kv.v, *args)
+        n_cache = 2 + len(self._state_buffers())
+        self._update_cache(out[:n_cache])
+        return out[n_cache], tuple(out[n_cache + 1:-1])
+
+    def _note_counters(self, values):
+        """One launch's counters, fetched with its tokens: each becomes
+        a span of its name in the profiler's trace, with the attributes
+        the decoder makes of it, and ``last_counters`` for the
+        scheduler's metrics."""
+        self.last_counters = {
+            name: self.decoder.counter_attrs(name, value)
+            for name, value in zip(self.counter_names, values)}
+        for name, attrs in self.last_counters.items():
+            with annotate(name, **attrs):
+                pass
+
     def _get_prefill_fn(self, bucket, greedy, top_k):
         # attached adapters switch to an extended program family (extra
         # LoRA readout operands); the base family's traces stay valid
@@ -454,6 +481,7 @@ class InferenceEngine:
         sampler = make_sampler(greedy, top_k)
         paged, ps = self.kv_layout == "paged", self.page_size
         n_state = len(self._state_buffers())
+        counted = {"counters": True} if self.counter_names else {}
 
         if paged:
             def prefill(params, k_cache, v_cache, *rest):
@@ -468,12 +496,12 @@ class InferenceEngine:
                 # b_stack (n,V,r), adapter_id scalar) — a per-tenant
                 # logits delta; the cache writes are adapter-independent.
                 state, rest = rest[:n_state], rest[n_state:]
-                kwargs = {}
+                kwargs = dict(counted)
                 if n_state:
                     kwargs["state_slot"], rest = rest[0], rest[1:]
                 ids, page_row, start, length, rng, temperature, top_p, \
                     *adapter_args = rest
-                hidden, cache = forward(
+                hidden, cache, *counters = forward(
                     params, ids, cfg, cache=(k_cache, v_cache) + state,
                     positions=start[None], page_tables=page_row[None],
                     valid_lens=length[None], page_size=ps, **kwargs)
@@ -484,7 +512,7 @@ class InferenceEngine:
                     logits = logits + \
                         (b_stack[aid] @ (a_stack[aid] @ last))[None]
                 token = sampler(logits, rng, temperature, top_p)[0]
-                return (*cache, token, logits[0])
+                return (*cache, token, *sum(counters, ()), logits[0])
         else:
             def prefill(params, k_cache, v_cache, ids, slot, start,
                         length, rng, temperature, top_p, *adapter_args):
@@ -542,6 +570,7 @@ class InferenceEngine:
         sampler = make_sampler(greedy, top_k)
         paged, ps = self.kv_layout == "paged", self.page_size
         n_state = len(self._state_buffers())
+        counted = {"counters": True} if self.counter_names else {}
 
         def _adapter_delta(hidden, a_stack, b_stack, adapter_ids):
             # per-slot LoRA readout: gather each slot's (A, B) pair and
@@ -560,12 +589,12 @@ class InferenceEngine:
                 # (slots,) int32; page_tables; rng, temperature, top_p;
                 # adapter args
                 state, rest = rest[:n_state], rest[n_state:]
-                kwargs = {}
+                kwargs = dict(counted)
                 if n_state:
                     kwargs["state_advance"], rest = rest[0], rest[1:]
                 tokens, lengths, page_tables, rng, temperature, top_p, \
                     *adapter_args = rest
-                hidden, cache = forward(
+                hidden, cache, *counters = forward(
                     params, tokens, cfg, cache=(k_cache, v_cache) + state,
                     positions=lengths, page_tables=page_tables,
                     valid_lens=jnp.full_like(lengths, tokens.shape[1]),
@@ -577,7 +606,7 @@ class InferenceEngine:
                 flat = logits.reshape(-1, logits.shape[-1])
                 chosen = sampler(flat, rng, temperature,
                                  top_p).reshape(tokens.shape)
-                return (*cache, chosen, logits)
+                return (*cache, chosen, *sum(counters, ()), logits)
         else:
             def decode(params, k_cache, v_cache, tokens, lengths, rng,
                        temperature, top_p, *adapter_args):
@@ -830,11 +859,12 @@ class InferenceEngine:
                 self._next_rng(), np.float32(temperature),
                 np.float32(top_p)) + extra
         with annotate("engine.prefill.dispatch"):
-            *cache, token, _ = fn(self.params, self.kv.k, self.kv.v, *args)
-            self._update_cache(cache)
+            token, counters = self._launch(fn, args)
             self.lengths[slot] = start + n
         with annotate("engine.prefill.fetch"):
-            return int(token)
+            token, counters = jax.device_get((token, counters))
+        self._note_counters(counters)
+        return int(token)
 
     def prefill(self, slot, prompt, sampling=None):
         """Single-shot prefill of a whole prompt (the unchunked path:
@@ -894,10 +924,10 @@ class InferenceEngine:
                 self._next_rng(), np.float32(temperature),
                 np.float32(top_p)) + extra
         with annotate("engine.decode.dispatch"):
-            *cache, chosen, _ = fn(self.params, self.kv.k, self.kv.v, *args)
-            self._update_cache(cache)
+            chosen, counters = self._launch(fn, args)
         with annotate("engine.decode.fetch"):
-            chosen = np.asarray(chosen)
+            chosen, counters = jax.device_get((chosen, counters))
+        self._note_counters(counters)
         return chosen[:, 0] if squeeze else chosen
 
     def verify_step(self, tokens, sampling=None):

@@ -100,6 +100,13 @@ class ContinuousBatchingScheduler:
         if self._record_metrics is not self.metrics:
             getattr(self._record_metrics, method)(*args, **kwargs)
 
+    def _account_counters(self):
+        """What the last launch's program counted, under the names the
+        decoder gave (engine.last_counters); nothing for most models."""
+        counters = getattr(self.engine, "last_counters", None)
+        if counters:
+            self._account("record_counters", counters)
+
     # ------------------------------------------------------------- intake
 
     def submit(self, prompt, max_new_tokens=None, eos_token_id=_UNSET,
@@ -314,6 +321,7 @@ class ContinuousBatchingScheduler:
                 dt = t.elapsed(reset=True)
                 with annotate("sched.prefill.commit"):
                     self._account("record_prefill", ln, dt)
+                    self._account_counters()
                     if req.span is not None:
                         now = time.time()
                         req.span.timed_child("prefill_chunk", now - dt, now,
@@ -464,6 +472,7 @@ class ContinuousBatchingScheduler:
             dt = t.elapsed(reset=True)
             with annotate("sched.decode.commit"):
                 self._account("record_decode", len(active), dt)
+                self._account_counters()
                 span_end = time.time()
                 for req in active:
                     self.engine.advance(req.slot)

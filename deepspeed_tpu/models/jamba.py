@@ -102,7 +102,7 @@ def config_from_hf(model, **overrides):
     """A :class:`JambaConfig` from the keys of a published
     ``config.json`` (``model_type: jamba``)."""
     assert model.get("num_experts", 1) == 1, \
-        "Jamba with sparse experts is not supported: no expert layer"
+        "models/jamba.py has no expert MLP (ops/moe.py has the layer)"
     return JambaConfig(
         vocab_size=model["vocab_size"], d_model=model["hidden_size"],
         n_layers=model["num_hidden_layers"],

@@ -121,6 +121,20 @@ class ServingMetrics:
         self.state_bytes = 0
         self.state_live_slots = 0
         self.state_resets = 0
+        # what the serving programs counted themselves, by the names
+        # the model's decoder gave: {name: {"launches": n, attribute:
+        # sum over launches}} (an expert model's "moe.load": rows,
+        # experts_hit, hottest_rows)
+        self.program_counters = {}
+
+    def record_counters(self, counters):
+        """One launch's program counters, ``{name: {attribute:
+        value}}``, added to the sums."""
+        for name, attrs in counters.items():
+            row = self.program_counters.setdefault(name, {"launches": 0})
+            row["launches"] += 1
+            for key, value in attrs.items():
+                row[key] = row.get(key, 0) + value
 
     def record_state_pool(self, slots, nbytes, live_slots):
         """The recurrent-state pool as the step leaves it: its slots and
@@ -257,4 +271,8 @@ class ServingMetrics:
                                  "bytes": self.state_bytes,
                                  "live_slots": self.state_live_slots,
                                  "resets": self.state_resets}
+        if self.program_counters:
+            out["program_counters"] = {
+                name: dict(row)
+                for name, row in self.program_counters.items()}
         return out

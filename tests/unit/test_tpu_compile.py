@@ -513,6 +513,109 @@ def test_jamba_programs_copy_no_state_slab_and_alias_both_pools(
         12 * 2 ** 30
 
 
+# ------------------------------------- LFM2: grouped matmul, expert layer
+@pytest.mark.parametrize("rows", [1024, 1536, 4096],
+                         ids=["chunk256", "decode384", "chunk1024"])
+@pytest.mark.parametrize("k, n", [(2048, 3584), (1792, 2048)],
+                         ids=["gate_up", "down"])
+def test_moe_gmm_compiles_at_the_published_widths(
+        one_chip, no_persistent_cache, rows, k, n):
+    """The grouped matmul over LFM2-8B-A1B's 32 experts at the rows of
+    a decode step of 384 slots and of prefill chunks, 4 experts a
+    token: gate and up side by side (2048 -> 2 x 1792), then down."""
+    from deepspeed_tpu.ops.pallas.moe import moe_gmm
+
+    def fn(lhs, rhs, sizes):
+        return moe_gmm(lhs, rhs, sizes, interpret=False)
+
+    assert _compile(fn, one_chip, ((rows, k), BF16), ((32, k, n), BF16),
+                    ((32,), I32)) == 1
+
+
+@pytest.fixture(scope="module")
+def lfm2_engine():
+    """A tiny LFM2 engine on the CPU whose programs are lowered at the
+    published widths (``jamba_engine`` says how)."""
+    import json
+    import os
+    import deepspeed_tpu as deepspeed
+    from deepspeed_tpu.models import lfm2
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2-8b-a1b-serve.json")) as f:
+        cell = json.load(f)
+    tiny = dict(cell["model"], hidden_size=64, intermediate_size=128,
+                moe_intermediate_size=32, num_attention_heads=4,
+                num_key_value_heads=2, vocab_size=128, num_experts=8)
+    eng = deepspeed.init_inference(
+        model=lfm2.make_lfm2_model(lfm2.config_from_hf(tiny), seed=0),
+        config={"inference": dict(cell["inference"], max_batch_size=2,
+                                  num_pages=256,
+                                  paged_attention_kernel="pallas")})
+    eng.model_config = lfm2.config_from_hf(cell["model"],
+                                           moe_kernel="pallas")
+    return eng, cell
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_lfm2_programs_run_the_kernels_and_alias_both_pools(
+        one_chip, no_persistent_cache, lfm2_engine, monkeypatch, program):
+    """``jit_prefill`` (the largest bucket) and ``jit_decode`` (every
+    slot) of LFM2-8B-A1B's first stage at the cell's pool shapes: two
+    grouped matmuls an expert layer; in decode the three attention
+    layers walk their pages in the grouped paged kernel at its second
+    shape (32 query heads on 8 key-value heads of 64); the page pool
+    and the tail pool, three donated buffers, come back in place; the
+    tail pool is never copied whole; and it fits the chip."""
+    from deepspeed_tpu.models import lfm2
+    eng, cell = lfm2_engine
+    cfg = eng.model_config
+    inference = cell["inference"]
+    slots, pages = inference["max_batch_size"], inference["num_pages"]
+    bucket, ps = inference["prefill_buckets"][-1], eng.page_size
+    row = inference["max_seq_len"] // ps
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(lambda: lfm2.LFM2Decoder(cfg).serving_params(
+        lfm2.init_params(cfg, 0), BF16))
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), params)
+    n_conv, n_attn = len(cfg.conv_layers), len(cfg.attention_layers)
+    pool = sds((pages + 1, n_attn, ps, cfg.n_kv_heads * cfg.d_head), BF16)
+    conv = sds((n_conv, slots, (cfg.conv_L - 1) * cfg.d_model), BF16)
+    rng = jax.random.PRNGKey(0)
+    tail = (sds(rng.shape, rng.dtype), sds((), F32), sds((), F32))
+    if program == "prefill":
+        fn = eng._get_prefill_fn(bucket, True, 0)
+        args = (sds((), I32), sds((1, bucket), I32), sds((row,), I32),
+                sds((), I32), sds((), I32))
+    else:
+        fn = eng._get_decode_fn(True, 0)
+        args = (sds((slots,), jnp.bool_), sds((slots, 1), I32),
+                sds((slots,), I32), sds((slots, row), I32))
+    compiled = fn.lower(params, pool, pool, conv, *args, *tail).compile()
+    text = compiled.as_text()
+
+    assert text.startswith("HloModule jit_" + program)
+    assert text.count("tpu_custom_call") == 2 * len(cfg.expert_layers) + \
+        (n_attn if program == "decode" else 0)
+    whole = "bf16[{},{},{}]".format(n_conv, slots,
+                                    (cfg.conv_L - 1) * cfg.d_model) + \
+        "{2,1,0:T(8,128)(2,1)} copy("
+    assert whole not in text
+    aliased = {int(out): int(arg) for out, arg in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+        text.split("\n", 1)[0])}
+    n_params = len(jax.tree_util.tree_leaves(params))
+    assert aliased == {i: n_params + i for i in range(3)}
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < \
+        14 * 2 ** 30
+
+
 def test_pallas_compiler_params_construct():
     """Every ``compiler_params`` a pallas_call site passes must construct
     under the installed jax — the sites are only reached with

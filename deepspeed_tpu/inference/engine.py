@@ -439,6 +439,20 @@ class InferenceEngine:
     def _state_buffers(self):
         return self.state.buffers() if self.state is not None else ()
 
+    def wait(self):
+        """Block until every serving program launched so far is over:
+        each returns the cache in place of the buffers it was given, so
+        the cache is ready when the program is. Sends the device
+        nothing, and returns in microseconds once the last launch's
+        tokens are on the host (the scheduler's timers fence on this)."""
+        buffers = self.kv.buffers() + self._state_buffers()
+        if self.drafter is not None and self.drafter.needs_model:
+            buffers += self.drafter.kv.buffers()
+        for buffer in buffers:
+            # the array's own method: jax.block_until_ready flattens a
+            # tree first, which costs more than the wait (two a launch)
+            buffer.block_until_ready()
+
     def _update_cache(self, buffers):
         """What a program returned in place of its donated buffers: the
         page (or slot) pool pair, then the recurrent state arrays."""
@@ -632,7 +646,12 @@ class InferenceEngine:
             self.telemetry.programs.observe_trace("decode", key)
         return fn
 
-    def _next_rng(self):
+    def _next_rng(self, greedy):
+        """The key a launch hands its sampler. A greedy program never
+        reads it, so it gets the stored key as it is (same type and
+        shape: the same program) and the device runs no split."""
+        if greedy:
+            return self._rng
         self._rng, key = jax.random.split(self._rng)
         return key
 
@@ -856,7 +875,7 @@ class InferenceEngine:
                 state += (np.int32(slot),)
             args = state + (
                 ids, where, np.int32(start), np.int32(n),
-                self._next_rng(), np.float32(temperature),
+                self._next_rng(greedy), np.float32(temperature),
                 np.float32(top_p)) + extra
         with annotate("engine.prefill.dispatch"):
             token, counters = self._launch(fn, args)
@@ -921,7 +940,7 @@ class InferenceEngine:
                 state += (advance,)
             args = state + (tokens, self.lengths.copy()) + (
                 (self.page_tables.copy(),) if paged else ()) + (
-                self._next_rng(), np.float32(temperature),
+                self._next_rng(greedy), np.float32(temperature),
                 np.float32(top_p)) + extra
         with annotate("engine.decode.dispatch"):
             chosen, counters = self._launch(fn, args)

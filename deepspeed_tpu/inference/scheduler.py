@@ -22,7 +22,10 @@ mid-decode exhaustion preempts the YOUNGEST decoding request (pages
 freed, request requeued; its context re-prefills on re-admission — the
 recompute-preemption discipline).
 
-Timing uses utils/timer.py's device-synchronized timers and lands in a
+Timing uses utils/timer.py's synchronized timers around each engine
+call, fenced on the buffers the engine's programs return
+(``InferenceEngine.wait``: a wait on the cache, no op sent to the device;
+training's timers send one). It lands in a
 :class:`utils.monitor.ServingMetrics` (prefill vs decode tokens/s, slot
 occupancy, queue depth, TTFT/TPOT, speculative acceptance), which the
 telemetry collector joins with page-pool occupancy and prefix-share
@@ -87,7 +90,9 @@ class ContinuousBatchingScheduler:
         self.queue = deque()
         self.slots = [None] * engine.num_slots
         self.results = {}
-        self.timers = SynchronizedWallClockTimer()
+        # the timers fence on the engine's own cache buffers: after a
+        # launch's fetch that is a check, not a round trip to the device
+        self.timers = SynchronizedWallClockTimer(fence=engine.wait)
         self._next_uid = 0
         self._admitted = 0
         self.steps = 0

@@ -48,24 +48,38 @@ def _device_synchronize():
 
 
 class SynchronizedWallClockTimer:
-    """Named timers whose start/stop sync outstanding device work."""
+    """Named timers whose start/stop sync outstanding device work.
+
+    ``fence``: what a start/stop waits on. None (training) is
+    :func:`_device_synchronize`, an op sent behind whatever the process
+    has in flight. A caller that holds the buffers its programs write
+    passes a wait on those (the serving scheduler:
+    ``InferenceEngine.wait``), which sends the device nothing."""
 
     class Timer:
-        def __init__(self, name):
+        def __init__(self, name, fence=None):
             self.name_ = name
+            self.fence_ = fence
             self.elapsed_ = 0.0
             self.started_ = False
             self.start_time = time.time()
 
+        def _sync(self):
+            if self.fence_ is None:
+                _device_synchronize()
+            else:
+                with annotate("timer.sync"):
+                    self.fence_()
+
         def start(self):
             assert not self.started_, "timer has already been started"
-            _device_synchronize()
+            self._sync()
             self.start_time = time.time()
             self.started_ = True
 
         def stop(self, reset=False):
             assert self.started_, "timer is not started"
-            _device_synchronize()
+            self._sync()
             if reset:
                 self.elapsed_ = time.time() - self.start_time
             else:
@@ -87,12 +101,13 @@ class SynchronizedWallClockTimer:
                 self.start()
             return elapsed_
 
-    def __init__(self):
+    def __init__(self, fence=None):
+        self.fence = fence
         self.timers = {}
 
     def __call__(self, name):
         if name not in self.timers:
-            self.timers[name] = self.Timer(name)
+            self.timers[name] = self.Timer(name, self.fence)
         return self.timers[name]
 
     @staticmethod

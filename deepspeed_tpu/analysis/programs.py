@@ -358,7 +358,7 @@ def inference_step_sequence(engine):
 
 def collect_inference_programs(engine):
     params = sds_tree(engine.params)
-    k_sds, v_sds = _sds(engine.kv.k), _sds(engine.kv.v)
+    pools = tuple(_sds(a) for a in engine.kv.buffers())
     rng = _rng_struct()
     temp = np.float32(1.0)
     top_p = np.float32(1.0)
@@ -369,18 +369,18 @@ def collect_inference_programs(engine):
     # advance mask (decode)
     pool = getattr(engine, "state", None)
     state = tuple(_sds(a) for a in pool.buffers()) if pool else ()
-    donate = tuple(range(1, 3 + len(state)))
+    donate = tuple(range(1, 1 + len(pools) + len(state)))
     specs = []
     greedy, top_k = True, 0
     for bucket in engine.prefill_buckets:
         ids = jax.ShapeDtypeStruct((1, bucket), np.int32)
         if paged:
-            args = (params, k_sds, v_sds) + state + (
+            args = (params,) + pools + state + (
                 (np.int32(0),) if state else ()) + (
                 ids, jax.ShapeDtypeStruct((engine.max_pages,), np.int32),
                 np.int32(0), np.int32(1), rng, temp, top_p)
         else:
-            args = (params, k_sds, v_sds, ids, np.int32(0), np.int32(0),
+            args = (params,) + pools + (ids, np.int32(0), np.int32(0),
                     np.int32(1), rng, temp, top_p)
         specs.append(ProgramSpec(
             name="prefill/b{}".format(bucket), family="inference",
@@ -399,13 +399,13 @@ def collect_inference_programs(engine):
         if paged:
             tables = jax.ShapeDtypeStruct(
                 (engine.num_slots, engine.max_pages), np.int32)
-            args = (params, k_sds, v_sds) + state + (
+            args = (params,) + pools + state + (
                 (jax.ShapeDtypeStruct((engine.num_slots,), np.bool_),)
                 if state else ()) + (tokens, lengths, tables, rng, temp,
                                      top_p)
         else:
-            args = (params, k_sds, v_sds, tokens, lengths, rng, temp,
-                    top_p)
+            args = (params,) + pools + (tokens, lengths, rng, temp,
+                                        top_p)
         specs.append(ProgramSpec(
             name=name, family="inference",
             build=lambda w=width: _unjitted_decode(engine, greedy, top_k,

@@ -1,13 +1,17 @@
 """What ``init_inference()`` asks of a model: the decoder protocol.
 
 The serving engine imports no model module. The model handed to it
-carries a ``decoder`` (``make_gpt2_model``, ``make_jamba_model`` and
-``make_lfm2_model`` attach one) with:
+carries a ``decoder`` (``make_gpt2_model``, ``make_jamba_model``,
+``make_lfm2_model`` and ``make_deepseek_v3_model`` attach one) with:
 
 * ``config``: ``vocab_size``, ``max_seq_len``, ``d_model``;
-* ``cache_spec()`` -> :class:`CacheSpec`: the keys and values it keeps
-  (``kv_layers`` layers of ``kv_heads x d_head``, in pages or slots)
-  and, optionally, per-slot recurrent state arrays;
+* ``cache_spec()`` -> :class:`CacheSpec`: what it keeps. One of three
+  kinds, or the first two together: keys and values (``kv_layers``
+  layers of ``kv_heads x d_head``, a PAIR of pools, in pages or
+  slots); per-slot recurrent ``state`` arrays beside them; or, with
+  ``page_lanes``, pages whose rows the decoder lays out itself (latent
+  attention: ONE pool of ``kv_layers`` layers, each token's row the
+  latent all heads share, padded to whole lanes; paged layout only);
 * ``serving_config(mesh)`` -> the model config the serving programs
   close over (deterministic, dense; raises for a mesh it cannot span);
   ``decode_config(config, paged_attention_kernel)`` -> the decode
@@ -16,7 +20,8 @@ carries a ``decoder`` (``make_gpt2_model``, ``make_jamba_model`` and
 * ``forward_hidden(params, ids, config, cache=, positions=,
   page_tables=, valid_lens=, page_size=[, state_slot= |
   state_advance=])`` -> ``(hidden, cache)`` over the cache pytree
-  ``(k, v, *state arrays)``; the two ``state_*`` arguments are passed
+  ``(*paged pools, *state arrays)`` (``(k, v, ...)``, or the one pool
+  of ``page_lanes``); the two ``state_*`` arguments are passed
   to a ``recurrent`` decoder only: ``state_slot`` with a prefill chunk
   (one slot; ``positions == 0`` marks a request's first chunk, which
   must start from a zero state whatever the slot held),
@@ -53,10 +58,16 @@ class StateSpec:
 
 @dataclass(frozen=True)
 class CacheSpec:
+    """``page_lanes``: None for the ``(k, v)`` pair of ``kv_heads *
+    d_head`` lanes each; else the lanes of a row of the ONE pool the
+    decoder lays out itself (640: latent rows, and no ``v``). A
+    multiple of the chip's 128 lanes: a pool whose minor dimension is
+    not stops the program on the chip."""
     kv_layers: int
     kv_heads: int
     d_head: int
     state: tuple = ()
+    page_lanes: int = None
 
 
 def decoder_of(model, module=None):
@@ -68,7 +79,8 @@ def decoder_of(model, module=None):
     raise AssertionError(
         "init_inference needs a model with a decoder at .decoder "
         "(inference/decoder.py; e.g. models.gpt2.make_gpt2_model, "
-        "models.jamba.make_jamba_model, models.lfm2.make_lfm2_model)")
+        "models.jamba.make_jamba_model, models.lfm2.make_lfm2_model, "
+        "models.deepseek_v3.make_deepseek_v3_model)")
 
 
 def refuse_recurrent(engine_or_decoder, what):
@@ -79,3 +91,12 @@ def refuse_recurrent(engine_or_decoder, what):
         raise ValueError(
             "{} cannot serve a model with recurrent layers: its state "
             "is not in the pages".format(what))
+
+
+def refuse_latent(spec, what):
+    """One sentence for every feature that takes a page for a ``(k,
+    v)`` pair of ``kv_heads x d_head`` rows."""
+    if spec.page_lanes is not None:
+        raise ValueError(
+            "{} cannot serve a model with latent pages: its page rows "
+            "are not keys and values".format(what))

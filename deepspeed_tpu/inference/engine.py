@@ -44,7 +44,7 @@ from ..runtime.executor.jit import jit_program
 from ..utils.annotate import annotate
 from ..utils.logging import logger
 from .config import DeepSpeedInferenceConfig
-from .decoder import decoder_of, refuse_recurrent
+from .decoder import decoder_of, refuse_latent, refuse_recurrent
 from .kv_cache import KVCache, PagedKVCache, StatePool
 from .paging import GARBAGE_PAGE, PageAllocator, PrefixCache
 from .sampling import make_sampler
@@ -170,6 +170,18 @@ class InferenceEngine:
             if ic.fleet_role is not None:
                 refuse_recurrent(self.decoder, "the fleet's page hand-off "
                                  "(inference.fleet)")
+        # pages laid out by the decoder (latent attention): the pool,
+        # its allocator and prefix sharing are the same; whatever takes
+        # a page for keys and values refuses them
+        if self.kv_layout != "paged":
+            refuse_latent(spec, "the slot layout (inference.kv_layout: "
+                          "\"slot\")")
+        if ic.spec_enabled:
+            refuse_latent(spec, "speculative decoding "
+                          "(inference.speculative)")
+        if ic.fleet_role is not None:
+            refuse_latent(spec, "the fleet's page hand-off "
+                          "(inference.fleet)")
         # per-slot recurrent state, a pool of its own beside the pages
         # (None for a model whose pages are its whole state)
         self.state = StatePool.allocate(spec.state, self.num_slots) \
@@ -180,7 +192,10 @@ class InferenceEngine:
                                              self.max_seq_len)
             self.kv = PagedKVCache.allocate(
                 num_pages, spec.kv_layers, spec.kv_heads, self.page_size,
-                spec.d_head, self.dtype, mesh=mesh)
+                spec.d_head, self.dtype, mesh=mesh, lanes=spec.page_lanes)
+            # what one cached token costs, pad lanes included: a reader
+            # of the pool's counters need not know the model
+            self.kv_token_bytes = self.kv.token_bytes
             self.allocator = PageAllocator(num_pages)
             # per-slot logical->physical map; GARBAGE_PAGE everywhere a
             # slot has no allocation (jit writes there are redirected
@@ -199,6 +214,8 @@ class InferenceEngine:
             self.kv = KVCache.allocate(
                 self.num_slots, spec.kv_layers, spec.kv_heads,
                 self.max_seq_len, spec.d_head, self.dtype, mesh=mesh)
+            self.kv_token_bytes = self.kv.nbytes // (
+                self.num_slots * self.max_seq_len)
             self.allocator = None
             self.page_tables = None
             self.page_counts = None
@@ -282,9 +299,9 @@ class InferenceEngine:
                 self.dtype_name, self.kv_layout,
                 self.kv.nbytes / 2 ** 20,
                 self.state.nbytes / 2 ** 20 if self.state else 0.0,
-                " pages={}x{} paged_attn={}".format(
+                " pages={}x{} paged_attn={} token_bytes={}".format(
                     self.allocator.num_pages, self.page_size,
-                    self.paged_attention_kernel)
+                    self.paged_attention_kernel, self.kv_token_bytes)
                 if self.kv_layout == "paged" else "",
                 " spec_k={} drafter={}".format(
                     self.spec_k, type(self.drafter).__name__)
@@ -455,18 +472,20 @@ class InferenceEngine:
 
     def _update_cache(self, buffers):
         """What a program returned in place of its donated buffers: the
-        page (or slot) pool pair, then the recurrent state arrays."""
-        self.kv.update(tuple(buffers[:2]))
+        page (or slot) pools, then the recurrent state arrays."""
+        n_kv = len(self.kv.buffers())
+        self.kv.update(tuple(buffers[:n_kv]))
         if self.state is not None:
-            self.state.update(tuple(buffers[2:]))
+            self.state.update(tuple(buffers[n_kv:]))
 
     def _launch(self, fn, args):
         """Run a serving program on the cache it donates. What it
         returns is the cache back in place, the chosen tokens, the
         decoder's counters (``counter_names``) and the logits. ->
         (tokens, counters), still on the device."""
-        out = fn(self.params, self.kv.k, self.kv.v, *args)
-        n_cache = 2 + len(self._state_buffers())
+        pools = self.kv.buffers()
+        out = fn(self.params, *pools, *args)
+        n_cache = len(pools) + len(self._state_buffers())
         self._update_cache(out[:n_cache])
         return out[n_cache], tuple(out[n_cache + 1:-1])
 
@@ -494,13 +513,15 @@ class InferenceEngine:
         forward, head = self.decoder.forward_hidden, self.decoder.logits
         sampler = make_sampler(greedy, top_k)
         paged, ps = self.kv_layout == "paged", self.page_size
-        n_state = len(self._state_buffers())
+        n_kv, n_state = len(self.kv.buffers()), len(self._state_buffers())
         counted = {"counters": True} if self.counter_names else {}
 
         if paged:
-            def prefill(params, k_cache, v_cache, *rest):
-                # rest: the recurrent state arrays (none for a model
-                # without) and, with them, slot (scalar int32: whose
+            def prefill(params, *rest):
+                # rest: the paged pools ((k, v), or the one pool a
+                # decoder lays out itself); the recurrent state arrays
+                # (none for a model without) and, with them, slot
+                # (scalar int32: whose
                 # state); then ids (1, bucket); page_row (max_pages,);
                 # start/length scalar int32 — the chunk covers positions
                 # [start, start+length); padded tokens redirect to the
@@ -509,6 +530,7 @@ class InferenceEngine:
                 # adapter args (when attached): (a_stack (n,r,d),
                 # b_stack (n,V,r), adapter_id scalar) — a per-tenant
                 # logits delta; the cache writes are adapter-independent.
+                pools, rest = rest[:n_kv], rest[n_kv:]
                 state, rest = rest[:n_state], rest[n_state:]
                 kwargs = dict(counted)
                 if n_state:
@@ -516,7 +538,7 @@ class InferenceEngine:
                 ids, page_row, start, length, rng, temperature, top_p, \
                     *adapter_args = rest
                 hidden, cache, *counters = forward(
-                    params, ids, cfg, cache=(k_cache, v_cache) + state,
+                    params, ids, cfg, cache=pools + state,
                     positions=start[None], page_tables=page_row[None],
                     valid_lens=length[None], page_size=ps, **kwargs)
                 last = jnp.take(hidden[0], length - 1, axis=0)     # (d,)
@@ -556,7 +578,8 @@ class InferenceEngine:
         # the function's name is the program's in a profiler trace
         # (module `jit_prefill`): a contract, pinned by a test. Every
         # cache buffer is donated and comes back in place
-        fn = jit_program(prefill, donate=tuple(range(1, 3 + n_state)))
+        fn = jit_program(prefill,
+                         donate=tuple(range(1, 1 + n_kv + n_state)))
         self._prefill_fns[key] = fn
         self.compile_stats["prefill_traces"] += 1
         if self.telemetry is not None:
@@ -583,7 +606,7 @@ class InferenceEngine:
         forward, head = self.decoder.forward_hidden, self.decoder.logits
         sampler = make_sampler(greedy, top_k)
         paged, ps = self.kv_layout == "paged", self.page_size
-        n_state = len(self._state_buffers())
+        n_kv, n_state = len(self.kv.buffers()), len(self._state_buffers())
         counted = {"counters": True} if self.counter_names else {}
 
         def _adapter_delta(hidden, a_stack, b_stack, adapter_ids):
@@ -596,12 +619,14 @@ class InferenceEngine:
                               b_stack[adapter_ids])    # (slots, width, V)
 
         if paged:
-            def decode(params, k_cache, v_cache, *rest):
-                # rest: the recurrent state arrays and, with them,
+            def decode(params, *rest):
+                # rest: the paged pools; the recurrent state arrays and,
+                # with them,
                 # advance (slots,) bool (the slots whose state this
                 # step advances); then tokens (slots, width); lengths
                 # (slots,) int32; page_tables; rng, temperature, top_p;
                 # adapter args
+                pools, rest = rest[:n_kv], rest[n_kv:]
                 state, rest = rest[:n_state], rest[n_state:]
                 kwargs = dict(counted)
                 if n_state:
@@ -609,7 +634,7 @@ class InferenceEngine:
                 tokens, lengths, page_tables, rng, temperature, top_p, \
                     *adapter_args = rest
                 hidden, cache, *counters = forward(
-                    params, tokens, cfg, cache=(k_cache, v_cache) + state,
+                    params, tokens, cfg, cache=pools + state,
                     positions=lengths, page_tables=page_tables,
                     valid_lens=jnp.full_like(lengths, tokens.shape[1]),
                     page_size=ps, **kwargs)
@@ -639,7 +664,8 @@ class InferenceEngine:
         # the function's name is the program's in a profiler trace:
         # module `jit_decode`, and its Mosaic call `%decode.N`, by which
         # the benchmark finds the paged kernel. Pinned by a test
-        fn = jit_program(decode, donate=tuple(range(1, 3 + n_state)))
+        fn = jit_program(decode,
+                         donate=tuple(range(1, 1 + n_kv + n_state)))
         self._decode_fns[key] = fn
         self.compile_stats["decode_traces"] += 1
         if self.telemetry is not None:
@@ -785,13 +811,14 @@ class InferenceEngine:
                 namespace=self._prefix_namespace(slot))
 
     def _page_copy(self, src, dst):
+        pools = self.kv.buffers()
         if self._page_copy_fn is None:
-            def copy(k, v, src, dst):
-                return (k.at[dst].set(k[src]), v.at[dst].set(v[src]))
-            self._page_copy_fn = jit_program(copy, donate=(0, 1))
-        k, v = self._page_copy_fn(self.kv.k, self.kv.v, jnp.int32(src),
-                                  jnp.int32(dst))
-        self.kv.update((k, v))
+            def copy(src, dst, *pools):
+                return tuple(p.at[dst].set(p[src]) for p in pools)
+            self._page_copy_fn = jit_program(
+                copy, donate=tuple(range(2, 2 + len(pools))))
+        self.kv.update(self._page_copy_fn(jnp.int32(src), jnp.int32(dst),
+                                          *pools))
 
     def _cow_writes(self, slot, first_pos, last_pos):
         """Copy-on-write: fork any SHARED page the coming write range

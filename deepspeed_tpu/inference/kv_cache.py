@@ -1,7 +1,9 @@
 """Preallocated serving caches: keys and values in a slot or a paged
-layout, and, for a model with recurrent layers, a pool of per-slot
-state beside them (:class:`StatePool`). What a model keeps it declares
-itself (inference/decoder.py ``CacheSpec``); no model is imported here.
+layout; for a model with recurrent layers, a pool of per-slot state
+beside them (:class:`StatePool`); and, for a model with latent
+attention, pages whose rows are the latents themselves (one pool, no
+``v``). What a model keeps it declares itself (inference/decoder.py
+``CacheSpec``); no model is imported here.
 
 **Slot layout** (:class:`KVCache`, the numerics oracle and default): one
 buffer pair ``(k, v)`` of shape ``(slots, layers, heads, max_seq,
@@ -23,6 +25,15 @@ tokens, not with
 ``slots * max_seq`` — and shared prompt prefixes map one set of pages
 into many tables (prefix sharing). Physical page 0 is the reserved
 garbage page: never allocated, the target of every masked/padded write.
+
+**Latent pages** (``CacheSpec.page_lanes``, paged layout only): the same
+pool, allocator, page tables and prefix sharing, but a token's row is
+what the decoder says: for latent attention (ops/mla.py) the ``kv_lora``
+latent and the rotated shared rope key, 576 values that all heads share,
+zero-padded to 640 lanes (5 x 128: a minor dimension that is not a
+multiple of the lanes stops the program on the chip), in ONE pool
+``k``; ``v`` is None. The decoder writes the pad lanes as zeros with
+every row, because a recycled page may hold anything there.
 
 Reuse, per cache kind. Keys and values: freed slots and recycled pages
 are reused WITHOUT clearing — the absolute-position causal mask in the
@@ -158,17 +169,25 @@ class PagedKVCache:
     not with the tokens read."""
 
     k: object
-    v: object
+    v: object                  # None where the decoder lays out one pool
     page_size: int
 
     @classmethod
     def allocate(cls, num_pages, layers, heads, page_size, d_head, dtype,
-                 mesh=None):
-        shape = (num_pages + 1, layers, page_size, heads * d_head)
-        k, v = _shard_heads(jnp.zeros(shape, dtype),
-                            jnp.zeros(shape, dtype), heads, mesh,
-                            PAGED_KV_CACHE_SPEC)
-        return cls(k, v, int(page_size))
+                 mesh=None, lanes=None):
+        """``lanes`` (``CacheSpec.page_lanes``): None for the pair of
+        ``heads * d_head``; else the lanes of a row of the ONE pool a
+        decoder lays out itself (replicated on a mesh)."""
+        if lanes is None:
+            shape = (num_pages + 1, layers, page_size, heads * d_head)
+            k, v = _shard_heads(jnp.zeros(shape, dtype),
+                                jnp.zeros(shape, dtype), heads, mesh,
+                                PAGED_KV_CACHE_SPEC)
+            return cls(k, v, int(page_size))
+        assert lanes % 128 == 0, \
+            "a page row of {} lanes is not a multiple of 128".format(lanes)
+        return cls(jnp.zeros((num_pages + 1, layers, page_size, lanes),
+                             dtype), None, int(page_size))
 
     @property
     def num_pages(self):
@@ -180,10 +199,17 @@ class PagedKVCache:
 
     @property
     def nbytes(self):
-        return self.k.size * self.k.dtype.itemsize * 2
+        return sum(a.size * a.dtype.itemsize for a in self.buffers())
+
+    @property
+    def token_bytes(self):
+        """Bytes one cached token costs, over all layers and pools, pad
+        lanes included."""
+        return sum(a.shape[1] * a.shape[3] * a.dtype.itemsize
+                   for a in self.buffers())
 
     def buffers(self):
-        return self.k, self.v
+        return (self.k,) if self.v is None else (self.k, self.v)
 
     def update(self, buffers):
-        self.k, self.v = buffers
+        self.k, self.v = (tuple(buffers) + (None,))[:2]

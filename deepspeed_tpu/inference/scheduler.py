@@ -74,6 +74,7 @@ class ContinuousBatchingScheduler:
     def __init__(self, engine, metrics=None, sampling=None):
         self.engine = engine
         self.metrics = metrics if metrics is not None else ServingMetrics()
+        self.metrics.kv_token_bytes = int(engine.kv_token_bytes)
         # telemetry records ALWAYS embed the engine-lifetime counters —
         # a caller-supplied per-call `metrics` is accounted in parallel,
         # never routed into the JSONL, or its zeroed counters would make

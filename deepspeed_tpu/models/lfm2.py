@@ -39,7 +39,6 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..inference.decoder import CacheSpec, StateSpec
 from ..ops import moe
@@ -470,13 +469,8 @@ class LFM2Decoder:
     @staticmethod
     def counter_attrs(name, value):
         """The attributes of the ``moe.load`` span of one launch, from
-        the load its program returned (host side, after the fetch):
-        ``rows`` routed, ``experts_hit`` (expert, layer) pairs that got
-        any, ``hottest_rows`` of the expert that got most."""
-        value = np.asarray(value)
-        return {"rows": int(value[0].sum()),
-                "experts_hit": int(value[1].sum()),
-                "hottest_rows": int(value[0].max())}
+        the load its program returned."""
+        return moe.load_attrs(value)
 
     forward_hidden = staticmethod(forward_hidden)
     logits = staticmethod(logits)

@@ -26,15 +26,18 @@ expert layers and returns with its tokens (inference/decoder.py
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .pallas import moe as kernels
 from .pallas.common import default_interpret
 
 
 def route(x, router_w, expert_bias, top_k, norm_topk_prob=True,
-          scaling=1.0):
+          scaling=1.0, norm_eps=1e-6):
     """x (T, d); router_w (d, E) float32; expert_bias (E,) float32 or
-    None. -> (chosen (T, top_k) int32, weights (T, top_k) float32)."""
+    None; ``norm_eps``: what the model's code adds to the chosen
+    scores' sum. -> (chosen (T, top_k) int32, weights (T, top_k)
+    float32)."""
     with jax.named_scope("moe.route"):
         scores = jax.nn.sigmoid(jnp.dot(
             x.astype(jnp.float32), router_w.astype(jnp.float32),
@@ -44,8 +47,18 @@ def route(x, router_w, expert_bias, top_k, norm_topk_prob=True,
         _, chosen = jax.lax.top_k(biased, top_k)
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
         if norm_topk_prob:
-            weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
+            weights = weights / (weights.sum(-1, keepdims=True) + norm_eps)
         return chosen.astype(jnp.int32), weights * scaling
+
+
+def load_attrs(load):
+    """What a decoder makes of a launch's summed load (host side,
+    after the fetch) for its ``moe.load`` span: ``rows`` routed,
+    ``experts_hit`` (expert, layer) pairs that got any,
+    ``hottest_rows`` of the expert that got most."""
+    load = np.asarray(load)
+    return {"rows": int(load[0].sum()), "experts_hit": int(load[1].sum()),
+            "hottest_rows": int(load[0].max())}
 
 
 def _use_pallas(kernel):

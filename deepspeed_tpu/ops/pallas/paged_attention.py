@@ -382,6 +382,171 @@ def _grouped_paged_attention(q, k_pool, v_pool, page_tables, positions,
         .reshape(b, s, h, dh)
 
 
+# Tokens a turn of the latent page walk fetches and folds: 32 pages of
+# 16 at 640 bf16 lanes, 640 KB a buffer half.
+_MLA_BLOCK_TOKENS = 512
+
+
+def _mla_kernel(pt_ref, pos_ref, vlen_ref, q_ref, pool_ref, o_ref, buf,
+                sem, half_ref, *, layer_idx, page_size, heads, rank,
+                sm_scale, seq, block):
+    """One slot's walk over LATENT pages (ops/mla.py, the absorbed
+    form): every head's query ``[q_lat | q_pe | 0]`` against one shared
+    "key-value head", the cached row ``[c~ | k_pe | 0]``, whose values
+    are the first ``rank`` lanes of the same fetched block: ONE pool,
+    one DMA a page, where the other kernels make two. The walk, its
+    double buffer and the prefetch across slots are :func:`_kernel`'s,
+    the rows of the two matmuls :func:`_grouped_kernel`'s (all ``seq *
+    heads`` queries of the slot at once); the masking contract is the
+    module's. Refs:
+
+    pt_ref (b, max_pages) / pos_ref (b,) / vlen_ref (b,): SMEM scalar
+    prefetch; q_ref (1, seq * heads, lanes), rows ordered (query,
+    head); pool_ref (pages+1, L, page_size, lanes) left in HBM; o_ref
+    (1, seq * heads, rank) fp32; buf (2, block * page_size, lanes);
+    half_ref (1,) SMEM: the buffer half of this slot's first block."""
+    i = pl.program_id(0)
+    num_slots = pl.num_programs(0)
+    max_pages = pt_ref.shape[1]
+    rows = seq * heads
+    tokens = block * page_size
+
+    def pages_of(slot):
+        live = pos_ref[slot] + vlen_ref[slot] - 1
+        return jnp.minimum(
+            jax.lax.div(jnp.maximum(live, 0), page_size) + 1, max_pages)
+
+    def transfer(slot, c, half, start):
+        # a block's last pages may lie past the live window: no copy,
+        # and what the buffer holds there is zeroed below
+        first = c * block
+
+        def page(j, carry):
+            copy = pltpu.make_async_copy(
+                pool_ref.at[pt_ref[slot, first + j], layer_idx],
+                buf.at[half, pl.ds(pl.multiple_of(j * page_size,
+                                                  page_size), page_size)],
+                sem.at[half])
+            copy.start() if start else copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(pages_of(slot) - first, 0, block),
+                          page, 0)
+
+    @pl.when(i == 0)
+    def _first_slot():
+        half_ref[0] = 0
+        transfer(0, 0, 0, True)
+
+    pos = pos_ref[i]
+    live = pos + vlen_ref[i] - 1           # last live absolute position
+    n_blocks = jax.lax.div(pages_of(i) + block - 1, block)
+    first_half = half_ref[0]
+    q = q_ref[0]                                       # (rows, lanes)
+    q_pos = pos + jax.lax.div(
+        jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 0), heads)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 1)
+    token = jax.lax.broadcasted_iota(jnp.int32, (tokens, 1), 0)
+
+    def body(c, carry):
+        acc, m, l = carry                  # (rows, rank), (rows, 1) x 2
+        half = jax.lax.rem(first_half + c, 2)
+        last = c + 1 == n_blocks
+        nxt_slot = jnp.where(last, i + 1, i)
+
+        @pl.when(nxt_slot < num_slots)
+        def _prefetch():
+            transfer(nxt_slot, jnp.where(last, 0, c + 1), 1 - half, True)
+
+        transfer(i, c, half, False)
+
+        # only a slot's last block reaches past its live window: zero
+        # the rows there in place (values, and the keys with them)
+        @pl.when(last)
+        def _zero_dead_rows():
+            blk = buf[half]
+            buf[half] = jnp.where(c * tokens + token <= live, blk,
+                                  jnp.zeros_like(blk))
+
+        blk = buf[half]
+        scores = jax.lax.dot_general(
+            q, blk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # (rows, tokens)
+        k_pos = c * tokens + col
+        scores = jnp.where(
+            jnp.logical_and(k_pos <= q_pos, k_pos <= live), scores, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+        pexp = jnp.exp(scores - m_new)
+        corr = jnp.exp(m - m_new)
+        acc = acc * corr + jax.lax.dot_general(
+            pexp.astype(blk.dtype), blk[:, :rank],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return acc, m_new, l * corr + jnp.sum(pexp, axis=-1, keepdims=True)
+
+    init = (jnp.zeros((rows, rank), jnp.float32),
+            jnp.full((rows, 1), NEG_INF, jnp.float32),
+            jnp.zeros((rows, 1), jnp.float32))
+    # a walk has a block at least, so every row's l counts a token
+    acc, _, l = jax.lax.fori_loop(0, n_blocks, body, init)
+    half_ref[0] = jax.lax.rem(first_half + n_blocks, 2)
+    o_ref[0] = acc / l
+
+
+def mla_decode(q_abs, pool, page_tables, positions, valid_lens, *,
+               layer_idx, page_size, rank, sm_scale, interpret=None):
+    """Absorbed latent attention (ops/mla.py) for ``s`` new queries a
+    slot against the latent page pool, whose rows for the SAME tokens
+    must already have landed. q_abs (b, s, h, lanes): ``[q_lat | q_pe |
+    0]``; pool (pages+1, layers, page_size, lanes): ``[c~ | k_pe | 0]``,
+    pad lanes zero in every live row; ``rank``: the lanes of a row that
+    are its value (a multiple of 128). Returns fp32 ctx_lat (b, s, h,
+    rank). One chip: the pool is replicated on a mesh."""
+    if interpret is None:
+        interpret = default_interpret()
+    b, s, h, lanes = q_abs.shape
+    if pool.shape[2:] != (page_size, lanes) or rank % 128 or lanes % 128:
+        raise ValueError(
+            "mla_decode wants a pool (pages+1, layers, page_size {}, "
+            "lanes {}) and whole-lane rank, got {} and rank {}".format(
+                page_size, lanes, pool.shape, rank))
+    rows = s * h
+    max_pages = page_tables.shape[1]
+    block = max(1, min(max_pages, _MLA_BLOCK_TOKENS // page_size))
+    window = max_pages * page_size
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, rows, lanes), lambda i, *_: (i, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, rows, rank), lambda i, *_: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, block * page_size, lanes), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ])
+    kernel = functools.partial(
+        _mla_kernel, layer_idx=layer_idx, page_size=page_size, heads=h,
+        rank=rank, sm_scale=sm_scale, seq=s, block=block)
+    cost = pl.CostEstimate(
+        flops=2 * b * rows * window * (lanes + rank),
+        bytes_accessed=(q_abs.size * q_abs.dtype.itemsize
+                        + b * window * lanes * pool.dtype.itemsize
+                        + b * rows * rank * 4),
+        transcendentals=b * rows * window)
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, rows, rank), jnp.float32),
+        cost_estimate=cost, interpret=interpret,
+        # a slot's first block is fetched during the slot before it
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="mla_decode",
+    )(page_tables.astype(jnp.int32), positions.astype(jnp.int32),
+      valid_lens.astype(jnp.int32),
+      q_abs.reshape(b, rows, lanes).astype(pool.dtype), pool)
+    return out.reshape(b, s, h, rank)
+
+
 def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
                     *, layer_idx, page_size, interpret=None, mesh=None):
     """Paged attention for ``s`` new queries per slot against the pool.

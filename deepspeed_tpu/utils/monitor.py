@@ -116,6 +116,9 @@ class ServingMetrics:
         self.spec_proposed = 0
         self.spec_accepted = 0
         self.spec_steps = 0
+        # bytes one cached token costs in the engine's pool, all layers
+        # and pad lanes included (keys and values, or a latent row)
+        self.kv_token_bytes = 0
         # the recurrent-state pool of a model that keeps one
         self.state_slots = 0
         self.state_bytes = 0
@@ -266,6 +269,8 @@ class ServingMetrics:
                            ("speculative", self.spec_dist())):
             if dist is not None:
                 out[name] = dist
+        if self.kv_token_bytes:
+            out["kv_token_bytes"] = self.kv_token_bytes
         if self.state_slots:
             out["state_pool"] = {"slots": self.state_slots,
                                  "bytes": self.state_bytes,

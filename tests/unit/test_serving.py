@@ -716,6 +716,17 @@ def _jamba_engine():
                         num_pages=24, prefill_buckets=[8, 16])
 
 
+def _deepseek_v3_engine(**inference):
+    from deepspeed_tpu.models import deepseek_v3
+    cfg = deepseek_v3.DeepseekV3Config(
+        vocab_size=128, d_model=32, n_layers=2, n_heads=2, qk_nope=16,
+        qk_rope=8, v_head=16, kv_rank=120, d_ff=64, d_expert=16,
+        n_experts=4, top_k=2, max_seq_len=64, dtype=jnp.float32)
+    return paged_engine(deepseek_v3.make_deepseek_v3_model(cfg, seed=0),
+                        paged_attention_kernel="xla", max_seq_len=64,
+                        num_pages=24, prefill_buckets=[8, 16], **inference)
+
+
 _NGRAM = {"enabled": True, "method": "ngram", "num_draft_tokens": 3}
 _MODEL_DRAFT = {"enabled": True, "method": "model", "num_draft_tokens": 3}
 
@@ -764,6 +775,14 @@ _FAMILY = {
     # the state the first left
     "jamba_two_chunks": (
         _jamba_engine, 6, (23, 5, 11), (21, 7, 19)),
+    # latent pages: 37 tokens over a largest bucket of 16 are three
+    # chunks, the later ones reading the earlier ones' rows from the
+    # pages in a loop whose length the data gives, not a program's shape
+    "deepseek_v3_three_chunks": (
+        _deepseek_v3_engine, 6, (37, 5, 11), (35, 7, 19)),
+    "deepseek_v3_prefix_cache": (
+        lambda: _deepseek_v3_engine(prefix_caching=True),
+        5, (20, 23, 9, 30), (21, 25, 11, 28)),
 }
 
 
@@ -796,7 +815,7 @@ def test_serving_program_family_is_closed(case, compiled_programs):
     sched = serve(second, 2)
     assert compiled_programs[before:] == []
     assert engine.compile_stats == stats
-    if case == "gpt2_prefix_cache":
+    if case.endswith("_prefix_cache"):
         assert engine.prefix_stats()["hits"] >= 2
     if case == "gpt2_drafter_near_ceiling":
         assert stats["decode_traces"] == 2       # "two widths", no third

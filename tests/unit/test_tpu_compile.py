@@ -616,6 +616,113 @@ def test_lfm2_programs_run_the_kernels_and_alias_both_pools(
         14 * 2 ** 30
 
 
+def test_mla_decode_compiles_at_the_published_widths(
+        one_chip, no_persistent_cache):
+    """The latent page walk at Moonlight-16B-A3B's attention: 16 heads'
+    absorbed queries of 640 lanes (512 latent + 64 rope + 64 of
+    padding) against one pool of 640-lane rows, 320 slots, a row of 512
+    pages of 16 tokens: a page is a (16, 640) bf16 slab, the values its
+    first 512 lanes. The lane rule that PR 22 paid for: 576 lanes, the
+    row without its padding, is not a multiple of 128."""
+    from deepspeed_tpu.ops.pallas.paged_attention import mla_decode
+
+    def fn(q, pool, page_tables, positions, valid_lens):
+        return mla_decode(q, pool, page_tables, positions, valid_lens,
+                          layer_idx=3, page_size=16, rank=512,
+                          sm_scale=192 ** -0.5, interpret=False)
+
+    b = 320
+    assert _compile(fn, one_chip, ((b, 1, 16, 640), BF16),
+                    ((75001, 5, 16, 640), BF16), ((b, 512), I32),
+                    ((b,), I32), ((b,), I32)) == 1
+
+
+@pytest.fixture(scope="module")
+def moonlight_engine():
+    """A tiny deepseek_v3 engine on the CPU whose programs are lowered
+    at the published widths (``jamba_engine`` says how)."""
+    import json
+    import os
+    import deepspeed_tpu as deepspeed
+    from deepspeed_tpu.models import deepseek_v3
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "moonlight-16b-a3b-serve.json")) as f:
+        cell = json.load(f)
+    tiny = dict(cell["model"], hidden_size=64, intermediate_size=128,
+                moe_intermediate_size=32, num_attention_heads=4,
+                qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+                kv_lora_rank=128, vocab_size=128, n_routed_experts=8)
+    eng = deepspeed.init_inference(
+        model=deepseek_v3.make_deepseek_v3_model(
+            deepseek_v3.config_from_hf(tiny), seed=0),
+        config={"inference": dict(cell["inference"], max_batch_size=2,
+                                  num_pages=1024,
+                                  paged_attention_kernel="pallas")})
+    eng.model_config = deepseek_v3.config_from_hf(cell["model"],
+                                                  moe_kernel="pallas")
+    return eng, cell
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_moonlight_programs_run_the_kernels_and_alias_the_latent_pool(
+        one_chip, no_persistent_cache, moonlight_engine, monkeypatch,
+        program):
+    """``jit_prefill`` (the largest bucket) and ``jit_decode`` (every
+    slot) of Moonlight-16B-A3B's first stage at the cell's pool shape:
+    two grouped matmuls an expert layer; in decode every layer walks
+    its latent pages in ``mla_decode``; the ONE pool of 640-lane rows is
+    donated and comes back in place, never copied whole (the prefill's
+    loop over key blocks reads it where it lies); and it fits the
+    chip."""
+    from deepspeed_tpu.models import deepseek_v3
+    eng, cell = moonlight_engine
+    cfg = eng.model_config
+    inference = cell["inference"]
+    slots, pages = inference["max_batch_size"], inference["num_pages"]
+    bucket, ps = inference["prefill_buckets"][-1], eng.page_size
+    row = inference["max_seq_len"] // ps
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(
+        lambda: deepseek_v3.DeepseekV3Decoder(cfg).serving_params(
+            deepseek_v3.init_params(cfg, 0), BF16))
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), params)
+    pool = sds((pages + 1, cfg.n_layers, ps, cfg.mla.lanes), BF16)
+    assert cfg.mla.lanes == 640
+    rng = jax.random.PRNGKey(0)
+    tail = (sds(rng.shape, rng.dtype), sds((), F32), sds((), F32))
+    if program == "prefill":
+        fn = eng._get_prefill_fn(bucket, True, 0)
+        args = (sds((1, bucket), I32), sds((row,), I32), sds((), I32),
+                sds((), I32))
+    else:
+        fn = eng._get_decode_fn(True, 0)
+        args = (sds((slots, 1), I32), sds((slots,), I32),
+                sds((slots, row), I32))
+    compiled = fn.lower(params, pool, *args, *tail).compile()
+    text = compiled.as_text()
+
+    assert text.startswith("HloModule jit_" + program)
+    assert text.count("tpu_custom_call") == 2 * len(cfg.expert_layers) + \
+        (cfg.n_layers if program == "decode" else 0)
+    assert ("mla_decode" in text) == (program == "decode")
+    whole = "bf16[{},{},{},{}]".format(pages + 1, cfg.n_layers, ps, 640)
+    assert not re.search(re.escape(whole) + r"\S* copy\(", text)
+    aliased = {int(out): int(arg) for out, arg in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+        text.split("\n", 1)[0])}
+    n_params = len(jax.tree_util.tree_leaves(params))
+    assert aliased == {0: n_params}
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < \
+        14.5 * 2 ** 30
+
+
 def test_pallas_compiler_params_construct():
     """Every ``compiler_params`` a pallas_call site passes must construct
     under the installed jax — the sites are only reached with

@@ -45,7 +45,7 @@ from ..utils.annotate import annotate
 from ..utils.logging import logger
 from .config import DeepSpeedInferenceConfig
 from .decoder import decoder_of, refuse_latent, refuse_recurrent
-from .kv_cache import KVCache, PagedKVCache, StatePool
+from .kv_cache import KVCache, PagedKVCache, StatePool, write_path
 from .paging import GARBAGE_PAGE, PageAllocator, PrefixCache
 from .sampling import make_sampler
 
@@ -501,6 +501,15 @@ class InferenceEngine:
             with annotate(name, **attrs):
                 pass
 
+    def _kv_write_attr(self, tokens):
+        """The ``kv_write`` attribute of a dispatch span: how the program
+        of ``tokens`` new tokens a slot writes them into the page pools
+        (``kv_cache.write_path``, the branch its trace took); nothing on
+        the slot layout, which has no pages."""
+        if self.kv_layout != "paged":
+            return {}
+        return {"kv_write": write_path(tokens, self.page_size)}
+
     def _get_prefill_fn(self, bucket, greedy, top_k):
         # attached adapters switch to an extended program family (extra
         # LoRA readout operands); the base family's traces stay valid
@@ -524,8 +533,8 @@ class InferenceEngine:
                 # (scalar int32: whose
                 # state); then ids (1, bucket); page_row (max_pages,);
                 # start/length scalar int32 — the chunk covers positions
-                # [start, start+length); padded tokens redirect to the
-                # garbage page via the masked scatter and leave a
+                # [start, start+length); padded tokens are masked out of
+                # the cache write (kv_cache.write_tokens) and leave a
                 # recurrent state as it was; rng, temperature, top_p;
                 # adapter args (when attached): (a_stack (n,r,d),
                 # b_stack (n,V,r), adapter_id scalar) — a per-tenant
@@ -904,7 +913,8 @@ class InferenceEngine:
                 ids, where, np.int32(start), np.int32(n),
                 self._next_rng(greedy), np.float32(temperature),
                 np.float32(top_p)) + extra
-        with annotate("engine.prefill.dispatch"):
+        with annotate("engine.prefill.dispatch",
+                      **self._kv_write_attr(bucket)):
             token, counters = self._launch(fn, args)
             self.lengths[slot] = start + n
         with annotate("engine.prefill.fetch"):
@@ -969,7 +979,8 @@ class InferenceEngine:
                 (self.page_tables.copy(),) if paged else ()) + (
                 self._next_rng(greedy), np.float32(temperature),
                 np.float32(top_p)) + extra
-        with annotate("engine.decode.dispatch"):
+        with annotate("engine.decode.dispatch",
+                      **self._kv_write_attr(width)):
             chosen, counters = self._launch(fn, args)
         with annotate("engine.decode.fetch"):
             chosen, counters = jax.device_get((chosen, counters))

@@ -161,8 +161,9 @@ class PagedKVCache:
     (inference/paging.py), so ``num_pages`` counts USABLE pages. Buffers
     are jax arrays updated functionally; the engine's jitted programs
     donate them, so steady-state serving writes in place (the compiled
-    programs alias both pools input to output, and their scatters
-    update the operand). Reads index it by (page, layer) together
+    programs alias both pools input to output; :func:`write_tokens` is
+    the one write and holds its contract). Reads index it by (page,
+    layer) together
     (models/gpt2.py ``_gather_pages``, the paged kernel's DMAs): a
     program that slices a layer out first, ``pool[:, layer]``, copies
     that layer's whole slab — the cost then grows with ``num_pages``,
@@ -213,3 +214,106 @@ class PagedKVCache:
 
     def update(self, buffers):
         self.k, self.v = (tuple(buffers) + (None,))[:2]
+
+
+def write_path(s, page_size):
+    """The granularity :func:`write_tokens` moves ``s`` new tokens a slot
+    at: ``"rows"`` below a page (a decode step, a speculative verify),
+    ``"pages"`` from a page up (a prefill chunk). Fixed by the shape, so
+    by the program (the ``kv_write`` attribute, docs/telemetry.md)."""
+    return "pages" if s >= page_size else "rows"
+
+
+def write_tokens(pools, news, layer_idx, page_tables, positions,
+                 valid_lens, page_size, mesh=None):
+    """THE write of new cache rows into the paged pools: every paged
+    model calls it (models/gpt2.py, jamba.py, lfm2.py, ops/mla.py).
+    ``pools``: the key and the value pool, or the one latent pool, each
+    ``(pages + 1, layers, page_size, lanes)``; ``news``: for each, the
+    ``(b, s, lanes)`` rows of the ``s`` tokens that slot b holds at
+    ``positions[b] + [0, s)``; ``page_tables`` (b, max_pages). Returns
+    the pools, updated in place under donation.
+
+    The contract, whatever the path: token i of slot b lands at
+    ``(page_tables[b, pos // page_size], layer_idx, pos % page_size)``
+    if ``i < valid_lens[b]`` and ``pos`` lies inside the slot's logical
+    window (``max_pages * page_size``); no other row of any page but the
+    garbage page 0 changes a bit. So rows at or past ``valid_len`` in a
+    slot's last page keep what they held, a padded bucket never touches
+    another sequence's pages, and a chunk may start in the middle of a
+    page and may run past the window. What page 0 holds afterwards is
+    unspecified (no read reaches it unmasked).
+
+    One masked write at two granularities, chosen by the shape
+    (:func:`write_path`). XLA lowers a scatter on the chip to one update
+    after another, about 130 ns each whatever it holds (a 2 KB row at
+    4-5% of what its bytes cost: ledger PR 38, 1.43 s of docs' 4.94 s
+    window), so a chunk of a page or more moves whole pages, one DMA
+    each (ops/pallas/page_write.py): the ``(s - 2) // page_size + 2``
+    page frames it can touch, 65 of 32 KB a layer and pool for a bucket
+    of 1,024 where there were 1,024 updates of 2 KB; only a chunk's
+    first and last page can hold rows to keep, and those two are read,
+    merged and written back. Below a page (``s == 1``, ``s == k + 1``)
+    there is no page to move: a row a token, the scatter as it was.
+    ``mesh``: the mesh the program spans, for the kernel's shard_map
+    (the scatter follows GSPMD)."""
+    if write_path(news[0].shape[1], page_size) == "pages":
+        return _write_pages(pools, news, layer_idx, page_tables,
+                            positions, valid_lens, page_size, mesh)
+    return _write_rows(pools, news, layer_idx, page_tables, positions,
+                       valid_lens, page_size)
+
+
+def _write_rows(pools, news, layer_idx, page_tables, positions,
+                valid_lens, page_size):
+    """One scatter update a token: padded tokens and positions past the
+    window redirect to the garbage page."""
+    b, s = news[0].shape[:2]
+    max_pages = page_tables.shape[1]
+    tok_pos = positions[:, None] + jnp.arange(s)[None, :]         # (b, s)
+    valid = (jnp.arange(s)[None, :] < valid_lens[:, None]) & \
+        (tok_pos < max_pages * page_size)
+    logical = jnp.clip(tok_pos // page_size, 0, max_pages - 1)
+    page = jnp.where(valid, jnp.take_along_axis(page_tables, logical,
+                                                axis=1), 0)
+    # the advanced (page, offset) indices broadcast to the front
+    flat_page, flat_off = page.reshape(-1), (tok_pos % page_size).reshape(-1)
+    return tuple(
+        pool.at[flat_page, layer_idx, flat_off, :].set(
+            new.reshape(b * s, -1).astype(pool.dtype))
+        for pool, new in zip(pools, news))
+
+
+def _write_pages(pools, news, layer_idx, page_tables, positions,
+                 valid_lens, page_size, mesh):
+    """One DMA a page (ops/pallas/page_write.py): the chunk's rows cut
+    into the page frames ``[positions // page_size, + frames)`` of each
+    slot, and for each frame its page and the rows of it that hold
+    valid tokens. A frame with none is not written."""
+    from ..ops.pallas.page_write import kv_page_write
+    b, s = news[0].shape[:2]
+    max_pages = page_tables.shape[1]
+    frames = (s - 2) // page_size + 2
+    first, shift = positions // page_size, positions % page_size
+    # tokens [0, limit) are valid: frame rows [shift, shift + limit)
+    limit = jnp.clip(jnp.minimum(
+        valid_lens, max_pages * page_size - positions), 0, s)
+    row0 = jnp.arange(frames)[None, :] * page_size
+    lo = jnp.clip(shift[:, None] - row0, 0, page_size)
+    hi = jnp.clip((shift + limit)[:, None] - row0, 0, page_size)
+    logical = jnp.clip(first[:, None] + jnp.arange(frames)[None, :],
+                       0, max_pages - 1)
+    page = jnp.where(hi > lo,
+                     jnp.take_along_axis(page_tables, logical, axis=1), 0)
+    meta = jnp.stack([page, lo, hi]).reshape(3, b * frames)
+
+    def framed(rows, shift):
+        # (s, lanes) -> (frames * page_size, lanes), row i at i + shift
+        rows = jnp.pad(rows, ((page_size, frames * page_size - s), (0, 0)))
+        return jax.lax.dynamic_slice_in_dim(rows, page_size - shift,
+                                            frames * page_size, axis=0)
+
+    return kv_page_write(
+        pools, [jax.vmap(framed)(new.astype(pool.dtype), shift).reshape(
+            b * frames, page_size, -1) for pool, new in zip(pools, news)],
+        meta, layer_idx, mesh=mesh)

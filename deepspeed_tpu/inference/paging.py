@@ -264,8 +264,8 @@ def plan_chunks(n_tokens, chunk_tokens, bucket_for, max_seq, start=0,
     would silently shift the write DOWN over live positions. A plan
     with such a chunk is merged back into one unchunked prefill when a
     bucket covers the whole span; otherwise the chunked plan stands
-    (the paged layout's per-token masked scatter is safe by
-    construction, and the slot path keeps a LOUD overrun assert)."""
+    (the paged layout's masked write, kv_cache.write_tokens, is safe
+    by construction, and the slot path keeps a LOUD overrun assert)."""
     if max_chunk is not None:
         chunk_tokens = min(chunk_tokens or max_chunk, max_chunk)
     if not chunk_tokens or n_tokens <= chunk_tokens:

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..inference.kv_cache import write_tokens
 from ..parallel.topology import MODEL_AXIS
 
 
@@ -463,12 +464,10 @@ def _paged_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
     d_head)`` — heads packed in the lane-aligned minor dimension
     (inference/kv_cache.py); ``page_tables`` (b, max_pages) int32 maps
     each slot's logical page j to a physical page (entry 0 = the
-    reserved garbage page). Token i of row b writes at physical
-    ``(page_tables[b, pos // page_size], pos % page_size)`` via one
-    masked scatter — padded
-    tokens (``i >= valid_lens[b]``) and positions past the logical
-    window redirect to the garbage page, so a bucket-padded prefill can
-    never touch another sequence's pages. Reads: the default "xla" path
+    reserved garbage page). The new keys and values are written first,
+    by ``kv_cache.write_tokens`` (the masked write and its contract: a
+    bucket-padded prefill can never touch another sequence's pages).
+    Reads: the default "xla" path
     gathers the slot's full logical window back into contiguous (b, h,
     max_pages*page_size, d_head) rows and runs the same masked
     attention as the slot layout — identical values in identical order,
@@ -481,7 +480,7 @@ def _paged_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
     and the softmax weights on the MXU in the pool's dtype, online
     softmax in float32 — same masking contract, ctx within 1e-5 of the
     gather path under a float32 pool, greedy streams byte-identical;
-    docs/pallas_kernels.md). The WRITE scatter is
+    docs/pallas_kernels.md). The WRITE is
     shared by both paths, so the cache bits never diverge.
     """
     b, s, d = x.shape
@@ -489,23 +488,13 @@ def _paged_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
     max_pages = page_tables.shape[1]
     q, k, v = _qkv_for_cache(x, block, config)
 
-    tok_pos = positions[:, None] + jnp.arange(s)[None, :]         # (b, s)
-    valid = (jnp.arange(s)[None, :] < valid_lens[:, None]) & \
-        (tok_pos < max_pages * page_size)
-    logical = jnp.clip(tok_pos // page_size, 0, max_pages - 1)
-    offset = tok_pos % page_size
-    page = jnp.take_along_axis(page_tables, logical, axis=1)
-    page = jnp.where(valid, page, 0)                # garbage-page redirect
-
-    # scatter the new K/V: one packed (h*dh) row per token — the
-    # advanced (page, offset) indices broadcast to the front
-    flat_page, flat_off = page.reshape(-1), offset.reshape(-1)
-    k_new = k.transpose(0, 2, 1, 3).reshape(b * s, -1)
-    v_new = v.transpose(0, 2, 1, 3).reshape(b * s, -1)
-    k_cache = k_cache.at[flat_page, layer_idx, flat_off, :].set(
-        k_new.astype(k_cache.dtype))
-    v_cache = v_cache.at[flat_page, layer_idx, flat_off, :].set(
-        v_new.astype(v_cache.dtype))
+    # one packed (h*dh) row per token
+    k_cache, v_cache = write_tokens(
+        (k_cache, v_cache),
+        (k.transpose(0, 2, 1, 3).reshape(b, s, -1),
+         v.transpose(0, 2, 1, 3).reshape(b, s, -1)),
+        layer_idx, page_tables, positions, valid_lens, page_size,
+        mesh=config.kernel_mesh)
 
     if config.paged_attention_kernel == "pallas":
         from ..ops.pallas.paged_attention import paged_attention

@@ -14,7 +14,8 @@ Serving keeps TWO kinds of state (``JambaDecoder.cache_spec``):
 
 * the attention layers' keys and values in the engine's page pool,
   ``(pages + 1, attention layers, page_size, n_kv_heads * d_head)``,
-  written by a masked scatter and read by one gather on (page, layer)
+  written by ``kv_cache.write_tokens`` and read by one gather on
+  (page, layer)
   as GPT-2's are;
 * per slot and Mamba layer a convolution tail, ``conv (mamba layers,
   slots, (d_conv - 1) * d_inner)`` (a slot's three last inputs side by
@@ -44,6 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from ..inference.decoder import CacheSpec, StateSpec
+from ..inference.kv_cache import write_tokens
 
 INIT_STD = 0.02
 DT_MIN, DT_MAX = 1e-3, 1e-1
@@ -324,24 +326,17 @@ def _attend(q, k_rows, v_rows, positions, valid_lens, config):
 def _attention_paged(u, lp, config, k_cache, v_cache, a, positions,
                      page_tables, valid_lens, page_size):
     """An attention layer against the page pool (``a``: the layer's
-    index among the attention layers): the same masked scatter and
-    (page, layer) gather as ``models/gpt2.py::_paged_attn_ctx``."""
+    index among the attention layers): ``kv_cache.write_tokens`` and
+    the (page, layer) gather of ``models/gpt2.py::_paged_attn_ctx``."""
     b, s, _ = u.shape
     h, kvh, dh = config.n_heads, config.n_kv_heads, config.d_head
     max_pages = page_tables.shape[1]
     q = (u @ lp["q"]).reshape(b, s, h, dh)
     k, v = u @ lp["k"], u @ lp["v"]                    # (b, s, kvh*dh)
-    tok_pos = positions[:, None] + jnp.arange(s)[None, :]
-    valid = (jnp.arange(s)[None, :] < valid_lens[:, None]) & \
-        (tok_pos < max_pages * page_size)
-    logical = jnp.clip(tok_pos // page_size, 0, max_pages - 1)
-    page = jnp.where(valid, jnp.take_along_axis(page_tables, logical,
-                                                axis=1), 0)
-    flat_page, flat_off = page.reshape(-1), (tok_pos % page_size).reshape(-1)
-    k_cache = k_cache.at[flat_page, a, flat_off, :].set(
-        k.reshape(b * s, -1).astype(k_cache.dtype))
-    v_cache = v_cache.at[flat_page, a, flat_off, :].set(
-        v.reshape(b * s, -1).astype(v_cache.dtype))
+    k_cache, v_cache = write_tokens(
+        (k_cache, v_cache), (k.reshape(b, s, -1), v.reshape(b, s, -1)),
+        a, page_tables, positions, valid_lens, page_size,
+        mesh=config.kernel_mesh)
 
     if config.paged_attention_kernel == "pallas":
         # the page-table walk in the kernel: the live pages and no

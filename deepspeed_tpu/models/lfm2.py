@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from ..inference.decoder import CacheSpec, StateSpec
+from ..inference.kv_cache import write_tokens
 from ..ops import moe
 from .jamba import _attend, _rms_norm
 
@@ -307,24 +308,17 @@ def _qkv(u, lp, config, tok_pos):
 def _attention_paged(u, lp, config, k_cache, v_cache, a, positions,
                      page_tables, valid_lens, page_size):
     """An attention layer against the page pool (``a``: the layer's
-    index among the attention layers): the masked scatter and (page,
-    layer) gather of ``models/jamba.py::_attention_paged``, the keys
-    stored normed and rotated."""
+    index among the attention layers): ``kv_cache.write_tokens`` and
+    the (page, layer) gather of ``models/jamba.py::_attention_paged``,
+    the keys stored normed and rotated."""
     b, s, _ = u.shape
     h, kvh, dh = config.n_heads, config.n_kv_heads, config.d_head
     max_pages = page_tables.shape[1]
     tok_pos = positions[:, None] + jnp.arange(s)[None, :]
     q, k, v = _qkv(u, lp, config, tok_pos)
-    valid = (jnp.arange(s)[None, :] < valid_lens[:, None]) & \
-        (tok_pos < max_pages * page_size)
-    logical = jnp.clip(tok_pos // page_size, 0, max_pages - 1)
-    page = jnp.where(valid, jnp.take_along_axis(page_tables, logical,
-                                                axis=1), 0)
-    flat_page, flat_off = page.reshape(-1), (tok_pos % page_size).reshape(-1)
-    k_cache = k_cache.at[flat_page, a, flat_off, :].set(
-        k.reshape(b * s, -1).astype(k_cache.dtype))
-    v_cache = v_cache.at[flat_page, a, flat_off, :].set(
-        v.reshape(b * s, -1).astype(v_cache.dtype))
+    k_cache, v_cache = write_tokens(
+        (k_cache, v_cache), (k.reshape(b, s, -1), v.reshape(b, s, -1)),
+        a, page_tables, positions, valid_lens, page_size)
 
     if config.paged_attention_kernel == "pallas":
         from ..ops.pallas.paged_attention import paged_attention

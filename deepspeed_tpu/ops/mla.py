@@ -51,6 +51,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from ..inference.kv_cache import write_tokens
+
 LANES = 128
 NEG_INF = -1e30
 # keys a turn of the up-projected form's loop makes and folds
@@ -258,8 +260,8 @@ def attention_dense(u, lp, dims):
 def attention_paged(u, lp, dims, pool, layer_idx, positions, page_tables,
                     valid_lens, page_size, kernel="xla"):
     """A layer's attention against the latent pages: the chunk's rows
-    are written first (the masked scatter of the other paged models:
-    padding and positions past the table land in the garbage page 0),
+    are written first (``kv_cache.write_tokens``, the write of every
+    paged model),
     then the chunk attends to the pages, its own rows among them, in
     the form its shape says (``s == 1``: absorbed). u (b, s, d);
     pool (pages + 1, layers, page_size, lanes). -> ((b, s, h v), pool)."""
@@ -268,14 +270,8 @@ def attention_paged(u, lp, dims, pool, layer_idx, positions, page_tables,
     tok_pos = positions[:, None] + jnp.arange(s)[None, :]
     q_nope, q_pe, rows = project(u, lp["q"], lp["kv_a"], lp["kv_norm"],
                                  dims, tok_pos)
-    valid = (jnp.arange(s)[None, :] < valid_lens[:, None]) & \
-        (tok_pos < max_pages * page_size)
-    logical = jnp.clip(tok_pos // page_size, 0, max_pages - 1)
-    page = jnp.where(valid, jnp.take_along_axis(page_tables, logical,
-                                                axis=1), 0)
-    pool = pool.at[page.reshape(-1), layer_idx,
-                   (tok_pos % page_size).reshape(-1), :].set(
-        rows.reshape(b * s, -1).astype(pool.dtype))
+    pool, = write_tokens((pool,), (rows,), layer_idx, page_tables,
+                         positions, valid_lens, page_size)
 
     if s == 1:
         ctx_lat = absorbed_attention(

@@ -556,7 +556,7 @@ def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
     (inference/kv_cache.py PAGED_KV_CACHE_SPEC), the rest replicated.
 
     ``q``: (b, s, h, dh) — the new tokens' queries (cache writes for the
-    SAME tokens must already have landed via the masked scatter, exactly
+    SAME tokens must already have landed, ``kv_cache.write_tokens``, exactly
     as on the XLA gather path; this kernel replaces only the read side).
     ``k_pool``/``v_pool``: (pages+1, layers, page_size, h*dh), or
     (..., kvh*dh) with ``kvh`` key-value heads each shared by ``h / kvh``

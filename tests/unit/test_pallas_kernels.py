@@ -336,8 +336,9 @@ def test_paged_attention_kernel_config_gate():
 
 
 def test_decode_program_carries_pallas_and_audits_clean():
-    # the decode family runs the kernel; prefill does not; the IR
-    # walker classifies the call as a compute segment; audit is clean
+    # the decode family runs the attention kernel; prefill does not: its
+    # one kernel a layer is the page write (kv_cache.write_tokens); the
+    # IR walker classifies the calls as compute segments; audit is clean
     from deepspeed_tpu.analysis.ir import walk
     from deepspeed_tpu.analysis.programs import collect_inference_programs
     eng = deepspeed.init_inference(
@@ -352,7 +353,10 @@ def test_decode_program_carries_pallas_and_audits_clean():
     assert all(e.kind == "compute" for e in calls)
     prefill = walk(jax.make_jaxpr(specs["prefill/b8"].build())
                    (*specs["prefill/b8"].args))
-    assert not [e for e in prefill.eqns if e.prim == "pallas_call"]
+    calls = [e for e in prefill.eqns if e.prim == "pallas_call"]
+    assert len(calls) == eng.model_config.n_layers
+    assert all(e.kind == "compute" and "kv_page_write" in str(e.eqn)
+               for e in calls)
     report = eng.audit()
     assert report.findings == [], [f.key for f in report.findings]
 

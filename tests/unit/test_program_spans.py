@@ -152,7 +152,7 @@ def test_step_and_request_attributes(traced):
     # and nothing is written that nothing reads
     assert {k for ev in lines[0] for k in ev[3]} == {
         "step", "uid", "queue_wait_us", "resumed", "tokens", "padded",
-        "first"}
+        "first", "kv_write"}
     # the bucket each chunk was padded to, and whose first chunk it was
     # (the one whose program starts a recurrent state from zeros)
     buckets = traced[5].engine.prefill_buckets
@@ -160,6 +160,20 @@ def test_step_and_request_attributes(traced):
                for c in chunks)
     assert sorted((c["uid"], c["first"]) for c in chunks) == \
         [(0, 1), (1, 0), (1, 1), (2, 1)]
+
+
+def test_dispatch_spans_say_how_the_program_writes_the_pool(traced):
+    """``kv_write``: a prefill chunk (a bucket of a page or more) moves
+    whole pages, a decode launch single rows
+    (``kv_cache.write_tokens``), each on every launch."""
+    by_name = {name: [ev[3].get("kv_write") for ev in traced[3][0]
+                      if ev[0] == name]
+               for name in ("engine.prefill.dispatch",
+                            "engine.decode.dispatch")}
+    assert len(by_name["engine.prefill.dispatch"]) == 4
+    assert set(by_name["engine.prefill.dispatch"]) == {"pages"}
+    assert by_name["engine.decode.dispatch"] and \
+        set(by_name["engine.decode.dispatch"]) == {"rows"}
 
 
 def test_serving_program_names_are_pinned(traced):

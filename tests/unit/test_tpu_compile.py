@@ -283,7 +283,109 @@ def test_paged_attention_block_walk_compiles_on_a_tensor_parallel_mesh(
         ((b,), I32, P())) == 1
 
 
+# ----------------------------------------------- the page write (prefill)
+# a cell's pool (pages + 1, layers, lanes), how many pools, its largest
+# prefill bucket and a row's pages (benchmark/configs/*.json)
+_WRITE_CELLS = {
+    "chat": ((3073, 24, 1024), 2, 512, 64),
+    "docs": ((8501, 24, 1024), 2, 1024, 64),
+    "rollouts": ((18001, 2, 128), 2, 512, 192),
+    "extract": ((30001, 3, 512), 2, 256, 192),
+    "reasoning": ((75001, 5, 640), 1, 512, 512),
+}
+
+
+def _write_args(cell, sharding_of):
+    """(pools, news, page_tables, positions, valid_lens) of one slot's
+    largest chunk in ``cell``; ``sharding_of(lanes_axis)`` places an
+    array whose dimension ``lanes_axis`` is the lanes (None: none)."""
+    (pages, layers, lanes), n_pools, bucket, row = _WRITE_CELLS[cell]
+
+    def sds(shape, dtype, lanes_axis=None):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=sharding_of(lanes_axis))
+
+    return ((sds((pages, layers, 16, lanes), BF16, 3),) * n_pools,
+            (sds((1, bucket, lanes), BF16, 2),) * n_pools,
+            sds((1, row), I32), sds((1,), I32), sds((1,), I32))
+
+
+@pytest.mark.parametrize("cell", sorted(_WRITE_CELLS))
+def test_kv_page_write_compiles_at_the_cells_shapes(
+        one_chip, no_persistent_cache, monkeypatch, cell):
+    """``kv_cache.write_tokens`` of a prefill chunk at every serving
+    cell's pool and largest bucket: one kernel for the layer's pools
+    (pages of 1,024, 128, 512 and 640 bf16 lanes; the select over the
+    two partly filled pages included), the pools aliased input to
+    output and never copied."""
+    from deepspeed_tpu.inference import kv_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pools, *rest = _write_args(cell, lambda lanes_axis: one_chip)
+    layer = pools[0].shape[1] - 1
+    compiled = jax.jit(
+        lambda pools, *rest: kv_cache.write_tokens(pools, *rest[:1], layer,
+                                                   *rest[1:], 16),
+        donate_argnums=0).lower(pools, *rest).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "kv_page_write" in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == sum(
+        int(np.prod(p.shape)) * 2 for p in pools)
+    assert memory.temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("cell", ["chat", "docs"])
+def test_kv_page_write_compiles_on_a_tensor_parallel_mesh(
+        four_chips, no_persistent_cache, monkeypatch, cell):
+    """The lanes split over ``model`` like the pool's (a shard moves
+    pages of 256 lanes): the kernel under its shard_map, no collective
+    beside it."""
+    from deepspeed_tpu.inference import kv_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sharding_of(lanes_axis):
+        spec = [None] * (lanes_axis or 0) + \
+            (["model"] if lanes_axis else [])
+        return NamedSharding(four_chips, P(*spec))
+
+    pools, *rest = _write_args(cell, sharding_of)
+    compiled = jax.jit(
+        lambda pools, *rest: kv_cache.write_tokens(
+            pools, *rest[:1], 3, *rest[1:], 16, mesh=four_chips),
+        donate_argnums=0).lower(pools, *rest).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "kv_page_write" in text
+    assert not re.search(r"all-gather|all-to-all|all-reduce|"
+                         r"collective-permute", text)
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        int(np.prod(p.shape)) * 2 // 4 for p in pools)
+
+
 # ------------------------------------- the serving engine's own programs
+def _page_writes(text, program, pool_shape):
+    """How a serving program writes its new cache rows
+    (``kv_cache.write_tokens``), read off its compiled text: the number
+    of ``kv_page_write`` kernels. A prefill program holds no scatter on
+    the pool, neither in the pool's own shape nor over the pool
+    flattened to rows, where XLA put a bucket's row updates (docs'
+    ``fusion bf16[3264384,1024]``, 1.43 s of a 4.94 s window, ledger
+    PR 38): whole pages move by DMA. A decode or verify program keeps
+    its row scatter and holds no page write."""
+    pages, layers, ps, lanes = pool_shape
+    shapes = ["bf16[{},{},{},{}]".format(*pool_shape),
+              "bf16[{},{}]".format(pages * layers * ps, lanes)]
+    scatters = [line.strip()[:160] for line in text.splitlines()
+                if " scatter(" in line and any(s in line for s in shapes)]
+    writes = sum("tpu_custom_call" in line and "kv_page_write" in line
+                 for line in text.splitlines())
+    if program == "prefill":
+        assert not scatters[:3]
+        assert shapes[1] not in text
+    else:
+        assert scatters and not writes
+    return writes
+
+
 @pytest.fixture(scope="module")
 def serving_engine():
     """Two layers at gpt2_medium widths behind ``init_inference`` on the
@@ -320,8 +422,9 @@ def test_serving_programs_copy_no_layer_slab(
     layer's whole slab of the pool, 101 MB in chat and 279 MB in docs,
     which prefill copied twice a layer while its read sliced the layer
     out before gathering (a row's 64 pages, 2 MB, were then taken from
-    the copy; 42-44% of docs' busy device time, ledger PR 26) — and
-    both donated pools come back in place."""
+    the copy; 42-44% of docs' busy device time, ledger PR 26) — both
+    donated pools come back in place, and the new rows are written as
+    :func:`_page_writes` says."""
     eng = serving_engine
     (pages, bucket, slots), row = _SERVING_CELLS[cell], eng.max_pages
     cfg = eng.model_config
@@ -351,8 +454,11 @@ def test_serving_programs_copy_no_layer_slab(
     slab = "bf16[{},{},{}]".format(pages + 1, ps, hd)
     assert not [line.strip()[:160] for line in text.splitlines()
                 if slab in line][:3]
-    assert text.count("tpu_custom_call") == \
-        (layers if program == "decode" else 0)
+    # a kernel a layer either way: decode's page walk, prefill's page
+    # write (both pools in one call)
+    assert text.count("tpu_custom_call") == layers
+    assert _page_writes(text, program, (pages + 1, layers, ps, hd)) == \
+        (layers if program == "prefill" else 0)
     aliased = {int(out): int(arg) for out, arg in re.findall(
         r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
         text.split("\n", 1)[0])}
@@ -498,10 +604,14 @@ def test_jamba_programs_copy_no_state_slab_and_alias_both_pools(
     for slab in slabs:
         assert not [line.strip()[:160] for line in text.splitlines()
                     if slab in line][:3], slab
-    # a kernel a Mamba layer; in decode the two attention layers walk
-    # their pages in the grouped paged kernel, prefill gathers its row
-    assert text.count("tpu_custom_call") == \
-        n_mamba + (n_attn if program == "decode" else 0)
+    # a kernel a Mamba layer, and one an attention layer: in decode the
+    # walk of its pages in the grouped paged kernel, in prefill (which
+    # gathers its row) the chunk's page write
+    assert text.count("tpu_custom_call") == n_mamba + n_attn
+    assert _page_writes(
+        text, program,
+        (pages + 1, n_attn, ps, cfg.n_kv_heads * cfg.d_head)) == \
+        (n_attn if program == "prefill" else 0)
     aliased = {int(out): int(arg) for out, arg in re.findall(
         r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
         text.split("\n", 1)[0])}
@@ -600,8 +710,14 @@ def test_lfm2_programs_run_the_kernels_and_alias_both_pools(
     text = compiled.as_text()
 
     assert text.startswith("HloModule jit_" + program)
-    assert text.count("tpu_custom_call") == 2 * len(cfg.expert_layers) + \
-        (n_attn if program == "decode" else 0)
+    # and a kernel an attention layer: decode's page walk, prefill's
+    # page write
+    assert text.count("tpu_custom_call") == \
+        2 * len(cfg.expert_layers) + n_attn
+    assert _page_writes(
+        text, program,
+        (pages + 1, n_attn, ps, cfg.n_kv_heads * cfg.d_head)) == \
+        (n_attn if program == "prefill" else 0)
     whole = "bf16[{},{},{}]".format(n_conv, slots,
                                     (cfg.conv_L - 1) * cfg.d_model) + \
         "{2,1,0:T(8,128)(2,1)} copy("
@@ -708,9 +824,14 @@ def test_moonlight_programs_run_the_kernels_and_alias_the_latent_pool(
     text = compiled.as_text()
 
     assert text.startswith("HloModule jit_" + program)
-    assert text.count("tpu_custom_call") == 2 * len(cfg.expert_layers) + \
-        (cfg.n_layers if program == "decode" else 0)
+    # and a kernel a layer: decode's latent page walk, prefill's page
+    # write (the one pool)
+    assert text.count("tpu_custom_call") == \
+        2 * len(cfg.expert_layers) + cfg.n_layers
     assert ("mla_decode" in text) == (program == "decode")
+    assert _page_writes(text, program,
+                        (pages + 1, cfg.n_layers, ps, 640)) == \
+        (cfg.n_layers if program == "prefill" else 0)
     whole = "bf16[{},{},{},{}]".format(pages + 1, cfg.n_layers, ps, 640)
     assert not re.search(re.escape(whole) + r"\S* copy\(", text)
     aliased = {int(out): int(arg) for out, arg in re.findall(

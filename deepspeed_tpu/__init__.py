@@ -5,6 +5,9 @@ Public surface parity with reference deepspeed/__init__.py: ``initialize()``,
 types, ops. Internals are JAX/XLA/pjit/Pallas over a device mesh — no
 torch, no NCCL.
 """
+import time as _time
+_IMPORT_START_S = _time.perf_counter()
+
 from .version import __version__, __version_info__
 
 from .utils.distributed import init_distributed
@@ -12,6 +15,9 @@ from .utils.logging import logger, log_dist
 from .runtime.config import DeepSpeedConfig, DeepSpeedConfigError
 from .runtime.activation_checkpointing import checkpointing
 from . import zero
+from .utils.annotate import (engine_tag, new_setup_row, record_setup_row,
+                             setup_span)
+from .utils.compile_cache import listen as _listen
 
 try:
     from .git_version_info import git_hash as __git_hash__, \
@@ -34,47 +40,53 @@ def initialize(args=None, model=None, optimizer=None, model_parameters=None,
     flax module instance paired with params via ``model_parameters``, or a
     :class:`deepspeed_tpu.pipe.PipelineModule` for pipeline parallelism.
     """
-    from .runtime.engine import DeepSpeedEngine
-    try:
-        from .runtime.pipe.module import PipelineModule
-        from .runtime.pipe.engine import PipelineEngine
-    except ImportError:  # pipeline stack not built yet
-        PipelineModule = ()
-        PipelineEngine = None
+    # the whole of it is the start-up record's ``setup.engine``
+    # (docs/telemetry.md, "Start-up record")
+    with setup_span("setup.engine", kind="train",
+                    engine=engine_tag("train")):
+        from .runtime.engine import DeepSpeedEngine
+        try:
+            from .runtime.pipe.module import PipelineModule
+            from .runtime.pipe.engine import PipelineEngine
+        except ImportError:  # pipeline stack not built yet
+            PipelineModule = ()
+            PipelineEngine = None
 
-    assert model is not None, "deepspeed.initialize requires a model"
+        assert model is not None, "deepspeed.initialize requires a model"
 
-    log_dist("DeepSpeedTPU info: version={}".format(__version__), ranks=[0])
+        log_dist("DeepSpeedTPU info: version={}".format(__version__),
+                 ranks=[0])
 
-    if dist_init_required is None or dist_init_required:
-        init_distributed()
+        if dist_init_required is None or dist_init_required:
+            init_distributed()
 
-    if config is None and config_params is not None:
-        config = config_params
+        if config is None and config_params is not None:
+            config = config_params
 
-    if not isinstance(model, PipelineModule):
-        engine = DeepSpeedEngine(args=args,
-                                 model=model,
-                                 optimizer=optimizer,
-                                 model_parameters=model_parameters,
-                                 training_data=training_data,
-                                 lr_scheduler=lr_scheduler,
-                                 mpu=mpu,
-                                 dist_init_required=dist_init_required,
-                                 collate_fn=collate_fn,
-                                 config_params=config)
-    else:
-        assert mpu is None, "mpu must be None with pipeline parallelism"
-        engine = PipelineEngine(args=args,
-                                model=model,
-                                optimizer=optimizer,
-                                model_parameters=model_parameters,
-                                training_data=training_data,
-                                lr_scheduler=lr_scheduler,
-                                mpu=model.mpu(),
-                                dist_init_required=dist_init_required,
-                                collate_fn=collate_fn,
-                                config_params=config)
+        if not isinstance(model, PipelineModule):
+            engine = DeepSpeedEngine(args=args,
+                                     model=model,
+                                     optimizer=optimizer,
+                                     model_parameters=model_parameters,
+                                     training_data=training_data,
+                                     lr_scheduler=lr_scheduler,
+                                     mpu=mpu,
+                                     dist_init_required=dist_init_required,
+                                     collate_fn=collate_fn,
+                                     config_params=config)
+        else:
+            assert mpu is None, "mpu must be None with pipeline parallelism"
+            engine = PipelineEngine(args=args,
+                                    model=model,
+                                    optimizer=optimizer,
+                                    model_parameters=model_parameters,
+                                    training_data=training_data,
+                                    lr_scheduler=lr_scheduler,
+                                    mpu=model.mpu(),
+                                    dist_init_required=dist_init_required,
+                                    collate_fn=collate_fn,
+                                    config_params=config)
+    log_dist(engine.startup_line(), ranks=[0])
 
     return_items = [engine, engine.optimizer, engine.training_dataloader,
                     engine.lr_scheduler]
@@ -116,34 +128,41 @@ def init_inference(model=None, config=None, mp_size=1, mesh=None,
     spec-verify programs before the engine is returned — findings warn,
     or raise when the config sets ``analysis.strict``.
     """
-    from .inference.engine import InferenceEngine
+    with setup_span("setup.engine", kind="inference",
+                    engine=engine_tag("inference")):
+        from .inference.engine import InferenceEngine
 
-    assert model is not None, "deepspeed.init_inference requires a model"
+        assert model is not None, "deepspeed.init_inference requires a model"
 
-    params = getattr(model, "params", None)
-    if replace_method and isinstance(params, dict):
-        tree = params.get("params", params)
-        if isinstance(tree, dict) and "transformer" in tree:
-            from .module_inject import (hf_gpt2_to_gpt2_params,
-                                        HFGPT2LayerPolicy)
-            model.params = hf_gpt2_to_gpt2_params(
-                params, policy=injection_policy or HFGPT2LayerPolicy)
+        params = getattr(model, "params", None)
+        if replace_method and isinstance(params, dict):
+            tree = params.get("params", params)
+            if isinstance(tree, dict) and "transformer" in tree:
+                from .module_inject import (hf_gpt2_to_gpt2_params,
+                                            HFGPT2LayerPolicy)
+                with setup_span("setup.params"):
+                    model.params = hf_gpt2_to_gpt2_params(
+                        params,
+                        policy=injection_policy or HFGPT2LayerPolicy)
 
-    log_dist("DeepSpeedTPU inference info: version={}".format(__version__),
-             ranks=[0])
+        log_dist("DeepSpeedTPU inference info: version={}".format(
+            __version__), ranks=[0])
 
-    if mesh is None and mp_size > 1:
-        from .parallel.topology import build_mesh
-        import jax
-        assert jax.device_count() % mp_size == 0, \
-            "mp_size {} does not divide device count {}".format(
-                mp_size, jax.device_count())
-        mesh = build_mesh(data=jax.device_count() // mp_size, model=mp_size)
+        if mesh is None and mp_size > 1:
+            from .parallel.topology import build_mesh
+            import jax
+            assert jax.device_count() % mp_size == 0, \
+                "mp_size {} does not divide device count {}".format(
+                    mp_size, jax.device_count())
+            mesh = build_mesh(data=jax.device_count() // mp_size,
+                              model=mp_size)
 
-    engine = InferenceEngine(model, config=config, mesh=mesh, dtype=dtype,
-                             seed=seed, draft_model=draft_model)
-    if audit:
-        engine.audit()
+        engine = InferenceEngine(model, config=config, mesh=mesh,
+                                 dtype=dtype, seed=seed,
+                                 draft_model=draft_model)
+        if audit:
+            engine.audit()
+    log_dist(engine.startup_line(), ranks=[0])
     return engine
 
 
@@ -166,3 +185,11 @@ def add_config_arguments(parser):
     (reference __init__.py:199)."""
     parser = _add_core_arguments(parser)
     return parser
+
+
+# the start-up record (docs/telemetry.md, "Start-up record"): what JAX
+# reports of every program's making is booked from here on, and the
+# import itself is the record's first row
+_listen()
+record_setup_row(new_setup_row("setup.import", _IMPORT_START_S,
+                               _time.perf_counter()))

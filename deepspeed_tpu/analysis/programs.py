@@ -420,25 +420,29 @@ def _unjitted_prefill(engine, bucket, greedy, top_k):
     jit cache (the audit must not inflate compile_stats or the trace
     registry)."""
     fns, stats = engine._prefill_fns, dict(engine.compile_stats)
-    tele = engine.telemetry
-    engine._prefill_fns, engine.telemetry = {}, None
+    tele, rows = engine.telemetry, engine._first_calls
+    engine._prefill_fns, engine.telemetry, engine._first_calls = {}, None, []
     try:
         fn = engine._get_prefill_fn(bucket, greedy, top_k)
     finally:
         engine._prefill_fns = fns
         engine.compile_stats = stats
         engine.telemetry = tele
+        engine._first_calls_over(discard=True)   # nor the start-up record
+        engine._first_calls = rows
     return fn.__wrapped__
 
 
 def _unjitted_decode(engine, greedy, top_k, width):
     fns, stats = engine._decode_fns, dict(engine.compile_stats)
-    tele = engine.telemetry
-    engine._decode_fns, engine.telemetry = {}, None
+    tele, rows = engine.telemetry, engine._first_calls
+    engine._decode_fns, engine.telemetry, engine._first_calls = {}, None, []
     try:
         fn = engine._get_decode_fn(greedy, top_k, width=width)
     finally:
         engine._decode_fns = fns
         engine.compile_stats = stats
         engine.telemetry = tele
+        engine._first_calls_over(discard=True)   # nor the start-up record
+        engine._first_calls = rows
     return fn.__wrapped__

@@ -40,8 +40,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..runtime.executor.jit import jit_program
-from ..utils.annotate import annotate
+from ..runtime.executor.jit import (first_call, first_call_over,
+                                    jit_program)
+from ..utils.annotate import (annotate, engine_tag, setup_span,
+                              startup_line, startup_report)
 from ..utils.logging import logger
 from .config import DeepSpeedInferenceConfig
 from .decoder import decoder_of, refuse_latent, refuse_recurrent
@@ -95,6 +97,12 @@ class InferenceEngine:
     def __init__(self, model, config=None, mesh=None, dtype=None, seed=0,
                  draft_model=None):
         from ..runtime.model import as_model
+        # this engine's rows of the start-up record (docs/telemetry.md,
+        # "Start-up record") carry it; ``launches`` is their ``step``;
+        # ``_first_calls``: the rows of programs made and not yet run
+        self.startup_tag = engine_tag("inference")
+        self.launches = 0
+        self._first_calls = []
         self.module = as_model(model)
         self.decoder = decoder_of(model, self.module)
         model_config = self.decoder.config
@@ -149,8 +157,13 @@ class InferenceEngine:
         self.num_slots = ic.max_batch_size
         self.prefill_buckets = ic.resolve_buckets(self.max_seq_len)
 
-        self.params = self._place_params(
-            self.decoder.serving_params(self.module.params, self.dtype))
+        with setup_span("setup.params", engine=self.startup_tag) as attrs:
+            self.params = self._place_params(
+                self.decoder.serving_params(self.module.params,
+                                            self.dtype))
+            leaves = jax.tree_util.tree_leaves(self.params)
+            attrs.update(leaves=len(leaves),
+                         bytes=sum(int(x.nbytes) for x in leaves))
 
         # ------------------------------------------------- KV cache layout
         spec = self.decoder.cache_spec()
@@ -182,51 +195,55 @@ class InferenceEngine:
         if ic.fleet_role is not None:
             refuse_latent(spec, "the fleet's page hand-off "
                           "(inference.fleet)")
-        # per-slot recurrent state, a pool of its own beside the pages
-        # (None for a model whose pages are its whole state)
-        self.state = StatePool.allocate(spec.state, self.num_slots) \
-            if spec.state else None
-        if self.kv_layout == "paged":
-            self.max_pages = -(-self.max_seq_len // self.page_size)
-            num_pages = ic.resolve_num_pages(self.num_slots,
-                                             self.max_seq_len)
-            self.kv = PagedKVCache.allocate(
-                num_pages, spec.kv_layers, spec.kv_heads, self.page_size,
-                spec.d_head, self.dtype, mesh=mesh, lanes=spec.page_lanes)
-            # what one cached token costs, pad lanes included: a reader
-            # of the pool's counters need not know the model
-            self.kv_token_bytes = self.kv.token_bytes
-            self.allocator = PageAllocator(num_pages)
-            # per-slot logical->physical map; GARBAGE_PAGE everywhere a
-            # slot has no allocation (jit writes there are redirected
-            # and reads position-masked)
-            self.page_tables = np.full((self.num_slots, self.max_pages),
-                                       GARBAGE_PAGE, np.int32)
-            self.page_counts = np.zeros((self.num_slots,), np.int32)
-            # pages matched at admission time per slot, so the first-
-            # chunk extension match knows where to resume
-            self._admit_matched = {}
-            self.prefix_cache = (
-                PrefixCache(self.allocator, self.page_size)
-                if ic.prefix_caching else None)
-        else:
-            self.max_pages = 0
-            self.kv = KVCache.allocate(
-                self.num_slots, spec.kv_layers, spec.kv_heads,
-                self.max_seq_len, spec.d_head, self.dtype, mesh=mesh)
-            self.kv_token_bytes = self.kv.nbytes // (
-                self.num_slots * self.max_seq_len)
-            self.allocator = None
-            self.page_tables = None
-            self.page_counts = None
-            self.prefix_cache = None
+        with setup_span("setup.cache", engine=self.startup_tag) as attrs:
+            # per-slot recurrent state, a pool of its own beside the pages
+            # (None for a model whose pages are its whole state)
+            self.state = StatePool.allocate(spec.state, self.num_slots) \
+                if spec.state else None
+            if self.kv_layout == "paged":
+                self.max_pages = -(-self.max_seq_len // self.page_size)
+                num_pages = ic.resolve_num_pages(self.num_slots,
+                                                 self.max_seq_len)
+                self.kv = PagedKVCache.allocate(
+                    num_pages, spec.kv_layers, spec.kv_heads, self.page_size,
+                    spec.d_head, self.dtype, mesh=mesh, lanes=spec.page_lanes)
+                # what one cached token costs, pad lanes included: a reader
+                # of the pool's counters need not know the model
+                self.kv_token_bytes = self.kv.token_bytes
+                self.allocator = PageAllocator(num_pages)
+                # per-slot logical->physical map; GARBAGE_PAGE everywhere a
+                # slot has no allocation (jit writes there are redirected
+                # and reads position-masked)
+                self.page_tables = np.full((self.num_slots, self.max_pages),
+                                           GARBAGE_PAGE, np.int32)
+                self.page_counts = np.zeros((self.num_slots,), np.int32)
+                # pages matched at admission time per slot, so the first-
+                # chunk extension match knows where to resume
+                self._admit_matched = {}
+                self.prefix_cache = (
+                    PrefixCache(self.allocator, self.page_size)
+                    if ic.prefix_caching else None)
+            else:
+                self.max_pages = 0
+                self.kv = KVCache.allocate(
+                    self.num_slots, spec.kv_layers, spec.kv_heads,
+                    self.max_seq_len, spec.d_head, self.dtype, mesh=mesh)
+                self.kv_token_bytes = self.kv.nbytes // (
+                    self.num_slots * self.max_seq_len)
+                self.allocator = None
+                self.page_tables = None
+                self.page_counts = None
+                self.prefix_cache = None
+            attrs["bytes"] = int(self.kv.nbytes) + (
+                int(self.state.nbytes) if self.state else 0)
 
         # paged-attention decode read path (docs/pallas_kernels.md):
         # resolved once at engine build; the DECODE program family runs
         # the Pallas page-walk kernel when "pallas", prefill and the
         # slot layout always keep the XLA oracle path
-        self.paged_attention_kernel = \
-            self._resolve_paged_attention_kernel()
+        with setup_span("setup.kernels", engine=self.startup_tag):
+            self.paged_attention_kernel = \
+                self._resolve_paged_attention_kernel()
 
         # host mirror of each slot's live length (tokens whose K/V are in
         # the cache); the scheduler owns slot assignment on top of this
@@ -254,9 +271,12 @@ class InferenceEngine:
                 assert draft_model is not None, \
                     "inference.speculative.method 'model' needs " \
                     "init_inference(..., draft_model=<small gpt2 Model>)"
-                self.drafter = ModelDrafter(
-                    draft_model, self.num_slots, self.max_seq_len,
-                    self.dtype, mesh=mesh)
+                with setup_span("setup.cache",
+                                engine=self.startup_tag) as attrs:
+                    self.drafter = ModelDrafter(
+                        draft_model, self.num_slots, self.max_seq_len,
+                        self.dtype, mesh=mesh, engine=self)
+                    attrs["bytes"] = int(self.drafter.kv.nbytes)
             else:
                 from .speculative import NGramDrafter
                 self.drafter = NGramDrafter(ic.spec_ngram_max,
@@ -306,6 +326,18 @@ class InferenceEngine:
                 " spec_k={} drafter={}".format(
                     self.spec_k, type(self.drafter).__name__)
                 if self.drafter is not None else ""))
+
+    def startup_report(self):
+        """This engine's rows of the start-up record (docs/telemetry.md,
+        "Start-up record"): its ``setup.engine`` and phases, and one
+        ``setup.program`` row for each program that has run."""
+        if self._first_calls:
+            self.wait()
+            self._first_calls_over()
+        return startup_report(self.startup_tag)
+
+    def startup_line(self):
+        return startup_line(self.startup_tag)
 
     def telemetry_snapshot(self):
         """Rolling serving aggregate (occupancy/queue-depth p50/p95,
@@ -494,6 +526,12 @@ class InferenceEngine:
         a span of its name in the profiler's trace, with the attributes
         the decoder makes of it, and ``last_counters`` for the
         scheduler's metrics."""
+        self.launches += 1
+        if self._first_calls:
+            # the tokens are on the host: a program this launch ran for
+            # the first time has run (docs/telemetry.md, "Start-up
+            # record"; nothing wraps the call itself)
+            self._first_calls_over()
         self.last_counters = {
             name: self.decoder.counter_attrs(name, value)
             for name, value in zip(self.counter_names, values)}
@@ -587,15 +625,31 @@ class InferenceEngine:
         # the function's name is the program's in a profiler trace
         # (module `jit_prefill`): a contract, pinned by a test. Every
         # cache buffer is donated and comes back in place
-        fn = jit_program(prefill,
-                         donate=tuple(range(1, 1 + n_kv + n_state)))
-        self._prefill_fns[key] = fn
-        self.compile_stats["prefill_traces"] += 1
+        return self._new_program(
+            self._prefill_fns, key, "prefill",
+            jit_program(prefill,
+                        donate=tuple(range(1, 1 + n_kv + n_state))))
+
+    def _new_program(self, cache, key, family, fn):
+        """The one intake of a serving program just made: into its
+        cache so that its first call writes the ``setup.program`` row
+        (docs/telemetry.md, "Start-up record"), into ``compile_stats``,
+        and into the compile observatory, where every new trace is a
+        distinct program and an unbounded bucket list shows up as a
+        recompile storm."""
+        self.compile_stats[family + "_traces"] += 1
         if self.telemetry is not None:
-            # compile observatory: every new trace is a distinct program;
-            # an unbounded bucket list shows up as a recompile storm
-            self.telemetry.programs.observe_trace("prefill", key)
+            self.telemetry.programs.observe_trace(family, key)
+        program = "verify" if family == "decode" and key[0] > 1 else family
+        cache[key] = fn
+        self._first_calls.append(first_call(
+            fn, program, key, self.startup_tag, self.launches))
         return fn
+
+    def _first_calls_over(self, discard=False):
+        for opened in self._first_calls:
+            first_call_over(opened, discard=discard)
+        self._first_calls = []
 
     def _get_decode_fn(self, greedy, top_k, width=1):
         """The fused all-slot decode program: ``width`` new tokens per
@@ -673,13 +727,10 @@ class InferenceEngine:
         # the function's name is the program's in a profiler trace:
         # module `jit_decode`, and its Mosaic call `%decode.N`, by which
         # the benchmark finds the paged kernel. Pinned by a test
-        fn = jit_program(decode,
-                         donate=tuple(range(1, 1 + n_kv + n_state)))
-        self._decode_fns[key] = fn
-        self.compile_stats["decode_traces"] += 1
-        if self.telemetry is not None:
-            self.telemetry.programs.observe_trace("decode", key)
-        return fn
+        return self._new_program(
+            self._decode_fns, key, "decode",
+            jit_program(decode,
+                        donate=tuple(range(1, 1 + n_kv + n_state))))
 
     def _next_rng(self, greedy):
         """The key a launch hands its sampler. A greedy program never
@@ -826,6 +877,9 @@ class InferenceEngine:
                 return tuple(p.at[dst].set(p[src]) for p in pools)
             self._page_copy_fn = jit_program(
                 copy, donate=tuple(range(2, 2 + len(pools))))
+            self._first_calls.append(first_call(
+                self._page_copy_fn, "page_copy", len(pools),
+                self.startup_tag, self.launches))
         self.kv.update(self._page_copy_fn(jnp.int32(src), jnp.int32(dst),
                                           *pools))
 

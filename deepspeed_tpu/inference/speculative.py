@@ -26,7 +26,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..runtime.executor.jit import jit_program
+from ..runtime.executor.jit import first_call, jit_program
 
 
 class NGramDrafter:
@@ -79,8 +79,12 @@ class ModelDrafter:
 
     needs_model = True
 
-    def __init__(self, model, num_slots, max_seq_len, dtype, mesh=None):
+    def __init__(self, model, num_slots, max_seq_len, dtype, mesh=None,
+                 engine=None):
         from ..runtime.model import as_model
+        # the serving engine whose start-up record the drafter's
+        # programs are rows of (``draft.prefill``, ``draft.propose``)
+        self.engine = engine
         from .decoder import refuse_recurrent
         from .kv_cache import KVCache
         self.module = as_model(model)
@@ -133,8 +137,15 @@ class ModelDrafter:
                 v_cache, v_row, slot, axis=0)
             return k_cache, v_cache
 
-        fn = jit_program(prefill, donate=(1, 2))
-        self._prefill_fns[bucket] = fn
+        return self._new_program(self._prefill_fns, bucket, "draft.prefill",
+                                 jit_program(prefill, donate=(1, 2)))
+
+    def _new_program(self, cache, key, program, fn):
+        cache[key] = fn
+        if self.engine is not None:  # (a drafter on its own: no record)
+            self.engine._first_calls.append(first_call(
+                fn, program, key, self.engine.startup_tag,
+                self.engine.launches))
         return fn
 
     def _get_propose_fn(self, k):
@@ -166,9 +177,8 @@ class ModelDrafter:
                 length=k + 1)
             return k_cache, v_cache, drafts.T[:, :k]    # (slots, k)
 
-        fn = jit_program(propose, donate=(1, 2))
-        self._propose_fns[k] = fn
-        return fn
+        return self._new_program(self._propose_fns, k, "draft.propose",
+                                 jit_program(propose, donate=(1, 2)))
 
     # ------------------------------------------------------------- serving
 

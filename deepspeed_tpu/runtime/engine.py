@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..parallel.topology import MeshGrid, DATA_AXIS, build_mesh
+from ..utils.annotate import (engine_tag, setup_span, startup_line,
+                              startup_report)
 from ..utils.logging import logger, log_dist
 from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 from . import checkpointing as ckpt
@@ -73,6 +75,20 @@ def _unique_shard_indices(arr):
     return out
 
 
+def _tree_size(*trees):
+    """``{leaves, bytes}`` of some trees of arrays, for a set-up span."""
+    leaves = jax.tree_util.tree_leaves(trees)
+    return {"leaves": len(leaves),
+            "bytes": sum(int(getattr(x, "nbytes", 0)) for x in leaves)}
+
+
+def _program_name(key):
+    """A step program's ``program`` in the start-up record: the
+    ``_jit_cache`` key's first word (``micro``, ``apply``,
+    ``fused_train``, ...); the whole key is the row's ``key``."""
+    return str(key[0]) if isinstance(key, tuple) and key else str(key)
+
+
 class DeepSpeedEngine:
     """Wraps a model to provide distributed data-parallel (+ZeRO) training on
     a TPU mesh with the DeepSpeed train API."""
@@ -86,6 +102,10 @@ class DeepSpeedEngine:
                  model_parameters=None, training_data=None, lr_scheduler=None,
                  mpu=None, dist_init_required=None, collate_fn=None,
                  config_params=None, dont_change_device=False, mesh=None):
+        # this engine's rows of the start-up record (docs/telemetry.md,
+        # "Start-up record") carry it
+        self.startup_tag = engine_tag("train")
+        self._first_call_row = None   # of a program made and not yet run
         self.client_optimizer = optimizer
         self.client_lr_scheduler = lr_scheduler
         self.training_data = training_data
@@ -852,9 +872,16 @@ class DeepSpeedEngine:
 
     def _init_state(self):
         """Place params/master/opt/grad-accum arrays with ZeRO shardings."""
-        plan = self.zero_plan
         self.host_state = None
         self.stream_runner = None
+        if self.zero_params_offload() or self.zero_cpu_offload():
+            # the master and the moments stay on the host: one phase
+            with setup_span("setup.optimizer", engine=self.startup_tag):
+                self._init_offload_state(self.zero_plan)
+        else:
+            self._init_device_state(self.zero_plan)
+
+    def _init_offload_state(self, plan):
         if self.zero_params_offload():
             # Streamed parameter offload (cpu_offload_params): the fp32
             # master + Adam moments live in HOST memory like classic
@@ -961,97 +988,105 @@ class DeepSpeedEngine:
             }
             self._init_qg_error(acc_grads)
             self.model.params = None
-            return
 
+    def _init_device_state(self, plan):
         # copy=True: jnp.asarray of same-dtype input is a VIEW of the
         # caller's arrays; the jitted step donates engine state, so an
         # aliased user array would be invalidated ("Buffer has been deleted
         # or donated") if the caller builds a second engine from it
-        params_f32 = jax.tree_util.tree_map(
-            lambda p: jnp.array(p, dtype=jnp.float32, copy=True),
-            self.model.params)
+        with setup_span("setup.params", engine=self.startup_tag) as attrs:
+            params_f32 = jax.tree_util.tree_map(
+                lambda p: jnp.array(p, dtype=jnp.float32, copy=True),
+                self.model.params)
 
-        param_sh = plan.tree_shardings(params_f32, "param")
-        master_sh = plan.tree_shardings(params_f32, "master")
-        grad_sh = plan.tree_shardings(params_f32, "grad")
+            param_sh = plan.tree_shardings(params_f32, "param")
+            master_sh = plan.tree_shardings(params_f32, "master")
+            grad_sh = plan.tree_shardings(params_f32, "grad")
 
-        compute_params = jax.tree_util.tree_map(
-            lambda p, s: jax.device_put(jnp.asarray(p, self.compute_dtype), s),
-            params_f32, param_sh)
-
-        if self.mixed_precision:
-            master = jax.tree_util.tree_map(
-                lambda p, s: jax.device_put(p, s), params_f32, master_sh)
-        else:
-            master = None
-
-        opt_target = master if self.mixed_precision else compute_params
-        opt_state = self.optimizer.init_state(opt_target)
-        # all per-param moments/buffers live with the master shards; state
-        # shapes may differ from param shapes (e.g. OnebitAdam's flat error
-        # buffers), so shardings come from each subtree's own leaves —
-        # unless the optimizer declares a placement (state_placements():
-        # OnebitAdam keeps the fused momentum replicated and the error
-        # tensors per-worker)
-        opt_state = {
-            key: val if key == "step" else jax.tree_util.tree_map(
-                lambda m, s: jax.device_put(m, s), val,
-                self._opt_state_shardings(key, val))
-            for key, val in opt_state.items()
-        }
-        acc_dtype = jnp.float32
-        if self._config.grad_accum_dtype == "bf16":
-            # (the cpu_offload path warned and returned above)
-            if self.gradient_accumulation_steps() > 1:
-                logger.warning(
-                    "grad_accum_dtype=bf16 with gradient_accumulation_"
-                    "steps=%d: bf16 summation across micro-steps is "
-                    "lossy (it is exact only at 1 step)",
-                    self.gradient_accumulation_steps())
-            elif self.compute_dtype != jnp.bfloat16:
-                logger.warning(
-                    "grad_accum_dtype=bf16 truncates %s gradients: "
-                    "storage is lossless only when the compute dtype "
-                    "is bf16 too", jnp.dtype(self.compute_dtype).name)
-            acc_dtype = jnp.bfloat16
-        if self._onebit_mode:
-            # per-worker LOCAL gradient accumulators: a leading (world,)
-            # dim sharded one row per device — the local-grad micro step
-            # writes its own row, the 1-bit exchange consumes them. The
-            # accumulation dtype stays fp32 (the exchange math is fp32).
-            if acc_dtype != jnp.float32:
-                logger.warning(
-                    "grad_accum_dtype=bf16 ignored under OneBitAdam: the "
-                    "compressed exchange consumes fp32 local grads")
-            w = self.dp_world_size
-            stacked_sh = self._stacked_grad_sharding()
-            acc_grads = jax.tree_util.tree_map(
-                lambda p: jax.device_put(
-                    jnp.zeros((w,) + p.shape, dtype=jnp.float32),
-                    stacked_sh), params_f32)
-        else:
-            acc_grads = jax.tree_util.tree_map(
+            compute_params = jax.tree_util.tree_map(
                 lambda p, s: jax.device_put(
-                    jnp.zeros(p.shape, dtype=acc_dtype), s),
-                params_f32, grad_sh)
+                    jnp.asarray(p, self.compute_dtype), s),
+                params_f32, param_sh)
 
-        # the scalar leaves are committed replicated on the mesh, as every
-        # step program returns them: left uncommitted, the SECOND call of
-        # each step program sees new input types and compiles it all again
-        replicated = NamedSharding(self.mesh, P())
-        opt_state["step"] = jax.device_put(opt_state["step"], replicated)
-        self.state = {
-            "params": compute_params,
-            "master": master,
-            "opt": opt_state,
-            "acc_grads": acc_grads,
-            "scaler": jax.device_put(
-                ls.loss_scaler_from_config(self._config), replicated),
-            # device-resident skipped-step counter: keeps skipped_steps exact
-            # even when the overflow flag is only fetched periodically
-            "skip_count": jax.device_put(jnp.int32(0), replicated),
-        }
-        self._init_qg_error(acc_grads)
+            if self.mixed_precision:
+                master = jax.tree_util.tree_map(
+                    lambda p, s: jax.device_put(p, s), params_f32,
+                    master_sh)
+            else:
+                master = None
+            attrs.update(_tree_size(compute_params, master))
+
+        with setup_span("setup.optimizer",
+                        engine=self.startup_tag) as attrs:
+            opt_target = master if self.mixed_precision else compute_params
+            opt_state = self.optimizer.init_state(opt_target)
+            # all per-param moments/buffers live with the master shards; state
+            # shapes may differ from param shapes (e.g. OnebitAdam's flat error
+            # buffers), so shardings come from each subtree's own leaves —
+            # unless the optimizer declares a placement (state_placements():
+            # OnebitAdam keeps the fused momentum replicated and the error
+            # tensors per-worker)
+            opt_state = {
+                key: val if key == "step" else jax.tree_util.tree_map(
+                    lambda m, s: jax.device_put(m, s), val,
+                    self._opt_state_shardings(key, val))
+                for key, val in opt_state.items()
+            }
+            acc_dtype = jnp.float32
+            if self._config.grad_accum_dtype == "bf16":
+                # (the cpu_offload path warned and returned above)
+                if self.gradient_accumulation_steps() > 1:
+                    logger.warning(
+                        "grad_accum_dtype=bf16 with gradient_accumulation_"
+                        "steps=%d: bf16 summation across micro-steps is "
+                        "lossy (it is exact only at 1 step)",
+                        self.gradient_accumulation_steps())
+                elif self.compute_dtype != jnp.bfloat16:
+                    logger.warning(
+                        "grad_accum_dtype=bf16 truncates %s gradients: "
+                        "storage is lossless only when the compute dtype "
+                        "is bf16 too", jnp.dtype(self.compute_dtype).name)
+                acc_dtype = jnp.bfloat16
+            if self._onebit_mode:
+                # per-worker LOCAL gradient accumulators: a leading (world,)
+                # dim sharded one row per device — the local-grad micro step
+                # writes its own row, the 1-bit exchange consumes them. The
+                # accumulation dtype stays fp32 (the exchange math is fp32).
+                if acc_dtype != jnp.float32:
+                    logger.warning(
+                        "grad_accum_dtype=bf16 ignored under OneBitAdam: the "
+                        "compressed exchange consumes fp32 local grads")
+                w = self.dp_world_size
+                stacked_sh = self._stacked_grad_sharding()
+                acc_grads = jax.tree_util.tree_map(
+                    lambda p: jax.device_put(
+                        jnp.zeros((w,) + p.shape, dtype=jnp.float32),
+                        stacked_sh), params_f32)
+            else:
+                acc_grads = jax.tree_util.tree_map(
+                    lambda p, s: jax.device_put(
+                        jnp.zeros(p.shape, dtype=acc_dtype), s),
+                    params_f32, grad_sh)
+
+            # the scalar leaves are committed replicated on the mesh, as every
+            # step program returns them: left uncommitted, the SECOND call of
+            # each step program sees new input types and compiles it all again
+            replicated = NamedSharding(self.mesh, P())
+            opt_state["step"] = jax.device_put(opt_state["step"], replicated)
+            self.state = {
+                "params": compute_params,
+                "master": master,
+                "opt": opt_state,
+                "acc_grads": acc_grads,
+                "scaler": jax.device_put(
+                    ls.loss_scaler_from_config(self._config), replicated),
+                # device-resident skipped-step counter: keeps skipped_steps
+                # exact even when the overflow flag is only fetched
+                # periodically
+                "skip_count": jax.device_put(jnp.int32(0), replicated),
+            }
+            self._init_qg_error(acc_grads)
+            attrs.update(_tree_size(opt_state, acc_grads))
         del params_f32
         self.model.params = None  # single source of truth is the state
 
@@ -1554,11 +1589,47 @@ class DeepSpeedEngine:
         return apply_step
 
     def _get_jit(self, key, builder, donate=(), **jit_kwargs):
+        if self._first_call_row is not None:
+            # the engine asks for its next program: the one it made
+            # last has been called
+            self._first_call_over()
         if key not in self._jit_cache:
             from .executor.jit import jit_program
-            self._jit_cache[key] = jit_program(builder(), donate=donate,
-                                               **jit_kwargs)
+            self._jit_cache[key] = self._first_call(
+                _program_name(key), key,
+                jit_program(builder(), donate=donate, **jit_kwargs))
         return self._jit_cache[key]
+
+    def _first_call(self, program, key, fn):
+        """A step program just made enters the start-up record
+        (docs/telemetry.md, "Start-up record"): its ``setup.program``
+        row stays open until the engine asks for another program or the
+        step ends, fenced on the engine's state. Nothing wraps the call
+        itself. -> ``fn``."""
+        from .executor.jit import first_call
+        if self._first_call_row is not None:
+            self._first_call_over()
+        self._first_call_row = first_call(fn, program, key,
+                                          self.startup_tag,
+                                          self.global_steps)
+        return fn
+
+    def _first_call_over(self):
+        from .executor.jit import first_call_over
+        jax.block_until_ready(self.state)
+        first_call_over(self._first_call_row)
+        self._first_call_row = None
+
+    def startup_report(self):
+        """This engine's rows of the start-up record (docs/telemetry.md,
+        "Start-up record"): its ``setup.engine`` and phases, and one
+        ``setup.program`` row for each step program that has run."""
+        if self._first_call_row is not None:
+            self._first_call_over()
+        return startup_report(self.startup_tag)
+
+    def startup_line(self):
+        return startup_line(self.startup_tag)
 
     # -------------------------------------------------------------- telemetry
     def _check_memory_breakdown(self):
@@ -1627,15 +1698,10 @@ class DeepSpeedEngine:
             return cached
         from ..telemetry import costs_of_compiled
         try:
-            t0 = time.time()
             costs = costs_of_compiled(fn, *args)
-            price_wall = time.time() - t0
             flops = float(costs.get("flops", 0.0) or 0.0)
             # compile observatory: the registry keeps the FULL cost dict
-            # and the pricing wall (an honest compile-cost proxy on
-            # backends where pricing is an AOT compile)
-            self.telemetry.programs.price(key, costs,
-                                          price_wall_s=price_wall)
+            self.telemetry.programs.price(key, costs)
         except Exception as err:  # noqa: BLE001 - never perturb the step
             logger.info("telemetry: cost_analysis unavailable for %r (%s)",
                         key, err)
@@ -2077,6 +2143,9 @@ class DeepSpeedEngine:
         except BaseException as err:
             self._tele_crash("train_step", err)
             raise
+        finally:
+            if self._first_call_row is not None:
+                self._first_call_over()
 
     def _step_impl(self, lr_kwargs=None):
         if self.wall_clock_breakdown():
@@ -2405,6 +2474,9 @@ class DeepSpeedEngine:
         except BaseException as err:
             self._tele_crash("train_batch", err)
             raise
+        finally:
+            if self._first_call_row is not None:
+                self._first_call_over()
 
     def _train_batch_impl(self, data_iter=None, batch=None):
         self._step_path = "fused"

@@ -11,8 +11,16 @@ ISSUE 19 the baseline for that rule is EMPTY).
 mirrors and ``analysis/rules.py``'s donation audit reads — one spelling
 per program, checked end to end: the engine passes it here, the plan
 records it, the auditor verifies the jitted program honors it.
+
+It is also where a program just made enters the start-up record
+(:func:`first_call`, docs/telemetry.md "Start-up record"): the row is
+opened here and closed by the engine once the first call is over;
+nothing wraps the call, and the engine's cache holds the jitted
+function itself from the start.
 """
 import jax
+
+from ...utils.compile_cache import close_program_row, open_program_row
 
 
 def jit_program(fn, donate=(), **jit_kwargs):
@@ -25,3 +33,17 @@ def jit_program(fn, donate=(), **jit_kwargs):
     if donate:
         jit_kwargs["donate_argnums"] = tuple(donate)
     return jax.jit(fn, **jit_kwargs)
+
+
+def first_call(fn, program, key, engine, step):
+    """Open the ``setup.program`` row [``program``, ``key``: the
+    engine's cache key, ``engine``: its tag, ``step``: its count of
+    launches or steps now] of ``fn``, which :func:`jit_program` has just
+    returned and the engine is about to call for the first time. ->
+    what :func:`first_call_over` closes it with."""
+    return open_program_row(
+        program, key, engine, int(step),
+        getattr(getattr(fn, "__wrapped__", None), "__name__", None))
+
+
+first_call_over = close_program_row

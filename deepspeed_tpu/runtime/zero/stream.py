@@ -229,8 +229,9 @@ class StreamedOffloadRunner:
     def _jit(self, key, builder):
         if key not in self._jit_cache:
             from ..executor.jit import jit_program
-            self._jit_cache[key] = jit_program(
-                builder(), donate=STREAM_DONATE.get(key[0], ()))
+            self._jit_cache[key] = self.engine._first_call(
+                "stream." + str(key[0]), key, jit_program(
+                    builder(), donate=STREAM_DONATE.get(key[0], ())))
         return self._jit_cache[key]
 
     def _run(self, key, builder, *args):

@@ -3,13 +3,13 @@ run, fed from the ``engine._jit_priced`` seam (training; zero/stream.py
 rides the same seam) and the inference engine's prefill/decode trace
 caches.
 
-Per program it records the key, the XLA ``cost_analysis`` dict and the
-wall time spent pricing it (on backends that only expose costs on the
-compiled object that pricing IS an AOT compile, so the wall is an honest
-compile-cost proxy), the call count, and the recompile count — read from
-the jit function's own executable cache (``fn._cache_size()``) where the
-jax build exposes it, so a silent shape-driven recompile under a stable
-engine key is still counted.
+Per program it records the key, the XLA ``cost_analysis`` dict, the
+call count, and the recompile count — read from the jit function's own
+executable cache (``fn._cache_size()``) where the jax build exposes it,
+so a silent shape-driven recompile under a stable engine key is still
+counted. What a program's making COST (tracing, lowering, compile or
+load, by program) is the start-up record's to say
+(``engine.startup_report()``, docs/telemetry.md "Start-up record").
 
 Two anomaly detectors flag into ``flags`` (and warn loudly, once each):
 
@@ -103,7 +103,6 @@ class ProgramRegistry:
             "recompiles": 0,
             "flops": None,
             "cost_analysis": None,
-            "price_wall_s": None,
         }
 
     # ----------------------------------------------------------- intake
@@ -150,7 +149,7 @@ class ProgramRegistry:
             self._flag(finding.key, finding.message)
         return entry
 
-    def price(self, key, costs, price_wall_s=None):
+    def price(self, key, costs):
         """Attach the program's cost analysis (computed once by the
         telemetry flops cache) to its registry entry. May run before the
         first observe_call — it only fills pricing fields, never the
@@ -165,8 +164,6 @@ class ProgramRegistry:
         entry["cost_analysis"] = {str(k): float(v)
                                   for k, v in costs.items()
                                   if isinstance(v, (int, float))}
-        if price_wall_s is not None:
-            entry["price_wall_s"] = float(price_wall_s)
 
     # ---------------------------------------------------------- auditing
     def _bump_family(self, family):

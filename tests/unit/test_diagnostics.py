@@ -611,7 +611,13 @@ def test_program_registry_prices_engine_programs(tmp_path):
     micro = snap["programs"]["micro"]
     assert micro["calls"] == 3
     assert micro["flops"] > 0 and micro["cost_analysis"]["flops"] > 0
-    assert micro["price_wall_s"] is not None
+    # what the program's making cost is the start-up record's to say:
+    # one row for the micro program, with JAX's own seconds
+    rows = [row["attrs"] for row in engine.startup_report()["rows"]
+            if row["name"] == "setup.program"]
+    assert sorted(r["program"] for r in rows) == ["apply", "micro"]
+    row = next(r for r in rows if r["program"] == "micro")
+    assert row["step"] == 0 and row["compile_s"] > 0
     # the first call's fresh-state signature may legitimately differ
     # from the steady state's (one extra executable); a STABLE loop must
     # not keep recompiling

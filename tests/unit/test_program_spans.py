@@ -89,7 +89,7 @@ def traced(tmp_path_factory):
             found = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
                       dict(ev.stats)) for ev in line.events
                      if ev.name.startswith(("sched.", "engine.",
-                                            "timer."))]
+                                            "timer.", "setup."))]
             if found:
                 lines.append(sorted(found, key=lambda e: (e[1], -e[2])))
     return plain, inside, metrics, lines, left_behind, sched
@@ -129,6 +129,10 @@ def test_span_is_present_and_nested_as_drawn(traced, name):
 
 
 def test_no_other_program_span_names(traced):
+    """The serving step's names and no other: the ``setup.`` spans of
+    the start-up record are admitted only in a session that opens
+    before the engine is built (test_setup_record.py), and this one
+    opened after every program had run once."""
     assert {ev[0] for ev in traced[3][0]} == set(CONTRACT)
 
 
@@ -213,6 +217,9 @@ def test_the_timers_reach_annotate_without_the_telemetry_package():
             (0, alias.name) for node in ast.walk(tree)
             if isinstance(node, ast.Import) for alias in node.names}
 
-    assert imported(leaf) == {(0, "contextlib"), (0, "jax.profiler")}
+    assert imported(leaf) == {
+        (0, "contextlib"), (0, "jax.profiler"),
+        # the start-up record's rows: a lock, a clock, a copy for readers
+        (0, "copy"), (0, "threading"), (0, "time")}
     assert all(level < 2 for level, _ in imported(timer))
     assert spans_mod.annotate is leaf.annotate

@@ -14,8 +14,16 @@ at d_head<=128: 8K*128*4B*2 = 8 MB); the query axis is blocked via the grid
 and the key axis by an in-kernel fori_loop over VMEM slices. Backward
 follows the standard flash decomposition (dq accumulated across the k loop;
 dk/dv accumulated in VMEM scratch across the sequential TPU grid).
+
+What a kernel traces follows what the call can see (all static): the bias
+operand and its add exist only where the caller gave a ``mask_bias``; the
+compare against the sequence's end only where the sequence is no multiple
+of the key block; the causal compare only where ``causal``; and a scale
+that is a power of two goes onto the q block instead of onto the scores
+(`_scale_folds`).
 """
 import functools
+import math
 import os
 
 import jax
@@ -26,6 +34,78 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
+
+# What every pallas_call of this file asks of the chip's VMEM. XLA gives a
+# Mosaic call 16 MiB unless the call asks; a v5e core has 128 MiB. Sized
+# from the block sets the tables below choose, not from the chip: the
+# resident-dq backward at its widest (width 1280, sequence 1024, blocks
+# (256, 512)) holds 33 MiB of blocks and scratch by `_bwd_resident_vmem_bytes`,
+# and the compiler's own (Bq, Bk) float32 intermediates come on top; a test
+# holds the tables to three quarters of the limit.
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
+
+
+def _compiler_params():
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def _call_kernel(fn, *args):
+    return fn(*args)
+
+
+# CPython 3.12 keeps a thread's frames in chunks of 16 KiB, and a call
+# that does not fit the current chunk maps a new one and unmaps it on
+# return. Tracing a kernel body is thousands of calls some 40 frames
+# deep; where a chunk's end falls inside that span, every call across it
+# pays both system calls (PR 40 met this in Mosaic's lowering). On the
+# chip's host the training step's 48 kernel bodies then traced in 102 s
+# (the parent's in 50: it sat on such an end too) against 14 s with the
+# calls below (PR 41). A frame larger than a chunk is given a chunk of
+# its own, twice its size: the kernel's tracing starts there with 32 KiB
+# before the next end, whatever the depth it was called at. The frame is
+# made large by its declared stack size alone; the code is `fn(*args)`.
+_KERNEL_FRAME_SLOTS = 4096          # 32 KiB of 8-byte slots
+_call_kernel.__code__ = _call_kernel.__code__.replace(
+    co_stacksize=_KERNEL_FRAME_SLOTS)
+
+
+def _scale_folds(sm_scale):
+    """Whether ``sm_scale`` is a power of two. Multiplying by one is
+    exact in every float dtype, so the scale can go onto the (Bq, d) q
+    block in the operand's own dtype instead of onto every (Bq, Bk) block
+    of float32 scores, and scores, ds, dq and dk come out bit for bit
+    (1/8 at d_head 64; not at d_head 80 or 128, where it stays on the
+    scores)."""
+    return math.frexp(sm_scale)[0] == 0.5
+
+
+def _when_live(qi, ki, block_q, block_k, causal, fn):
+    """Run ``fn`` for the grid cell's (q block, k block) pair unless the
+    pair lies wholly above the causal diagonal."""
+    if causal:
+        pl.when(ki * block_k < (qi + 1) * block_q)(fn)
+    else:
+        fn()
+
+
+def _score_mask(qi, ki, *, block_q, block_k, causal, seq_len, keys=None):
+    """The (Bq, keys) mask of a block pair's scores (``keys``: the k
+    block's first keys, all ``block_k`` of them by default), or None where
+    every score counts: no compare is traced for a sequence that is a
+    multiple of ``block_k`` (no zero-padded k tail) nor for a non-causal
+    call."""
+    mask = None
+    shape = (block_q, keys or block_k)
+    if seq_len % block_k:
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        mask = k_pos < seq_len              # zero-padded k tail
+    if causal:
+        # q_pos >= k_pos, with the grid position on the scalar side
+        ahead = (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                 - jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+        under = ahead >= ki * block_k - qi * block_q
+        mask = under if mask is None else jnp.logical_and(mask, under)
+    return mask
 
 
 def _pad_kv(k, v, block_k):
@@ -49,48 +129,95 @@ def _num_visible(qi, block_q, block_k, num_k_blocks, causal):
     return jnp.minimum(visible, num_k_blocks)
 
 
+def _head_group(num_heads, d_head):
+    """Heads a 128-lane tile holds whole (2 at d_head 64, 4 at 32): the
+    resident kernels take them together (`_tile_operands`). 1 where a
+    head fills a tile or more, where heads do not tile 128 lanes (d_head
+    80), or where the heads do not come out even."""
+    g = 128 // d_head if d_head < 128 and 128 % d_head == 0 else 1
+    return g if num_heads % g == 0 else 1
+
+
+def _tile_operands(tile, g, d_head, scale=None):
+    """A tile of ``g`` heads side by side -> (own, [the tile with every
+    lane but head j's zeroed, for j in range(g)]), ``own[j]`` the (1, g*d)
+    lane mask of head j. A head's matmul against the zeroed copy contracts
+    over the whole tile and costs the MXU the pass d_head lanes cost, and
+    every load and store is a whole tile (at d_head 64 every odd head's
+    own slice would start at lane 64). ``scale``: multiplied in, in
+    float32, on the way."""
+    x = tile if scale is None else tile.astype(jnp.float32) * scale
+    if g == 1:
+        return [None], [x.astype(tile.dtype)]
+    x = x.astype(jnp.float32)    # v5e's vector unit selects no bfloat16
+    head_of_lane = jax.lax.broadcasted_iota(
+        jnp.int32, (1, g * d_head), 1) // d_head
+    own = [head_of_lane == j for j in range(g)]
+    return own, [jnp.where(o, x, 0).astype(tile.dtype) for o in own]
+
+
 def _fwd_compute(q, load_kv, out_dtype, *, qi, sm_scale, block_q, block_k,
-                 num_k_blocks, causal, seq_len, load_bias=None):
+                 num_k_blocks, causal, seq_len, load_bias=None, d_head=None):
     """Online-softmax forward over one q block. ``load_kv(ki)`` returns the
-    ki-th (Bk, d) K/V slices — the only layout-dependent part, so the 3D
+    ki-th (Bk, w) K/V slices — the only layout-dependent part, so the 3D
     (bh, s, d) and 4D (b, s, h, d) kernels share this body.
     ``load_bias(ki)`` (optional) returns a (1, Bk) additive score bias —
-    the key-padding mask path."""
-    d = q.shape[-1]
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
+    the key-padding mask path.
+
+    ``d_head`` below ``q``'s width w: the slice is a tile of w / d_head
+    heads (`_tile_operands`), whose chains are independent in ONE loop
+    body, so that the MXU works on one head's matmuls under the other's
+    softmax; a head's p @ v yields all w lanes, of which its own are kept.
+    Returns (out (Bq, w), lse (Bq, heads in the tile))."""
+    w = q.shape[-1]
+    g = w // (d_head or w)
+    fold = _scale_folds(sm_scale)
+    own, q_of = _tile_operands(q, g, w // g, sm_scale if fold else None)
+
+    def lanes(per_head):
+        """(Bq, 1) values a head -> (Bq, w), each in its head's lanes."""
+        full = per_head[0]
+        for o, x in zip(own[1:], per_head[1:]):
+            full = jnp.where(o, x, full)
+        return full
 
     def body(ki, carry):
-        acc, m, l = carry
+        acc, ms, ls = carry
         k_blk, v_blk = load_kv(ki)
-        s_blk = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # (Bq, Bk)
-        if load_bias is not None:
-            s_blk = s_blk + load_bias(ki)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s_blk.shape, 1)
-        mask = k_pos < seq_len          # zero-padded k tail
-        if causal:
-            mask = jnp.logical_and(mask, q_pos >= k_pos)
-        s_blk = jnp.where(mask, s_blk, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s_blk, axis=-1, keepdims=True))
-        p = jnp.exp(s_blk - m_new)
-        corr = jnp.exp(m - m_new)
-        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return acc, m_new, l
+        mask = _score_mask(qi, ki, block_q=block_q, block_k=block_k,
+                           causal=causal, seq_len=seq_len)
+        new_ms, new_ls, corrs, pvs = [], [], [], []
+        for q_j, m, l in zip(q_of, ms, ls):
+            s_blk = jax.lax.dot_general(
+                q_j, k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)       # (Bq, Bk)
+            if not fold:
+                s_blk = s_blk * sm_scale
+            if load_bias is not None:
+                s_blk = s_blk + load_bias(ki)
+            if mask is not None:
+                s_blk = jnp.where(mask, s_blk, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s_blk, axis=-1, keepdims=True))
+            p = jnp.exp(s_blk - m_new)
+            corr = jnp.exp(m - m_new)
+            new_ms.append(m_new)
+            new_ls.append(l * corr + jnp.sum(p, axis=-1, keepdims=True))
+            corrs.append(corr)
+            pvs.append(jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))      # (Bq, w)
+        return acc * lanes(corrs) + lanes(pvs), tuple(new_ms), tuple(new_ls)
 
-    acc = jnp.zeros((block_q, d), jnp.float32)
-    m = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((block_q, 1), jnp.float32)
+    carry = (jnp.zeros((block_q, w), jnp.float32),
+             (jnp.full((block_q, 1), NEG_INF, jnp.float32),) * g,
+             (jnp.zeros((block_q, 1), jnp.float32),) * g)
     visible = _num_visible(qi, block_q, block_k, num_k_blocks, causal)
-    acc, m, l = jax.lax.fori_loop(0, visible, body, (acc, m, l))
+    acc, ms, ls = jax.lax.fori_loop(0, visible, body, carry)
 
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    return (acc / l_safe).astype(out_dtype), m + jnp.log(l_safe)
+    ls = [jnp.where(l == 0.0, 1.0, l) for l in ls]
+    lse = [m + jnp.log(l) for m, l in zip(ms, ls)]
+    return ((acc / lanes(ls)).astype(out_dtype),
+            lse[0] if g == 1 else jnp.concatenate(lse, axis=1))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, block_q,
@@ -108,21 +235,26 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, block_q,
     lse_ref[0] = lse                                     # (Bq, 1)
 
 
-def _fwd_kernel_packed_resident(q_ref, k_ref, v_ref, bias_ref, o_ref,
-                                lse_ref, *, sm_scale, block_q, block_k,
-                                num_k_blocks, causal, seq_len, num_heads,
-                                d_head):
+def _fwd_kernel_packed_resident(q_ref, k_ref, v_ref, *rest, sm_scale,
+                                block_q, block_k, num_k_blocks, causal,
+                                seq_len, num_heads, d_head, has_bias):
     """(b, s, h*d)-packed forward, whole K/V resident in VMEM: the fast
     path for ordinary sequence lengths. The k loop's online-softmax state
     lives in registers (no scratch round-trips), which measures ~3x faster
-    than the streaming variant at GPT-2 shapes; VMEM bounds it to roughly
-    s*h*d <= ~1M elements (seq 1024 at width 1024)."""
+    than the streaming variant at GPT-2 shapes. Heads go a 128-lane tile
+    at a time (`_head_group`). ``rest``: the bias ref where the caller
+    gave a ``mask_bias`` (``has_bias``), then the outputs."""
+    bias_ref = rest[0] if has_bias else None
+    o_ref, lse_ref = rest[-2:]
     qi = pl.program_id(1)
     q_all = q_ref[0]                                      # (Bq, h*d)
-    load_bias = lambda ki: bias_ref[0, :, pl.ds(ki * block_k, block_k)]
+    load_bias = None
+    if has_bias:
+        load_bias = lambda ki: bias_ref[0, :, pl.ds(ki * block_k, block_k)]
     outs, lses = [], []
-    for hi in range(num_heads):
-        sl = slice(hi * d_head, (hi + 1) * d_head)
+    g = _head_group(num_heads, d_head)
+    for hi in range(0, num_heads, g):
+        sl = slice(hi * d_head, (hi + g) * d_head)
         load_kv = lambda ki, sl=sl: (
             k_ref[0, pl.ds(ki * block_k, block_k), sl],
             v_ref[0, pl.ds(ki * block_k, block_k), sl])
@@ -130,22 +262,30 @@ def _fwd_kernel_packed_resident(q_ref, k_ref, v_ref, bias_ref, o_ref,
                                 sm_scale=sm_scale, block_q=block_q,
                                 block_k=block_k, num_k_blocks=num_k_blocks,
                                 causal=causal, seq_len=seq_len,
-                                load_bias=load_bias)
+                                load_bias=load_bias, d_head=d_head)
         outs.append(out)
         lses.append(lse)
     o_ref[0] = jnp.concatenate(outs, axis=1)
     lse_ref[0] = jnp.concatenate(lses, axis=1)            # (Bq, h)
 
 
-# whole-K/V fwd stays fast up to this many packed elements (s * h * d),
-# calibrated for bf16 operands (2 MB per K/V buffer); wider dtypes halve
-# it. Beyond, the streaming kernel keeps long sequences compiling.
-RESIDENT_FWD_MAX_ELEMS = 1024 * 1024
+# The whole-K/V forward runs up to this many packed elements (s * h * d)
+# of bf16 operands (4 MB a K/V buffer, 18 MiB of VMEM in all by
+# `_fwd_resident_vmem_bytes`); wider dtypes halve it. Measured on the chip
+# (PR 41, tests/perf/flash_attention_microbench.py) against the streaming
+# kernel it replaces there: width 1280 at s 1024 1.39 against 2.88 ms,
+# gpt2-xl's 1600 1.00 against 1.78, width 1024 at s 2048 2.08 against
+# 3.48. Beyond, the streaming kernel keeps long sequences compiling.
+RESIDENT_FWD_MAX_ELEMS = 2 * 1024 * 1024
 
 
-def _fwd_kernel_packed(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
-                       acc_s, m_s, l_s, *, sm_scale, block_q, block_k,
-                       num_k_blocks, causal, seq_len, num_heads, d_head):
+def _resident_fwd_fits(hd, s_p, itemsize):
+    return s_p * hd * itemsize <= RESIDENT_FWD_MAX_ELEMS * 2
+
+
+def _fwd_kernel_packed(q_ref, k_ref, v_ref, *rest, sm_scale, block_q,
+                       block_k, num_k_blocks, causal, seq_len, num_heads,
+                       d_head, has_bias):
     """(b, s, h*d)-packed forward: operands stay in the model's natural
     activation layout (the qkv matmul's output), so no host-side head
     transpose ever happens — the (b,s,h,d)->(bh,s,d) relayout at d_head 64
@@ -156,10 +296,12 @@ def _fwd_kernel_packed(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
     online-softmax state (acc/m/l per head) carried in VMEM scratch across
     the sequential innermost k dimension, so sequence length is bounded by
     HBM, not by whole-K/V VMEM residency. Causal cells above the diagonal
-    are skipped (~2x less MXU work)."""
+    are skipped (~2x less MXU work). ``rest``: the bias ref where the
+    caller gave a ``mask_bias``, the outputs, the scratch."""
+    bias_ref = rest[0] if has_bias else None
+    o_ref, lse_ref, acc_s, m_s, l_s = rest[-5:]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    k_base = ki * block_k
 
     @pl.when(ki == 0)
     def _init():
@@ -167,18 +309,9 @@ def _fwd_kernel_packed(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         m_s[:] = jnp.full_like(m_s, NEG_INF)
         l_s[:] = jnp.zeros_like(l_s)
 
-    live = k_base < (qi + 1) * block_q if causal else True
-
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = k_base + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    mask = k_pos < seq_len                  # zero-padded k tail
-    if causal:
-        mask = jnp.logical_and(mask, q_pos >= k_pos)
-
-    @pl.when(live)
     def _accumulate():
+        mask = _score_mask(qi, ki, block_q=block_q, block_k=block_k,
+                           causal=causal, seq_len=seq_len)
         for hi in range(num_heads):
             sl = slice(hi * d_head, (hi + 1) * d_head)
             q = q_ref[0][:, sl]                           # (Bq, d)
@@ -187,8 +320,10 @@ def _fwd_kernel_packed(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
             s_blk = jax.lax.dot_general(
                 q, k_blk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale
-            s_blk = s_blk + bias_ref[0]                   # (1, Bk) bias
-            s_blk = jnp.where(mask, s_blk, NEG_INF)
+            if has_bias:
+                s_blk = s_blk + bias_ref[0]               # (1, Bk) bias
+            if mask is not None:
+                s_blk = jnp.where(mask, s_blk, NEG_INF)
             m_old = m_s[:, hi:hi + 1]                     # (Bq, 1)
             m_new = jnp.maximum(m_old,
                                 jnp.max(s_blk, axis=-1, keepdims=True))
@@ -200,6 +335,8 @@ def _fwd_kernel_packed(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
             acc_s[:, sl] = acc_s[:, sl] * corr + jax.lax.dot_general(
                 p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+
+    _when_live(qi, ki, block_q, block_k, causal, _accumulate)
 
     @pl.when(ki == num_k_blocks - 1)
     def _flush():
@@ -303,63 +440,70 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd_head_terms(q, k_blk, v_blk, do, lse, delta, mask, sm_scale, bias):
+def _bwd_head_terms(q, k_blk, v_blk, do, lse, delta, mask, sm_scale, bias,
+                    folded=False):
     """Per-head backward intermediates shared by the packed dq and dk/dv
     kernels (one definition so a numerics change cannot diverge them):
-    p = masked softmax probabilities, ds = dL/dscores (input dtype).
-    ``bias`` is the (1, Bk) additive score bias (key-padding mask)."""
+    p = softmax probabilities, ds = dL/dscores (input dtype). ``mask``:
+    the (Bq, Bk) mask of the pair's scores, else None (`_score_mask`);
+    ``bias``: the (1, Bk) additive score bias (key-padding mask), else
+    None. ``folded``: ``q`` arrives times ``sm_scale`` (a power of two,
+    `_scale_folds`) and ds leaves WITHOUT it: dk = ds^T (sm_scale q) is
+    then what it was bit for bit, and the caller scales its (Bq, d) dq
+    update instead of two (Bq, Bk) blocks."""
     s_blk = jax.lax.dot_general(
         q, k_blk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale      # (Bq, Bk)
-    s_blk = s_blk + bias
-    p = jnp.where(mask, jnp.exp(s_blk - lse), 0.0)
+        preferred_element_type=jnp.float32)                 # (Bq, Bk)
+    if not folded:
+        s_blk = s_blk * sm_scale
+    if bias is not None:
+        s_blk = s_blk + bias
+    p = jnp.exp(s_blk - lse)
+    if mask is not None:
+        p = jnp.where(mask, p, 0.0)
     dp = jax.lax.dot_general(
         do, v_blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
-    return p, ds
+    ds = p * (dp - delta)
+    if not folded:
+        ds = ds * sm_scale
+    return p, ds.astype(q.dtype)
 
 
 def _bwd_dq_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          bias_ref, dq_ref, dq_acc, *, sm_scale, block_q,
-                          block_k, num_k_blocks, causal, seq_len, num_heads,
-                          d_head):
+                          *rest, sm_scale, block_q, block_k, num_k_blocks,
+                          causal, seq_len, num_heads, d_head, has_bias):
     """Packed-layout dq: grid (b, q blocks, k blocks), accumulating into a
     (Bq, h*d) fp32 scratch across the (sequential, innermost) k dimension.
     The flash backward is split MaxText-style into a dq kernel and a dk/dv
-    kernel, both with every operand blocked — whole-K/V (or whole-q)
-    residency blows the 16M scoped-vmem limit once hd reaches GPT-2-medium
-    width and the pipeline double-buffers."""
+    kernel, both with every operand blocked, so the sequence length is
+    bounded by HBM and not by VMEM. ``rest``: the bias ref where the
+    caller gave a ``mask_bias``, the output, the scratch."""
+    bias_ref = rest[0] if has_bias else None
+    dq_ref, dq_acc = rest[-2:]
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    k_base = ki * block_k
 
     @pl.when(ki == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    live = k_base < (qi + 1) * block_q if causal else True
-
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = k_base + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    mask = k_pos < seq_len
-    if causal:
-        mask = jnp.logical_and(mask, q_pos >= k_pos)
-
-    @pl.when(live)
     def _accumulate():
+        mask = _score_mask(qi, ki, block_q=block_q, block_k=block_k,
+                           causal=causal, seq_len=seq_len)
+        bias = bias_ref[0] if has_bias else None
         for hi in range(num_heads):
             sl = slice(hi * d_head, (hi + 1) * d_head)
             k_blk = k_ref[0][:, sl]                       # (Bk, d)
             _, ds = _bwd_head_terms(
-                q_ref[0][:, sl], k_blk, v_ref[0][:, sl], do_ref[0][:, sl],
-                lse_ref[0][:, hi:hi + 1], delta_ref[0][:, hi:hi + 1],
-                mask, sm_scale, bias_ref[0])
+                q_ref[0][:, sl], k_blk, v_ref[0][:, sl],
+                do_ref[0][:, sl], lse_ref[0][:, hi:hi + 1],
+                delta_ref[0][:, hi:hi + 1], mask, sm_scale, bias)
             dq_acc[:, sl] = dq_acc[:, sl] + jax.lax.dot_general(
                 ds, k_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+
+    _when_live(qi, ki, block_q, block_k, causal, _accumulate)
 
     @pl.when(ki == num_k_blocks - 1)
     def _flush():
@@ -367,37 +511,31 @@ def _bwd_dq_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dkv_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           bias_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                           sm_scale, block_q, block_k, num_q_blocks, causal,
-                           seq_len, num_heads, d_head):
+                           *rest, sm_scale, block_q, block_k, num_q_blocks,
+                           num_k_blocks, causal, seq_len, num_heads, d_head,
+                           has_bias):
     """Packed-layout dk/dv: grid (b, k blocks, q blocks) — each cell sees
     one (Bq, h*d) q/do slab and one (Bk, h*d) K/V slab, accumulating into
     (Bk, h*d) fp32 scratch across the (sequential, innermost) q dimension.
-    Keeping q/do whole in VMEM instead blows the 16M scoped limit once the
-    pipeline double-buffers them. Causal cells above the diagonal are
-    skipped (pl.when), matching the forward's ~2x saving."""
+    Causal cells above the diagonal are skipped (pl.when), matching the
+    forward's ~2x saving. The q rows past the sequence's end are the
+    caller's zero padding: zero q and do put exactly zero into dk and dv,
+    so only the padded KEYS mask (`_score_mask`). ``rest``: the bias ref
+    where the caller gave a ``mask_bias``, the outputs, the scratch."""
+    bias_ref = rest[0] if has_bias else None
+    dk_ref, dv_ref, dk_acc, dv_acc = rest[-4:]
     ki = pl.program_id(1)
     qi = pl.program_id(2)
-    k_base = ki * block_k
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    live = (qi + 1) * block_q > k_base if causal else True
-
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = k_base + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    # mask padded q rows (they SUM into dk/dv) and padded k cols
-    mask = jnp.logical_and(q_pos < seq_len, k_pos < seq_len)
-    if causal:
-        mask = jnp.logical_and(mask, q_pos >= k_pos)
-
-    @pl.when(live)
     def _accumulate():
+        mask = _score_mask(qi, ki, block_q=block_q, block_k=block_k,
+                           causal=causal, seq_len=seq_len)
+        bias = bias_ref[0] if has_bias else None
         for hi in range(num_heads):
             sl = slice(hi * d_head, (hi + 1) * d_head)
             q = q_ref[0][:, sl]                           # (Bq, d)
@@ -405,13 +543,15 @@ def _bwd_dkv_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             p, ds = _bwd_head_terms(
                 q, k_ref[0][:, sl], v_ref[0][:, sl], do,
                 lse_ref[0][:, hi:hi + 1], delta_ref[0][:, hi:hi + 1],
-                mask, sm_scale, bias_ref[0])
+                mask, sm_scale, bias)
             dv_acc[:, sl] = dv_acc[:, sl] + jax.lax.dot_general(
                 p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             dk_acc[:, sl] = dk_acc[:, sl] + jax.lax.dot_general(
                 ds, q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+
+    _when_live(qi, ki, block_q, block_k, causal, _accumulate)
 
     @pl.when(qi == num_q_blocks - 1)
     def _flush():
@@ -449,7 +589,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     grid = (bh, pl.cdiv(s, block_q))
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
     kv_spec = pl.BlockSpec((1, s_p, d), lambda b, i: (b, 0, 0))
-    out, lse = pl.pallas_call(
+    out, lse = _call_kernel(pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, block_q=block_q,
                           block_k=block_k, num_k_blocks=num_k_blocks,
                           causal=causal, seq_len=s),
@@ -460,11 +600,12 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
         out_shape=(jax.ShapeDtypeStruct((bh, s, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, s, 1), jnp.float32)),
         interpret=interpret,
+        compiler_params=_compiler_params(),
         cost_estimate=_attn_cost(
             mults=2, n=bh, s_q=s, s_k=s, d=d, heads=1, causal=causal,
             operands=(q, k, v),
             out_bytes=q.size * q.dtype.itemsize + bh * s * 4),
-    )(q, k, v)
+    ), q, k, v)
     return out, lse
 
 
@@ -480,7 +621,7 @@ def _bwd(q, k, v, o, do, lse, sm_scale, causal, block_q, block_k, interpret):
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
     kv_spec = pl.BlockSpec((1, s_p, d), lambda b, i: (b, 0, 0))
     lse_spec = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))
-    dq, dk, dv = pl.pallas_call(
+    dq, dk, dv = _call_kernel(pl.pallas_call(
         functools.partial(_bwd_kernel, sm_scale=sm_scale, block_q=block_q,
                           block_k=block_k, num_k_blocks=num_k_blocks,
                           causal=causal, num_q_blocks=num_q_blocks,
@@ -494,18 +635,21 @@ def _bwd(q, k, v, o, do, lse, sm_scale, causal, block_q, block_k, interpret):
         scratch_shapes=[pltpu.VMEM((s_p, d), jnp.float32),
                         pltpu.VMEM((s_p, d), jnp.float32)],
         interpret=interpret,
+        compiler_params=_compiler_params(),
         cost_estimate=_attn_cost(
             mults=5, n=bh, s_q=s, s_k=s, d=d, heads=1, causal=causal,
             operands=(q, k, v, o, do, lse),
             out_bytes=3 * q.size * q.dtype.itemsize),
-    )(q, k, v, o, do, lse)
+    ), q, k, v, o, do, lse)
     return dq, dk[:, :s], dv[:, :s]
 
 
 def _pad_bias(bias, b, s, block_k):
-    """(b, s) / (b, 1, s) additive bias -> (b, 1, s_p) fp32. The k-tail
-    padding value (0) is harmless: padded keys are masked by seq_len
-    in-kernel. (The zero-bias default lives in flash_attention_bshd.)"""
+    """(b, s) / (b, 1, s) additive bias -> (b, 1, s_p) fp32; None (the
+    caller has no mask) stays None. The k-tail padding value (0) is
+    harmless: padded keys are masked by seq_len in-kernel."""
+    if bias is None:
+        return None
     pad = (-s) % block_k
     if bias.ndim == 2:
         bias = bias[:, None, :]
@@ -518,10 +662,9 @@ def _pad_bias(bias, b, s, block_k):
 def _fwd_packed(q, k, v, bias, sm_scale, causal, block_q, block_k,
                 interpret, num_heads):
     """q/k/v: (b, s, h*d) packed; returns (out (b, s, h*d), lse (b, s, h)).
-    Every operand is blocked (grid b x q x k); sequence length is bounded
-    by HBM only. ``bias``: (b, 1, s_p) fp32 additive scores (key-padding
-    mask), always present (zeros when unused — the uniform operand keeps
-    one kernel per path)."""
+    ``bias``: (b, 1, s_p) fp32 additive scores (key-padding mask), or None
+    where the caller has none: the kernels then take no bias operand and
+    add nothing to the scores."""
     b, s, hd = q.shape
     d = hd // num_heads
     block_q = min(block_q, s)
@@ -529,69 +672,63 @@ def _fwd_packed(q, k, v, bias, sm_scale, causal, block_q, block_k,
     k, v = _pad_kv(k, v, block_k)
     s_p = k.shape[1]
     num_k_blocks = s_p // block_k
+    has_bias = bias is not None
+    operands = (q, k, v) + ((bias,) if has_bias else ())
+    statics = dict(sm_scale=sm_scale, block_q=block_q, block_k=block_k,
+                   num_k_blocks=num_k_blocks, causal=causal, seq_len=s,
+                   num_heads=num_heads, d_head=d, has_bias=has_bias)
+    out_shape = (jax.ShapeDtypeStruct((b, s, hd), q.dtype),
+                 jax.ShapeDtypeStruct((b, s, num_heads), jnp.float32))
+    cost = _attn_cost(
+        mults=2, n=b, s_q=s, s_k=s, d=d, heads=num_heads, causal=causal,
+        operands=operands,
+        out_bytes=q.size * q.dtype.itemsize + b * s * num_heads * 4)
 
-    if s_p * hd * q.dtype.itemsize <= RESIDENT_FWD_MAX_ELEMS * 2:
+    if _resident_fwd_fits(hd, s_p, q.dtype.itemsize):
         # fast path: K/V whole per (batch, q-block) cell, softmax state in
         # registers across an in-kernel fori over k blocks
-        grid = (b, pl.cdiv(s, block_q))
         q_spec = pl.BlockSpec((1, block_q, hd), lambda bi, qi: (bi, qi, 0))
         kv_spec = pl.BlockSpec((1, s_p, hd), lambda bi, qi: (bi, 0, 0))
         bias_spec = pl.BlockSpec((1, 1, s_p), lambda bi, qi: (bi, 0, 0))
-        return pl.pallas_call(
-            functools.partial(_fwd_kernel_packed_resident,
-                              sm_scale=sm_scale, block_q=block_q,
-                              block_k=block_k, num_k_blocks=num_k_blocks,
-                              causal=causal, seq_len=s,
-                              num_heads=num_heads, d_head=d),
-            grid=grid,
-            in_specs=[q_spec, kv_spec, kv_spec, bias_spec],
+        return _call_kernel(pl.pallas_call(
+            functools.partial(_fwd_kernel_packed_resident, **statics),
+            grid=(b, pl.cdiv(s, block_q)),
+            in_specs=[q_spec, kv_spec, kv_spec] + [bias_spec] * has_bias,
             out_specs=(q_spec,
                        pl.BlockSpec((1, block_q, num_heads),
                                     lambda bi, qi: (bi, qi, 0))),
-            out_shape=(jax.ShapeDtypeStruct((b, s, hd), q.dtype),
-                       jax.ShapeDtypeStruct((b, s, num_heads),
-                                            jnp.float32)),
+            out_shape=out_shape,
             interpret=interpret,
-            cost_estimate=_attn_cost(
-                mults=2, n=b, s_q=s, s_k=s, d=d, heads=num_heads,
-                causal=causal, operands=(q, k, v, bias),
-                out_bytes=q.size * q.dtype.itemsize
-                + b * s * num_heads * 4),
-        )(q, k, v, bias)
+            compiler_params=_compiler_params(),
+            cost_estimate=cost,
+        ), *operands)
 
-    grid = (b, pl.cdiv(s, block_q), num_k_blocks)
+    # every operand blocked (grid b x q x k): sequence length is bounded
+    # by HBM only
     q_spec = pl.BlockSpec((1, block_q, hd), lambda bi, qi, ki: (bi, qi, 0))
     kv_spec = pl.BlockSpec((1, block_k, hd), lambda bi, qi, ki: (bi, ki, 0))
     bias_spec = pl.BlockSpec((1, 1, block_k), lambda bi, qi, ki: (bi, 0, ki))
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel_packed, sm_scale=sm_scale,
-                          block_q=block_q, block_k=block_k,
-                          num_k_blocks=num_k_blocks, causal=causal,
-                          seq_len=s, num_heads=num_heads, d_head=d),
-        grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec, bias_spec],
+    return _call_kernel(pl.pallas_call(
+        functools.partial(_fwd_kernel_packed, **statics),
+        grid=(b, pl.cdiv(s, block_q), num_k_blocks),
+        in_specs=[q_spec, kv_spec, kv_spec] + [bias_spec] * has_bias,
         out_specs=(q_spec,
                    pl.BlockSpec((1, block_q, num_heads),
                                 lambda bi, qi, ki: (bi, qi, 0))),
-        out_shape=(jax.ShapeDtypeStruct((b, s, hd), q.dtype),
-                   jax.ShapeDtypeStruct((b, s, num_heads), jnp.float32)),
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32),
                         pltpu.VMEM((block_q, num_heads), jnp.float32),
                         pltpu.VMEM((block_q, num_heads), jnp.float32)],
         interpret=interpret,
-        cost_estimate=_attn_cost(
-            mults=2, n=b, s_q=s, s_k=s, d=d, heads=num_heads,
-            causal=causal, operands=(q, k, v, bias),
-            out_bytes=q.size * q.dtype.itemsize + b * s * num_heads * 4),
-    )(q, k, v, bias)
-    return out, lse
+        compiler_params=_compiler_params(),
+        cost_estimate=cost,
+    ), *operands)
 
 
 def _bwd_fused_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                             bias_ref, dq_hbm, dk_ref, dv_ref, dk_acc,
-                             dv_acc, dq_vmem, sem_rd, sem_wr, *, sm_scale,
-                             block_q, block_k, num_q_blocks, causal,
-                             seq_len, num_heads, d_head):
+                             *rest, sm_scale, block_q, block_k,
+                             num_q_blocks, num_k_blocks, causal, seq_len,
+                             num_heads, d_head, has_bias):
     """Single-pass packed backward: grid (b, k blocks, q blocks). One walk
     of the (q, k) block pairs computes ALL of dq/dk/dv — 5 dots per pair
     vs the split kernels' 7 (each split pass re-derives s = qk^T and
@@ -603,30 +740,22 @@ def _bwd_fused_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     well-defined on the sequential TPU grid (the BlockSpec pipeline offers
     no such guarantee for revisited blocks, which is why round 2 split the
     kernels); the blocking transfers are ~1 MB against ~ms of MXU work
-    per step."""
+    per step. ``rest``: the bias ref where the caller gave a
+    ``mask_bias``, the outputs, the scratch."""
+    bias_ref = rest[0] if has_bias else None
+    dq_hbm, dk_ref, dv_ref, dk_acc, dv_acc, dq_vmem, sem_rd, sem_wr = \
+        rest[-8:]
     bi = pl.program_id(0)
     ki = pl.program_id(1)
     qi = pl.program_id(2)
-    k_base = ki * block_k
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    live = (qi + 1) * block_q > k_base if causal else True
-
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = k_base + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    mask = k_pos < seq_len
-    if causal:
-        mask = jnp.logical_and(mask, q_pos >= k_pos)
-
     dq_slice = dq_hbm.at[bi, pl.ds(qi * block_q, block_q)]
 
-    @pl.when(live)
     def _compute():
         # causality keeps ki == 0 live for every row, so the first visit
         # of each dq block is always at ki == 0: zero-init there, read the
@@ -641,6 +770,9 @@ def _bwd_fused_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             cp.start()
             cp.wait()
 
+        mask = _score_mask(qi, ki, block_q=block_q, block_k=block_k,
+                           causal=causal, seq_len=seq_len)
+        bias = bias_ref[0] if has_bias else None
         for hi in range(num_heads):
             sl = slice(hi * d_head, (hi + 1) * d_head)
             q = q_ref[0][:, sl]
@@ -649,7 +781,7 @@ def _bwd_fused_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             p, ds = _bwd_head_terms(
                 q, k_blk, v_ref[0][:, sl], do,
                 lse_ref[0][:, hi:hi + 1], delta_ref[0][:, hi:hi + 1],
-                mask, sm_scale, bias_ref[0])
+                mask, sm_scale, bias)
             dq_vmem[:, sl] = dq_vmem[:, sl] + jax.lax.dot_general(
                 ds, k_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -664,6 +796,8 @@ def _bwd_fused_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         cp.start()
         cp.wait()
 
+    _when_live(qi, ki, block_q, block_k, causal, _compute)
+
     @pl.when(qi == num_q_blocks - 1)
     def _flush():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
@@ -671,86 +805,121 @@ def _bwd_fused_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_fused_kernel_packed_resident_dq(
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref, dq_ref,
-        dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, block_q, block_k,
-        num_q_blocks, causal, seq_len, num_heads, d_head):
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest, sm_scale,
+        block_q, block_k, num_q_blocks, num_k_blocks, causal, seq_len,
+        num_heads, d_head, has_bias):
     """Single-pass packed backward with dq RESIDENT in VMEM. Same grid
     (b, k blocks, q blocks) and 5-dots-per-pair math as the DMA variant
-    above, but dq accumulates into a whole-(s, h*d) fp32 OUTPUT block whose
-    index map ignores (ki, qi) — the standard Pallas accumulator pattern:
-    a revisited output block stays in VMEM across grid steps and is copied
-    out once, when the block index changes (here: at each batch row's last
-    step). The cross-k-walk dq accumulation therefore costs NO DMAs — the
-    DMA variant's per-step blocking read-modify-write waits (~1 MB each
-    way against only ~µs of MXU work per step) were exactly why it
-    measured 0.7-0.9x of the split pair. Feasible when s*h*d*4B fits
-    scoped VMEM next to the block operands (RESIDENT_DQ_MAX_BYTES)."""
+    above, but dq accumulates over the k walk in a whole-(s, h*d) fp32
+    scratch and leaves once, at the batch row's last step, through an
+    output block whose index map ignores (ki, qi). The cross-k-walk dq
+    accumulation therefore costs NO DMAs — the DMA variant's per-step
+    blocking read-modify-write waits (~1 MB each way against only ~µs of
+    MXU work per step) were exactly why it measured 0.7-0.9x of the split
+    pair. Feasible when three such slabs (the scratch and the output's two
+    buffers) fit VMEM next to the block operands (RESIDENT_DQ_MAX_BYTES).
+    (dq leaves as fp32 and XLA casts it: a bf16 output measured 0.2 ms a
+    layer slower at the training cell's shape, XLA then copies it into
+    the qkv cotangent in a pass of its own.)
+
+    Heads go a 128-lane tile at a time (`_tile_operands`): every
+    accumulator update is a whole tile's read-modify-write. Where the k
+    block is a multiple of the q block, a pair that the diagonal crosses
+    works on the keys its queries see and no others: one body a count of
+    live sub-blocks, chosen by the grid position (at (256, 512) a third of
+    the live pairs are half dead). ``rest``: the bias ref where the
+    caller gave a ``mask_bias``, the outputs, the scratch."""
+    bias_ref = rest[0] if has_bias else None
+    dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, dq_acc = rest[-6:]
     ki = pl.program_id(1)
     qi = pl.program_id(2)
-    k_base = ki * block_k
 
     @pl.when(jnp.logical_and(ki == 0, qi == 0))
     def _init_dq():
-        dq_ref[0] = jnp.zeros_like(dq_ref[0])
+        dq_acc[:] = jnp.zeros_like(dq_acc)
 
     @pl.when(qi == 0)
     def _init_kv():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    live = (qi + 1) * block_q > k_base if causal else True
-
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = k_base + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    mask = k_pos < seq_len
-    if causal:
-        mask = jnp.logical_and(mask, q_pos >= k_pos)
-
     rows = pl.ds(qi * block_q, block_q)
-    # Mosaic requires lane-dim store OFFSETS into pipeline output refs to
-    # be provably 128-aligned (scratch refs like dk_acc/dv_acc carry no
-    # such constraint), so dq updates are read-modified-written in chunks
-    # of the fewest heads whose width lands every chunk boundary on a
-    # 128 multiple — 2 heads at d_head 64, 1 (plain per-head) at >= 128.
-    # A whole-width concat instead costs an extra (block_q, hd) fp32
-    # stack temp, which re-overflows scoped VMEM at the bench shape.
-    import math
-    heads_per_chunk = 128 // math.gcd(d_head, 128) if d_head % 128 else 1
+    g = _head_group(num_heads, d_head)
+    fold = _scale_folds(sm_scale)
+    tn = (((0,), (0,)), ((), ()))
 
-    @pl.when(live)
-    def _compute():
-        for c0 in range(0, num_heads, heads_per_chunk):
-            chunk = range(c0, min(c0 + heads_per_chunk, num_heads))
-            dq_upds = []
-            for hi in chunk:
-                sl = slice(hi * d_head, (hi + 1) * d_head)
-                q = q_ref[0][:, sl]
-                do = do_ref[0][:, sl]
-                k_blk = k_ref[0][:, sl]
+    def _compute(keys=block_k):
+        # ``keys``: the block's first keys, those that a query here sees
+        mask = _score_mask(qi, ki, block_q=block_q, block_k=block_k,
+                           causal=causal, seq_len=seq_len, keys=keys)
+        bias = bias_ref[0][:, :keys] if has_bias else None
+        for h0 in range(0, num_heads, g):
+            sl = slice(h0 * d_head, (h0 + g) * d_head)
+            k_t = k_ref[0][:keys, sl]
+            v_t = v_ref[0][:keys, sl]
+            own, q_of = _tile_operands(q_ref[0][:, sl], g, d_head,
+                                       sm_scale if fold else None)
+            _, do_of = _tile_operands(do_ref[0][:, sl], g, d_head)
+            dq_u = dk_u = dv_u = None
+            for j, (q_j, do_j) in enumerate(zip(q_of, do_of)):
+                hi = h0 + j
                 p, ds = _bwd_head_terms(
-                    q, k_blk, v_ref[0][:, sl], do,
-                    lse_ref[0][:, hi:hi + 1], delta_ref[0][:, hi:hi + 1],
-                    mask, sm_scale, bias_ref[0])
-                dq_upds.append(jax.lax.dot_general(
-                    ds, k_blk, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32))
-                dv_acc[:, sl] = dv_acc[:, sl] + jax.lax.dot_general(
-                    p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                    q_j, k_t, v_t, do_j, lse_ref[0][:, hi:hi + 1],
+                    delta_ref[0][:, hi:hi + 1], mask, sm_scale, bias,
+                    folded=fold)
+                # ds @ k fills the whole tile: head j's lanes are kept.
+                # p^T @ do_j and ds^T @ q_j are zero outside them: summed.
+                dq_j = jax.lax.dot_general(
+                    ds, k_t, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
-                dk_acc[:, sl] = dk_acc[:, sl] + jax.lax.dot_general(
-                    ds, q, (((0,), (0,)), ((), ())),
+                dv_j = jax.lax.dot_general(
+                    p.astype(do_j.dtype), do_j, tn,
                     preferred_element_type=jnp.float32)
-            upd = (dq_upds[0] if len(dq_upds) == 1
-                   else jnp.concatenate(dq_upds, axis=1))
-            csl = slice(c0 * d_head, (c0 + len(dq_upds)) * d_head)
-            dq_ref[0, rows, csl] = dq_ref[0, rows, csl] + upd
+                dk_j = jax.lax.dot_general(
+                    ds, q_j, tn, preferred_element_type=jnp.float32)
+                if j == 0:
+                    dq_u, dk_u, dv_u = dq_j, dk_j, dv_j
+                else:
+                    dq_u = jnp.where(own[j], dq_j, dq_u)
+                    dk_u = dk_u + dk_j
+                    dv_u = dv_u + dv_j
+            dv_acc[:keys, sl] = dv_acc[:keys, sl] + dv_u
+            dk_acc[:keys, sl] = dk_acc[:keys, sl] + dk_u
+            if fold:
+                dq_u = dq_u * sm_scale
+            dq_acc[rows, sl] = dq_acc[rows, sl] + dq_u
+
+    n_sub = block_k // block_q if causal and block_k % block_q == 0 else 1
+    if n_sub == 1:
+        _when_live(qi, ki, block_q, block_k, causal, _compute)
+    else:
+        # the diagonal leaves this q block the first m * block_q keys of
+        # this k block (m < 1: none, the pair is dead)
+        m = qi - ki * n_sub + 1
+        for j in range(1, n_sub):
+            pl.when(m == j)(functools.partial(_compute, j * block_q))
+        pl.when(m >= n_sub)(_compute)
 
     @pl.when(qi == num_q_blocks - 1)
     def _flush():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(jnp.logical_and(ki == num_k_blocks - 1,
+                             qi == num_q_blocks - 1))
+    def _flush_dq():
+        dq_ref[0] = dq_acc[:]
+
+
+def _q_side_padded(q, do, lse, delta, block_q):
+    """The q-side arrays zero-padded to a block_q multiple, for uniform
+    in-kernel slicing. Zero q and do rows put exactly zero into dq, dk and
+    dv, so no kernel masks them."""
+    pad_q = (-q.shape[1]) % block_q
+    if not pad_q:
+        return q, do, lse, delta
+    pad3 = lambda t: jnp.pad(t, ((0, 0), (0, pad_q), (0, 0)))
+    return pad3(q), pad3(do), pad3(lse), pad3(delta)
 
 
 def _bwd_fused_packed(q, k, v, bias, o, do, lse, sm_scale, causal, block_q,
@@ -770,67 +939,60 @@ def _bwd_fused_packed(q, k, v, bias, o, do, lse, sm_scale, causal, block_q,
 
     delta = (do.astype(jnp.float32).reshape(b, s, num_heads, d)
              * o.astype(jnp.float32).reshape(b, s, num_heads, d)).sum(-1)
-
-    pad_q = (-s) % block_q
-    if pad_q:
-        pad3 = lambda t: jnp.pad(t, ((0, 0), (0, pad_q), (0, 0)))
-        q_p, do_p, lse_p, delta_p = (pad3(q), pad3(do), pad3(lse),
-                                     pad3(delta))
-    else:
-        q_p, do_p, lse_p, delta_p = q, do, lse, delta
+    q_p, do_p, lse_p, delta_p = _q_side_padded(q, do, lse, delta, block_q)
     s_qp = q_p.shape[1]
     nqb = s_qp // block_q
 
-    q_blk = pl.BlockSpec((1, block_q, hd), lambda bi, ki, qi: (bi, qi, 0))
+    # A dead cell (q block wholly above the k block's diagonal) names the
+    # k block's first live q block: the pipeline fetches nothing for a
+    # block it already holds (1.70 -> 1.62 ms a layer at the training
+    # cell's shape, where 2 of 8 cells a batch row are dead).
+    if causal:
+        q_row = lambda ki, qi: jnp.maximum(qi, (ki * block_k) // block_q)
+    else:
+        q_row = lambda ki, qi: qi
+    q_blk = pl.BlockSpec((1, block_q, hd),
+                         lambda bi, ki, qi: (bi, q_row(ki, qi), 0))
     kv_blk = pl.BlockSpec((1, block_k, hd), lambda bi, ki, qi: (bi, ki, 0))
     lse_blk = pl.BlockSpec((1, block_q, num_heads),
-                           lambda bi, ki, qi: (bi, qi, 0))
+                           lambda bi, ki, qi: (bi, q_row(ki, qi), 0))
     bias_blk = pl.BlockSpec((1, 1, block_k), lambda bi, ki, qi: (bi, 0, ki))
-
+    has_bias = bias is not None
+    operands = (q_p, k, v, do_p, lse_p, delta_p) + (bias,) * has_bias
+    in_specs = [q_blk, kv_blk, kv_blk, q_blk, lse_blk, lse_blk] \
+        + [bias_blk] * has_bias
+    statics = dict(sm_scale=sm_scale, block_q=block_q, block_k=block_k,
+                   num_q_blocks=nqb, num_k_blocks=num_k_blocks,
+                   causal=causal, seq_len=s, num_heads=num_heads, d_head=d,
+                   has_bias=has_bias)
+    out_shape = (jax.ShapeDtypeStruct((b, s_qp, hd), jnp.float32),
+                 jax.ShapeDtypeStruct((b, s_kp, hd), q.dtype),
+                 jax.ShapeDtypeStruct((b, s_kp, hd), q.dtype))
+    acc = pltpu.VMEM((block_k, hd), jnp.float32)
     cost = _attn_cost(
         mults=5, n=b, s_q=s, s_k=s, d=d, heads=num_heads, causal=causal,
-        operands=(q_p, k, v, do_p, lse_p, delta_p, bias),
+        operands=operands,
         out_bytes=b * s_qp * hd * 4 + 2 * k.size * k.dtype.itemsize)
     if _resident_dq_fits(hd, s_qp):
-        dq_f32, dk, dv = pl.pallas_call(
-            functools.partial(
-                _bwd_fused_kernel_packed_resident_dq, sm_scale=sm_scale,
-                block_q=block_q, block_k=block_k, num_q_blocks=nqb,
-                causal=causal, seq_len=s, num_heads=num_heads, d_head=d),
-            grid=(b, num_k_blocks, nqb),
-            in_specs=[q_blk, kv_blk, kv_blk, q_blk, lse_blk, lse_blk,
-                      bias_blk],
-            out_specs=(pl.BlockSpec((1, s_qp, hd),
-                                    lambda bi, ki, qi: (bi, 0, 0)),
-                       kv_blk, kv_blk),
-            out_shape=(jax.ShapeDtypeStruct((b, s_qp, hd), jnp.float32),
-                       jax.ShapeDtypeStruct((b, s_kp, hd), q.dtype),
-                       jax.ShapeDtypeStruct((b, s_kp, hd), q.dtype)),
-            scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
-                            pltpu.VMEM((block_k, hd), jnp.float32)],
-            interpret=interpret,
-            cost_estimate=cost,
-        )(q_p, k, v, do_p, lse_p, delta_p, bias)
-        return dq_f32[:, :s].astype(q.dtype), dk[:, :s], dv[:, :s]
-
-    dq_f32, dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_fused_kernel_packed, sm_scale=sm_scale, block_q=block_q,
-            block_k=block_k, num_q_blocks=nqb, causal=causal, seq_len=s,
-            num_heads=num_heads, d_head=d),
+        kernel = _bwd_fused_kernel_packed_resident_dq
+        dq_spec = pl.BlockSpec((1, s_qp, hd), lambda bi, ki, qi: (bi, 0, 0))
+        dq_scratch = [pltpu.VMEM((s_qp, hd), jnp.float32)]
+    else:
+        kernel = _bwd_fused_kernel_packed
+        dq_spec = pl.BlockSpec(memory_space=pl.ANY)
+        dq_scratch = [pltpu.VMEM((block_q, hd), jnp.float32),
+                      pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA]
+    dq_f32, dk, dv = _call_kernel(pl.pallas_call(
+        functools.partial(kernel, **statics),
         grid=(b, num_k_blocks, nqb),
-        in_specs=[q_blk, kv_blk, kv_blk, q_blk, lse_blk, lse_blk, bias_blk],
-        out_specs=(pl.BlockSpec(memory_space=pl.ANY), kv_blk, kv_blk),
-        out_shape=(jax.ShapeDtypeStruct((b, s_qp, hd), jnp.float32),
-                   jax.ShapeDtypeStruct((b, s_kp, hd), q.dtype),
-                   jax.ShapeDtypeStruct((b, s_kp, hd), q.dtype)),
-        scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
-                        pltpu.VMEM((block_k, hd), jnp.float32),
-                        pltpu.VMEM((block_q, hd), jnp.float32),
-                        pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
+        in_specs=in_specs,
+        out_specs=(dq_spec, kv_blk, kv_blk),
+        out_shape=out_shape,
+        scratch_shapes=[acc, acc] + dq_scratch,
         interpret=interpret,
+        compiler_params=_compiler_params(),
         cost_estimate=cost,
-    )(q_p, k, v, do_p, lse_p, delta_p, bias)
+    ), *operands)
     return dq_f32[:, :s].astype(q.dtype), dk[:, :s], dv[:, :s]
 
 
@@ -896,8 +1058,9 @@ def _bwd_fused_grouped(q, k, v, bias, o, do, lse, sm_scale, causal,
 
 def _bwd_split_packed(q, k, v, bias, o, do, lse, sm_scale, causal, block_q,
                       block_k, interpret, num_heads):
-    """Two pallas calls (dq; then dk/dv over k-blocks) — the fallback for
-    widths whose fused working set overflows scoped vmem."""
+    """Two pallas calls (dq; then dk/dv over k-blocks), every operand
+    blocked — the path of sequences whose resident fp32 dq slab outgrows
+    `RESIDENT_DQ_MAX_BYTES`."""
     b, s, hd = q.shape
     d = hd // num_heads
     block_q = min(block_q, s)
@@ -905,28 +1068,18 @@ def _bwd_split_packed(q, k, v, bias, o, do, lse, sm_scale, causal, block_q,
     k, v = _pad_kv(k, v, block_k)
     s_kp = k.shape[1]
     num_k_blocks = s_kp // block_k
-    num_q_blocks = pl.cdiv(s, block_q)
-    # NOTE: a whole-K/V-resident backward (mirroring the resident forward)
-    # was tried and cannot compile at GPT-2 widths — the pipeline double-
-    # buffers the constant-index whole operands, so K+V (4M at s1024 x
-    # hd1024 bf16) plus whole q/do in the dk/dv pass overflow the 16M
-    # scoped-vmem budget; the split streaming kernels below stand.
 
     # delta_i = sum_d do*o per head: (b, s, h) fp32 (XLA fuses this)
     delta = (do.astype(jnp.float32).reshape(b, s, num_heads, d)
              * o.astype(jnp.float32).reshape(b, s, num_heads, d)).sum(-1)
-
-    # q-side arrays host-padded to a block_q multiple (zeros) for uniform
-    # in-kernel slicing; padded rows are masked via q_pos in-kernel.
-    pad_q = (-s) % block_q
-    if pad_q:
-        pad3 = lambda t: jnp.pad(t, ((0, 0), (0, pad_q), (0, 0)))
-        q_p, do_p, lse_p, delta_p = (pad3(q), pad3(do), pad3(lse),
-                                     pad3(delta))
-    else:
-        q_p, do_p, lse_p, delta_p = q, do, lse, delta
+    q_p, do_p, lse_p, delta_p = _q_side_padded(q, do, lse, delta, block_q)
     s_qp = q_p.shape[1]
     nqb = s_qp // block_q
+    has_bias = bias is not None
+    operands = (q_p, k, v, do_p, lse_p, delta_p) + (bias,) * has_bias
+    statics = dict(sm_scale=sm_scale, block_q=block_q, block_k=block_k,
+                   num_k_blocks=num_k_blocks, causal=causal, seq_len=s,
+                   num_heads=num_heads, d_head=d, has_bias=has_bias)
 
     dq_q_spec = pl.BlockSpec((1, block_q, hd), lambda bi, qi, ki: (bi, qi, 0))
     dq_kv_spec = pl.BlockSpec((1, block_k, hd), lambda bi, qi, ki: (bi, ki, 0))
@@ -934,24 +1087,21 @@ def _bwd_split_packed(q, k, v, bias, o, do, lse, sm_scale, causal, block_q,
                                lambda bi, qi, ki: (bi, qi, 0))
     dq_bias_spec = pl.BlockSpec((1, 1, block_k),
                                 lambda bi, qi, ki: (bi, 0, ki))
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel_packed, sm_scale=sm_scale,
-                          block_q=block_q, block_k=block_k,
-                          num_k_blocks=num_k_blocks, causal=causal,
-                          seq_len=s, num_heads=num_heads, d_head=d),
+    dq = _call_kernel(pl.pallas_call(
+        functools.partial(_bwd_dq_kernel_packed, **statics),
         grid=(b, nqb, num_k_blocks),
         in_specs=[dq_q_spec, dq_kv_spec, dq_kv_spec, dq_q_spec,
-                  dq_lse_spec, dq_lse_spec, dq_bias_spec],
+                  dq_lse_spec, dq_lse_spec] + [dq_bias_spec] * has_bias,
         out_specs=dq_q_spec,
         out_shape=jax.ShapeDtypeStruct((b, s_qp, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
         interpret=interpret,
+        compiler_params=_compiler_params(),
         cost_estimate=_attn_cost(
             mults=3, n=b, s_q=s, s_k=s, d=d, heads=num_heads,
-            causal=causal,
-            operands=(q_p, k, v, do_p, lse_p, delta_p, bias),
+            causal=causal, operands=operands,
             out_bytes=b * s_qp * hd * q.dtype.itemsize),
-    )(q_p, k, v, do_p, lse_p, delta_p, bias)
+    ), *operands)
     dq = dq[:, :s]
 
     q_blk = pl.BlockSpec((1, block_q, hd), lambda bi, ki, qi: (bi, qi, 0))
@@ -959,49 +1109,51 @@ def _bwd_split_packed(q, k, v, bias, o, do, lse, sm_scale, causal, block_q,
     lse_blk = pl.BlockSpec((1, block_q, num_heads),
                            lambda bi, ki, qi: (bi, qi, 0))
     bias_blk = pl.BlockSpec((1, 1, block_k), lambda bi, ki, qi: (bi, 0, ki))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel_packed, sm_scale=sm_scale,
-                          block_q=block_q, block_k=block_k,
-                          num_q_blocks=nqb, causal=causal, seq_len=s,
-                          num_heads=num_heads, d_head=d),
+    dk, dv = _call_kernel(pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel_packed, num_q_blocks=nqb,
+                          **statics),
         grid=(b, num_k_blocks, nqb),
-        in_specs=[q_blk, kv_blk, kv_blk, q_blk, lse_blk, lse_blk, bias_blk],
+        in_specs=[q_blk, kv_blk, kv_blk, q_blk, lse_blk, lse_blk]
+        + [bias_blk] * has_bias,
         out_specs=(kv_blk, kv_blk),
         out_shape=(jax.ShapeDtypeStruct((b, s_kp, hd), q.dtype),
                    jax.ShapeDtypeStruct((b, s_kp, hd), q.dtype)),
         scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
                         pltpu.VMEM((block_k, hd), jnp.float32)],
         interpret=interpret,
+        compiler_params=_compiler_params(),
         cost_estimate=_attn_cost(
             mults=4, n=b, s_q=s, s_k=s, d=d, heads=num_heads,
-            causal=causal,
-            operands=(q_p, k, v, do_p, lse_p, delta_p, bias),
+            causal=causal, operands=operands,
             out_bytes=2 * k.size * k.dtype.itemsize),
-    )(q_p, k, v, do_p, lse_p, delta_p, bias)
+    ), *operands)
     return dq, dk[:, :s], dv[:, :s]
 
 
-# Packed-kernel block defaults: q 256 (a 512 q-block on (Bq, h*d) slabs
-# tips the 16M scoped-vmem limit at GPT-2 width), k 512 (fewer, larger
-# dots amortize the MXU fill/drain latency that dominates at d_head 64:
-# measured 11.0 -> 6.8 ms/layer fwd at the GPT-2-medium bench shape;
-# k = 1024 measured worse and OOMs the backward).
+# Packed-kernel block defaults: q 256, k 512 (fewer, larger dots amortize
+# the MXU fill/drain latency that dominates at d_head 64). Swept again at
+# the training cell's shape (20, 1024, 16 x 64, bf16) with
+# `VMEM_LIMIT_BYTES` in place (PR 41): forward 1.39 ms a layer at
+# (256, 512), against 1.96 at (256, 256), 1.95 at (512, 256), 2.02 at
+# (512, 512), 1.81 at (256, 1024), 1.68 at (128, 512): a larger block is
+# no longer refused, it is slower (its float32 scores pass through VMEM).
 DEFAULT_BLOCK_PACKED = 256
 DEFAULT_BLOCK_PACKED_K = 512
 
 
 # The single-pass FUSED backward (5 dots/pair vs the split kernels' 7)
 # carries a larger VMEM working set (k/v + dk/dv scratch + the resident
-# dq slab), so a single kernel call caps out at hd = 1280 (measured
-# compile limit). Wider models need not fall back to the split kernels:
+# dq slab); a single kernel call is capped at hd = 1280 (the compile limit
+# measured inside XLA's default 16 MiB; not swept again since the calls
+# ask for `VMEM_LIMIT_BYTES`). Wider models need not fall back to the
+# split kernels:
 # attention is independent per head, so _bwd_packed slices the packed
 # width into head GROUPS of <= FUSED_GROUP_TARGET and runs the fused
 # kernel per group — gpt2-xl (25 heads x 64 = 1600) runs as two groups
-# (13 + 12 heads, widths 832/768) with the fat (256, 256) blocks the
-# <=1024 path earns.
+# (13 + 12 heads, widths 832/768) with the blocks the <=1024 path earns.
 #
 # DEFAULT: AUTO — the resident-dq fused kernel wherever its fp32 dq slab
-# fits scoped VMEM next to the block operands, the split pair elsewhere.
+# fits its budget (`RESIDENT_DQ_MAX_BYTES`), the split pair elsewhere.
 # History: round 2 shipped the fused kernel with dq as an HBM
 # read-modify-write behind explicit DMA waits; that variant's advantage
 # was environment-dependent (1.12x over split in one session, 0.7-0.9x
@@ -1031,32 +1183,61 @@ def _bwd_mode_from_env():
 BWD_MODE = _bwd_mode_from_env()
 FUSED_BWD_MAX_WIDTH = 1280
 FUSED_GROUP_TARGET = 1024
-# Budget for the resident-dq fused kernel's whole-(s, hd) fp32 dq block:
-# alongside the double-buffered (256, hd) operand slabs and the dk/dv
-# scratch/outputs, 6 MB keeps hd 1024 comfortable to s 1536 and the
-# grouped widths (<= 1280 after padding) to s 1024 inside the 16 MB
-# scoped-VMEM limit; longer sequences take the split pair (measured
-# faster than the DMA fused variant).
-RESIDENT_DQ_MAX_BYTES = 6 * 2**20
+# Budget for the resident-dq fused kernel's whole-(s, hd) fp32 dq slab,
+# of which VMEM holds three (the accumulator and the output's two
+# buffers): 8 MiB takes hd 1024 to s 2048 (38.5 MiB in all by
+# `_bwd_resident_vmem_bytes`; measured there 2.81 ms a layer against the
+# split pair's 5.06, PR 41) and hd 1280 to s 1536. Longer sequences take
+# the split pair (measured faster than the DMA fused variant).
+RESIDENT_DQ_MAX_BYTES = 8 * 2**20
+
+
+def _tile_lanes(n):
+    return -(-n // 128) * 128
+
+
+def _fwd_resident_vmem_bytes(block_q, s_p, hd, num_heads, itemsize):
+    """VMEM the resident forward's specs take: the pipeline holds two
+    buffers of every operand and output block (the (Bq, Bk) float32
+    intermediates of the heads in flight come on top)."""
+    q_out = 2 * block_q * hd * itemsize + block_q * _tile_lanes(num_heads) * 4
+    return 2 * (q_out + 2 * s_p * hd * itemsize)
+
+
+def _bwd_resident_vmem_bytes(block_q, block_k, s_qp, hd, num_heads,
+                             itemsize):
+    """VMEM the resident-dq backward's specs take: two buffers of every
+    operand and output block (the whole fp32 dq among them), one of each
+    scratch (dk, dv, dq accumulators)."""
+    q_side = 2 * block_q * (hd * itemsize + _tile_lanes(num_heads) * 4)
+    kv_side = 4 * block_k * hd * itemsize      # k, v in; dk, dv out
+    slab = s_qp * hd * 4
+    return 2 * (q_side + kv_side + slab) + 2 * block_k * hd * 4 + slab
 
 
 def _resident_dq_fits(hd, s_qp):
     return s_qp * hd * 4 <= RESIDENT_DQ_MAX_BYTES
 
 
-def _resident_blocks(w):
-    """Measured-fastest (block_q, block_k) for the resident-dq kernel by
-    the width the kernel RUNS at (s=1024-class; XL_BWD_COMPARE.json +
-    in-session sweeps): fat (256, 256) blocks fit next to the dq slab to
-    width 896 (the gpt2-xl 13-head group pads there); at 1024 they
-    overflow scoped VMEM by 256K and (128, 256) is the fastest fit; at
-    1280 even that overflows and (256, 128) stands. block_k stays a
+def _resident_blocks(w, s_qp=1024, itemsize=2):
+    """(block_q, block_k) for the resident-dq kernel at the width ``w`` it
+    RUNS at. (256, 512) measured fastest at every shape swept on the chip
+    with `VMEM_LIMIT_BYTES` in place (PR 41, ms a layer, bf16, 20k
+    tokens): width 768 1.22 against 1.30 at (256, 256); 1024 1.62 against
+    1.70 at (256, 256), 1.81 at (128, 512), 2.28 at (128, 256), 1.97 at
+    (512, 256), 3.6 at (512, 512) and 3.8 at (256, 1024); 1280 1.62
+    against 2.19 at (256, 128); gpt2-xl's two groups 1.04 against 1.11 at
+    (256, 256). The k block twice the q block is what lets a diagonal
+    pair walk half a block. Where the specs' VMEM (`_bwd_resident_vmem_
+    bytes`: it grows with width, sequence and itemsize) passes three
+    quarters of the limit the calls ask for — float32 operands at the
+    longest resident sequences — the k block halves. block_k stays a
     128-multiple (the bias block's lane dim)."""
-    if w <= 896:
-        return (256, 256)
-    if w <= 1024:
-        return (128, 256)
-    return (256, 128)
+    for blocks in ((256, 512), (256, 256)):
+        if _bwd_resident_vmem_bytes(*blocks, s_qp, w, max(w // 64, 1),
+                                    itemsize) <= VMEM_LIMIT_BYTES * 3 // 4:
+            return blocks
+    return (128, 256)
 
 
 def _est_s_qp(s):
@@ -1132,28 +1313,27 @@ def _head_groups(num_heads, d_head):
     return None
 
 
-def auto_blocks(hd, num_heads=None, seq_len=None):
+def auto_blocks(hd, num_heads=None, seq_len=None, itemsize=2):
     """BACKWARD (block_q, block_k) for the packed kernels by activation
     width h*d, keyed to the path _bwd_packed will take (pass seq_len so
     the fused-vs-split fit decision matches the dispatcher's; without it
     the fused family is assumed where width allows). Fused (one walk
-    computes dq/dk/dv): (256, 256) measures fastest to GPT-2-medium width
-    (8.3 vs the split path's 9.6 ms at the bench shape), (128, 256) at
-    hd 1280. Wider widths run the fused kernel per HEAD GROUP of width
-    <= FUSED_GROUP_TARGET, so they get the fat (256, 256) blocks of the
-    <=1024 case — keyed on the PADDED width the kernel really runs at
-    (e.g. 20 heads of d=80 split 10+10 is 800 wide on paper but pads to
-    1280, where (256, 256) overflows vmem). Split fallback: the bwd
-    kernels hold q/do (Bq, hd) and k/v (Bk, hd) slabs double-buffered
-    plus a (Bq or Bk, hd) fp32 scratch in the 16M scoped-vmem budget;
-    (256, 512) measures fastest up to GPT-2-medium width but overflows
-    by ~1M at gpt2-xl's hd=1600, so split blocks shrink as the width
+    computes dq/dk/dv) with dq resident: `_resident_blocks`, keyed on
+    the PADDED width the kernel really runs at (wider widths run the
+    fused kernel per HEAD GROUP of width <= FUSED_GROUP_TARGET; 20 heads
+    of d=80 split 10+10 is 800 wide on paper but pads to 1280). The
+    forced DMA variant and the split fallback keep the blocks they were
+    tuned to inside XLA's default 16 MiB of VMEM (rounds 3-5; not swept
+    again since the calls ask for `VMEM_LIMIT_BYTES`): the split kernels
+    hold q/do (Bq, hd) and k/v (Bk, hd) slabs double-buffered plus a
+    (Bq or Bk, hd) fp32 scratch, and their blocks shrink as the width
     grows."""
     seq_len = seq_len if seq_len else 1024
     plan, w = _bwd_dispatch(hd, num_heads, seq_len)
     if plan in ("fused", "grouped"):
-        if _resident_dq_fits(w, _est_s_qp(seq_len)):
-            return _resident_blocks(w)
+        s_qp = _est_s_qp(seq_len)
+        if _resident_dq_fits(w, s_qp):
+            return _resident_blocks(w, s_qp, itemsize)
         # forced fused past the resident budget -> the explicit-DMA
         # variant, whose working set has no resident slab: the round-3
         # tuned blocks stand
@@ -1165,12 +1345,15 @@ def auto_blocks(hd, num_heads=None, seq_len=None):
     return 128, 256
 
 
-def auto_fwd_blocks(hd):
-    """FORWARD (block_q, block_k): lighter working set than the backward
-    (no fp32 dq scratch, fewer operands), so the measured-fast (256, 512)
-    holds to wider models; past hd=1024 the conservative (256, 256) keeps
-    the streaming kernel comfortably inside scoped vmem."""
-    if hd <= 1024:
+def auto_fwd_blocks(hd, seq_len=None, itemsize=2):
+    """FORWARD (block_q, block_k). The resident kernel (``seq_len`` given
+    and inside `RESIDENT_FWD_MAX_ELEMS`): (256, 512) at every width (at
+    1280: 1.39 ms a layer against 1.95 at (256, 256), PR 41). The
+    streaming kernel, and a caller that names no sequence: (256, 512) to
+    hd 1024, (256, 256) past it, as tuned inside XLA's default 16 MiB."""
+    resident = seq_len is not None and \
+        _resident_fwd_fits(hd, seq_len, itemsize)
+    if resident or hd <= 1024:
         return DEFAULT_BLOCK_PACKED, DEFAULT_BLOCK_PACKED_K
     return 256, 256
 
@@ -1210,14 +1393,16 @@ def _flash_bwd_bshd_rule(sm_scale, causal, block_q, interpret, block_k,
     bbk = bwd_block_k or block_k
     # bias was padded to the FWD block_k grain; re-pad to the bwd grain so
     # the kernels' (1, 1, block_k) bias slices can never run off the end
-    bias_b = _pad_bias(bias_p[:, 0, :s], b, s, min(bbk, s))
+    bias_b = None if bias_p is None else \
+        _pad_bias(bias_p[:, 0, :s], b, s, min(bbk, s))
     dq, dk, dv = _bwd_packed(pack(q), pack(k), pack(v), bias_b, out,
                              pack(do), lse, scale, causal,
                              bbq, bbk, interpret, h)
     unpack = lambda t: t.reshape(b, s, h, d)
     # bias is a MASK, not a trainable term: zero cotangent by contract
     # (the wrapper stop_gradients it too)
-    return unpack(dq), unpack(dk), unpack(dv), jnp.zeros_like(bias_p[:, :, :s])
+    d_bias = None if bias_p is None else jnp.zeros_like(bias_p[:, :, :s])
+    return unpack(dq), unpack(dk), unpack(dv), d_bias
 
 
 _flash_bshd_core.defvjp(_flash_fwd_bshd_rule, _flash_bwd_bshd_rule)
@@ -1238,22 +1423,23 @@ def flash_attention_bshd(q, k, v, sm_scale=None, causal=True,
     (0 keep / -1e9 drop — the BERT key-padding mask). Treated as a
     constant: no gradient flows into it."""
     b, s, h, d = q.shape
-    # None block args resolve by width so EVERY caller (GPT-2, the BERT
-    # encoder layer, module_inject'ed models) stays inside scoped vmem.
-    # Explicit FWD blocks do NOT flow into the backward: the bwd kernels'
-    # working set is larger, so a caller tuning only the forward (e.g.
-    # block_q=512) would silently push the bwd past the 16M scoped-vmem
-    # budget auto_blocks exists to respect. Sweep the bwd with the
-    # explicit bwd_block_* args (tests/perf/sweep_flash_bwd_blocks.py).
-    fq, fk = auto_fwd_blocks(h * d)
-    bq_auto, bk_auto = auto_blocks(h * d, num_heads=h, seq_len=s)
+    # None block args resolve by width, sequence and itemsize so EVERY
+    # caller (GPT-2, the BERT encoder layer, module_inject'ed models)
+    # gets the measured blocks inside `VMEM_LIMIT_BYTES`. Explicit FWD
+    # blocks do NOT flow into the backward: its working set is larger and
+    # its best blocks are others (a forward tuned to block_q=512 would
+    # run the backward at a third of its speed). Sweep both with
+    # tests/perf/flash_attention_microbench.py.
+    itemsize = q.dtype.itemsize
+    fq, fk = auto_fwd_blocks(h * d, s, itemsize)
+    bq_auto, bk_auto = auto_blocks(h * d, num_heads=h, seq_len=s,
+                                   itemsize=itemsize)
     bwd_block_q = bwd_block_q or bq_auto
     bwd_block_k = bwd_block_k or bk_auto
     block_q = block_q or fq
     block_k = block_k or fk
-    if mask_bias is None:
-        bias = jnp.zeros((b, 1, s), jnp.float32)
-    else:
+    bias = None      # no operand and no add in the kernels (GPT-2)
+    if mask_bias is not None:
         bias = jax.lax.stop_gradient(mask_bias.astype(jnp.float32))
         if bias.ndim == 2:
             bias = bias[:, None, :]
@@ -1291,11 +1477,12 @@ def fused_ln_qkv_attention(x, ln_scale, ln_bias, qkv_w, qkv_b, num_heads,
     """x: (b, s, d_model) -> attention context (b, s, d_model), causal,
     sm_scale fixed at 1/sqrt(d_head). None block args resolve by width
     (auto_fwd_blocks / auto_blocks); explicit fwd blocks do NOT flow into
-    the bwd (its vmem budget is tighter — pass bwd_block_* to tune it)."""
+    the bwd (its best blocks are others — pass bwd_block_* to tune it)."""
     hd = x.shape[-1]
-    fq, fk = auto_fwd_blocks(hd)
+    itemsize = x.dtype.itemsize
+    fq, fk = auto_fwd_blocks(hd, x.shape[1], itemsize)
     bq_auto, bk_auto = auto_blocks(hd, num_heads=num_heads,
-                                   seq_len=x.shape[1])
+                                   seq_len=x.shape[1], itemsize=itemsize)
     bwd_block_q = bwd_block_q or bq_auto
     bwd_block_k = bwd_block_k or bk_auto
     return _fused_lnqkv_core(x, ln_scale, ln_bias, qkv_w, qkv_b, num_heads,
@@ -1320,13 +1507,8 @@ def _fused_lnqkv_attn_fwd(x, ln_scale, ln_bias, qkv_w, qkv_b, num_heads,
     b, s, hd = x.shape
     d = hd // num_heads
     q, k, v = _lnqkv(x, ln_scale, ln_bias, qkv_w, qkv_b, eps)
-    # the kernels clamp block_k to min(block_k, s); pad the (zero) bias at
-    # the SAME clamped grain or its lane count falls out of step with the
-    # padded k length for s < block_k (matters the day a key-padding mask
-    # is threaded through this op)
-    bk = min(block_k, s)
-    bias = jnp.zeros((b, 1, ((s + bk - 1) // bk) * bk), jnp.float32)
-    out, lse = _fwd_packed(q, k, v, bias, 1.0 / (d ** 0.5), causal,
+    # no key-padding mask reaches this op: the kernels take no bias
+    out, lse = _fwd_packed(q, k, v, None, 1.0 / (d ** 0.5), causal,
                            block_q, block_k, interpret, num_heads)
     return out, (x, ln_scale, ln_bias, qkv_w, qkv_b, out, lse)
 
@@ -1339,9 +1521,7 @@ def _fused_lnqkv_attn_bwd(num_heads, eps, causal, block_q, block_k,
     (q, k, v), lnqkv_vjp = jax.vjp(
         lambda x_, s_, b_, w_, bb_: _lnqkv(x_, s_, b_, w_, bb_, eps),
         x, ln_scale, ln_bias, qkv_w, qkv_b)
-    bbk = min(bwd_block_k, s)
-    bias = jnp.zeros((b, 1, ((s + bbk - 1) // bbk) * bbk), jnp.float32)
-    dq, dk, dv = _bwd_packed(q, k, v, bias, out, do, lse,
+    dq, dk, dv = _bwd_packed(q, k, v, None, out, do, lse,
                              1.0 / (d ** 0.5), causal, bwd_block_q,
                              bwd_block_k, interpret, num_heads)
     return lnqkv_vjp([dq, dk, dv])  # list: matches _lnqkv's jnp.split output

@@ -300,12 +300,148 @@ def test_fused_attn_under_remat_matches():
                                    rtol=1e-5, atol=1e-5)
 
 
+# ---------------------------------------------------------------------------
+# What a block pair traces follows what the call can see (PR 41): no bias
+# operand without a ``mask_bias``, no tail compare for a sequence that is a
+# block's multiple, the scale on q where it is a power of two, heads a
+# 128-lane tile at a time, and in the resident backward a diagonal pair
+# that walks only the keys its queries see.
+# ---------------------------------------------------------------------------
+def _dense_reference(q, k, v, causal):
+    d = q.shape[-1]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                        k.astype(jnp.float32)) / (d ** 0.5)
+    if causal:
+        s = q.shape[1]
+        scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32))
+
+
+def _assert_value_and_grads_match(flash_fn, ref_fn, q, k, v,
+                                  tol=1e-4, grad_tol=2e-3):
+    def loss(fn):
+        def of(q, k, v):
+            out = fn(q, k, v).astype(jnp.float32)
+            return jnp.sum(out * jnp.sin(out))
+        return of
+    np.testing.assert_allclose(np.asarray(loss(flash_fn)(q, k, v)),
+                               np.asarray(loss(ref_fn)(q, k, v)),
+                               rtol=tol, atol=tol)
+    got = jax.grad(loss(flash_fn), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(ref_fn), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b_ in zip("qkv", got, want):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b_, np.float32),
+            rtol=grad_tol, atol=grad_tol, err_msg="d" + name)
+
+
+def _packed(causal, block_q, block_k, **kw):
+    from deepspeed_tpu.ops.transformer.flash_attention import (
+        flash_attention_bshd)
+    return lambda q, k, v: flash_attention_bshd(
+        q, k, v, None, causal, block_q, True, block_k,
+        bwd_block_q=block_q, bwd_block_k=block_k, **kw)
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (128, 256), (256, 128),
+                                    (256, 256)], ids=str)
+def test_packed_block_shapes_match_reference(blocks):
+    """s 512 cut four ways: pairs wholly under the diagonal, pairs the
+    diagonal crosses, and (k block twice the q block) pairs half above
+    it, which the resident backward walks by live sub-blocks. Two heads
+    of 64: one 128-lane tile (`_head_group`)."""
+    q, k, v = rand_qkv(1, 512, 2, 64, seed=41)
+    _assert_value_and_grads_match(_packed(True, *blocks),
+                                  reference_causal_attention, q, k, v)
+
+
+@pytest.mark.parametrize("s", [320, 402])
+def test_packed_tail_under_a_wide_k_block(s):
+    """A sequence that is no multiple of the block (the tail compare is
+    traced) with the k block twice the q block (the diagonal pair's
+    sub-block walk): padded keys and padded query rows count nowhere."""
+    q, k, v = rand_qkv(1, s, 2, 64, seed=43)
+    _assert_value_and_grads_match(_packed(True, 128, 256),
+                                  reference_causal_attention, q, k, v)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_a_zero_mask_bias_is_no_mask_bias(causal):
+    """The bias operand exists only where the caller gave a mask_bias;
+    a bias of zeros gives what no bias gives, value and gradients."""
+    q, k, v = rand_qkv(2, 256, 2, 64, seed=47)
+    zero = jnp.zeros((2, 256), jnp.float32)
+    loss = lambda fn: lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+    with_bias = _packed(causal, 128, 128, mask_bias=zero)
+    without = _packed(causal, 128, 128)
+    np.testing.assert_allclose(np.asarray(with_bias(q, k, v)),
+                               np.asarray(without(q, k, v)),
+                               rtol=1e-6, atol=1e-6)
+    for a, b_ in zip(
+            jax.grad(loss(with_bias), argnums=(0, 1, 2))(q, k, v),
+            jax.grad(loss(without), argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (128, 256)], ids=str)
+def test_packed_non_causal_matches_reference(blocks):
+    """Non-causal at a block's multiple: no mask is traced at all."""
+    from deepspeed_tpu.ops.transformer import flash_attention as fa
+    assert fa._score_mask(0, 0, block_q=128, block_k=128, causal=False,
+                          seq_len=256) is None
+    q, k, v = rand_qkv(1, 256, 2, 64, seed=53)
+    _assert_value_and_grads_match(
+        _packed(False, *blocks),
+        lambda q, k, v: _dense_reference(q, k, v, False), q, k, v)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_packed_d_head_80_keeps_the_scale_on_the_scores(causal):
+    """1/sqrt(80) is no power of two: the scale stays on the float32
+    scores, and heads of 80 lanes go one at a time."""
+    from deepspeed_tpu.ops.transformer import flash_attention as fa
+    assert not fa._scale_folds(80 ** -0.5) and fa._head_group(2, 80) == 1
+    q, k, v = rand_qkv(1, 256, 2, 80, seed=59)
+    _assert_value_and_grads_match(
+        _packed(causal, 128, 128),
+        lambda q, k, v: _dense_reference(q, k, v, causal), q, k, v)
+
+
+def test_packed_bf16_tiles_match_reference():
+    """The training cell's dtype: bfloat16 operands into the MXU, float32
+    softmax; the folded scale (1/8) is exact in bfloat16."""
+    q, k, v = (t.astype(jnp.bfloat16) for t in rand_qkv(1, 256, 4, 64, 61))
+    _assert_value_and_grads_match(
+        _packed(True, 128, 256),
+        lambda q, k, v: _dense_reference(q, k, v, True), q, k, v,
+        tol=2e-2, grad_tol=4e-2)
+
+
+@pytest.mark.parametrize("d_head,folds", [(16, True), (32, False),
+                                          (64, True), (80, False),
+                                          (128, False), (256, True)])
+def test_scale_folds_only_for_a_power_of_two(d_head, folds):
+    from deepspeed_tpu.ops.transformer import flash_attention as fa
+    assert fa._scale_folds(1.0 / d_head ** 0.5) is folds
+
+
+@pytest.mark.parametrize("heads,d_head,group", [
+    (16, 64, 2), (12, 64, 2), (25, 64, 1), (14, 64, 2), (8, 32, 4),
+    (6, 32, 1), (20, 80, 1), (16, 128, 1), (4, 256, 1)])
+def test_head_group_is_the_heads_a_tile_holds(heads, d_head, group):
+    from deepspeed_tpu.ops.transformer import flash_attention as fa
+    assert fa._head_group(heads, d_head) == group
+
+
 def test_auto_blocks_by_width(monkeypatch):
     """Width-aware block defaults, keyed to the backward path taken. AUTO
     mode (the default) runs the resident-dq fused kernel wherever its fp32
-    dq slab fits VMEM — (256, 256)-class blocks, per head group past the
-    single-call cap — and the split pair for long sequences or when
-    forced (DS_FLASH_BWD_MODE=split)."""
+    dq slab fits its budget — (256, 512) blocks since the calls ask for
+    `VMEM_LIMIT_BYTES` (PR 41), per head group past the single-call cap —
+    and the split pair for long sequences or when forced
+    (DS_FLASH_BWD_MODE=split)."""
     from deepspeed_tpu.ops.transformer import flash_attention as fa
     monkeypatch.setattr(fa, "BWD_MODE", "split")
     assert fa._fused_plan(1024, 16, 1024) == "split"
@@ -316,22 +452,75 @@ def test_auto_blocks_by_width(monkeypatch):
     # auto at model context lengths: fused family
     assert fa._fused_plan(1024, 16, 1024) == "fused"
     assert fa._fused_plan(1280, 20, 1024) == "fused"
-    assert fa.auto_blocks(768, num_heads=12, seq_len=1024) == (256, 256)
-    assert fa.auto_blocks(1024, num_heads=16, seq_len=1024) == (128, 256)
-    assert fa.auto_blocks(1280, num_heads=20, seq_len=1024) == (256, 128)
+    assert fa.auto_blocks(768, num_heads=12, seq_len=1024) == (256, 512)
+    assert fa.auto_blocks(1024, num_heads=16, seq_len=1024) == (256, 512)
+    assert fa.auto_blocks(1280, num_heads=20, seq_len=1024) == (256, 512)
     # gpt2-xl: 25 heads x 64 -> two fused groups (13+12, widths 832/768,
-    # padded 896/768 -> fat blocks)
+    # padded 896/768)
     assert fa._fused_plan(1600, 25, 1024) == "grouped"
-    assert fa.auto_blocks(1600, num_heads=25) == (256, 256)
-    # 20 heads x 80 groups 10+10 but PADS to 16 heads = width 1280: the
-    # resident kernel there needs (256, 128), not the narrow-group blocks
-    assert fa.auto_blocks(1600, num_heads=20, seq_len=1024) == (256, 128)
+    assert fa.auto_blocks(1600, num_heads=25) == (256, 512)
+    # 20 heads x 80 groups 10+10 and PADS to 16 heads = width 1280
+    assert fa.auto_blocks(1600, num_heads=20, seq_len=1024) == (256, 512)
     assert fa.auto_blocks(1600) == (128, 256)   # no head info: split
-    # long sequence: the resident dq slab outgrows VMEM -> split pair
+    # long sequence: the resident dq slab outgrows its budget -> split
     assert fa._fused_plan(1024, 16, 4096) == "split"
     assert fa.auto_blocks(1024, num_heads=16, seq_len=4096) == (256, 512)
     assert fa.auto_fwd_blocks(1024) == (256, 512)
     assert fa.auto_fwd_blocks(1600) == (256, 256)
+
+
+@pytest.mark.parametrize("hd,heads,seq,itemsize,blocks", [
+    (768, 12, 1024, 2, (256, 512)),
+    (1024, 16, 1024, 2, (256, 512)),      # the training cell
+    (1280, 20, 1024, 2, (256, 512)),
+    (1600, 25, 1024, 2, (256, 512)),      # gpt2-xl's two groups
+    (1024, 16, 2048, 2, (256, 512)),      # the largest resident dq slab
+    (1024, 16, 1024, 4, (256, 512)),      # float32 operands (the tests')
+    (1024, 16, 2048, 4, (256, 256)),      # ... past 3/4 of the limit
+    (1024, 16, 4096, 2, (256, 512)),      # split pair, as before
+])
+def test_auto_blocks_follow_width_sequence_and_itemsize(
+        hd, heads, seq, itemsize, blocks, monkeypatch):
+    from deepspeed_tpu.ops.transformer import flash_attention as fa
+    monkeypatch.setattr(fa, "BWD_MODE", "auto")
+    assert fa.auto_blocks(hd, num_heads=heads, seq_len=seq,
+                          itemsize=itemsize) == blocks
+
+
+@pytest.mark.parametrize("hd,seq,blocks", [
+    (1024, 1024, (256, 512)), (1280, 1024, (256, 512)),
+    (1600, 1024, (256, 512)),             # K/V resident
+    (1024, 2048, (256, 512)), (1600, 2048, (256, 256)),   # streaming
+    (1024, None, (256, 512)), (1600, None, (256, 256))])
+def test_auto_fwd_blocks_by_kernel(hd, seq, blocks):
+    from deepspeed_tpu.ops.transformer import flash_attention as fa
+    assert fa.auto_fwd_blocks(hd, seq) == blocks
+
+
+@pytest.mark.parametrize("b,s,h,d,itemsize", [
+    (20, 1024, 16, 64, 2),                # gpt2-350m-train.seq1024
+    (20, 1024, 12, 64, 2), (16, 1024, 20, 64, 2), (8, 1024, 14, 64, 2),
+    (10, 2048, 16, 64, 2), (4, 1024, 16, 64, 4), (4, 2048, 16, 64, 4),
+    (2, 1024, 16, 80, 2)], ids=str)
+def test_table_blocks_fit_the_vmem_limit(b, s, h, d, itemsize):
+    """The block sets the tables choose, reckoned from the calls' specs
+    (two buffers a blocked operand or output, one a scratch), fit the
+    ``vmem_limit_bytes`` every call of the file asks for, with a quarter
+    of it left for the kernels' (Bq, Bk) float32 intermediates."""
+    from deepspeed_tpu.ops.transformer import flash_attention as fa
+    hd = h * d
+    room = fa.VMEM_LIMIT_BYTES * 3 // 4
+    assert fa._compiler_params().vmem_limit_bytes == fa.VMEM_LIMIT_BYTES \
+        <= 96 * 2 ** 20                   # the chip has 128 MiB
+    fq, _ = fa.auto_fwd_blocks(hd, s, itemsize)
+    if fa._resident_fwd_fits(hd, s, itemsize):
+        assert fa._fwd_resident_vmem_bytes(fq, s, hd, h, itemsize) <= room
+    else:                                 # the streaming forward's turn
+        assert (s, itemsize) == (2048, 4)
+    assert fa._fused_plan(hd, h, s, mode="auto") == "fused"
+    bq, bk = fa.auto_blocks(hd, num_heads=h, seq_len=s, itemsize=itemsize)
+    assert bk % 128 == 0 and s % bq == 0
+    assert fa._bwd_resident_vmem_bytes(bq, bk, s, hd, h, itemsize) <= room
 
 
 def test_head_groups_partition():
@@ -397,9 +586,12 @@ def test_bwd_packed_dispatch_plan():
     assert fa._fused_plan(16 * 64, 16, 8192, mode="auto") == "split"
     assert fa._fused_plan(16 * 64, 16, 8192, mode="fused") == "fused"
     assert fa._fused_plan(16 * 64, 16, 1024, mode="split") == "split"
-    # resident fit boundary: 6 MB budget / fp32 -> s*hd <= 1.5M elements
+    # resident fit boundary: 8 MiB budget / fp32 -> s*hd <= 2M elements
     assert fa._resident_dq_fits(1024, 1536)
-    assert not fa._resident_dq_fits(1024, 2048)
+    assert fa._resident_dq_fits(1024, 2048)
+    assert not fa._resident_dq_fits(1024, 2304)
+    assert fa._fused_plan(16 * 64, 16, 2048, mode="auto") == "fused"
+    assert fa._fused_plan(16 * 64, 16, 2304, mode="auto") == "split"
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -460,3 +652,44 @@ def test_fused_bwd_chunked_rmw_d80(causal):
     (both 128-multiples — the Mosaic constraint on output-ref stores).
     Numerics must match the split pair exactly."""
     _check_packed_bwd_matches_split(1, 160, 10, 80, causal, seed=11)
+
+
+def test_a_kernel_is_traced_from_the_head_of_a_stack_chunk():
+    """CPython 3.12 keeps a thread's frames in 16 KiB chunks; a call that
+    crosses a chunk's end maps a chunk and unmaps it on return, and where
+    that end falls inside the frames a kernel body's tracing calls
+    through, the tracing pays it thousands of times (PERF.md section 6,
+    PR 41: 102 s against 14 on the chip's host). `_call_kernel`'s frame is
+    larger than a chunk, so what it calls starts at the head of a fresh
+    one, whatever the depth it is called at."""
+    import statistics
+    import sys
+    import time
+    from deepspeed_tpu.ops.transformer import flash_attention as fa
+    assert fa._call_kernel(lambda a, b: a - b, 5, 3) == 2
+    assert fa._call_kernel.__code__.co_stacksize * 8 >= 2 * 16384
+
+    def leaf():
+        pass
+
+    def hot():
+        start = time.perf_counter()
+        for _ in range(20000):
+            leaf()
+        return time.perf_counter() - start
+
+    def at_depth(n, fn):
+        return fn() if n == 0 else at_depth(n - 1, fn)
+
+    limit = sys.getrecursionlimit()
+    sys.setrecursionlimit(max(limit, 2000))
+    try:
+        plain = [at_depth(n, hot) for n in range(300)]
+        headed = [at_depth(n, lambda: fa._call_kernel(hot))
+                  for n in range(300)]
+    finally:
+        sys.setrecursionlimit(limit)
+    if max(plain) < 20 * statistics.median(plain):
+        pytest.skip("this interpreter shows no chunk boundary to avoid")
+    # (the third largest: a loaded host may stall a reading or two)
+    assert sorted(headed)[-3] < max(plain) / 5

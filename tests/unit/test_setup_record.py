@@ -192,7 +192,20 @@ def test_startup_report_and_line(built):
     assert len(_named(other.startup_report()["rows"], "setup.engine")) == 1
 
 
-def test_an_event_outside_every_row_lands_in_the_other_row():
+@pytest.fixture
+def fresh_record(monkeypatch):
+    """A record and a thread's open rows of the test's own, as a new
+    process has them. What an xdist worker ran before this file decides
+    both otherwise: the bounded record may be full (a full record takes
+    no row), and a program that an earlier test made and never called
+    leaves its row open on the thread, where it takes the events meant
+    for the ``setup.programs.other`` row and the cache's loads."""
+    import threading
+    monkeypatch.setattr(annotate, "_setup_rows", [])
+    monkeypatch.setattr(compile_cache, "_open", threading.local())
+
+
+def test_an_event_outside_every_row_lands_in_the_other_row(fresh_record):
     def a_small_program_of_setup(x):
         return x * 3 + 1
 
@@ -267,7 +280,8 @@ def test_the_listener_is_registered_once_however_many_engines():
             if fn is compile_cache._on_event] == [compile_cache._on_event]
 
 
-def test_jit_program_returns_the_jitted_function_and_nothing_wraps_it():
+def test_jit_program_returns_the_jitted_function_and_nothing_wraps_it(
+        fresh_record):
     """The engine's cache holds what ``jit_program`` returned from the
     start; ``first_call`` opens the program's row, ``first_call_over``
     closes it, and the call between them is the caller's own."""
@@ -336,7 +350,8 @@ def persistent_cache(tmp_path):
     compilation_cache.reset_cache()
 
 
-def test_the_row_says_whether_the_cache_had_the_program(persistent_cache):
+def test_the_row_says_whether_the_cache_had_the_program(persistent_cache,
+                                                        fresh_record):
     """With a persistent cache that keeps every program, a first start
     compiles (``cache: miss``) and a second one, which has nothing in
     memory, loads (``hit``): ``compile_s`` is then the load."""

@@ -32,6 +32,8 @@ BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 # (batch, seq, heads, d_head) at the widths the repo trains
 GPT2_MEDIUM = (4, 1024, 16, 64)
 GPT2_XL = (2, 1024, 25, 64)
+# ... and at the batch the benchmark's training cell runs (micro-batch 20)
+TRAIN_CELL = (20, 1024, 16, 64)
 
 
 @pytest.fixture(scope="module")
@@ -81,11 +83,16 @@ def _compile(fn, sharding, *shapes):
 
 
 # ---------------------------------------------------------------- flash
-@pytest.mark.parametrize("shape", [GPT2_MEDIUM, GPT2_XL],
-                         ids=["gpt2_medium", "gpt2_xl"])
+@pytest.mark.parametrize("shape", [GPT2_MEDIUM, GPT2_XL, TRAIN_CELL],
+                         ids=["gpt2_medium", "gpt2_xl", "train_cell"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
 def test_flash_attention_bshd_compiles(one_chip, no_persistent_cache,
                                        shape, grad):
+    """``train_cell`` is the benchmark's training shape, batch 20: at the
+    batch of 4 XLA holds the kernels' whole operands in VMEM (``S(1)``),
+    and the scoped limit a call meets there is not the one the cell
+    meets (a backward at (512, 512) compiles at 4 and fails at 20 inside
+    XLA's default 16 MiB)."""
     from deepspeed_tpu.ops.transformer.flash_attention import \
         flash_attention_bshd
 
@@ -99,10 +106,12 @@ def test_flash_attention_bshd_compiles(one_chip, no_persistent_cache,
     assert _compile(fn, one_chip, *[(shape, BF16)] * 3) >= 1
 
 
-@pytest.mark.parametrize("shape", [GPT2_MEDIUM, GPT2_XL],
-                         ids=["gpt2_medium", "gpt2_xl"])
+@pytest.mark.parametrize("shape", [GPT2_MEDIUM, GPT2_XL, TRAIN_CELL],
+                         ids=["gpt2_medium", "gpt2_xl", "train_cell"])
 def test_fused_ln_qkv_attention_grad_compiles(one_chip,
                                               no_persistent_cache, shape):
+    """``train_cell``: the program `gpt2-350m-train.seq1024` runs a layer
+    (see `test_flash_attention_bshd_compiles` on why batch 4 is not it)."""
     from deepspeed_tpu.ops.transformer.flash_attention import \
         fused_ln_qkv_attention
     b, s, h, dh = shape

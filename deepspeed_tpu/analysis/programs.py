@@ -358,7 +358,7 @@ def inference_step_sequence(engine):
 
 def collect_inference_programs(engine):
     params = sds_tree(engine.params)
-    pools = tuple(_sds(a) for a in engine.kv.buffers())
+    pools = tuple(_sds(a) for a in engine._pools())
     rng = _rng_struct()
     temp = np.float32(1.0)
     top_p = np.float32(1.0)
@@ -370,6 +370,18 @@ def collect_inference_programs(engine):
     pool = getattr(engine, "state", None)
     state = tuple(_sds(a) for a in pool.buffers()) if pool else ()
     donate = tuple(range(1, 1 + len(pools) + len(state)))
+    groups = engine.page_groups if paged else []
+
+    def tables(*lead):
+        """The page tables a program is handed: one (.., max_pages)
+        array, or for a decoder with page groups a table and a base a
+        group (inference/engine.py)."""
+        if not getattr(engine, "_grouped", False):
+            return jax.ShapeDtypeStruct(lead + (engine.max_pages,), np.int32)
+        return (tuple(jax.ShapeDtypeStruct(lead + (g.max_pages,), np.int32)
+                      for g in groups),
+                tuple(jax.ShapeDtypeStruct(lead, np.int32) for _ in groups))
+
     specs = []
     greedy, top_k = True, 0
     for bucket in engine.prefill_buckets:
@@ -377,8 +389,7 @@ def collect_inference_programs(engine):
         if paged:
             args = (params,) + pools + state + (
                 (np.int32(0),) if state else ()) + (
-                ids, jax.ShapeDtypeStruct((engine.max_pages,), np.int32),
-                np.int32(0), np.int32(1), rng, temp, top_p)
+                ids, tables(), np.int32(0), np.int32(1), rng, temp, top_p)
         else:
             args = (params,) + pools + (ids, np.int32(0), np.int32(0),
                     np.int32(1), rng, temp, top_p)
@@ -397,11 +408,10 @@ def collect_inference_programs(engine):
         tokens = jax.ShapeDtypeStruct((engine.num_slots, width), np.int32)
         lengths = jax.ShapeDtypeStruct((engine.num_slots,), np.int32)
         if paged:
-            tables = jax.ShapeDtypeStruct(
-                (engine.num_slots, engine.max_pages), np.int32)
             args = (params,) + pools + state + (
                 (jax.ShapeDtypeStruct((engine.num_slots,), np.bool_),)
-                if state else ()) + (tokens, lengths, tables, rng, temp,
+                if state else ()) + (tokens, lengths,
+                                     tables(engine.num_slots), rng, temp,
                                      top_p)
         else:
             args = (params,) + pools + (tokens, lengths, rng, temp,

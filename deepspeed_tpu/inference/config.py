@@ -230,11 +230,13 @@ class DeepSpeedInferenceConfig:
 
         self.num_pages = sub.get(INFERENCE_NUM_PAGES,
                                  INFERENCE_NUM_PAGES_DEFAULT)
-        _require(self.num_pages is None or
-                 (isinstance(self.num_pages, int) and
-                  not isinstance(self.num_pages, bool) and
-                  self.num_pages >= 1),
-                 "{} must be an int >= 1 or null, got {!r}".format(
+        counts = self.num_pages if isinstance(self.num_pages, list) \
+            else [self.num_pages]
+        _require(self.num_pages is None or (counts and all(
+            isinstance(n, int) and not isinstance(n, bool) and n >= 1
+            for n in counts)),
+                 "{} must be an int >= 1, a list of them (one a page "
+                 "group of the model) or null, got {!r}".format(
                      INFERENCE_NUM_PAGES, self.num_pages))
         _require(not (INFERENCE_NUM_PAGES in sub and
                       INFERENCE_KV_POOL_FRACTION in sub),
@@ -376,13 +378,20 @@ class DeepSpeedInferenceConfig:
                      INFERENCE_FLEET, FLEET_ADAPTER_RANK,
                      self.fleet_adapter_rank))
 
-    def resolve_num_pages(self, slots, max_seq_len):
+    def resolve_num_pages(self, slots, max_seq_len, group=0):
         """Usable page-pool size for a concrete engine geometry: the
-        explicit ``num_pages``, else ``ceil(kv_pool_fraction * slots *
+        explicit ``num_pages`` (of page group ``group``, where a list
+        gives one a group), else ``ceil(kv_pool_fraction * slots *
         max_seq / kv_block_size)`` — fraction 1.0 = exactly the slot
         layout's HBM footprint. Always at least one full sequence."""
         pages_per_seq = -(-max_seq_len // self.kv_block_size)
-        if self.num_pages is not None:
+        if isinstance(self.num_pages, list):
+            _require(group < len(self.num_pages),
+                     "{} gives {} page counts, the model has a page "
+                     "group {}".format(INFERENCE_NUM_PAGES,
+                                       len(self.num_pages), group))
+            n = self.num_pages[group]
+        elif self.num_pages is not None:
             n = self.num_pages
         else:
             n = -(-int(self.kv_pool_fraction * slots * max_seq_len)

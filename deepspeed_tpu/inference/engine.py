@@ -46,9 +46,11 @@ from ..utils.annotate import (annotate, engine_tag, setup_span,
                               startup_line, startup_report)
 from ..utils.logging import logger
 from .config import DeepSpeedInferenceConfig
-from .decoder import decoder_of, refuse_latent, refuse_recurrent
+from .decoder import (decoder_of, refuse_latent, refuse_recurrent,
+                      refuse_windowed)
 from .kv_cache import KVCache, PagedKVCache, StatePool, write_path
-from .paging import GARBAGE_PAGE, PageAllocator, PrefixCache
+from .paging import (GARBAGE_PAGE, GroupPages, PagePoolExhausted,
+                     PrefixCache)
 from .sampling import make_sampler
 
 _UNSET = object()    # "argument not given" (None means "no EOS token")
@@ -195,28 +197,60 @@ class InferenceEngine:
         if ic.fleet_role is not None:
             refuse_latent(spec, "the fleet's page hand-off "
                           "(inference.fleet)")
+        # paged layers in groups, a windowed one among them perhaps
+        # (inference/decoder.py): whatever takes a page for the whole
+        # of a position's state in every layer refuses them
+        if self.kv_layout != "paged":
+            refuse_windowed(spec, "the slot layout (inference.kv_layout: "
+                            "\"slot\")")
+        if ic.prefix_caching:
+            refuse_windowed(spec,
+                            "prefix caching (inference.prefix_caching)")
+        if ic.spec_enabled:
+            refuse_windowed(spec, "speculative decoding "
+                            "(inference.speculative)")
+        if ic.fleet_role is not None:
+            refuse_windowed(spec, "the fleet's page hand-off "
+                            "(inference.fleet)")
         with setup_span("setup.cache", engine=self.startup_tag) as attrs:
             # per-slot recurrent state, a pool of its own beside the pages
             # (None for a model whose pages are its whole state)
             self.state = StatePool.allocate(spec.state, self.num_slots) \
                 if spec.state else None
+            # a decoder that declares groups is handed a table a group
+            # and each table's base; any other the one table, as ever
+            self._grouped = bool(spec.groups)
             if self.kv_layout == "paged":
                 self.max_pages = -(-self.max_seq_len // self.page_size)
-                num_pages = ic.resolve_num_pages(self.num_slots,
-                                                 self.max_seq_len)
-                self.kv = PagedKVCache.allocate(
-                    num_pages, spec.kv_layers, spec.kv_heads, self.page_size,
-                    spec.d_head, self.dtype, mesh=mesh, lanes=spec.page_lanes)
+                # a pool pair, an allocator and a table a slot for each
+                # group; GARBAGE_PAGE everywhere a slot has no allocation
+                # (jit writes there are redirected and reads
+                # position-masked)
+                self.kv_groups, self.page_groups = [], []
+                for g, group in enumerate(spec.page_groups):
+                    num_pages = self._group_num_pages(ic, g, group)
+                    self.kv_groups.append(PagedKVCache.allocate(
+                        num_pages, group.layers, spec.kv_heads,
+                        self.page_size, spec.d_head, self.dtype, mesh=mesh,
+                        lanes=spec.page_lanes))
+                    self.page_groups.append(GroupPages(
+                        num_pages, self.num_slots, self.max_pages,
+                        self.page_size, window=group.window,
+                        chunk_tokens=self.prefill_buckets[-1]))
+                # the FIRST group under the names there were before there
+                # were groups (the same objects: the fleet's hand-off and
+                # the tests read and write them)
+                first = self.page_groups[0]
+                self.kv = self.kv_groups[0]
+                self.allocator = first.allocator
+                self.page_tables, self.page_counts = first.tables, \
+                    first.counts
+                self._windowed = tuple(g for g in self.page_groups
+                                       if g.window is not None)
                 # what one cached token costs, pad lanes included: a reader
                 # of the pool's counters need not know the model
-                self.kv_token_bytes = self.kv.token_bytes
-                self.allocator = PageAllocator(num_pages)
-                # per-slot logical->physical map; GARBAGE_PAGE everywhere a
-                # slot has no allocation (jit writes there are redirected
-                # and reads position-masked)
-                self.page_tables = np.full((self.num_slots, self.max_pages),
-                                           GARBAGE_PAGE, np.int32)
-                self.page_counts = np.zeros((self.num_slots,), np.int32)
+                self.kv_token_bytes = sum(kv.token_bytes
+                                          for kv in self.kv_groups)
                 # pages matched at admission time per slot, so the first-
                 # chunk extension match knows where to resume
                 self._admit_matched = {}
@@ -225,17 +259,19 @@ class InferenceEngine:
                     if ic.prefix_caching else None)
             else:
                 self.max_pages = 0
+                self.page_groups, self._windowed = [], ()
                 self.kv = KVCache.allocate(
                     self.num_slots, spec.kv_layers, spec.kv_heads,
                     self.max_seq_len, spec.d_head, self.dtype, mesh=mesh)
                 self.kv_token_bytes = self.kv.nbytes // (
                     self.num_slots * self.max_seq_len)
+                self.kv_groups = [self.kv]
                 self.allocator = None
                 self.page_tables = None
                 self.page_counts = None
                 self.prefix_cache = None
-            attrs["bytes"] = int(self.kv.nbytes) + (
-                int(self.state.nbytes) if self.state else 0)
+            attrs["bytes"] = sum(int(kv.nbytes) for kv in self.kv_groups) \
+                + (int(self.state.nbytes) if self.state else 0)
 
         # paged-attention decode read path (docs/pallas_kernels.md):
         # resolved once at engine build; the DECODE program family runs
@@ -317,15 +353,36 @@ class InferenceEngine:
             "layout={} kv_cache={:.1f} MB state_pool={:.1f} MB{}{}".format(
                 self.num_slots, self.max_seq_len, self.prefill_buckets,
                 self.dtype_name, self.kv_layout,
-                self.kv.nbytes / 2 ** 20,
+                sum(kv.nbytes for kv in self.kv_groups) / 2 ** 20,
                 self.state.nbytes / 2 ** 20 if self.state else 0.0,
                 " pages={}x{} paged_attn={} token_bytes={}".format(
-                    self.allocator.num_pages, self.page_size,
+                    "+".join("{}{}".format(
+                        g.allocator.num_pages,
+                        "" if g.window is None else "(window {}, table "
+                        "{})".format(g.window, g.max_pages))
+                        for g in self.page_groups), self.page_size,
                     self.paged_attention_kernel, self.kv_token_bytes)
                 if self.kv_layout == "paged" else "",
                 " spec_k={} drafter={}".format(
                     self.spec_k, type(self.drafter).__name__)
                 if self.drafter is not None else ""))
+
+    def _group_num_pages(self, ic, g, group):
+        """The pool size of page group ``g``: ``inference.num_pages``
+        (its entry ``g`` where that is a list). A windowed group that
+        the config gives no count gets what every slot's promise and
+        the one chunk beyond them need (paging.GroupPages); one that it
+        does must hold a chunk's pages at the least."""
+        if group.window is None:
+            return ic.resolve_num_pages(self.num_slots, self.max_seq_len,
+                                        group=g)
+        steady, width = GroupPages.spans(
+            group.window, self.page_size, self.prefill_buckets[-1],
+            self.max_pages)
+        if isinstance(ic.num_pages, list):
+            return ic.resolve_num_pages(self.num_slots,
+                                        width * self.page_size, group=g)
+        return self.num_slots * steady + width - steady
 
     def startup_report(self):
         """This engine's rows of the start-up record (docs/telemetry.md,
@@ -363,6 +420,8 @@ class InferenceEngine:
         }
         if self.kv_layout == "paged":
             state["page_counts"] = [int(n) for n in self.page_counts]
+            if self._grouped:
+                state["page_groups"] = [g.stats() for g in self.page_groups]
         return state
 
     def debug_dump(self, reason="debug_dump"):
@@ -488,13 +547,26 @@ class InferenceEngine:
     def _state_buffers(self):
         return self.state.buffers() if self.state is not None else ()
 
+    def _pools(self):
+        """The cache's page (or slot) pools, group after group."""
+        return sum((kv.buffers() for kv in self.kv_groups), ())
+
+    def _after_eviction(self, take, group, slot, upto_tokens):
+        """``take(slot, upto_tokens)`` (a group's ``admit`` or ``grow``,
+        which just found its pool short) once more, after the prefix
+        cache gave up what the slot lacks."""
+        if self.prefix_cache is None:
+            return False
+        self.prefix_cache.evict(group.shortfall(slot, upto_tokens))
+        return take(slot, upto_tokens)
+
     def wait(self):
         """Block until every serving program launched so far is over:
         each returns the cache in place of the buffers it was given, so
         the cache is ready when the program is. Sends the device
         nothing, and returns in microseconds once the last launch's
         tokens are on the host (the scheduler's timers fence on this)."""
-        buffers = self.kv.buffers() + self._state_buffers()
+        buffers = self._pools() + self._state_buffers()
         if self.drafter is not None and self.drafter.needs_model:
             buffers += self.drafter.kv.buffers()
         for buffer in buffers:
@@ -505,17 +577,20 @@ class InferenceEngine:
     def _update_cache(self, buffers):
         """What a program returned in place of its donated buffers: the
         page (or slot) pools, then the recurrent state arrays."""
-        n_kv = len(self.kv.buffers())
-        self.kv.update(tuple(buffers[:n_kv]))
+        at = 0
+        for kv in self.kv_groups:
+            n = len(kv.buffers())
+            kv.update(tuple(buffers[at:at + n]))
+            at += n
         if self.state is not None:
-            self.state.update(tuple(buffers[n_kv:]))
+            self.state.update(tuple(buffers[at:]))
 
     def _launch(self, fn, args):
         """Run a serving program on the cache it donates. What it
         returns is the cache back in place, the chosen tokens, the
         decoder's counters (``counter_names``) and the logits. ->
         (tokens, counters), still on the device."""
-        pools = self.kv.buffers()
+        pools = self._pools()
         out = fn(self.params, *pools, *args)
         n_cache = len(pools) + len(self._state_buffers())
         self._update_cache(out[:n_cache])
@@ -560,8 +635,9 @@ class InferenceEngine:
         forward, head = self.decoder.forward_hidden, self.decoder.logits
         sampler = make_sampler(greedy, top_k)
         paged, ps = self.kv_layout == "paged", self.page_size
-        n_kv, n_state = len(self.kv.buffers()), len(self._state_buffers())
+        n_kv, n_state = len(self._pools()), len(self._state_buffers())
         counted = {"counters": True} if self.counter_names else {}
+        grouped = self._grouped
 
         if paged:
             def prefill(params, *rest):
@@ -573,7 +649,9 @@ class InferenceEngine:
                 # start/length scalar int32 — the chunk covers positions
                 # [start, start+length); padded tokens are masked out of
                 # the cache write (kv_cache.write_tokens) and leave a
-                # recurrent state as it was; rng, temperature, top_p;
+                # recurrent state as it was (a decoder with page groups:
+                # page_row is (a row a group, each row's base)); rng,
+                # temperature, top_p;
                 # adapter args (when attached): (a_stack (n,r,d),
                 # b_stack (n,V,r), adapter_id scalar) — a per-tenant
                 # logits delta; the cache writes are adapter-independent.
@@ -584,9 +662,15 @@ class InferenceEngine:
                     kwargs["state_slot"], rest = rest[0], rest[1:]
                 ids, page_row, start, length, rng, temperature, top_p, \
                     *adapter_args = rest
+                if grouped:
+                    rows, bases = page_row
+                    kwargs["page_bases"] = tuple(b[None] for b in bases)
+                    tables = tuple(row[None] for row in rows)
+                else:
+                    tables = page_row[None]
                 hidden, cache, *counters = forward(
                     params, ids, cfg, cache=pools + state,
-                    positions=start[None], page_tables=page_row[None],
+                    positions=start[None], page_tables=tables,
                     valid_lens=length[None], page_size=ps, **kwargs)
                 last = jnp.take(hidden[0], length - 1, axis=0)     # (d,)
                 logits = head(params, last[None])                  # (1, V)
@@ -669,8 +753,9 @@ class InferenceEngine:
         forward, head = self.decoder.forward_hidden, self.decoder.logits
         sampler = make_sampler(greedy, top_k)
         paged, ps = self.kv_layout == "paged", self.page_size
-        n_kv, n_state = len(self.kv.buffers()), len(self._state_buffers())
+        n_kv, n_state = len(self._pools()), len(self._state_buffers())
         counted = {"counters": True} if self.counter_names else {}
+        grouped = self._grouped
 
         def _adapter_delta(hidden, a_stack, b_stack, adapter_ids):
             # per-slot LoRA readout: gather each slot's (A, B) pair and
@@ -687,8 +772,9 @@ class InferenceEngine:
                 # with them,
                 # advance (slots,) bool (the slots whose state this
                 # step advances); then tokens (slots, width); lengths
-                # (slots,) int32; page_tables; rng, temperature, top_p;
-                # adapter args
+                # (slots,) int32; page_tables (a decoder with page groups:
+                # (a table a group, each table's bases)); rng,
+                # temperature, top_p; adapter args
                 pools, rest = rest[:n_kv], rest[n_kv:]
                 state, rest = rest[:n_state], rest[n_state:]
                 kwargs = dict(counted)
@@ -696,6 +782,8 @@ class InferenceEngine:
                     kwargs["state_advance"], rest = rest[0], rest[1:]
                 tokens, lengths, page_tables, rng, temperature, top_p, \
                     *adapter_args = rest
+                if grouped:
+                    page_tables, kwargs["page_bases"] = page_tables
                 hidden, cache, *counters = forward(
                     params, tokens, cfg, cache=pools + state,
                     positions=lengths, page_tables=page_tables,
@@ -768,10 +856,24 @@ class InferenceEngine:
         return self._plan_executor.lifetime_snapshot()
 
     def page_pool_stats(self):
-        """``{num_pages, pages_in_use, occupancy}`` — None on the slot
-        layout (it has no pool to meter)."""
-        return self.allocator.stats() if self.allocator is not None \
-            else None
+        """``{num_pages, pages_in_use, occupancy}`` of the first page
+        group, and for a decoder with page groups ``groups``: the same
+        of each, a windowed one's table width, promises and the pages
+        it gave back as they slid out — None on the slot layout (it
+        has no pool to meter)."""
+        if self.allocator is None:
+            return None
+        stats = self.allocator.stats()
+        if self._grouped:
+            stats["groups"] = [g.stats() for g in self.page_groups]
+        return stats
+
+    def group_page_counts(self, slots):
+        """-> (pages the ``slots`` hold in each group, pages each group
+        gave back as they slid out so far): what a step's span and the
+        serving metrics say of a decoder with page groups."""
+        return ([int(g.counts[slots].sum()) for g in self.page_groups],
+                [g.freed for g in self.page_groups])
 
     def prefix_stats(self):
         return self.prefix_cache.stats() if self.prefix_cache is not None \
@@ -792,28 +894,31 @@ class InferenceEngine:
         if self.kv_layout != "paged":
             return True
         n = len(context)
-        row = self.page_tables[slot]
-        matched = []
+        first, matched = self.page_groups[0], []
         if self.prefix_cache is not None:
             # cap the match below the full prompt: the first sampled
             # token's logits must come from at least one real forward
             matched, _ = self.prefix_cache.match(
                 context, n - 1, namespace=self._prefix_namespace(slot))
-        need = self.pages_for(n) - len(matched)
-        if not self.allocator.can_alloc(need) and \
-                self.prefix_cache is not None:
-            self.prefix_cache.evict(need)
-        if not self.allocator.can_alloc(need):
-            if self.prefix_cache is not None:
+            # the shared pages lead the slot's row, admit takes the rest
+            first.tables[slot, :len(matched)] = matched
+            first.counts[slot] = len(matched)
+        # every group has room, or none is touched: a group without a
+        # window takes the context's pages, a windowed one the promise
+        # of a decode step's (paging.GroupPages.admit)
+        for i, group in enumerate(self.page_groups):
+            if group.admit(slot, n) or self._after_eviction(
+                    group.admit, group, slot, n):
+                continue
+            if matched:
                 # refs AND stats roll back: a queued request retrying
                 # admission every step must not inflate the hit gauges
+                first.tables[slot, :len(matched)] = GARBAGE_PAGE
+                first.counts[slot] = 0
                 self.prefix_cache.unmatch(matched)
+            for taken in self.page_groups[:i]:
+                taken.release(slot)
             return False
-        for j, page in enumerate(matched):
-            row[j] = page
-        for j in range(len(matched), self.pages_for(n)):
-            row[j] = self.allocator.alloc()
-        self.page_counts[slot] = self.pages_for(n)
         self._admit_matched[slot] = len(matched)
         return True
 
@@ -839,24 +944,21 @@ class InferenceEngine:
         return (have + len(extra)) * self.page_size
 
     def ensure_pages(self, slot, upto_tokens):
-        """Grow ``slot``'s allocation to cover ``upto_tokens`` logical
-        positions. False when the pool is exhausted (after trying
-        prefix-cache eviction) — the scheduler preempts."""
+        """Every group's pages for ``slot``'s positions below
+        ``upto_tokens``, a windowed group first giving back what no
+        query at the slot's length or later can see. False when a pool
+        is exhausted (after trying prefix-cache eviction): the
+        scheduler preempts; the other groups keep what they took (the
+        slot uses or frees it)."""
         if self.kv_layout != "paged":
             return True
-        need = min(self.pages_for(upto_tokens), self.max_pages)
-        cur = int(self.page_counts[slot])
-        if need <= cur:
-            return True
-        if not self.allocator.can_alloc(need - cur) and \
-                self.prefix_cache is not None:
-            self.prefix_cache.evict(need - cur)
-        if not self.allocator.can_alloc(need - cur):
-            return False
-        for j in range(cur, need):
-            self.page_tables[slot, j] = self.allocator.alloc()
-        self.page_counts[slot] = need
-        return True
+        for group in self._windowed:
+            group.slide(slot, int(self.lengths[slot]))
+        ok = True
+        for group in self.page_groups:
+            ok = (group.grow(slot, upto_tokens) or self._after_eviction(
+                group.grow, group, slot, upto_tokens)) and ok
+        return ok
 
     def register_prefix(self, slot, context):
         """Record the prompt's FULL pages in the prefix cache once its
@@ -946,7 +1048,21 @@ class InferenceEngine:
                 a_stack, b_stack = self._adapter_stack
                 extra = (a_stack, b_stack,
                          np.int32(self.slot_adapters[slot]))
-            if self.kv_layout == "paged":
+            if self._grouped:
+                # the chunk's keys and, in a windowed group, the window
+                # before them
+                assert self.lengths[slot] == start, \
+                    "a chunk at {} of a slot {} tokens long".format(
+                        start, self.lengths[slot])
+                if not self.ensure_pages(slot, start + n):
+                    raise PagePoolExhausted(
+                        "no pages for a prefill chunk of {} tokens at "
+                        "{}".format(n, start))
+                where = (tuple(g.tables[slot].copy()
+                               for g in self.page_groups),
+                         tuple(np.int32(g.base[slot] * self.page_size)
+                               for g in self.page_groups))
+            elif self.kv_layout == "paged":
                 self._cow_writes(slot, start, start + n - 1)
                 where = self.page_tables[slot].copy()
             else:
@@ -971,6 +1087,10 @@ class InferenceEngine:
                       **self._kv_write_attr(bucket)):
             token, counters = self._launch(fn, args)
             self.lengths[slot] = start + n
+            # the launch has its own copy of the tables: what no later
+            # query sees goes back now, not at the next chunk
+            for group in self._windowed:
+                group.slide(slot, start + n)
         with annotate("engine.prefill.fetch"):
             token, counters = jax.device_get((token, counters))
         self._note_counters(counters)
@@ -1029,8 +1149,13 @@ class InferenceEngine:
                     advance[:] = False
                     advance[list(active)] = True
                 state += (advance,)
-            args = state + (tokens, self.lengths.copy()) + (
-                (self.page_tables.copy(),) if paged else ()) + (
+            if self._grouped:
+                tables = ((tuple(g.tables.copy() for g in self.page_groups),
+                           tuple(g.base * np.int32(self.page_size)
+                                 for g in self.page_groups)),)
+            else:
+                tables = (self.page_tables.copy(),) if paged else ()
+            args = state + (tokens, self.lengths.copy()) + tables + (
                 self._next_rng(greedy), np.float32(temperature),
                 np.float32(top_p)) + extra
         with annotate("engine.decode.dispatch",
@@ -1062,10 +1187,8 @@ class InferenceEngine:
         """Retire a slot: release its pages back to the pool (shared
         prefix pages just drop one reference) and zero its length."""
         if self.kv_layout == "paged":
-            for j in range(int(self.page_counts[slot])):
-                self.allocator.free(int(self.page_tables[slot, j]))
-            self.page_tables[slot, :] = GARBAGE_PAGE
-            self.page_counts[slot] = 0
+            for group in self.page_groups:
+                group.release(slot)
             self._admit_matched.pop(slot, None)
         self.lengths[slot] = 0
         self.slot_adapters[slot] = 0

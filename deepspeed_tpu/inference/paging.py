@@ -18,12 +18,21 @@ control flow, not math:
     prefix. The cache holds its own reference on every registered page,
     so retiring the sequence that populated it does not free the pages;
     LRU eviction drops that reference.
+  * :class:`GroupPages` — one GROUP of paged layers' allocator and
+    page table a slot (inference/decoder.py ``PageGroup``). A group
+    with a ``window`` keeps a SLIDING table: column 0 is the first page
+    that holds a key a coming query can see, the pages before it went
+    back to the allocator when they slid out, and the table's width is
+    bounded by the window and the largest chunk whatever
+    ``max_seq_len`` is.
   * :func:`plan_chunks` — chunked-prefill schedule with the slot-layout
     write-safety guarantee (start + bucket never exceeds max_seq, or the
     clamped ``dynamic_update_slice`` would shift the write window down
     over live positions).
 """
 from collections import OrderedDict
+
+import numpy as np
 
 GARBAGE_PAGE = 0
 
@@ -115,6 +124,136 @@ class PageAllocator:
                 "pages_in_use": self.pages_in_use,
                 "occupancy": (self.pages_in_use / self.num_pages
                               if self.num_pages else 0.0)}
+
+
+class GroupPages:
+    """The host side of one group of paged layers: an allocator, a
+    table ``tables[slot]`` and the columns of it that hold pages
+    (``counts[slot]``). Column ``c`` of a slot's row holds the page of
+    its tokens ``[(base[slot] + c) * page_size, + page_size)``.
+
+    Without a ``window`` ``base`` stays 0 and a slot keeps every page
+    until :meth:`release`: the table and the allocator as they were
+    before there were groups.
+
+    With a ``window`` (query ``t`` sees key ``j`` iff ``0 <= t - j <
+    window``) the table SLIDES: :meth:`slide` to the position of the
+    next query gives back every page none of whose tokens that query or
+    a later one can see, moves the row left and raises ``base``, so
+    that column 0 is always the first page with a visible key. A slot
+    then holds at most ``steady`` pages between two launches (a decode
+    step's ``window`` keys) and ``max_pages`` during a chunk of
+    ``chunk_tokens`` (its keys, and the ``window - 1`` before them):
+    the table is that wide whatever ``max_seq_len`` is. Admission does
+    not take pages from such a group, it takes a PROMISE of ``steady``
+    of them (``reserved``), and one chunk's worth beyond every promise
+    is kept for the one chunk that runs at a time: a slot admitted can
+    always get the pages of its next launch, so no request waits or is
+    preempted for this group's pages."""
+
+    def __init__(self, num_pages, num_slots, max_pages, page_size,
+                 window=None, chunk_tokens=1):
+        self.allocator = PageAllocator(num_pages)
+        self.page_size, self.window = int(page_size), window
+        self.steady = self.reserved = 0
+        if window is not None:
+            self.steady, max_pages = self.spans(
+                window, page_size, chunk_tokens, max_pages)
+            assert num_pages >= max_pages, \
+                "a windowed pool of {} pages cannot hold one chunk's {} " \
+                "pages".format(num_pages, max_pages)
+        self.max_pages = int(max_pages)
+        self.tables = np.full((num_slots, self.max_pages), GARBAGE_PAGE,
+                              np.int32)
+        self.counts = np.zeros((num_slots,), np.int32)
+        # the logical page in column 0 (0 for ever without a window)
+        self.base = np.zeros((num_slots,), np.int32)
+        self._promised = np.zeros((num_slots,), bool)
+        self.freed = 0         # pages given back as they slid out
+
+    @staticmethod
+    def spans(window, page_size, chunk_tokens, max_pages):
+        """-> (pages a slot of a windowed group holds for a decode
+        step, pages it holds for a chunk of ``chunk_tokens``: the
+        table's width). ``q`` queries in a row see ``window + q - 1``
+        keys, which begin anywhere in a page."""
+        span = lambda q: min(max_pages, (window + q - 2) // page_size + 2)
+        return span(1), span(chunk_tokens)
+
+    def pages_for(self, n_tokens):
+        return -(-n_tokens // self.page_size)
+
+    def admit(self, slot, n_tokens):
+        """Room for a request of ``n_tokens`` so far: its pages
+        (without a window), or the promise of ``steady`` pages. False,
+        with nothing taken, where the pool has none."""
+        if self.window is None:
+            return self.grow(slot, n_tokens)
+        chunk = self.max_pages - self.steady
+        if self.reserved + self.steady + chunk > self.allocator.num_pages:
+            return False
+        self.reserved += self.steady
+        self._promised[slot] = True
+        return True
+
+    def slide(self, slot, position):
+        """Give back the pages no query at ``position`` or later can
+        see. -> how many."""
+        if self.window is None:
+            return 0
+        first = max(0, position - self.window + 1) // self.page_size
+        gone = first - int(self.base[slot])
+        if gone <= 0:
+            return 0
+        row, held = self.tables[slot], int(self.counts[slot])
+        n = min(gone, held)
+        for page in row[:n].tolist():
+            self.allocator.free(page)
+        row[:held - n] = row[n:held]
+        row[held - n:held] = GARBAGE_PAGE
+        self.counts[slot], self.base[slot] = held - n, first
+        self.freed += n
+        return n
+
+    def shortfall(self, slot, upto_tokens):
+        """Pages the slot lacks to cover ``upto_tokens`` positions."""
+        need = min(self.pages_for(upto_tokens) - int(self.base[slot]),
+                   self.max_pages)
+        return max(0, need - int(self.counts[slot]))
+
+    def grow(self, slot, upto_tokens):
+        """Pages for the slot's positions below ``upto_tokens``. False,
+        with nothing taken, where the pool has too few."""
+        cur = int(self.counts[slot])
+        need = min(-(-upto_tokens // self.page_size) - int(self.base[slot]),
+                   self.max_pages)
+        if need <= cur:                 # every decode step but one in 16
+            return True
+        if not self.allocator.can_alloc(need - cur):
+            return False
+        for j in range(cur, need):
+            self.tables[slot, j] = self.allocator.alloc()
+        self.counts[slot] = need
+        return True
+
+    def release(self, slot):
+        """Every page of the slot back to the pool (a shared one drops
+        a reference), and its promise."""
+        for page in self.tables[slot, :int(self.counts[slot])].tolist():
+            self.allocator.free(page)
+        self.tables[slot, :] = GARBAGE_PAGE
+        self.counts[slot] = self.base[slot] = 0
+        if self._promised[slot]:
+            self._promised[slot] = False
+            self.reserved -= self.steady
+
+    def stats(self):
+        out = self.allocator.stats()
+        if self.window is not None:
+            out.update(window=self.window, table_width=self.max_pages,
+                       pages_promised=self.reserved,
+                       pages_freed_sliding=self.freed)
+        return out
 
 
 class PrefixCache:

@@ -20,7 +20,13 @@ byte-for-byte.
 Paged-pool pressure: admission that cannot allocate stays queued;
 mid-decode exhaustion preempts the YOUNGEST decoding request (pages
 freed, request requeued; its context re-prefills on re-admission — the
-recompute-preemption discipline).
+recompute-preemption discipline). A model whose paged layers stand in
+groups (inference/decoder.py) has a pool a group: a request is admitted
+when EVERY pool has room for it, a decode step that any pool cannot
+serve preempts, and a retired or preempted request's pages go back to
+every pool. A windowed group's pages go back as they slide out of the
+window, in the step that makes it so: ``window_freed`` on the step's
+``sched.decode.pages`` and ``sched.prefill.chunk`` spans.
 
 Timing uses utils/timer.py's synchronized timers around each engine
 call, fenced on the buffers the engine's programs return
@@ -98,6 +104,16 @@ class ContinuousBatchingScheduler:
         self._admitted = 0
         self.steps = 0
         self.preemptions = 0
+        # a decoder with page groups: what each group gave back as its
+        # pages slid out of a window, as last noted (_note_group_pages)
+        self._grouped = bool(getattr(engine, "_grouped", False))
+        self._group_freed = 0
+        if self._grouped:
+            self._windowed = [g.window is not None
+                              for g in engine.page_groups]
+            self._window_pool = sum(
+                g.allocator.num_pages for g in engine.page_groups
+                if g.window is not None)
 
     def _account(self, method, *args, **kwargs):
         """Apply one ServingMetrics update to the caller's object AND
@@ -112,6 +128,25 @@ class ContinuousBatchingScheduler:
         counters = getattr(self.engine, "last_counters", None)
         if counters:
             self._account("record_counters", counters)
+
+    def _note_group_pages(self, span, slots=None):
+        """A decoder with page groups: on ``span`` the pages its
+        windowed groups gave back since the last note (``window_freed``)
+        and, with the decoding ``slots``, the pages they hold in the
+        groups without a window and with one and the windowed pools'
+        size; the serving metrics get the same counts."""
+        live, freed = self.engine.group_page_counts(
+            slots if slots is not None else [])
+        attrs = {"window_freed": sum(freed) - self._group_freed}
+        self._group_freed = sum(freed)
+        if slots is not None:
+            window_live = sum(n for n, w in zip(live, self._windowed) if w)
+            attrs.update(full_live=sum(live) - window_live,
+                         window_live=window_live,
+                         window_pool=self._window_pool)
+            self._account("record_group_pages", live, freed)
+        if span is not None:
+            span.set_metadata(**attrs)
 
     # ------------------------------------------------------------- intake
 
@@ -312,7 +347,8 @@ class ContinuousBatchingScheduler:
             # state (where the model keeps one) from zeros
             first = start == 0
             with annotate("sched.prefill.chunk", uid=req.uid, tokens=ln,
-                          padded=self.engine.bucket_for(ln), first=first):
+                          padded=self.engine.bucket_for(ln),
+                          first=first) as span:
                 if first and self.engine.state is not None:
                     self._account("record_state_reset")
                 chunk = req.context[start:start + ln]
@@ -325,6 +361,8 @@ class ContinuousBatchingScheduler:
                                                   sampling=self.sampling)
                 t.stop()
                 dt = t.elapsed(reset=True)
+                if self._grouped:
+                    self._note_group_pages(span)
                 with annotate("sched.prefill.commit"):
                     self._account("record_prefill", ln, dt)
                     self._account_counters()
@@ -375,7 +413,7 @@ class ContinuousBatchingScheduler:
         return k
 
     def _decode(self, retired):
-        with annotate("sched.decode.pages"):
+        with annotate("sched.decode.pages") as span:
             active = [r for r in self.slots
                       if r is not None and r.state == "decode"]
             if not active:
@@ -410,6 +448,8 @@ class ContinuousBatchingScheduler:
             pending = [0] * slots
             for req in active:
                 pending[req.slot] = req.generated[-1]
+            if self._grouped:
+                self._note_group_pages(span, [r.slot for r in active])
 
         if k_eff >= 1:
             # ---- speculative: draft k, verify all slots in one pass

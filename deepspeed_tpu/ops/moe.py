@@ -1,11 +1,12 @@
 """A sparse expert layer for any model file: a float32 router over ALL
 the model's experts, a range of them held here, no token dropped.
 
-``route`` scores every expert (``sigmoid(x W_g)``, float32 whatever the
-activations are), chooses ``top_k`` of them per token by score PLUS a
-per-expert selection bias (the bias shifts the choice only), and
-weights the chosen by their scores, renormalised over the chosen where
-the model says so. ``expert_ffn`` then computes the part of the layer's
+``route`` scores every expert (``sigmoid(x W_g)``, or ``softmax(x W_g)``
+over all of them where the model says ``scoring="softmax"``; float32
+whatever the activations are), chooses ``top_k`` of them per token by
+score PLUS a per-expert selection bias (the bias shifts the choice
+only), and weights the chosen by their scores, renormalised over the
+chosen where the model says so. ``expert_ffn`` then computes the part of the layer's
 result that the experts HELD here give (``experts_held``: a range
 ``(first, past the last)`` of expert ids; all of them on a chip that
 holds the whole layer, a share under expert parallelism): the routed
@@ -32,14 +33,19 @@ from .pallas import moe as kernels
 from .pallas.common import default_interpret
 
 
+SCORINGS = {"sigmoid": jax.nn.sigmoid,
+            "softmax": lambda z: jax.nn.softmax(z, axis=-1)}
+
+
 def route(x, router_w, expert_bias, top_k, norm_topk_prob=True,
-          scaling=1.0, norm_eps=1e-6):
+          scaling=1.0, norm_eps=1e-6, scoring="sigmoid"):
     """x (T, d); router_w (d, E) float32; expert_bias (E,) float32 or
     None; ``norm_eps``: what the model's code adds to the chosen
-    scores' sum. -> (chosen (T, top_k) int32, weights (T, top_k)
-    float32)."""
+    scores' sum; ``scoring``: each expert's score of its own
+    (``"sigmoid"``) or a probability over all experts (``"softmax"``).
+    -> (chosen (T, top_k) int32, weights (T, top_k) float32)."""
     with jax.named_scope("moe.route"):
-        scores = jax.nn.sigmoid(jnp.dot(
+        scores = SCORINGS[scoring](jnp.dot(
             x.astype(jnp.float32), router_w.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
         biased = scores if expert_bias is None else \

@@ -243,17 +243,28 @@ def _kernel(pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref, v_pool_ref,
         o_ref[0, j:j + 1, :] = jnp.sum(mine, axis=0, keepdims=True)
 
 
-def _grouped_kernel(pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref,
+def _grouped_kernel(pos_ref, vlen_ref, pt_ref, q_ref, k_pool_ref,
                     v_pool_ref, o_ref, k_buf, v_buf, k_sem, v_sem, *,
                     layer_idx, page_size, kv_heads, group, d_head,
-                    sm_scale, seq, chunk):
+                    sm_scale, seq, chunk, window=None):
     """One slot's page-table walk where ``group`` query heads share
     each key-value head (grouped-query attention; ``kv_heads = 1`` is
-    multi-query). The masking contract is :func:`_kernel`'s. What
-    differs: a page of ``kv_heads * d_head`` lanes is small (4 KB at
-    one head of 128), so pages are fetched ``chunk`` at a time into one
-    buffer of ``chunk * page_size`` tokens (the next chunk's copies in
-    flight while this one is on the MXU), and a key-value head's
+    multi-query). The masking contract is :func:`_kernel`'s, and with a
+    ``window`` a query also sees only the last ``window`` keys, its own
+    among them (``q_pos - k_pos < window``): the walk then starts at
+    the first page that holds a key the slot's first query can see and
+    masks the older keys of that page (with a sliding table,
+    inference/paging.py ``GroupPages``, that page is column 0).
+    ``window=None`` is the program there was before it.
+    ``pt_ref`` is the slot's own row of the page table, a (1, 1,
+    max_pages) block in scalar memory beside the two prefetched
+    scalars, not the whole table: 128 slots x 2,048 pages are all of
+    that memory's 1 MB, and a walk reads its own row only (rollouts and
+    extract measured the same either way, PERF.md section 6, PR 42).
+    What differs: a page of ``kv_heads * d_head`` lanes is small (4 KB
+    at one head of 128), so pages are fetched ``chunk`` at a time into
+    one buffer of ``chunk * page_size`` tokens (the next chunk's copies
+    in flight while this one is on the MXU), and a key-value head's
     ``seq * group`` queries are the rows of ONE matmul per chunk.
 
     q_ref / o_ref (1, kv_heads, seq * group, d_head), rows ordered
@@ -264,18 +275,28 @@ def _grouped_kernel(pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref,
     vlen = vlen_ref[i]
     live = pos + vlen - 1                  # last live absolute position
     n_pages = jnp.maximum(live, 0) // page_size + 1
-    n_chunks = (n_pages + chunk - 1) // chunk
+    if window is None:
+        first_page = first_token = 0
+        n_chunks = (n_pages + chunk - 1) // chunk
+    else:
+        first_page = jnp.maximum(pos - window + 1, 0) // page_size
+        first_token = first_page * page_size
+        n_chunks = jnp.maximum(n_pages - first_page + chunk - 1, 0) // chunk
     rows, tokens = seq * group, chunk * page_size
+
+    def past_first(offset, first):
+        # counted from the first page the walk fetches
+        return offset if window is None else first + offset
 
     def transfer(slot, c, start):
         # a chunk's last pages may lie past the live window: no copy,
         # and what the buffer holds there is masked below
         for j in range(chunk):
-            p = c * chunk + j
+            p = past_first(c * chunk + j, first_page)
 
             @pl.when(p < n_pages)
             def _copy():
-                phys = pt_ref[i, p]
+                phys = pt_ref[0, 0, p]
                 dst = pl.ds(j * page_size, page_size)
                 for pool, buf, sem in ((k_pool_ref, k_buf, k_sem),
                                        (v_pool_ref, v_buf, v_sem)):
@@ -300,9 +321,11 @@ def _grouped_kernel(pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref,
             transfer(jax.lax.rem(c + 1, 2), c + 1, True)
 
         transfer(slot, c, False)
-        k_pos = c * tokens + col
+        k_pos = past_first(c * tokens + col, first_token)
         mask = jnp.logical_and(k_pos <= q_pos, k_pos <= live)
-        vmask = (c * tokens + vcol) <= live
+        if window is not None:
+            mask = jnp.logical_and(mask, q_pos - k_pos < window)
+        vmask = past_first(c * tokens + vcol, first_token) <= live
         k_all, v_all = k_buf[slot], v_buf[slot]
         out = []
         for h in range(kv_heads):
@@ -334,10 +357,11 @@ def _grouped_kernel(pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref,
 
 def _grouped_paged_attention(q, k_pool, v_pool, page_tables, positions,
                              valid_lens, *, layer_idx, page_size,
-                             interpret, chunk=8):
+                             interpret, chunk=8, window=None):
     """:func:`paged_attention` for pools of fewer key-value heads than
     query heads. q (b, s, h, dh); pools (pages+1, layers, page_size,
-    kvh * dh) with ``h % kvh == 0``."""
+    kvh * dh) with ``h % kvh == 0``; ``window``: :func:`_grouped_kernel`'s
+    (``positions`` and the table then count from the same origin)."""
     b, s, h, dh = q.shape
     kvh = k_pool.shape[3] // dh
     group = h // kvh
@@ -348,10 +372,14 @@ def _grouped_paged_attention(q, k_pool, v_pool, page_tables, positions,
     q = q.reshape(b, s, kvh, group, dh).transpose(0, 2, 1, 3, 4) \
         .reshape(b, kvh, rows, dh)
     block = pl.BlockSpec((1, kvh, rows, dh), lambda i, *_: (i, 0, 0, 0))
+    # the slot's row (b, 1, max_pages): a block's last two dimensions
+    # are the array's
+    table = pl.BlockSpec((1, 1, max_pages), lambda i, *_: (i, 0, 0),
+                         memory_space=pltpu.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=2,
         grid=(b,),
-        in_specs=[block, pl.BlockSpec(memory_space=pl.ANY),
+        in_specs=[table, block, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=block,
         scratch_shapes=[
@@ -363,21 +391,22 @@ def _grouped_paged_attention(q, k_pool, v_pool, page_tables, positions,
     kernel = functools.partial(
         _grouped_kernel, layer_idx=layer_idx, page_size=page_size,
         kv_heads=kvh, group=group, d_head=dh,
-        sm_scale=1.0 / math.sqrt(dh), seq=s, chunk=chunk)
-    window = max_pages * page_size
+        sm_scale=1.0 / math.sqrt(dh), seq=s, chunk=chunk,
+        **({} if window is None else {"window": window}))
+    span = max_pages * page_size
     cost = pl.CostEstimate(
-        flops=4 * b * s * window * h * dh,
+        flops=4 * b * s * span * h * dh,
         bytes_accessed=(q.size * q.dtype.itemsize
-                        + 2 * b * window * kvh * dh
+                        + 2 * b * span * kvh * dh
                         * k_pool.dtype.itemsize + b * s * h * dh * 4),
-        transcendentals=b * s * window * h)
+        transcendentals=b * s * span * h)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, rows, dh), jnp.float32),
         cost_estimate=cost, interpret=interpret,
         name="paged_attention_grouped",
-    )(page_tables.astype(jnp.int32), positions.astype(jnp.int32),
-      valid_lens.astype(jnp.int32), q, k_pool, v_pool)
+    )(positions.astype(jnp.int32), valid_lens.astype(jnp.int32),
+      page_tables.astype(jnp.int32)[:, None, :], q, k_pool, v_pool)
     return out.reshape(b, kvh, s, group, dh).transpose(0, 2, 1, 3, 4) \
         .reshape(b, s, h, dh)
 
@@ -548,7 +577,8 @@ def mla_decode(q_abs, pool, page_tables, positions, valid_lens, *,
 
 
 def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
-                    *, layer_idx, page_size, interpret=None, mesh=None):
+                    *, layer_idx, page_size, interpret=None, mesh=None,
+                    window=None):
     """Paged attention for ``s`` new queries per slot against the pool.
     ``mesh``: the mesh the calling program spans — the kernel then runs
     under a shard_map over it (common.shard_kernel), heads split over
@@ -563,12 +593,16 @@ def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
     query heads (grouped-query attention: :func:`_grouped_kernel`);
     ``page_tables``: (b, max_pages) int32; ``positions``/``valid_lens``:
     (b,) int32. ``layer_idx`` is trace-static (the model's python layer
-    loop). Returns fp32 ctx (b, s, h, dh) — with a float32 pool within
-    1e-5 of the slot oracle's dense masked softmax (same contributing
-    entries, online accumulation order).
+    loop). ``window`` (grouped pools on one chip only): a query sees
+    the last ``window`` keys, its own among them. Returns fp32 ctx
+    (b, s, h, dh) — with a float32 pool within 1e-5 of the slot
+    oracle's dense masked softmax (same contributing entries, online
+    accumulation order).
     """
     if interpret is None:
         interpret = default_interpret()
+    if window is not None and mesh is not None:
+        raise ValueError("a windowed page walk has no mesh form yet")
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
         from ...parallel.topology import MODEL_AXIS
@@ -588,7 +622,10 @@ def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
         # fewer key-value heads than query heads: the grouped kernel
         return _grouped_paged_attention(
             q, k_pool, v_pool, page_tables, positions, valid_lens,
-            layer_idx=layer_idx, page_size=page_size, interpret=interpret)
+            layer_idx=layer_idx, page_size=page_size, interpret=interpret,
+            window=window)
+    if window is not None:
+        raise ValueError("only the grouped page walk takes a window")
     if k_pool.shape[2:] != (page_size, hd):
         raise ValueError(
             "paged_attention wants pools (pages+1, layers, page_size {}, "

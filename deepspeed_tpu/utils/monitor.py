@@ -129,6 +129,16 @@ class ServingMetrics:
         # sum over launches}} (an expert model's "moe.load": rows,
         # experts_hit, hottest_rows)
         self.program_counters = {}
+        # a model whose paged layers stand in groups: the pages the
+        # decoding slots hold in each group as the last step found them,
+        # and the pages each group has given back so far as they slid
+        # out of a window (0 for a group without one)
+        self.group_pages_live = []
+        self.group_pages_freed = []
+
+    def record_group_pages(self, live, freed):
+        self.group_pages_live = list(live)
+        self.group_pages_freed = list(freed)
 
     def record_counters(self, counters):
         """One launch's program counters, ``{name: {attribute:
@@ -276,6 +286,9 @@ class ServingMetrics:
                                  "bytes": self.state_bytes,
                                  "live_slots": self.state_live_slots,
                                  "resets": self.state_resets}
+        if self.group_pages_live:
+            out["page_groups"] = {"live": self.group_pages_live,
+                                  "freed": self.group_pages_freed}
         if self.program_counters:
             out["program_counters"] = {
                 name: dict(row)

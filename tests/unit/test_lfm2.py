@@ -298,9 +298,9 @@ def _layer_inputs(T=24, d=32, E=8, ff=16, seed=0):
             0.2 * f(E, ff, d))
 
 
-def _dense_layer(x, router, bias, w13, w2, top_k):
+def _dense_layer(x, router, bias, w13, w2, top_k, **routing):
     """Every expert on every token, masked by the routing."""
-    chosen, weights = moe.route(x, router, bias, top_k)
+    chosen, weights = moe.route(x, router, bias, top_k, **routing)
     ff = w2.shape[1]
     out = jnp.zeros_like(x)
     for e in range(w13.shape[0]):
@@ -340,14 +340,16 @@ def test_expert_layer_matches_every_expert_applied_densely(kernel):
 
 
 @pytest.mark.pallas
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
 @pytest.mark.parametrize("kernel", ["xla", "pallas"])
-def test_four_shares_of_the_experts_add_up_to_the_whole_layer(kernel):
-    """The guide's share test: the router over all 8 experts, each
-    share computing its own two experts' part; the parts add up to the
-    uncut layer's result, and the loads to its load."""
+def test_four_shares_of_the_experts_add_up_to_the_whole_layer(kernel,
+                                                              scoring):
+    """The guide's share test: the router over all 8 experts (by either
+    scoring), each share computing its own two experts' part; the parts
+    add up to the uncut layer's result, and the loads to its load."""
     x, router, bias, w13, w2 = _layer_inputs(seed=2)
-    want, _ = _dense_layer(x, router, bias, w13, w2, 2)
-    c, w = moe.route(x, router, bias, 2)
+    want, _ = _dense_layer(x, router, bias, w13, w2, 2, scoring=scoring)
+    c, w = moe.route(x, router, bias, 2, scoring=scoring)
     whole, whole_load = moe.expert_ffn(x, c, w, w13, w2, (0, 8), 8,
                                        kernel=kernel)
     parts = [moe.expert_ffn(x, c, w, w13[a:a + 2], w2[a:a + 2],

@@ -853,6 +853,120 @@ def test_moonlight_programs_run_the_kernels_and_alias_the_latent_pool(
         14.5 * 2 ** 30
 
 
+@pytest.mark.parametrize("window, row", [(None, 2048), (1024, 193)],
+                         ids=["full", "window"])
+def test_grouped_walk_compiles_at_mellum_widths(
+        one_chip, no_persistent_cache, window, row):
+    """The grouped page walk at Mellum2-12B-A2.5B's attention, 32 query
+    heads on 4 key-value heads of 128, 128 slots: a full layer over a
+    row of 2,048 pages (32,768 positions; the table goes to the kernel
+    a slot's row at a time: whole it is all of scalar memory's 1 MB,
+    which the compiler refused) and a sliding layer over its sliding
+    table of 193 columns with ``window=1024``."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+
+    def fn(q, k_pool, v_pool, page_tables, positions, valid_lens):
+        return paged_attention(q, k_pool, v_pool, page_tables, positions,
+                               valid_lens, layer_idx=1, page_size=16,
+                               interpret=False, window=window)
+
+    b, pages = 128, 65001 if window is None else 8577
+    layers = 2 if window is None else 6
+    pool = ((pages, layers, 16, 512), BF16)
+    assert _compile(fn, one_chip, ((b, 1, 32, 128), BF16), pool, pool,
+                    ((b, row), I32), ((b,), I32), ((b,), I32)) == 1
+
+
+@pytest.fixture(scope="module")
+def mellum_engine():
+    """A tiny Mellum engine on the CPU whose programs are lowered at
+    the published widths (``jamba_engine`` says how)."""
+    import json
+    import os
+    import deepspeed_tpu as deepspeed
+    from deepspeed_tpu.models import mellum
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mellum2-12b-a2.5b-serve.json")) as f:
+        cell = json.load(f)
+    tiny = dict(cell["model"], hidden_size=64, moe_intermediate_size=32,
+                num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+                vocab_size=128, num_experts=8, num_experts_per_tok=2)
+    eng = deepspeed.init_inference(
+        model=mellum.make_mellum_model(mellum.config_from_hf(tiny), seed=0),
+        config={"inference": dict(cell["inference"], max_batch_size=2,
+                                  num_pages=[4096, 512],
+                                  paged_attention_kernel="pallas")})
+    eng.model_config = mellum.config_from_hf(cell["model"],
+                                             moe_kernel="pallas")
+    return eng, cell
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_mellum_programs_run_the_kernels_and_alias_both_groups(
+        one_chip, no_persistent_cache, mellum_engine, monkeypatch, program):
+    """``jit_prefill`` (the largest bucket) and ``jit_decode`` (every
+    slot) of Mellum2-12B-A2.5B's first stage at the cell's pool shapes,
+    a table and a base a page group: two grouped matmuls a layer; in
+    decode every layer walks its group's pages in the grouped paged
+    kernel (the sliding layers over their 193 columns); in prefill a
+    page write a layer and the chunk's attention in blocks of keys (no
+    array of the whole context's scores); both groups' pool pairs, four
+    donated buffers, come back in place; and it fits the chip."""
+    from deepspeed_tpu.models import mellum
+    eng, cell = mellum_engine
+    cfg = eng.model_config
+    inference = cell["inference"]
+    slots, pages = inference["max_batch_size"], inference["num_pages"]
+    bucket, ps = inference["prefill_buckets"][-1], eng.page_size
+    rows = (inference["max_seq_len"] // ps, eng.page_groups[1].max_pages)
+    assert rows[1] == (cfg.window + bucket) // ps + 1 == 193
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(lambda: mellum.MellumDecoder(cfg).serving_params(
+        mellum.init_params(cfg, 0), BF16))
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), params)
+    lanes = cfg.n_kv_heads * cfg.d_head
+    layers = (len(cfg.full_layers), len(cfg.sliding_layers))
+    pools = [sds((n + 1, l, ps, lanes), BF16)
+             for n, l in zip(pages, layers) for _ in "kv"]
+    rng = jax.random.PRNGKey(0)
+    tail = (sds(rng.shape, rng.dtype), sds((), F32), sds((), F32))
+    if program == "prefill":
+        fn = eng._get_prefill_fn(bucket, True, 0)
+        args = (sds((1, bucket), I32),
+                (tuple(sds((r,), I32) for r in rows),
+                 (sds((), I32), sds((), I32))), sds((), I32), sds((), I32))
+    else:
+        fn = eng._get_decode_fn(True, 0)
+        args = (sds((slots, 1), I32), sds((slots,), I32),
+                (tuple(sds((slots, r), I32) for r in rows),
+                 (sds((slots,), I32), sds((slots,), I32))))
+    compiled = fn.lower(params, *pools, *args, *tail).compile()
+    text = compiled.as_text()
+
+    assert text.startswith("HloModule jit_" + program)
+    assert text.count("tpu_custom_call") == 3 * cfg.n_layers
+    assert ("paged_attention_grouped" in text) == (program == "decode")
+    for n, l in zip(pages, layers):
+        assert _page_writes(text, program, (n + 1, l, ps, lanes)) == \
+            (cfg.n_layers if program == "prefill" else 0)
+    # the scores of a chunk against the whole context: never an array
+    assert "f32[1,4,8,{},{}]".format(bucket, rows[0] * ps) not in text
+    aliased = {int(out): int(arg) for out, arg in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+        text.split("\n", 1)[0])}
+    n_params = len(jax.tree_util.tree_leaves(params))
+    assert aliased == {i: n_params + i for i in range(4)}
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < \
+        14.5 * 2 ** 30
+
+
 def test_pallas_compiler_params_construct():
     """Every ``compiler_params`` a pallas_call site passes must construct
     under the installed jax — the sites are only reached with

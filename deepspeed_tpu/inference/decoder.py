@@ -22,7 +22,11 @@ attach one) with:
 * ``serving_config(mesh)`` -> the model config the serving programs
   close over (deterministic, dense; raises for a mesh it cannot span);
   ``decode_config(config, paged_attention_kernel)`` -> the decode
-  program family's variant of it;
+  program family's variant of it for the engine's resolved read path;
+  optionally ``prefill_config(config, paged_attention_kernel)`` -> the
+  prefill family's (a decoder whose chunks have a kernel of their own:
+  Mellum's ``chunk_attention``); without it prefill closes over
+  ``serving_config``'s, the XLA path;
 * ``serving_params(params, dtype)`` -> the weights as served;
 * ``forward_hidden(params, ids, config, cache=, positions=,
   page_tables=, valid_lens=, page_size=[, state_slot= |

@@ -273,13 +273,13 @@ class InferenceEngine:
             attrs["bytes"] = sum(int(kv.nbytes) for kv in self.kv_groups) \
                 + (int(self.state.nbytes) if self.state else 0)
 
-        # paged-attention decode read path (docs/pallas_kernels.md):
-        # resolved once at engine build; the DECODE program family runs
-        # the Pallas page-walk kernel when "pallas", prefill and the
-        # slot layout always keep the XLA oracle path
-        with setup_span("setup.kernels", engine=self.startup_tag):
+        # the paged read paths, resolved once (docs/pallas_kernels.md):
+        # decode's, and prefill's where the decoder has one (Mellum)
+        with setup_span("setup.kernels", engine=self.startup_tag) as attrs:
             self.paged_attention_kernel = \
                 self._resolve_paged_attention_kernel()
+            attrs["prefill_attn"] = self.prefill_attention_kernel = getattr(
+                self._prefill_config(), "paged_attention_kernel", "xla")
 
         # host mirror of each slot's live length (tokens whose K/V are in
         # the cache); the scheduler owns slot assignment on top of this
@@ -355,13 +355,15 @@ class InferenceEngine:
                 self.dtype_name, self.kv_layout,
                 sum(kv.nbytes for kv in self.kv_groups) / 2 ** 20,
                 self.state.nbytes / 2 ** 20 if self.state else 0.0,
-                " pages={}x{} paged_attn={} token_bytes={}".format(
+                " pages={}x{} paged_attn={} prefill_attn={} "
+                "token_bytes={}".format(
                     "+".join("{}{}".format(
                         g.allocator.num_pages,
                         "" if g.window is None else "(window {}, table "
                         "{})".format(g.window, g.max_pages))
                         for g in self.page_groups), self.page_size,
-                    self.paged_attention_kernel, self.kv_token_bytes)
+                    self.paged_attention_kernel,
+                    self.prefill_attention_kernel, self.kv_token_bytes)
                 if self.kv_layout == "paged" else "",
                 " spec_k={} drafter={}".format(
                     self.spec_k, type(self.drafter).__name__)
@@ -449,12 +451,11 @@ class InferenceEngine:
                             strict=strict)
 
     def _resolve_paged_attention_kernel(self):
-        """``inference.paged_attention_kernel`` tri-state -> the decode
-        family's concrete read path ("pallas" | "xla"). Fallbacks are
-        LOUD: a "pallas" request the engine cannot honor (the slot
-        layout) warns and runs the XLA oracle instead of silently doing
-        nothing. On a mesh the kernel runs under a shard_map over it,
-        heads split over ``model`` (ops/pallas/paged_attention.py)."""
+        """``inference.paged_attention_kernel`` tri-state -> the paged
+        read path ("pallas" | "xla") of the decode family, and of the
+        prefill family where the decoder has one (``_prefill_config``).
+        A "pallas" the slot layout cannot honor WARNS and runs XLA. On
+        a mesh the walk is shard_mapped, heads over ``model``."""
         key = self.inference_config.paged_attention_kernel
         if self.kv_layout != "paged":
             if key == "pallas":
@@ -624,14 +625,13 @@ class InferenceEngine:
         return {"kv_write": write_path(tokens, self.page_size)}
 
     def _get_prefill_fn(self, bucket, greedy, top_k):
-        # attached adapters switch to an extended program family (extra
-        # LoRA readout operands); the base family's traces stay valid
+        # attached adapters: an extended family (extra readout operands)
         key = (bucket, greedy, top_k, "adapters") \
             if self.adapters is not None else (bucket, greedy, top_k)
         fn = self._prefill_fns.get(key)
         if fn is not None:
             return fn
-        cfg = self.model_config
+        cfg = self._prefill_config()
         forward, head = self.decoder.forward_hidden, self.decoder.logits
         sampler = make_sampler(greedy, top_k)
         paged, ps = self.kv_layout == "paged", self.page_size
@@ -744,10 +744,10 @@ class InferenceEngine:
         fn = self._decode_fns.get(key)
         if fn is not None:
             return fn
-        # decode is the ONE family that may run the Pallas paged-
-        # attention kernel (docs/pallas_kernels.md dispatch rules);
-        # self.model_config keeps the gather path for prefill and every
-        # oracle comparison
+        # the decode family's read path (docs/pallas_kernels.md dispatch
+        # rules): under "pallas" every paged decoder walks its pages in
+        # a kernel; self.model_config keeps the XLA path, the oracle of
+        # every comparison (prefill's variant: _prefill_config)
         cfg = self.decoder.decode_config(self.model_config,
                                          self.paged_attention_kernel)
         forward, head = self.decoder.forward_hidden, self.decoder.logits
@@ -1173,6 +1173,15 @@ class InferenceEngine:
         the position AFTER tokens[i, :j+1]; the scheduler accepts the
         longest prefix with drafts[j] == chosen[j-1]."""
         return self.decode_step(tokens, sampling=sampling)
+
+    def _prefill_config(self):
+        """The config the PREFILL family's programs close over:
+        ``model_config``, or what the decoder makes of it for the
+        resolved read path (decoder.py, ``prefill_config``: a decoder
+        whose chunks have a kernel of their own)."""
+        make = getattr(self.decoder, "prefill_config", None)
+        return self.model_config if make is None else \
+            make(self.model_config, self.paged_attention_kernel)
 
     def advance(self, slot, n=1):
         """Account ``n`` committed cache writes for ``slot`` (its live

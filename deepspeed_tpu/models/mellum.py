@@ -26,11 +26,13 @@ and the sliding layers', whose table slides and whose pages go back to
 their pool as they leave the window (inference/paging.py
 ``GroupPages``). ``forward_hidden`` is handed a table a group and each
 table's base: a sliding layer reads and writes at ``position - base``,
-and rotates by the absolute position. A decode step reads the pages with
-the grouped page walk (ops/pallas/paged_attention.py, ``window`` in the
-sliding layers), a prompt chunk in blocks of keys with a running softmax
-(ops/chunk_attention.py), whose loop is as long as the blocks that hold a
-visible key.
+and rotates by the absolute position. Both read in blocks of keys with a
+running softmax, as long a walk as the blocks that hold a visible key:
+on the chip (``paged_attention_kernel: pallas``) a decode step in the
+grouped page walk (ops/pallas/paged_attention.py, ``window`` in the
+sliding layers) and a prompt chunk in ``chunk_attention``
+(ops/pallas/chunk_attention.py); elsewhere both in XLA's loop
+(ops/chunk_attention.py), the oracle of the two kernels.
 
 The serving programs return, beside the hidden states, the expert
 layers' summed load (``counters``: ``moe.load``; inference/decoder.py).
@@ -46,7 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..inference.decoder import CacheSpec, PageGroup
-from ..inference.kv_cache import write_tokens
+from ..inference.kv_cache import write_path, write_tokens
 from ..ops import moe
 from ..ops.chunk_attention import (block_tokens, blocked_attention,
                                    paged_blocked_attention)
@@ -97,8 +99,8 @@ class MellumConfig:
     dtype: object = jnp.bfloat16      # matrices, embedding, activations
     # "pallas" (ops/pallas/moe.py) | "xla" (lax.ragged_dot) | "auto"
     moe_kernel: str = "auto"
-    # a decode step's paged read: "pallas" (the grouped page walk) |
-    # "xla" (the blocked attention a chunk takes)
+    # the paged read: "pallas" (a decode or verify step: the grouped
+    # page walk; a chunk: chunk_attention) | "xla" (the blocked loop)
     paged_attention_kernel: str = "xla"
 
     @property
@@ -291,9 +293,11 @@ def _attention_paged(u, lp, config, i, pools, a, positions, page_tables,
                      base, valid_lens, page_size):
     """Layer ``i`` against its group's pages (``a``: its index among the
     group's layers; ``base`` (b,): the absolute position of the table's
-    first token): ``kv_cache.write_tokens``, then the page walk (a
-    decode step under ``paged_attention_kernel: pallas``) or the
-    blocked attention, both at ``positions - base``."""
+    first token): ``kv_cache.write_tokens``, then the read at
+    ``positions - base``: under ``paged_attention_kernel: pallas`` the
+    page walk for a launch that wrote rows (a decode or verify step) and
+    ``chunk_attention`` for one that wrote pages (a chunk), else the
+    blocked loop."""
     b, s, _ = u.shape
     window = config.window if config.is_sliding(i) else None
     tok_pos = positions[:, None] + jnp.arange(s)[None, :]
@@ -302,14 +306,18 @@ def _attention_paged(u, lp, config, i, pools, a, positions, page_tables,
     k_pool, v_pool = write_tokens(
         pools, (k.reshape(b, s, -1), v.reshape(b, s, -1)), a, page_tables,
         at, valid_lens, page_size)
-    if config.paged_attention_kernel == "pallas":
+    if config.paged_attention_kernel != "pallas":
+        ctx = paged_blocked_attention(q, k_pool, v_pool, a, page_tables, at,
+                                      valid_lens, page_size, window)
+    elif write_path(s, page_size) == "pages":
+        from ..ops.pallas.chunk_attention import chunk_attention
+        ctx = chunk_attention(q, k_pool, v_pool, a, page_tables, at,
+                              valid_lens, page_size, window)
+    else:
         from ..ops.pallas.paged_attention import paged_attention
         ctx = paged_attention(q, k_pool, v_pool, page_tables, at,
                               valid_lens, layer_idx=a, page_size=page_size,
                               window=window)
-    else:
-        ctx = paged_blocked_attention(q, k_pool, v_pool, a, page_tables, at,
-                                      valid_lens, page_size, window)
     return ctx.astype(u.dtype).reshape(b, s, -1) @ lp["o"], (k_pool, v_pool)
 
 
@@ -417,6 +425,9 @@ class MellumDecoder:
     def decode_config(self, config, paged_attention_kernel):
         return dataclasses.replace(
             config, paged_attention_kernel=paged_attention_kernel)
+
+    # a chunk has a kernel of its own under the same key
+    prefill_config = decode_config
 
     def serving_params(self, params, dtype):
         def cast(path, x):

@@ -1,0 +1,183 @@
+"""The chunk-attention kernel (ops/pallas/chunk_attention.py) under the
+Pallas interpreter against its oracle, the XLA loop it stands in for on
+the chip (ops/chunk_attention.py::paged_blocked_attention): shuffled
+page tables, windows inside a block, across blocks and wider than every
+key, chunks that start at 0, inside and past the window, padded rows,
+NaN past the live length, and tiles that really come from the shapes.
+The interpreter proves numerics, not compilability: the chip's compiler
+has its cases in test_tpu_compile.py."""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.ops.chunk_attention import paged_blocked_attention
+from deepspeed_tpu.ops.pallas import chunk_attention as kernel_module
+from deepspeed_tpu.ops.pallas.chunk_attention import chunk_attention, tiles
+
+pytestmark = pytest.mark.pallas
+
+PAGE = 8
+# (query heads, key-value heads, d_head): Mellum's group of 8 on 4 heads
+# of 128, and a smaller shape of group 1
+WIDE, NARROW = (32, 4, 128), (2, 2, 32)
+
+
+def _inputs(seed, b, s, shape, max_pages, layers=2, dtype=jnp.float32):
+    h, kvh, dh = shape
+    rng = np.random.default_rng(seed)
+    pages = b * max_pages + 3
+    pools = [jnp.asarray(rng.normal(size=(pages + 1, layers, PAGE, kvh * dh)),
+                         dtype) for _ in range(2)]
+    q = jnp.asarray(rng.normal(size=(b, s, h, dh)), dtype)
+    tables = rng.permutation(np.arange(1, pages + 1))[:b * max_pages] \
+        .reshape(b, max_pages).astype(np.int32)
+    return q, pools, jnp.asarray(tables)
+
+
+def _both(q, pools, tables, positions, valid, window, tile=None, layer=1):
+    positions = jnp.asarray(positions, jnp.int32)
+    valid = jnp.asarray(valid, jnp.int32)
+    want = paged_blocked_attention(q, *pools, layer, tables, positions,
+                                   valid, PAGE, window)
+    got = kernel_module._call(
+        q, *pools, jnp.full((1,), layer, jnp.int32), tables, positions,
+        valid, window=window, interpret=True, tile=tile)
+    return np.asarray(got), np.asarray(want)
+
+
+# tiles of 8 queries and blocks of 16 keys: a window of 5 lies inside a
+# block, one of 20 across two, one of 4096 is wider than every key
+@pytest.mark.parametrize("window", [None, 5, 20, 4096],
+                         ids=["none", "in_block", "across", "wider"])
+@pytest.mark.parametrize("start", [0, 11, 40],
+                         ids=["at_0", "inside_window", "past_window"])
+@pytest.mark.parametrize("b", [1, 2])
+def test_the_kernel_matches_the_loop(window, start, b):
+    """Shuffled tables, every slot at its own start, tiles smaller than
+    the chunk and blocks smaller than the table, so that a tile walks
+    some blocks, skips others and takes both bodies."""
+    s, max_pages = 32, 12
+    q, pools, tables = _inputs(1, b, s, NARROW, max_pages)
+    positions = [start, max(start - 3, 0)][:b]
+    got, want = _both(q, pools, tables, positions, [s] * b, window,
+                      tile=(8, 16, 4))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("shape", [WIDE, NARROW], ids=["group8", "group1"])
+@pytest.mark.parametrize("window", [None, 24], ids=["full", "window"])
+def test_tiles_come_from_the_shapes(shape, window):
+    """No tile handed in: what :func:`tiles` makes of the shapes, at
+    Mellum's head layout (8 query heads a key-value head of 128) and at
+    a smaller one of group 1."""
+    b, s, max_pages = 2, 16, 6
+    h, kvh, dh = shape
+    tq, tk, sub = tiles(s, h // kvh, dh, kvh * dh, 4, max_pages * PAGE,
+                        PAGE, window)
+    assert s % tq == 0 and tk % PAGE == 0 and tk <= max_pages * PAGE
+    assert (tq * h // kvh) % sub == 0 and sub % (h // kvh) == 0
+    q, pools, tables = _inputs(2, b, s, shape, max_pages)
+    got, want = _both(q, pools, tables, [17, 3], [s, s], window)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_tiles_at_the_cells_shapes():
+    """Mellum's chunk of 2,048 queries over bfloat16 pools of 512 lanes:
+    256 queries x 8 heads a tile; a full layer's blocks are the 1,024
+    keys the module's budget holds, 512 rows a turn; a sliding layer's
+    half its window, 1,024 rows a turn; a bucket shorter than a tile is
+    one tile."""
+    full, sliding = 2048 * 16, 193 * 16
+    assert tiles(2048, 8, 128, 512, 2, full, 16) == (256, 1024, 512)
+    assert tiles(2048, 8, 128, 512, 2, sliding, 16, 1024) == (256, 512, 1024)
+    assert tiles(128, 8, 128, 512, 2, sliding, 16, 1024) == (128, 512, 1024)
+    # float32 pools: half the keys a block, twice the rows a turn
+    assert tiles(2048, 8, 128, 512, 4, full, 16) == (256, 512, 1024)
+
+
+@pytest.mark.parametrize("window", [None, 20], ids=["full", "window"])
+def test_padded_rows_are_finite_and_a_tile_past_them_is_zero(window):
+    """``valid_lens`` short of ``s``: the live rows are the loop's, the
+    padded rows of a tile that holds a live one are finite, and a tile
+    wholly past the live length fetched nothing and wrote zeros."""
+    s, max_pages = 32, 12
+    q, pools, tables = _inputs(3, 2, s, NARROW, max_pages)
+    valid = [13, 8]
+    got, want = _both(q, pools, tables, [21, 0], valid, window, tile=(8, 16, 4))
+    for slot, n in enumerate(valid):
+        np.testing.assert_allclose(got[slot, :n], want[slot, :n], atol=2e-5)
+        assert np.isfinite(got[slot]).all()
+        first_dead_tile = -(-n // 8) * 8
+        assert not got[slot, first_dead_tile:].any()
+        assert got[slot, :n].any()
+
+
+@pytest.mark.parametrize("window", [None, 20], ids=["full", "window"])
+def test_nan_past_the_live_length_reaches_no_query(window):
+    """A slot's last page partly live, NaN in every pool row past the
+    live length (a recycled page): masked scores weigh nothing, and the
+    value side is zeroed so that ``0 * NaN`` is never formed."""
+    s, max_pages, start, n = 16, 6, 9, 13
+    q, pools, tables = _inputs(4, 1, s, NARROW, max_pages)
+    live = start + n                       # tokens the slot holds
+    clean = _both(q, pools, tables, [start], [n], window, tile=(8, 16, 4))[0]
+    poisoned = []
+    for pool in pools:
+        rows = np.array(pool[tables[0]])   # (max_pages, layers, PAGE, lanes)
+        flat = rows.transpose(1, 0, 2, 3).reshape(
+            rows.shape[1], max_pages * PAGE, -1)
+        flat[:, live:] = np.nan
+        rows = flat.reshape(rows.shape[1], max_pages, PAGE, -1) \
+            .transpose(1, 0, 2, 3)
+        poisoned.append(pool.at[tables[0]].set(jnp.asarray(rows)))
+    assert np.isnan(np.asarray(poisoned[1])).any()
+    got, want = _both(q, poisoned, tables, [start], [n], window,
+                      tile=(8, 16, 4))
+    assert np.isfinite(got[0, :n]).all()
+    np.testing.assert_array_equal(got[0, :n], clean[0, :n])
+    np.testing.assert_allclose(got[0, :n], want[0, :n], atol=2e-5)
+
+
+def test_a_window_no_key_is_older_than_is_the_windowless_program():
+    """Bit for bit, as the walk's test holds the walk."""
+    q, pools, tables = _inputs(5, 2, 32, NARROW, 12)
+    args = (q, pools, tables, [40, 7], [32, 29])
+    none = _both(*args, None, tile=(8, 16, 4))[0]
+    wide = _both(*args, 4096, tile=(8, 16, 4))[0]
+    np.testing.assert_array_equal(none, wide)
+
+
+def test_bfloat16_pools_enter_the_matmuls_as_stored():
+    """The loop's arithmetic: operands in the pool's dtype, float32
+    statistics, the weights cast to the values' dtype."""
+    q, pools, tables = _inputs(6, 1, 32, WIDE, 12, dtype=jnp.bfloat16)
+    got, want = _both(q, pools, tables, [50], [32], 24, tile=(16, 32, 64))
+    np.testing.assert_allclose(got, want, atol=2e-2)
+
+
+def test_the_layer_is_data():
+    """One traced kernel serves a group's layers: the layer is an
+    operand, and a traced value at the public entry."""
+    q, pools, tables = _inputs(7, 1, 16, NARROW, 6, layers=3)
+    positions, valid = jnp.asarray([5], jnp.int32), jnp.asarray([16], jnp.int32)
+
+    @jax.jit
+    def run(layer):
+        return chunk_attention(q, *pools, layer, tables, positions, valid,
+                               PAGE, 12, interpret=True)
+
+    for layer in (0, 2):
+        want = paged_blocked_attention(q, *pools, layer, tables, positions,
+                                       valid, PAGE, 12)
+        np.testing.assert_allclose(run(jnp.int32(layer)), want, atol=2e-5)
+    assert run._cache_size() == 1
+
+
+def test_pools_of_another_layout_are_refused():
+    q, pools, tables = _inputs(8, 1, 16, NARROW, 6)
+    with pytest.raises(ValueError, match="chunk_attention wants pools"):
+        chunk_attention(q, *pools, 0, tables, jnp.zeros((1,), jnp.int32),
+                        jnp.full((1,), 16, jnp.int32), PAGE * 2,
+                        interpret=True)

@@ -350,6 +350,35 @@ def test_the_engine_with_the_kernels_interpreted_matches_the_oracles():
     assert eng.generate(prompts, max_new_tokens=10) == want
 
 
+@pytest.mark.pallas
+def test_the_engine_says_which_read_path_each_family_holds():
+    """Under ``pallas`` a chunk (a page of tokens or more) goes to
+    ``chunk_attention`` and a decode step to the page walk; the start-up
+    record's ``setup.kernels`` row and the engine say which path the
+    prefill family holds. ``auto`` off the chip keeps both in XLA's
+    loop, the oracle."""
+    from deepspeed_tpu.analysis.auditor import engine_program_specs
+    eng = _engine(inference={"paged_attention_kernel": "pallas"})
+    assert (eng.paged_attention_kernel, eng.prefill_attention_kernel) == \
+        ("pallas", "pallas")
+    kernels = [row for row in eng.startup_report()["rows"]
+               if row["name"] == "setup.kernels"]
+    assert [row["attrs"]["prefill_attn"] for row in kernels] == ["pallas"]
+    for spec in engine_program_specs(eng):
+        text = str(jax.make_jaxpr(spec.build())(*spec.args))
+        chunk, walk = ("name=chunk_attention" in text,
+                       "name=paged_attention_grouped" in text)
+        assert (chunk, walk) == ((True, False) if "prefill" in spec.name
+                                 else (False, True)), spec.name
+    auto = _engine()
+    assert (auto.paged_attention_kernel, auto.prefill_attention_kernel) == \
+        ("xla", "xla")
+    for spec in engine_program_specs(auto):
+        text = str(jax.make_jaxpr(spec.build())(*spec.args))
+        assert "name=chunk_attention" not in text and \
+            "name=paged_attention_grouped" not in text
+
+
 def test_the_audit_lowers_the_programs_with_a_table_a_group():
     """``engine.audit()`` (the AOT shard-lint) builds each serving
     program's arguments itself: with a table and a base a page group in

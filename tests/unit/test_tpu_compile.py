@@ -877,6 +877,48 @@ def test_grouped_walk_compiles_at_mellum_widths(
                     ((b, row), I32), ((b,), I32), ((b,), I32)) == 1
 
 
+@pytest.mark.parametrize("bucket", [512, 1024, 2048])
+@pytest.mark.parametrize("window, row", [(None, 2048), (1024, 193)],
+                         ids=["full", "window"])
+def test_chunk_attention_compiles_at_mellum_widths(
+        one_chip, no_persistent_cache, window, row, bucket):
+    """A prefill chunk's attention over its pages at Mellum2-12B-A2.5B's
+    attention (32 query heads on 4 key-value heads of 128, pages of 16)
+    for every bucket of the ide cell: a full layer over its slot's row
+    of 2,048 pages in scalar memory, a sliding layer over its sliding
+    table of 193 columns with ``window=1024``; the layer is an operand.
+    The tiles come from these shapes and ``vmem_limit_bytes`` with them
+    (the scoped default of 16 MB would refuse the tile)."""
+    from deepspeed_tpu.ops.pallas.chunk_attention import chunk_attention
+
+    def fn(q, k_pool, v_pool, layer, page_tables, positions, valid_lens):
+        return chunk_attention(q, k_pool, v_pool, layer, page_tables,
+                               positions, valid_lens, 16, window,
+                               interpret=False)
+
+    pages, layers = (70001, 2) if window is None else (8577, 6)
+    pool = ((pages, layers, 16, 512), BF16)
+    assert _compile(fn, one_chip, ((1, bucket, 32, 128), BF16), pool, pool,
+                    ((), I32), ((1, row), I32), ((1,), I32),
+                    ((1,), I32)) == 1
+
+
+@pytest.mark.parametrize("engine", ["serving_engine", "jamba_engine",
+                                    "lfm2_engine", "moonlight_engine"])
+def test_the_other_families_prefill_closes_over_what_it_did(request, engine):
+    """GPT-2, Jamba, LFM2 and Moonlight under ``paged_attention_kernel:
+    pallas``: their decoders have no prefill variant of the config, so
+    ``_get_prefill_fn`` closes over ``model_config`` itself, the XLA
+    path, and their prefill programs are the programs they were (the
+    cases above that count each program's kernels hold the text)."""
+    eng = request.getfixturevalue(engine)
+    eng = eng[0] if isinstance(eng, tuple) else eng
+    assert eng.paged_attention_kernel == "pallas"
+    assert eng._prefill_config() is eng.model_config
+    assert eng.prefill_attention_kernel == "xla"
+    assert eng.model_config.paged_attention_kernel == "xla"
+
+
 @pytest.fixture(scope="module")
 def mellum_engine():
     """A tiny Mellum engine on the CPU whose programs are lowered at
@@ -911,9 +953,11 @@ def test_mellum_programs_run_the_kernels_and_alias_both_groups(
     a table and a base a page group: two grouped matmuls a layer; in
     decode every layer walks its group's pages in the grouped paged
     kernel (the sliding layers over their 193 columns); in prefill a
-    page write a layer and the chunk's attention in blocks of keys (no
-    array of the whole context's scores); both groups' pool pairs, four
-    donated buffers, come back in place; and it fits the chip."""
+    page write a layer and the chunk's attention in the
+    ``chunk_attention`` kernel in all 8 layers (no loop that carries a
+    float32 accumulator through HBM, no array of a block's scores); both
+    groups' pool pairs, four donated buffers, come back in place; and
+    it fits the chip."""
     from deepspeed_tpu.models import mellum
     eng, cell = mellum_engine
     cfg = eng.model_config
@@ -950,8 +994,15 @@ def test_mellum_programs_run_the_kernels_and_alias_both_groups(
     text = compiled.as_text()
 
     assert text.startswith("HloModule jit_" + program)
-    assert text.count("tpu_custom_call") == 3 * cfg.n_layers
+    assert eng.prefill_attention_kernel == "pallas"
+    chunk_calls = len(re.findall(
+        r"%chunk_attention(?:\.\d+)? = .*tpu_custom_call", text))
+    assert chunk_calls == (cfg.n_layers if program == "prefill" else 0)
+    assert text.count("tpu_custom_call") == 3 * cfg.n_layers + chunk_calls
     assert ("paged_attention_grouped" in text) == (program == "decode")
+    # XLA's loop over key blocks carried f32[1,4,8,bucket,128] and made
+    # f32[1,4,8,bucket,512] scores a turn
+    assert not re.search(r"f32\[1,4,8,\d+,(128|512)\]", text)
     for n, l in zip(pages, layers):
         assert _page_writes(text, program, (n + 1, l, ps, lanes)) == \
             (cfg.n_layers if program == "prefill" else 0)

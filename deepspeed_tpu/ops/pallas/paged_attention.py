@@ -22,27 +22,27 @@ d_head * itemsize`` a page, and no more than a row has — from static
 shapes only: 8 pages (128 tokens, 256 KB a buffer half) at GPT-2
 medium's 1,024 bf16 lanes, 32 where a tensor-parallel shard holds a
 quarter of the heads. A page fetched alone (32 KB) leaves the loop
-waiting on a DMA's latency every 16 tokens.
+waiting on a DMA's latency every 16 tokens. The grouped walk
+(:func:`_grouped_block`) takes 512 tokens a turn and fetches the block
+WHOLE, dead pages too: straight-line copies and one wait a pool.
 
-One pass over the packed lanes folds every head (:func:`_kernel`): the
+One pass over the packed lanes folds every head (:func:`_kernel`, and
+:func:`_grouped_kernel` where query heads share key-value heads): the
 slot's queries are laid out block-diagonally, row ``(query, head)``
 holding that head's ``d_head`` lanes and zeros elsewhere, so the scores
 of all heads against a block of keys are ONE matmul ``(s * h, h * dh) x
 (tokens, h * dh)^T`` and the weighted values ONE matmul ``(s * h,
 tokens) x (tokens, h * dh)``, whose block diagonal is picked out after
-the walk (h times the useful flops of the second matmul, nothing beside
-the block's fetch, to keep every operand lane-dense: no 64-lane slice of
-a page, no per-head carry to put back together).
+the walk: every operand lane-dense, no 64-lane slice of a page.
 
-Precision: K and V enter the MXU in the pool's dtype, as stored (no
-float32 copy of a page), the queries cast to it (the bf16 the model's
-qkv matmul produced, under a bf16 pool), ``sm_scale`` multiplies the
-float32 scores, every accumulation and every softmax statistic is
-float32, and the weights ``exp(scores - m)`` enter the second matmul in
-the pool's dtype, as the flash kernels' do
-(ops/transformer/flash_attention.py) and as the chip's default matmul
-precision did to the float32 copies this kernel used to make. With a
-float32 pool nothing is rounded.
+Precision, both walks: K and V enter the MXU in the pool's dtype, as
+stored (no float32 copy of a page), the queries cast to it (the bf16
+the model's qkv matmul produced, under a bf16 pool), ``sm_scale``
+multiplies the float32 scores, every accumulation and every softmax
+statistic is float32, and the weights ``exp(scores - m)`` enter the
+second matmul in the pool's dtype, as the flash kernels' do
+(ops/transformer/flash_attention.py) and the families' XLA reads
+(models/jamba.py ``_attend``). With a float32 pool nothing is rounded.
 
 Masking contract (bit-compatible with the slot oracle,
 ``_attend_cache_rows``):
@@ -75,13 +75,13 @@ The grid is one step a slot, in order (a slot's first block is fetched
 during the slot before it, so the axis is ``arbitrary``, not
 ``parallel``); the page tables, positions and valid lengths ride
 ``PrefetchScalarGridSpec`` scalar prefetch so the DMA source indices, of
-this slot and the next, are known before the body runs. Off-TPU it runs
-under the Pallas interpreter (``interpret=True``) — the numerics-pinning
-vehicle for tier-1/dryrun, not a serving configuration
-(``inference.paged_attention_kernel: "auto"`` keeps CPU on the XLA
-gather path). Flops are pinned to the dense math via ``pl.CostEstimate``
-so the compile-observatory/cost-analysis pricing seam sees the same
-count the XLA path reports.
+this slot and the next, are known before the body runs (the grouped
+walk's table comes a slot's ROW and the next slot's at a time, two
+blocks of one array in scalar memory: 128 x 2,048 entries are all of
+it). Off-TPU the kernels run under the Pallas interpreter, the
+numerics-pinning vehicle for tier-1/dryrun, not a serving configuration
+(``paged_attention_kernel: "auto"`` keeps CPU on the XLA gather path).
+Flops are pinned to the dense math via ``pl.CostEstimate``.
 """
 import functools
 import math
@@ -243,172 +243,208 @@ def _kernel(pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref, v_pool_ref,
         o_ref[0, j:j + 1, :] = jnp.sum(mine, axis=0, keepdims=True)
 
 
-def _grouped_kernel(pos_ref, vlen_ref, pt_ref, q_ref, k_pool_ref,
-                    v_pool_ref, o_ref, k_buf, v_buf, k_sem, v_sem, *,
-                    layer_idx, page_size, kv_heads, group, d_head,
-                    sm_scale, seq, chunk, window=None):
-    """One slot's page-table walk where ``group`` query heads share
-    each key-value head (grouped-query attention; ``kv_heads = 1`` is
-    multi-query). The masking contract is :func:`_kernel`'s, and with a
-    ``window`` a query also sees only the last ``window`` keys, its own
-    among them (``q_pos - k_pos < window``): the walk then starts at
-    the first page that holds a key the slot's first query can see and
-    masks the older keys of that page (with a sliding table,
-    inference/paging.py ``GroupPages``, that page is column 0).
-    ``window=None`` is the program there was before it.
-    ``pt_ref`` is the slot's own row of the page table, a (1, 1,
-    max_pages) block in scalar memory beside the two prefetched
-    scalars, not the whole table: 128 slots x 2,048 pages are all of
-    that memory's 1 MB, and a walk reads its own row only (rollouts and
-    extract measured the same either way, PERF.md section 6, PR 42).
-    What differs: a page of ``kv_heads * d_head`` lanes is small (4 KB
-    at one head of 128), so pages are fetched ``chunk`` at a time into
-    one buffer of ``chunk * page_size`` tokens (the next chunk's copies
-    in flight while this one is on the MXU), and a key-value head's
-    ``seq * group`` queries are the rows of ONE matmul per chunk.
+# Tokens a turn of the grouped walk fetches and folds: of 128 / 256 / 512
+# / 1,024 the best or within 5% of it at the three cells' shapes (PERF.md
+# section 6, PR 46): 32 pages of 16, 512 KB a buffer half at 512 bf16 lanes.
+_GROUPED_BLOCK_TOKENS = 512
 
-    q_ref / o_ref (1, kv_heads, seq * group, d_head), rows ordered
-    (query, head of the group); k/v_buf (2, chunk * page_size,
-    kv_heads * d_head)."""
-    i = pl.program_id(0)
-    pos = pos_ref[i]
-    vlen = vlen_ref[i]
-    live = pos + vlen - 1                  # last live absolute position
-    n_pages = jnp.maximum(live, 0) // page_size + 1
-    if window is None:
-        first_page = first_token = 0
-        n_chunks = (n_pages + chunk - 1) // chunk
-    else:
-        first_page = jnp.maximum(pos - window + 1, 0) // page_size
-        first_token = first_page * page_size
-        n_chunks = jnp.maximum(n_pages - first_page + chunk - 1, 0) // chunk
-    rows, tokens = seq * group, chunk * page_size
 
-    def past_first(offset, first):
-        # counted from the first page the walk fetches
-        return offset if window is None else first + offset
+def _grouped_block(max_pages, page_size, seq, window):
+    """Pages one turn of the grouped walk fetches and folds, all of them
+    whether live or not: ``_GROUPED_BLOCK_TOKENS`` of tokens, and no
+    more than a row has. The pages a ``window`` can touch are known, so
+    a windowed walk takes them in its fewest turns, evenly, in whole
+    lane tiles of tokens (65 pages: 3 turns of 24, not of 32)."""
+    block = max(1, _GROUPED_BLOCK_TOKENS // page_size)
+    if window is not None:
+        span = (window + seq - 2) // page_size + 2
+        turns, tile = -(-span // block), max(1, 128 // page_size)
+        block = min(block, -(-span // (turns * tile)) * tile)
+    return min(block, max_pages)
 
-    def transfer(slot, c, start):
-        # a chunk's last pages may lie past the live window: no copy,
-        # and what the buffer holds there is masked below
-        for j in range(chunk):
-            p = past_first(c * chunk + j, first_page)
 
-            @pl.when(p < n_pages)
-            def _copy():
-                phys = pt_ref[0, 0, p]
-                dst = pl.ds(j * page_size, page_size)
-                for pool, buf, sem in ((k_pool_ref, k_buf, k_sem),
-                                       (v_pool_ref, v_buf, v_sem)):
-                    copy = pltpu.make_async_copy(
-                        pool.at[phys, layer_idx], buf.at[slot, dst],
-                        sem.at[slot])
-                    copy.start() if start else copy.wait()
+def _grouped_kernel(pos_ref, vlen_ref, pt_ref, nxt_ref, q_ref, k_pool_ref,
+                    v_pool_ref, o_ref, k_buf, v_buf, k_sem, v_sem, half_ref,
+                    *, layer_idx, page_size, kv_heads, group, d_head,
+                    sm_scale, seq, block, window=None):
+    """One slot's page-table walk where ``group`` query heads share each
+    key-value head (``kv_heads = 1`` is multi-query), ``block`` pages
+    and every head a loop turn, folded as :func:`_kernel` folds them
+    (its precision, masking contract and prefetch across slots): the
+    queries come in the pool's dtype, block-diagonal over its packed
+    lanes (row ``(query, head)`` holds the head's query in its key-value
+    head's ``d_head`` lanes), so all heads' scores are ONE matmul a
+    block and their weighted values one more, whose block diagonal is
+    picked out after the walk. With a ``window`` a query also sees only
+    its last ``window`` keys, its own among them: the walk starts at the
+    first page with a key the slot's first query can see (column 0 of a
+    sliding table, inference/paging.py) and masks that page's older
+    keys; ``None`` traces no window at all. Refs as :func:`_kernel`'s,
+    but pt_ref / nxt_ref (1, 1, max_pages): this slot's row of the page
+    table and the next slot's in SMEM (the whole table would fill it);
+    q_ref (1, seq * heads, lanes); o_ref (1, seq * heads, d_head)."""
+    i, num_slots = pl.program_id(0), pl.num_programs(0)
+    max_pages, heads = pt_ref.shape[2], kv_heads * group
+    rows, tokens = seq * heads, block * page_size
 
-    transfer(0, 0, True)
-    q_pos = pos + jax.lax.broadcasted_iota(
-        jnp.int32, (rows, tokens), 0) // group
+    def walk_of(slot):     # (first page walked, pages to the last live one)
+        live = pos_ref[slot] + vlen_ref[slot] - 1
+        seen = 0 if window is None else pos_ref[slot] - window + 1
+        return jax.lax.div(jnp.maximum(seen, 0), page_size), jnp.minimum(
+            jax.lax.div(jnp.maximum(live, 0), page_size) + 1, max_pages)
+
+    def fetch(at, half, of_next=False, unroll=True):
+        # ALL the block's pages from column ``at`` of this slot's row or the
+        # next's: one wait a pool covers them (past the live: garbage, masked)
+        def page(j, carry):
+            column = jnp.minimum(at + j, max_pages - 1)
+            phys = jnp.where(of_next, nxt_ref[0, 0, column],
+                             pt_ref[0, 0, column])
+            dst = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            for pool, buf, sem in ((k_pool_ref, k_buf, k_sem),
+                                   (v_pool_ref, v_buf, v_sem)):
+                pltpu.make_async_copy(pool.at[phys, layer_idx],
+                                      buf.at[half, dst], sem.at[half]).start()
+            return carry
+        jax.lax.fori_loop(0, block, page, 0, unroll=unroll)  # traced once
+
+    first, pages = walk_of(i)
+
+    @pl.when(i == 0)
+    def _first_slot():
+        half_ref[0] = 0
+        fetch(first, 0, unroll=False)      # once a call: a rolled loop
+
+    nxt_first, _ = walk_of(jnp.minimum(i + 1, num_slots - 1))
+    pos, live = pos_ref[i], pos_ref[i] + vlen_ref[i] - 1   # the last live
+    # a block at least: the next slot's first is fetched during it
+    n_blocks = jnp.maximum(jax.lax.div(pages - first + block - 1, block), 1)
+    first_half = half_ref[0]
     col = jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 1)
-    vcol = jax.lax.broadcasted_iota(jnp.int32, (tokens, 1), 0)
-    qs = [q_ref[0, h].astype(jnp.float32) * sm_scale
-          for h in range(kv_heads)]                       # (rows, dh)
+    q_pos = pos + jax.lax.div(
+        jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 0), heads)
+    token = jax.lax.broadcasted_iota(jnp.int32, (tokens, 1), 0)
 
     def body(c, carry):
-        slot = jax.lax.rem(c, 2)
+        acc, m, l = carry              # (rows, lanes), (rows, 1) x 2 fp32
+        half = jax.lax.rem(first_half + c, 2)
+        at, last = first + c * block, c + 1 == n_blocks
+        base = at * page_size              # the block's first position
 
-        @pl.when(c + 1 < n_chunks)
+        # in flight meanwhile: the next block, or the next slot's first
+        @pl.when(jnp.logical_or(jnp.logical_not(last), i + 1 < num_slots))
         def _prefetch():
-            transfer(jax.lax.rem(c + 1, 2), c + 1, True)
+            fetch(jnp.where(last, nxt_first, at + block), 1 - half, last)
 
-        transfer(slot, c, False)
-        k_pos = past_first(c * tokens + col, first_token)
+        for buf, sem in ((k_buf, k_sem), (v_buf, v_sem)):   # the whole block
+            pltpu.make_async_copy(buf.at[half], buf.at[half],
+                                  sem.at[half]).wait()
+
+        @pl.when(last)     # as _kernel does, and the dead pages with it
+        def _zero_dead_values():
+            v_blk = v_buf[half]
+            v_buf[half] = jnp.where(base + token <= live, v_blk,
+                                    jnp.zeros_like(v_blk))
+
+        scores = jax.lax.dot_general(
+            q_ref[0], k_buf[half], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # (rows, tokens)
+        k_pos = base + col
         mask = jnp.logical_and(k_pos <= q_pos, k_pos <= live)
         if window is not None:
             mask = jnp.logical_and(mask, q_pos - k_pos < window)
-        vmask = past_first(c * tokens + vcol, first_token) <= live
-        k_all, v_all = k_buf[slot], v_buf[slot]
-        out = []
-        for h in range(kv_heads):
-            acc, m, l = carry[h]
-            sl = slice(h * d_head, (h + 1) * d_head)
-            k_h = k_all[:, sl].astype(jnp.float32)
-            v_h = jnp.where(vmask, v_all[:, sl].astype(jnp.float32), 0.0)
-            scores = jax.lax.dot_general(
-                qs[h], k_h, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)       # (rows, tokens)
-            scores = jnp.where(mask, scores, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
-            pexp = jnp.exp(scores - m_new)
-            corr = jnp.exp(m - m_new)
-            out.append((acc * corr + jax.lax.dot_general(
-                pexp, v_h, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32),
-                m_new, l * corr + jnp.sum(pexp, axis=-1, keepdims=True)))
-        return tuple(out)
+        scores = jnp.where(mask, scores, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+        pexp = jnp.exp(scores - m_new)
+        corr = jnp.exp(m - m_new)
+        acc = acc * corr + jax.lax.dot_general(
+            pexp.astype(v_buf.dtype), v_buf[half], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return acc, m_new, l * corr + jnp.sum(pexp, axis=-1, keepdims=True)
 
-    init = tuple((jnp.zeros((rows, d_head), jnp.float32),
-                  jnp.full((rows, 1), NEG_INF, jnp.float32),
-                  jnp.zeros((rows, 1), jnp.float32))
-                 for _ in range(kv_heads))
-    final = jax.lax.fori_loop(0, n_chunks, body, init)
-    for h, (acc, _, l) in enumerate(final):
-        o_ref[0, h] = acc / jnp.where(l == 0.0, 1.0, l)
+    acc, _, l = jax.lax.fori_loop(0, n_blocks, body, (
+        jnp.zeros((rows, kv_heads * d_head), jnp.float32),
+        jnp.full((rows, 1), NEG_INF, jnp.float32),
+        jnp.zeros((rows, 1), jnp.float32)))
+    half_ref[0] = jax.lax.rem(first_half + n_blocks, 2)
+    # of all the packed lanes a row keeps its own key-value head's
+    out = acc / jnp.where(l == 0.0, 1.0, l)
+    kv_head = jax.lax.div(jax.lax.rem(jax.lax.broadcasted_iota(
+        jnp.int32, (rows, 1), 0), heads), group)
+    o_ref[0] = sum(jnp.where(kv_head == h, out[:, h * d_head:(h + 1) * d_head],
+                             0.0) for h in range(kv_heads))
 
 
 def _grouped_paged_attention(q, k_pool, v_pool, page_tables, positions,
                              valid_lens, *, layer_idx, page_size,
-                             interpret, chunk=8, window=None):
+                             interpret, block=None, window=None):
     """:func:`paged_attention` for pools of fewer key-value heads than
     query heads. q (b, s, h, dh); pools (pages+1, layers, page_size,
     kvh * dh) with ``h % kvh == 0``; ``window``: :func:`_grouped_kernel`'s
-    (``positions`` and the table then count from the same origin)."""
+    (``positions`` and the table then count from the same origin);
+    ``block``: pages a loop turn (:func:`_grouped_block`'s unless
+    given)."""
     b, s, h, dh = q.shape
-    kvh = k_pool.shape[3] // dh
+    lanes = k_pool.shape[3]
+    kvh = lanes // dh
     group = h // kvh
-    rows = s * group
+    rows = s * h
     max_pages = page_tables.shape[1]
-    chunk = min(chunk, max_pages)
-    # rows of one key-value head: (query, head of its group)
-    q = q.reshape(b, s, kvh, group, dh).transpose(0, 2, 1, 3, 4) \
-        .reshape(b, kvh, rows, dh)
-    block = pl.BlockSpec((1, kvh, rows, dh), lambda i, *_: (i, 0, 0, 0))
-    # the slot's row (b, 1, max_pages): a block's last two dimensions
-    # are the array's
-    table = pl.BlockSpec((1, 1, max_pages), lambda i, *_: (i, 0, 0),
-                         memory_space=pltpu.SMEM)
+    if block is None:
+        block = _grouped_block(max_pages, page_size, s, window)
+    # the queries block-diagonal over the pool's packed lanes, in its
+    # dtype: row (query, head) holds the head's query in the lanes of
+    # its key-value head
+    own = (jnp.arange(rows)[:, None] % h // group
+           == jnp.arange(lanes)[None, :] // dh)
+    q = jnp.where(own, jnp.tile(q.reshape(b, rows, dh), (1, 1, kvh)),
+                  0).astype(k_pool.dtype)
+    # a slot's row (b, 1, max_pages): a block's last two dimensions are
+    # the array's; the same array twice, the next slot's row beside it
+    tables = page_tables.astype(jnp.int32)[:, None, :]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
-        in_specs=[table, block, pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=block,
+        in_specs=[
+            pl.BlockSpec((1, 1, max_pages), lambda i, *_: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, max_pages),
+                         lambda i, *_: (jnp.minimum(i + 1, b - 1), 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, rows, lanes), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, rows, dh), lambda i, *_: (i, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, chunk * page_size, kvh * dh), k_pool.dtype),
-            pltpu.VMEM((2, chunk * page_size, kvh * dh), v_pool.dtype),
+            pltpu.VMEM((2, block * page_size, lanes), k_pool.dtype),
+            pltpu.VMEM((2, block * page_size, lanes), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
         ])
     kernel = functools.partial(
         _grouped_kernel, layer_idx=layer_idx, page_size=page_size,
         kv_heads=kvh, group=group, d_head=dh,
-        sm_scale=1.0 / math.sqrt(dh), seq=s, chunk=chunk,
+        sm_scale=1.0 / math.sqrt(dh), seq=s, block=block,
         **({} if window is None else {"window": window}))
     span = max_pages * page_size
     cost = pl.CostEstimate(
         flops=4 * b * s * span * h * dh,
         bytes_accessed=(q.size * q.dtype.itemsize
-                        + 2 * b * span * kvh * dh
+                        + 2 * b * span * lanes
                         * k_pool.dtype.itemsize + b * s * h * dh * 4),
         transcendentals=b * s * span * h)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, rows, dh), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, rows, dh), jnp.float32),
         cost_estimate=cost, interpret=interpret,
+        # a slot's first block is fetched during the slot before it
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         name="paged_attention_grouped",
     )(positions.astype(jnp.int32), valid_lens.astype(jnp.int32),
-      page_tables.astype(jnp.int32)[:, None, :], q, k_pool, v_pool)
-    return out.reshape(b, kvh, s, group, dh).transpose(0, 2, 1, 3, 4) \
-        .reshape(b, s, h, dh)
+      tables, tables, q, k_pool, v_pool)
+    return out.reshape(b, s, h, dh)
 
 
 # Tokens a turn of the latent page walk fetches and folds: 32 pages of

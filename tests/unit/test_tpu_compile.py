@@ -195,25 +195,57 @@ def test_paged_attention_block_walk_compiles_at_the_cells_shapes(
                     ((b, row), I32), ((b,), I32), ((b,), I32)) == 1
 
 
-# ------------------------------------------------- kernels on a mesh
-def test_paged_attention_compiles_at_one_kv_head_of_128(
-        one_chip, no_persistent_cache):
-    """The grouped kernel at AI21-Jamba2-3B's attention layers: 20
-    query heads of 128 on one key-value head, 256 slots, a row of 192
-    pages of 16 tokens; a page is a (16, 128) bf16 tile."""
-    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+# --------------------------------------- paged attention, grouped walk
+# cell: slots, query heads, key-value heads, d_head, pages a row, pages
+# + 1, layers, window, pages a block: the decode programs that run the
+# grouped walk (Mellum's full and sliding layers, LFM2's, Jamba's)
+_GROUPED_CELLS = {
+    "ide_full": (128, 32, 4, 128, 2048, 70001, 2, None, 32),
+    "ide_window": (128, 32, 4, 128, 193, 8577, 6, 1024, 24),
+    "extract": (384, 32, 8, 64, 192, 30001, 3, None, 32),
+    "rollouts": (384, 20, 1, 128, 192, 27001, 2, None, 32),
+}
+
+
+def _compile_grouped_walk(one_chip, cell, width):
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        _grouped_block, paged_attention)
+    b, h, kvh, dh, row, pages, layers, window, block = _GROUPED_CELLS[cell]
+    assert _grouped_block(row, 16, 1, window) == block
 
     def fn(q, k_pool, v_pool, page_tables, positions, valid_lens):
         return paged_attention(q, k_pool, v_pool, page_tables, positions,
-                               valid_lens, layer_idx=1, page_size=16,
-                               interpret=False)
+                               valid_lens, layer_idx=layers - 1,
+                               page_size=16, interpret=False, window=window)
 
-    b = 256
-    pool = ((18001, 2, 16, 128), BF16)
-    assert _compile(fn, one_chip, ((b, 1, 20, 128), BF16), pool, pool,
-                    ((b, 192), I32), ((b,), I32), ((b,), I32)) == 1
+    pool = ((pages, layers, 16, kvh * dh), BF16)
+    return _compile(fn, one_chip, ((b, width, h, dh), BF16), pool, pool,
+                    ((b, row), I32), ((b,), I32), ((b,), I32))
 
 
+def test_paged_attention_compiles_at_one_kv_head_of_128(
+        one_chip, no_persistent_cache):
+    """The grouped kernel at AI21-Jamba2-3B's attention layers: 20
+    query heads of 128 on one key-value head, the rollouts cell's 384
+    slots, a row of 192 pages of 16 tokens; a page is a (16, 128) bf16
+    tile, a block 32 of them."""
+    assert _compile_grouped_walk(one_chip, "rollouts", 1) == 1
+
+
+@pytest.mark.parametrize("width", [1, 2], ids=["decode", "verify_width_2"])
+@pytest.mark.parametrize("cell", sorted(_GROUPED_CELLS))
+def test_grouped_walk_compiles_at_the_cells_shapes(
+        one_chip, no_persistent_cache, cell, width):
+    """The grouped walk with bfloat16 pools at each cell's slots, table
+    width and window: a block of 512 tokens (384 with Mellum's window:
+    its 65 pages in three even turns) fetched whole into a double
+    buffer, the slot's row of the table and the next slot's in scalar
+    memory, every head's scores one matmul over the packed lanes (at
+    LFM2's heads of 64 no half-tile slice of a page)."""
+    assert _compile_grouped_walk(one_chip, cell, width) == 1
+
+
+# ------------------------------------------------- kernels on a mesh
 def _mesh_compile(fn, mesh, *args):
     """``args``: (shape, dtype, PartitionSpec) placed on ``mesh``."""
     sds = [jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(mesh, spec))

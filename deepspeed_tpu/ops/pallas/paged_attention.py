@@ -447,65 +447,70 @@ def _grouped_paged_attention(q, k_pool, v_pool, page_tables, positions,
     return out.reshape(b, s, h, dh)
 
 
-# Tokens a turn of the latent page walk fetches and folds: 32 pages of
-# 16 at 640 bf16 lanes, 640 KB a buffer half.
+# Tokens a turn of the latent page walk fetches and folds, all of them
+# whether live or not: 32 pages of 16 at 640 bf16 lanes, 640 KB a buffer
+# half (PERF.md section 6, PR 47).
 _MLA_BLOCK_TOKENS = 512
 
 
 def _mla_kernel(pt_ref, pos_ref, vlen_ref, q_ref, pool_ref, o_ref, buf,
                 sem, half_ref, *, layer_idx, page_size, heads, rank,
-                sm_scale, seq, block):
+                sm_scale, seq, block, max_pages):
     """One slot's walk over LATENT pages (ops/mla.py, the absorbed
     form): every head's query ``[q_lat | q_pe | 0]`` against one shared
     "key-value head", the cached row ``[c~ | k_pe | 0]``, whose values
     are the first ``rank`` lanes of the same fetched block: ONE pool,
-    one DMA a page, where the other kernels make two. The walk, its
-    double buffer and the prefetch across slots are :func:`_kernel`'s,
-    the rows of the two matmuls :func:`_grouped_kernel`'s (all ``seq *
-    heads`` queries of the slot at once); the masking contract is the
-    module's. Refs:
+    one DMA a page, where the other kernels make two. The fetch and the
+    fold are :func:`_grouped_kernel`'s: a block is fetched WHOLE, the
+    table's entries past a slot's live pages (the garbage page) with the
+    rest, by straight-line starts and ONE wait on a descriptor of the
+    whole buffer half; the next block, or after a slot's last the next
+    slot's first, is in flight meanwhile; all ``seq * heads`` queries of
+    the slot fold a block at once. The masking contract is the module's,
+    and what a dead page brought is zeroed on a slot's last block, the
+    only one that can hold any. Refs:
 
-    pt_ref (b, max_pages) / pos_ref (b,) / vlen_ref (b,): SMEM scalar
-    prefetch; q_ref (1, seq * heads, lanes), rows ordered (query,
-    head); pool_ref (pages+1, L, page_size, lanes) left in HBM; o_ref
-    (1, seq * heads, rank) fp32; buf (2, block * page_size, lanes);
-    half_ref (1,) SMEM: the buffer half of this slot's first block."""
+    pt_ref (b * max_pages,): the table's rows end to end (a start is
+    scalar work, and an entry of a 2-D table in scalar memory is a
+    tile's address arithmetic further away) / pos_ref (b,) / vlen_ref
+    (b,): SMEM scalar prefetch; q_ref (1, seq * heads, lanes), rows
+    ordered (query, head); pool_ref (pages+1, L, page_size, lanes) left
+    in HBM; o_ref (1, seq * heads, rank) fp32; buf (2, block *
+    page_size, lanes); half_ref (1,) SMEM: the buffer half of this
+    slot's first block."""
     i = pl.program_id(0)
     num_slots = pl.num_programs(0)
-    max_pages = pt_ref.shape[1]
     rows = seq * heads
     tokens = block * page_size
 
-    def pages_of(slot):
-        live = pos_ref[slot] + vlen_ref[slot] - 1
-        return jnp.minimum(
-            jax.lax.div(jnp.maximum(live, 0), page_size) + 1, max_pages)
-
-    def transfer(slot, c, half, start):
-        # a block's last pages may lie past the live window: no copy,
-        # and what the buffer holds there is zeroed below
-        first = c * block
+    def fetch(slot, at, half, unroll=True):
+        # ALL the block's pages from column ``at`` of the slot's row: one
+        # wait covers them (past the live: the garbage page, zeroed below)
+        row = slot * max_pages
+        first = row + at                   # an entry is ``first`` + a constant
 
         def page(j, carry):
-            copy = pltpu.make_async_copy(
-                pool_ref.at[pt_ref[slot, first + j], layer_idx],
-                buf.at[half, pl.ds(pl.multiple_of(j * page_size,
-                                                  page_size), page_size)],
-                sem.at[half])
-            copy.start() if start else copy.wait()
+            entry = first + j
+            if max_pages % block:          # the last block overhangs the row
+                entry = jnp.minimum(entry, row + max_pages - 1)
+            phys = pt_ref[entry]
+            dst = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            pltpu.make_async_copy(pool_ref.at[phys, layer_idx],
+                                  buf.at[half, dst], sem.at[half]).start()
             return carry
-
-        jax.lax.fori_loop(0, jnp.clip(pages_of(slot) - first, 0, block),
-                          page, 0)
+        jax.lax.fori_loop(0, block, page, 0, unroll=unroll)  # traced once
 
     @pl.when(i == 0)
     def _first_slot():
         half_ref[0] = 0
-        transfer(0, 0, 0, True)
+        fetch(0, 0, 0, unroll=False)       # once a call: a rolled loop
 
     pos = pos_ref[i]
     live = pos + vlen_ref[i] - 1           # last live absolute position
-    n_blocks = jax.lax.div(pages_of(i) + block - 1, block)
+    pages = jnp.minimum(
+        jax.lax.div(jnp.maximum(live, 0), page_size) + 1, max_pages)
+    # a block at least: the next slot's first is fetched during it
+    n_blocks = jax.lax.div(pages + block - 1, block)
     first_half = half_ref[0]
     q = q_ref[0]                                       # (rows, lanes)
     q_pos = pos + jax.lax.div(
@@ -519,11 +524,13 @@ def _mla_kernel(pt_ref, pos_ref, vlen_ref, q_ref, pool_ref, o_ref, buf,
         last = c + 1 == n_blocks
         nxt_slot = jnp.where(last, i + 1, i)
 
+        # in flight meanwhile: the next block, or the next slot's first
         @pl.when(nxt_slot < num_slots)
         def _prefetch():
-            transfer(nxt_slot, jnp.where(last, 0, c + 1), 1 - half, True)
+            fetch(nxt_slot, jnp.where(last, 0, (c + 1) * block), 1 - half)
 
-        transfer(i, c, half, False)
+        pltpu.make_async_copy(buf.at[half], buf.at[half],
+                              sem.at[half]).wait()     # the whole block
 
         # only a slot's last block reaches past its live window: zero
         # the rows there in place (values, and the keys with them)
@@ -564,8 +571,13 @@ def mla_decode(q_abs, pool, page_tables, positions, valid_lens, *,
     must already have landed. q_abs (b, s, h, lanes): ``[q_lat | q_pe |
     0]``; pool (pages+1, layers, page_size, lanes): ``[c~ | k_pe | 0]``,
     pad lanes zero in every live row; ``rank``: the lanes of a row that
-    are its value (a multiple of 128). Returns fp32 ctx_lat (b, s, h,
-    rank). One chip: the pool is replicated on a mesh."""
+    are its value (a multiple of 128). A slot's row is walked in blocks
+    of ``_MLA_BLOCK_TOKENS`` (no more than the table holds), each
+    fetched whole: every entry of ``page_tables`` is read as a page of
+    the pool, so the entries past a slot's live pages name one that may
+    be read (the garbage page, inference/paging.py). Returns fp32
+    ctx_lat (b, s, h, rank). One chip: the pool is replicated on a
+    mesh."""
     if interpret is None:
         interpret = default_interpret()
     b, s, h, lanes = q_abs.shape
@@ -591,7 +603,8 @@ def mla_decode(q_abs, pool, page_tables, positions, valid_lens, *,
         ])
     kernel = functools.partial(
         _mla_kernel, layer_idx=layer_idx, page_size=page_size, heads=h,
-        rank=rank, sm_scale=sm_scale, seq=s, block=block)
+        rank=rank, sm_scale=sm_scale, seq=s, block=block,
+        max_pages=max_pages)
     cost = pl.CostEstimate(
         flops=2 * b * rows * window * (lanes + rank),
         bytes_accessed=(q_abs.size * q_abs.dtype.itemsize
@@ -606,8 +619,8 @@ def mla_decode(q_abs, pool, page_tables, positions, valid_lens, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name="mla_decode",
-    )(page_tables.astype(jnp.int32), positions.astype(jnp.int32),
-      valid_lens.astype(jnp.int32),
+    )(page_tables.astype(jnp.int32).reshape(-1),
+      positions.astype(jnp.int32), valid_lens.astype(jnp.int32),
       q_abs.reshape(b, rows, lanes).astype(pool.dtype), pool)
     return out.reshape(b, s, h, rank)
 

@@ -1,10 +1,11 @@
 """Device time of the grouped page walk (``paged_attention_grouped``,
-ops/pallas/paged_attention.py) for one layer's decode step at the shapes
-of the three cells that run it, ONE chip, bfloat16 pools, read from a
-profiler trace (not a host clock). Needs a TPU.
+ops/pallas/paged_attention.py) and of the latent one (``mla_decode``)
+for one layer's decode step at the shapes of the four cells that run
+them, ONE chip, bfloat16 pools, read from a profiler trace (not a host
+clock). Needs a TPU.
 
     chiprun -- python tests/perf/paged_walk_microbench.py \
-        [--shapes ide_full,ide_window,extract,rollouts] \
+        [--shapes ide_full,ide_window,extract,rollouts,reasoning] \
         [--blocks 0,8,16,32,64] \
         [--module label=path/to/paged_attention.py[@chunk=16]]
 
@@ -19,6 +20,16 @@ layout with it), the share of the HBM roofline (the live pages' bytes of
 K and V over 819 GB/s over the kernel's time: what
 ``paged_attention_roofline.ide`` prices) and the largest difference from
 the first kernel of the line's shape.
+
+The latent shape (``reasoning``: Moonlight's 16 heads over rows of 640
+lanes, 5 layers in the pool so that the layer strides as in the cell)
+reads the ``%mla_decode`` events; its share prices the live pages'
+USEFUL bytes, 576 of a row's 640 lanes, as ``mla_decode_roofline`` does;
+``dead_fetched_share`` is the share of the pages a whole-block fetch
+brings that lie past a slot's live ones (``@live_only=1`` after a
+``--module``'s path: that kernel fetches live pages only, the parent's
+before PR 47). ``--blocks`` and ``@block=`` set the module's
+``_MLA_BLOCK_TOKENS`` there: the block follows from it.
 """
 import argparse
 import importlib
@@ -44,6 +55,11 @@ SHAPES = {
     "extract": (384, 32, 8, 64, 192, None, (300, 1300)),
     "rollouts": (384, 20, 1, 128, 192, None, (300, 1500)),
 }
+# moonlight-16b-a3b-serve.reasoning: slots, heads, a row's lanes, the
+# lanes that are its value, its useful lanes, table columns, layers in
+# the pool, live tokens a slot (lognormal about 2,300, clipped: the mean
+# comes out near 2,500)
+LATENT = {"reasoning": (320, 16, 640, 512, 576, 512, 5, (1000, 6000))}
 
 
 def load(path):
@@ -54,7 +70,7 @@ def load(path):
     return module
 
 
-def traced(fn, args):
+def traced(fn, args, event="paged_attention_grouped"):
     import jax
     out = jax.block_until_ready(fn(*args))
     jax.block_until_ready(fn(*args))
@@ -65,12 +81,75 @@ def traced(fn, args):
         jax.block_until_ready(last)
         jax.profiler.stop_trace()
         kernels, busy_ms, _ = kernel_ms(tmp, ITERS)
-        return out, kernels.get("paged_attention_grouped", 0.0), busy_ms
+        return out, kernels.get(event, 0.0), busy_ms
+
+
+def table_of(rng, pages, columns):
+    """A page table whose rows hold each slot's live pages, drawn
+    without order from 1 .. the pages' sum, then the garbage page."""
+    import numpy as np
+    total = int(pages.sum())
+    table = np.zeros((len(pages), columns), np.int32)
+    order = rng.permutation(np.arange(1, total + 1))
+    for i, at in enumerate(np.cumsum(pages) - pages):
+        table[i, :pages[i]] = order[at:at + pages[i]]
+    return table, total
+
+
+def latent(name, kernels, rng):
+    """One line a kernel and block for the latent walk at ``name``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    b, h, lanes, rank, useful, columns, layers, (low, high) = LATENT[name]
+    live = np.clip(rng.lognormal(np.log(2300.0), 0.45, b), low,
+                   high).astype(np.int64)
+    pages = -(-live // PAGE)
+    table, total = table_of(rng, pages, columns)
+    # one layer's rows under every layer (a draw of the whole pool would
+    # take twice its 5 GB in float32 on the way), pad lanes zero
+    pool = jnp.tile(jax.random.normal(
+        jax.random.PRNGKey(1), (total + 1, 1, PAGE, lanes), jnp.bfloat16)
+        * (jnp.arange(lanes) < useful).astype(jnp.bfloat16),
+        (1, layers, 1, 1))
+    q = jax.random.normal(jax.random.PRNGKey(3), (b, 1, h, lanes),
+                          jnp.bfloat16).at[..., useful:].set(0)
+    args = (q, pool, jnp.asarray(table), jnp.asarray(live - 1, jnp.int32),
+            jnp.ones((b,), jnp.int32))
+    floor_s = total * PAGE * useful * 2 / PEAK_BYTES_PER_S
+    own = {id(m): m._MLA_BLOCK_TOKENS for _, m, _ in kernels}
+    first = None
+    for label, module, more in kernels:
+        more = dict(more)
+        live_only = more.pop("live_only", 0)
+        block = more.pop("block", 0) or own[id(module)] // PAGE
+        fetched = -(-pages // block) * block
+        line = dict(shape=name, kernel=label, block=block,
+                    mean_live_tokens=float(live.mean()),
+                    pages_read=total, dead_fetched_share=0.0 if live_only
+                    else round(1 - total / int(fetched.sum()), 4), **more)
+        try:
+            module._MLA_BLOCK_TOKENS = block * PAGE
+            fn = jax.jit(lambda *a: module.mla_decode(
+                *a, layer_idx=layers - 2, page_size=PAGE, rank=rank,
+                sm_scale=192 ** -0.5, interpret=False, **more))
+            out, walk_ms, call_ms = traced(fn, args, "mla_decode")
+        except Exception as e:  # noqa: BLE001 - a refused block
+            line["error"] = str(e)[-300:]
+            print(json.dumps(line), flush=True)
+            continue
+        first = out if first is None else first
+        line.update(
+            kernel_ms=walk_ms, call_ms=call_ms,
+            hbm_roofline_share=round(floor_s / (walk_ms * 1e-3), 4),
+            max_abs_diff=float(jnp.max(jnp.abs(out - first))),
+            device=jax.devices()[0].device_kind)
+        print(json.dumps(line), flush=True)
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--shapes", default=",".join([*SHAPES, *LATENT]))
     ap.add_argument("--blocks", default="0")
     ap.add_argument("--module", action="append", default=[])
     ns = ap.parse_args()
@@ -94,14 +173,13 @@ def main():
                 for v in ns.blocks.split(",")]
     rng = np.random.default_rng(0)
     for name in ns.shapes.split(","):
+        if name in LATENT:
+            latent(name, kernels, rng)
+            continue
         b, h, kvh, dh, columns, window, (low, high) = SHAPES[name]
         live = rng.integers(low, high, b)
         pages = -(-live // PAGE)
-        total = int(pages.sum())
-        table = np.zeros((b, columns), np.int32)
-        order = rng.permutation(np.arange(1, total + 1))
-        for i, at in enumerate(np.cumsum(pages) - pages):
-            table[i, :pages[i]] = order[at:at + pages[i]]
+        table, total = table_of(rng, pages, columns)
         shape = (total + 1, 2, PAGE, kvh * dh)
         k_pool, v_pool = (jax.random.normal(jax.random.PRNGKey(i), shape,
                                             jnp.bfloat16) for i in (1, 2))

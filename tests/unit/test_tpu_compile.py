@@ -773,14 +773,17 @@ def test_lfm2_programs_run_the_kernels_and_alias_both_pools(
         14 * 2 ** 30
 
 
+@pytest.mark.parametrize("seq", [1, 2], ids=["decode", "verify_2"])
 def test_mla_decode_compiles_at_the_published_widths(
-        one_chip, no_persistent_cache):
+        one_chip, no_persistent_cache, seq):
     """The latent page walk at Moonlight-16B-A3B's attention: 16 heads'
     absorbed queries of 640 lanes (512 latent + 64 rope + 64 of
     padding) against one pool of 640-lane rows, 320 slots, a row of 512
     pages of 16 tokens: a page is a (16, 640) bf16 slab, the values its
     first 512 lanes. The lane rule that PR 22 paid for: 576 lanes, the
-    row without its padding, is not a multiple of 128."""
+    row without its padding, is not a multiple of 128. A block's 32
+    page copies are straight-line starts behind one wait (PR 47); two
+    queries a slot are 32 rows of the same two matmuls."""
     from deepspeed_tpu.ops.pallas.paged_attention import mla_decode
 
     def fn(q, pool, page_tables, positions, valid_lens):
@@ -789,7 +792,7 @@ def test_mla_decode_compiles_at_the_published_widths(
                           sm_scale=192 ** -0.5, interpret=False)
 
     b = 320
-    assert _compile(fn, one_chip, ((b, 1, 16, 640), BF16),
+    assert _compile(fn, one_chip, ((b, seq, 16, 640), BF16),
                     ((75001, 5, 16, 640), BF16), ((b, 512), I32),
                     ((b,), I32), ((b,), I32)) == 1
 

@@ -241,9 +241,9 @@ def check_telemetry_snapshot(snap):
         if not isinstance(srv, dict):
             problems.append("telemetry.serving is not a dict")
         else:
-            # serving-memory/latency gauges (ISSUE 7): optional — a
-            # slot-layout engine emits none — but when present they
-            # must carry their numeric fields
+            # serving-memory/latency gauges (ISSUE 7): optional (null
+            # until the feature that produces them has fired) — but
+            # when present they must carry their numeric fields
             for key, want in SERVING_SUBDICT_KEYS.items():
                 sub = srv.get(key)
                 if sub is None:

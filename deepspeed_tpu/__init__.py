@@ -102,25 +102,28 @@ def init_inference(model=None, config=None, mp_size=1, mesh=None,
     Mirrors reference ``deepspeed.init_inference(model, mp_size, dtype,
     injection_policy, replace_method, ...)`` alongside :func:`initialize`.
     Returns an :class:`deepspeed_tpu.inference.InferenceEngine` with a
-    preallocated slot-based KV cache, jitted prefill/decode paths and a
-    continuous-batching scheduler (``engine.generate(prompts)``).
+    preallocated pool of KV pages and a page table a slot, jitted
+    prefill/decode paths and a continuous-batching scheduler
+    (``engine.generate(prompts)``). With no ``inference`` section the
+    pool holds ``max_batch_size * max_seq_len`` tokens.
 
-    ``model`` is a :class:`deepspeed_tpu.Model` carrying a GPT2Config at
-    ``.config`` (``models.gpt2.make_gpt2_model``). ``config`` is a
+    ``model`` is a :class:`deepspeed_tpu.Model` that carries a decoder
+    (inference/decoder.py; ``models.gpt2.make_gpt2_model`` and the other
+    families' ``make_*_model`` attach one). ``config`` is a
     ds_config dict/path whose ``inference`` section sets max_batch_size,
     max_seq_len, prefill_buckets, dtype and sampling defaults. ``mp_size``
     > 1 (or an explicit ``mesh`` with a ``model`` axis) shards params with
-    the model's Megatron partition specs and the KV cache over its heads
-    axis. When ``replace_method`` is truthy (default "auto") and
+    the model's Megatron partition specs and the page pool over its
+    packed heads axis. When ``replace_method`` is truthy (default "auto") and
     ``model.params`` is an HF-flax GPT-2 tree (a ``transformer`` subtree),
     the params are converted IN PLACE via
     ``module_inject.hf_gpt2_to_gpt2_params`` using ``injection_policy``
     (default ``HFGPT2LayerPolicy``) — mirroring the reference's
     module-mutating injection.
 
-    ``inference.kv_layout: "paged"`` switches the engine to the paged KV
-    cache (+ ``prefix_caching``, ``speculative`` — docs/inference.md);
-    ``draft_model`` supplies the small GPT-2 drafter that
+    ``inference.kv_block_size`` / ``num_pages`` / ``kv_pool_fraction``
+    size the pool; ``prefix_caching`` and ``speculative`` build on it
+    (docs/inference.md). ``draft_model`` supplies the small GPT-2 drafter that
     ``inference.speculative.method: "model"`` requires.
 
     ``audit=True`` runs the ahead-of-time shard-lint

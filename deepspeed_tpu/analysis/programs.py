@@ -362,7 +362,6 @@ def collect_inference_programs(engine):
     rng = _rng_struct()
     temp = np.float32(1.0)
     top_p = np.float32(1.0)
-    paged = engine.kv_layout == "paged"
     n_buckets = len(engine.prefill_buckets)
     # a model with recurrent layers: its state arrays ride behind the
     # page pool, donated like it, then the slot (prefill) or the
@@ -370,7 +369,7 @@ def collect_inference_programs(engine):
     pool = getattr(engine, "state", None)
     state = tuple(_sds(a) for a in pool.buffers()) if pool else ()
     donate = tuple(range(1, 1 + len(pools) + len(state)))
-    groups = engine.page_groups if paged else []
+    groups = engine.page_groups
 
     def tables(*lead):
         """The page tables a program is handed: one (.., max_pages)
@@ -386,18 +385,14 @@ def collect_inference_programs(engine):
     greedy, top_k = True, 0
     for bucket in engine.prefill_buckets:
         ids = jax.ShapeDtypeStruct((1, bucket), np.int32)
-        if paged:
-            args = (params,) + pools + state + (
-                (np.int32(0),) if state else ()) + (
-                ids, tables(), np.int32(0), np.int32(1), rng, temp, top_p)
-        else:
-            args = (params,) + pools + (ids, np.int32(0), np.int32(0),
-                    np.int32(1), rng, temp, top_p)
         specs.append(ProgramSpec(
             name="prefill/b{}".format(bucket), family="inference",
-            build=lambda b=bucket: _unjitted_prefill(engine, b, greedy,
-                                                     top_k),
-            args=args, donate=donate, mesh=engine.mesh,
+            build=lambda b=bucket: _unjitted(engine, "prefill", b, greedy,
+                                             top_k),
+            args=(params,) + pools + state + (
+                (np.int32(0),) if state else ()) + (
+                ids, tables(), np.int32(0), np.int32(1), rng, temp, top_p),
+            donate=donate, mesh=engine.mesh,
             # no allow_weak needed: every scalar operand is an explicit
             # np.int32/np.float32 (strong-typed)
             taint_paths=("0",), trace_bound=n_buckets))
@@ -407,50 +402,33 @@ def collect_inference_programs(engine):
     for name, width in widths:
         tokens = jax.ShapeDtypeStruct((engine.num_slots, width), np.int32)
         lengths = jax.ShapeDtypeStruct((engine.num_slots,), np.int32)
-        if paged:
-            args = (params,) + pools + state + (
+        specs.append(ProgramSpec(
+            name=name, family="inference",
+            build=lambda w=width: _unjitted(engine, "decode", greedy,
+                                            top_k, w),
+            args=(params,) + pools + state + (
                 (jax.ShapeDtypeStruct((engine.num_slots,), np.bool_),)
                 if state else ()) + (tokens, lengths,
                                      tables(engine.num_slots), rng, temp,
-                                     top_p)
-        else:
-            args = (params,) + pools + (tokens, lengths, rng, temp,
-                                        top_p)
-        specs.append(ProgramSpec(
-            name=name, family="inference",
-            build=lambda w=width: _unjitted_decode(engine, greedy, top_k,
-                                                   w),
-            args=args, donate=donate, mesh=engine.mesh,
+                                     top_p),
+            donate=donate, mesh=engine.mesh,
             taint_paths=("0",), trace_bound=len(widths)))
     return specs
 
 
-def _unjitted_prefill(engine, bucket, greedy, top_k):
-    """The prefill factory's traced fn WITHOUT entering the engine's
-    jit cache (the audit must not inflate compile_stats or the trace
-    registry)."""
-    fns, stats = engine._prefill_fns, dict(engine.compile_stats)
+def _unjitted(engine, family, *key):
+    """The ``family`` ("prefill" | "decode") factory's traced fn
+    WITHOUT entering the engine's jit cache (the audit must not inflate
+    compile_stats or the trace registry)."""
+    cache = "_{}_fns".format(family)
+    fns, stats = getattr(engine, cache), dict(engine.compile_stats)
     tele, rows = engine.telemetry, engine._first_calls
-    engine._prefill_fns, engine.telemetry, engine._first_calls = {}, None, []
+    setattr(engine, cache, {})
+    engine.telemetry, engine._first_calls = None, []
     try:
-        fn = engine._get_prefill_fn(bucket, greedy, top_k)
+        fn = getattr(engine, "_get_{}_fn".format(family))(*key)
     finally:
-        engine._prefill_fns = fns
-        engine.compile_stats = stats
-        engine.telemetry = tele
-        engine._first_calls_over(discard=True)   # nor the start-up record
-        engine._first_calls = rows
-    return fn.__wrapped__
-
-
-def _unjitted_decode(engine, greedy, top_k, width):
-    fns, stats = engine._decode_fns, dict(engine.compile_stats)
-    tele, rows = engine.telemetry, engine._first_calls
-    engine._decode_fns, engine.telemetry, engine._first_calls = {}, None, []
-    try:
-        fn = engine._get_decode_fn(greedy, top_k, width=width)
-    finally:
-        engine._decode_fns = fns
+        setattr(engine, cache, fns)
         engine.compile_stats = stats
         engine.telemetry = tele
         engine._first_calls_over(discard=True)   # nor the start-up record

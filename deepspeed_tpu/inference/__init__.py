@@ -2,8 +2,11 @@
 
 Subsystem layout:
   config.py      — the ds_config ``inference`` section
-  kv_cache.py    — slot (contiguous) + paged (page-pool) KV caches,
-                   heads-sharded
+  kv_cache.py    — the page-pool KV cache every engine serves from
+                   (heads-sharded), the per-slot state pool, and the
+                   model drafter's contiguous cache
+  decoder.py     — what the engine asks of a model, and what each
+                   serving feature needs of a cache (``refuse``)
   paging.py      — host-side page allocator / prefix cache / chunk plans
   engine.py      — InferenceEngine: jitted prefill + fused decode/verify
   sampling.py    — jit-compatible greedy/temperature/top-k/top-p

@@ -51,26 +51,25 @@ INFERENCE_TOP_P = "top_p"
 INFERENCE_TOP_P_DEFAULT = 1.0        # 1.0 disables nucleus filtering
 
 # ---- paged KV cache (docs/inference.md "Paged KV cache") -------------
-# "slot": one contiguous [slots, layers, heads, max_seq, d_head] buffer
-# (the numerics oracle, default); "paged": global page pool + per-
-# sequence page tables — HBM scales with live tokens, enables prefix
-# sharing, admission beyond slots*max_seq worth of mixed lengths.
+# Every engine serves from a global page pool and per-sequence page
+# tables. The key that once chose between that and a contiguous layout
+# is read so that configs that say "paged" keep loading; it selects
+# nothing (the slot layout went in PR 48).
 INFERENCE_KV_LAYOUT = "kv_layout"
-INFERENCE_KV_LAYOUT_DEFAULT = "slot"
-_KV_LAYOUTS = ("slot", "paged")
 
 INFERENCE_KV_BLOCK_SIZE = "kv_block_size"       # tokens per page
 INFERENCE_KV_BLOCK_SIZE_DEFAULT = 16
 
-# pool size: explicit page count, OR a fraction of the slot layout's
-# footprint (num_pages = ceil(fraction * slots * max_seq / block)).
-# Setting both is a config error — one budget, stated once.
+# pool size: explicit page count, OR a fraction of slots * max_seq
+# tokens (num_pages = ceil(fraction * slots * max_seq / block); 1.0:
+# every slot can fill its whole sequence). Setting both is a config
+# error — one budget, stated once.
 INFERENCE_NUM_PAGES = "num_pages"
 INFERENCE_NUM_PAGES_DEFAULT = None
 INFERENCE_KV_POOL_FRACTION = "kv_pool_fraction"
 INFERENCE_KV_POOL_FRACTION_DEFAULT = 1.0
 
-# hash-matched shared prompt prefixes (system-prompt dedup); paged only
+# hash-matched shared prompt prefixes (system-prompt dedup)
 INFERENCE_PREFIX_CACHING = "prefix_caching"
 INFERENCE_PREFIX_CACHING_DEFAULT = False
 
@@ -81,8 +80,9 @@ INFERENCE_PREFIX_CACHING_DEFAULT = False
 #   "pallas" - force the kernel (interpreter mode off-TPU — how tier-1
 #              pins parity);
 #   "xla"    - force the gather-back (the numerics oracle).
-# Decode-family only; prefill always runs the gather path. Loud no-op on
-# the slot layout or under a tensor-parallel mesh (engine resolves).
+# Decode-family only, and prefill's where the decoder has a kernel for
+# its chunks; on a tensor-parallel mesh the walk is shard_mapped, heads
+# over ``model`` (engine resolves).
 INFERENCE_PAGED_ATTENTION_KERNEL = "paged_attention_kernel"
 INFERENCE_PAGED_ATTENTION_KERNEL_DEFAULT = "auto"
 _PAGED_ATTENTION_KERNELS = ("auto", "pallas", "xla")
@@ -214,11 +214,11 @@ class DeepSpeedInferenceConfig:
                                                          self.top_p))
 
         # ---- paged KV / prefix sharing / chunked prefill -------------
-        self.kv_layout = str(sub.get(INFERENCE_KV_LAYOUT,
-                                     INFERENCE_KV_LAYOUT_DEFAULT)).lower()
-        _require(self.kv_layout in _KV_LAYOUTS,
-                 "{} must be one of {}, got {!r}".format(
-                     INFERENCE_KV_LAYOUT, _KV_LAYOUTS, self.kv_layout))
+        layout = sub.get(INFERENCE_KV_LAYOUT, "paged")
+        _require(layout == "paged",
+                 "{} {!r}: the slot layout was removed in PR 48; every "
+                 "engine serves from pages; drop the key".format(
+                     INFERENCE_KV_LAYOUT, layout))
 
         self.kv_block_size = sub.get(INFERENCE_KV_BLOCK_SIZE,
                                      INFERENCE_KV_BLOCK_SIZE_DEFAULT)
@@ -252,10 +252,6 @@ class DeepSpeedInferenceConfig:
 
         self.prefix_caching = bool(sub.get(INFERENCE_PREFIX_CACHING,
                                            INFERENCE_PREFIX_CACHING_DEFAULT))
-        _require(not (self.prefix_caching and self.kv_layout != "paged"),
-                 "{} requires {} \"paged\" (the slot layout has no pages "
-                 "to share)".format(INFERENCE_PREFIX_CACHING,
-                                    INFERENCE_KV_LAYOUT))
 
         self.paged_attention_kernel = str(sub.get(
             INFERENCE_PAGED_ATTENTION_KERNEL,
@@ -328,11 +324,6 @@ class DeepSpeedInferenceConfig:
                  "{}.{} must be one of {} or null, got {!r}".format(
                      INFERENCE_FLEET, FLEET_ROLE, _FLEET_ROLES,
                      self.fleet_role))
-        _require(not (self.fleet_role is not None and
-                      self.kv_layout != "paged"),
-                 "{}.{} needs {} \"paged\" (page-table slices are the "
-                 "handoff format)".format(INFERENCE_FLEET, FLEET_ROLE,
-                                          INFERENCE_KV_LAYOUT))
         self.fleet_handoff_quantize = bool(
             fleet.get(FLEET_HANDOFF_QUANTIZE, False))
         self.fleet_handoff_block_size = fleet.get(

@@ -8,14 +8,13 @@ attach one) with:
 * ``config``: ``vocab_size``, ``max_seq_len``, ``d_model``;
 * ``cache_spec()`` -> :class:`CacheSpec`: what it keeps. One of three
   kinds, or the first two together: keys and values (``kv_layers``
-  layers of ``kv_heads x d_head``, a PAIR of pools, in pages or
-  slots); per-slot recurrent ``state`` arrays beside them; or, with
-  ``page_lanes``, pages whose rows the decoder lays out itself (latent
-  attention: ONE pool of ``kv_layers`` layers, each token's row the
-  latent all heads share, padded to whole lanes; paged layout only).
-  The paged layers may stand in GROUPS (``CacheSpec.groups``, paged
-  layout only): each group has a pool pair, an allocator and a page
-  table a slot of its own, and an optional ``window``: a windowed
+  layers of ``kv_heads x d_head``, a PAIR of page pools); per-slot
+  recurrent ``state`` arrays beside them; or, with ``page_lanes``,
+  pages whose rows the decoder lays out itself (latent attention: ONE
+  pool of ``kv_layers`` layers, each token's row the latent all heads
+  share, padded to whole lanes). The paged layers may stand in GROUPS
+  (``CacheSpec.groups``): each group has a pool pair, an allocator and
+  a page table a slot of its own, and an optional ``window``: a windowed
   group's layers see the last ``window`` keys only, its table slides
   (column 0 is the first page that holds a visible key) and the pages
   that slid out go back to its allocator;
@@ -57,6 +56,9 @@ attach one) with:
 state: prefix sharing, drafting and the fleet's page hand-off refuse
 such a model at construction, as they refuse a windowed group (a page
 is then not the whole of a position's state in every layer either).
+Which feature needs what of a cache is said once, in ``FEATURES``
+below; ``refuse`` is what the engine, the drafter and the fleet's roles
+call.
 """
 from dataclasses import dataclass
 
@@ -141,32 +143,49 @@ def decoder_of(model, module=None):
         "paged layers in groups)")
 
 
-def _refuse(refused, what, model, why):
-    """The one sentence of every refusal below."""
-    if refused:
-        raise ValueError("{} cannot serve a model with {}: {}".format(
-            what, model, why))
+# What a serving feature needs of a cache, each need as (what the model
+# has that breaks it, why that breaks it, the test of decoder and spec):
+# the sentence a refusal says. A cache kind that meets a need in a new
+# way changes the test here and nothing in the engine.
+_PAGES_HOLD_THE_STATE = (
+    "recurrent layers", "its state is not in the pages",
+    lambda decoder, spec: getattr(decoder, "recurrent", False))
+_ROWS_ARE_KEYS_AND_VALUES = (
+    "latent pages", "its page rows are not keys and values",
+    lambda decoder, spec: spec.page_lanes is not None)
+_ONE_TABLE = (
+    "sliding-window layers or several page groups",
+    "a page is not the whole of a position's state in every layer",
+    lambda decoder, spec: not spec.one_table)
+# a slot's pages are the whole of its state: nothing beside them, and
+# each the whole of its positions' state in every paged layer
+_WHOLE_STATE = (_PAGES_HOLD_THE_STATE, _ONE_TABLE)
+# ... and what they hold is keys and values
+_WHOLE_STATE_IN_KEYS_AND_VALUES = _WHOLE_STATE + (_ROWS_ARE_KEYS_AND_VALUES,)
+
+# feature -> (what a refusal calls it, what it needs of a cache).
+# Latent pages keep prefix sharing: a shared page is shared whatever
+# its rows hold.
+FEATURES = {
+    "prefix_caching": ("prefix caching (inference.prefix_caching)",
+                       _WHOLE_STATE),
+    "speculative": ("speculative decoding (inference.speculative)",
+                    _WHOLE_STATE_IN_KEYS_AND_VALUES),
+    "handoff": ("the fleet's page hand-off (inference.fleet)",
+                _WHOLE_STATE_IN_KEYS_AND_VALUES),
+    # the model drafter keeps the DRAFT model's keys and values in a
+    # contiguous cache of its own (inference/speculative.py)
+    "draft_cache": ("a draft model's contiguous cache",
+                    _WHOLE_STATE_IN_KEYS_AND_VALUES),
+}
 
 
-def refuse_recurrent(engine_or_decoder, what):
-    """For every feature that takes the pages for the whole of a
-    request's state."""
-    decoder = getattr(engine_or_decoder, "decoder", engine_or_decoder)
-    _refuse(getattr(decoder, "recurrent", False), what,
-            "recurrent layers", "its state is not in the pages")
-
-
-def refuse_latent(spec, what):
-    """For every feature that takes a page for a ``(k, v)`` pair of
-    ``kv_heads x d_head`` rows."""
-    _refuse(spec.page_lanes is not None, what, "latent pages",
-            "its page rows are not keys and values")
-
-
-def refuse_windowed(spec, what):
-    """For every feature that takes a page for the whole of a
-    position's state in every layer: a windowed group gives back the
-    pages that slid out, and several groups have a table each."""
-    _refuse(not spec.one_table, what,
-            "sliding-window layers or several page groups",
-            "a page is not the whole of a position's state in every layer")
+def refuse(decoder, spec, feature):
+    """Raise where ``feature`` (a key of ``FEATURES``) cannot serve the
+    model of ``decoder`` and its ``cache_spec()`` ``spec``: the one
+    place that says what a cache kind cannot serve."""
+    what, needs = FEATURES[feature]
+    for model, why, unmet in needs:
+        if unmet(decoder, spec):
+            raise ValueError("{} cannot serve a model with {}: {}".format(
+                what, model, why))

@@ -2,15 +2,16 @@
 (inference/decoder.py): GPT-2, whose layers all keep keys and values,
 and Jamba, whose state-space layers keep a per-slot recurrent state
 beside the two attention layers' pages. No model module is imported
-here.
+here, and what a cache kind cannot serve is the decoder module's to
+say (``decoder.refuse``).
 
 The serving counterpart of ``runtime/engine.py``'s training engine,
 returned by ``deepspeed_tpu.init_inference()``. Jitted hot paths:
 
   * ``prefill`` — embed one request's prompt (or one CHUNK of it, padded
     to a length bucket so the number of jit traces is bounded by the
-    bucket list), write its K/V into the request's cache slot/pages,
-    sample the first token on the final chunk;
+    bucket list), write its K/V into the request's pages, sample the
+    first token on the final chunk;
   * ``decode_step`` — one token for EVERY slot in a single fused step
     (slots, 1) -> logits -> sample, writing K/V at each slot's live
     length. Inactive slots compute garbage that the scheduler ignores;
@@ -19,20 +20,16 @@ returned by ``deepspeed_tpu.init_inference()``. Jitted hot paths:
     per slot in one fused (slots, k+1) pass; the scheduler accepts the
     longest prefix the target agrees with (inference/speculative.py).
 
-Two KV layouts (``inference.kv_layout``):
-
-  * ``slot`` (default, the numerics oracle): one contiguous
-    ``(slots, layers, heads, max_seq, d_head)`` buffer pair;
-  * ``paged``: a pooled ``(pages, layers, page_size, heads * d_head)``
-    buffer pair plus host-side page tables (inference/paging.py) —
-    pages allocate on demand as sequences grow, shared prompt prefixes
-    map one set of pages into many tables (copy-on-write), and HBM
-    scales with live tokens instead of ``slots * max_seq``.
+One KV layout: a pooled ``(pages, layers, page_size, heads * d_head)``
+buffer pair a page group plus host-side page tables
+(inference/paging.py) — pages allocate on demand as sequences grow,
+shared prompt prefixes map one set of pages into many tables
+(copy-on-write), and HBM scales with live tokens instead of ``slots *
+max_seq``. With nothing set the pool holds ``slots * max_seq`` tokens.
 
 Tensor parallelism: params are placed via the model's
-``partition_spec_fn`` (Megatron column/row layout) and both cache
-layouts shard their heads (kv_cache.KV_CACHE_SPEC /
-PAGED_KV_CACHE_SPEC), so XLA runs
+``partition_spec_fn`` (Megatron column/row layout) and the page pools
+shard their packed heads (kv_cache.PAGED_KV_CACHE_SPEC), so XLA runs
 decode with each model shard attending over exactly the heads it owns.
 """
 import numpy as np
@@ -46,9 +43,8 @@ from ..utils.annotate import (annotate, engine_tag, setup_span,
                               startup_line, startup_report)
 from ..utils.logging import logger
 from .config import DeepSpeedInferenceConfig
-from .decoder import (decoder_of, refuse_latent, refuse_recurrent,
-                      refuse_windowed)
-from .kv_cache import KVCache, PagedKVCache, StatePool, write_path
+from .decoder import decoder_of, refuse
+from .kv_cache import PagedKVCache, StatePool, write_path
 from .paging import (GARBAGE_PAGE, GroupPages, PagePoolExhausted,
                      PrefixCache)
 from .sampling import make_sampler
@@ -108,7 +104,6 @@ class InferenceEngine:
         self.module = as_model(model)
         self.decoder = decoder_of(model, self.module)
         model_config = self.decoder.config
-        self.recurrent = bool(getattr(self.decoder, "recurrent", False))
         # counters the decoder's serving programs return beside their
         # tokens (inference/decoder.py), by name; what they count is
         # the decoder's business
@@ -167,51 +162,15 @@ class InferenceEngine:
             attrs.update(leaves=len(leaves),
                          bytes=sum(int(x.nbytes) for x in leaves))
 
-        # ------------------------------------------------- KV cache layout
+        # ------------------------------------------------------ KV cache
         spec = self.decoder.cache_spec()
-        self.kv_layout = ic.kv_layout
         self.page_size = ic.kv_block_size
-        if self.recurrent:
-            if self.kv_layout != "paged":
-                raise ValueError(
-                    "a model with recurrent layers is served from the "
-                    "paged layout only (inference.kv_layout: \"paged\")")
-            if ic.prefix_caching:
-                refuse_recurrent(self.decoder,
-                                 "prefix caching (inference.prefix_caching)")
-            if ic.spec_enabled:
-                refuse_recurrent(self.decoder, "speculative decoding "
-                                 "(inference.speculative)")
-            if ic.fleet_role is not None:
-                refuse_recurrent(self.decoder, "the fleet's page hand-off "
-                                 "(inference.fleet)")
-        # pages laid out by the decoder (latent attention): the pool,
-        # its allocator and prefix sharing are the same; whatever takes
-        # a page for keys and values refuses them
-        if self.kv_layout != "paged":
-            refuse_latent(spec, "the slot layout (inference.kv_layout: "
-                          "\"slot\")")
-        if ic.spec_enabled:
-            refuse_latent(spec, "speculative decoding "
-                          "(inference.speculative)")
-        if ic.fleet_role is not None:
-            refuse_latent(spec, "the fleet's page hand-off "
-                          "(inference.fleet)")
-        # paged layers in groups, a windowed one among them perhaps
-        # (inference/decoder.py): whatever takes a page for the whole
-        # of a position's state in every layer refuses them
-        if self.kv_layout != "paged":
-            refuse_windowed(spec, "the slot layout (inference.kv_layout: "
-                            "\"slot\")")
-        if ic.prefix_caching:
-            refuse_windowed(spec,
-                            "prefix caching (inference.prefix_caching)")
-        if ic.spec_enabled:
-            refuse_windowed(spec, "speculative decoding "
-                            "(inference.speculative)")
-        if ic.fleet_role is not None:
-            refuse_windowed(spec, "the fleet's page hand-off "
-                            "(inference.fleet)")
+        # what this cache kind cannot serve (inference/decoder.py)
+        for feature, on in (("prefix_caching", ic.prefix_caching),
+                            ("speculative", ic.spec_enabled),
+                            ("handoff", ic.fleet_role is not None)):
+            if on:
+                refuse(self.decoder, spec, feature)
         with setup_span("setup.cache", engine=self.startup_tag) as attrs:
             # per-slot recurrent state, a pool of its own beside the pages
             # (None for a model whose pages are its whole state)
@@ -220,56 +179,42 @@ class InferenceEngine:
             # a decoder that declares groups is handed a table a group
             # and each table's base; any other the one table, as ever
             self._grouped = bool(spec.groups)
-            if self.kv_layout == "paged":
-                self.max_pages = -(-self.max_seq_len // self.page_size)
-                # a pool pair, an allocator and a table a slot for each
-                # group; GARBAGE_PAGE everywhere a slot has no allocation
-                # (jit writes there are redirected and reads
-                # position-masked)
-                self.kv_groups, self.page_groups = [], []
-                for g, group in enumerate(spec.page_groups):
-                    num_pages = self._group_num_pages(ic, g, group)
-                    self.kv_groups.append(PagedKVCache.allocate(
-                        num_pages, group.layers, spec.kv_heads,
-                        self.page_size, spec.d_head, self.dtype, mesh=mesh,
-                        lanes=spec.page_lanes))
-                    self.page_groups.append(GroupPages(
-                        num_pages, self.num_slots, self.max_pages,
-                        self.page_size, window=group.window,
-                        chunk_tokens=self.prefill_buckets[-1]))
-                # the FIRST group under the names there were before there
-                # were groups (the same objects: the fleet's hand-off and
-                # the tests read and write them)
-                first = self.page_groups[0]
-                self.kv = self.kv_groups[0]
-                self.allocator = first.allocator
-                self.page_tables, self.page_counts = first.tables, \
-                    first.counts
-                self._windowed = tuple(g for g in self.page_groups
-                                       if g.window is not None)
-                # what one cached token costs, pad lanes included: a reader
-                # of the pool's counters need not know the model
-                self.kv_token_bytes = sum(kv.token_bytes
-                                          for kv in self.kv_groups)
-                # pages matched at admission time per slot, so the first-
-                # chunk extension match knows where to resume
-                self._admit_matched = {}
-                self.prefix_cache = (
-                    PrefixCache(self.allocator, self.page_size)
-                    if ic.prefix_caching else None)
-            else:
-                self.max_pages = 0
-                self.page_groups, self._windowed = [], ()
-                self.kv = KVCache.allocate(
-                    self.num_slots, spec.kv_layers, spec.kv_heads,
-                    self.max_seq_len, spec.d_head, self.dtype, mesh=mesh)
-                self.kv_token_bytes = self.kv.nbytes // (
-                    self.num_slots * self.max_seq_len)
-                self.kv_groups = [self.kv]
-                self.allocator = None
-                self.page_tables = None
-                self.page_counts = None
-                self.prefix_cache = None
+            self.max_pages = -(-self.max_seq_len // self.page_size)
+            # a pool pair, an allocator and a table a slot for each
+            # group; GARBAGE_PAGE everywhere a slot has no allocation
+            # (jit writes there are redirected and reads
+            # position-masked)
+            self.kv_groups, self.page_groups = [], []
+            for g, group in enumerate(spec.page_groups):
+                num_pages = self._group_num_pages(ic, g, group)
+                self.kv_groups.append(PagedKVCache.allocate(
+                    num_pages, group.layers, spec.kv_heads,
+                    self.page_size, spec.d_head, self.dtype, mesh=mesh,
+                    lanes=spec.page_lanes))
+                self.page_groups.append(GroupPages(
+                    num_pages, self.num_slots, self.max_pages,
+                    self.page_size, window=group.window,
+                    chunk_tokens=self.prefill_buckets[-1]))
+            # the FIRST group under the names there were before there
+            # were groups (the same objects: the fleet's hand-off and
+            # the tests read and write them)
+            first = self.page_groups[0]
+            self.kv = self.kv_groups[0]
+            self.allocator = first.allocator
+            self.page_tables, self.page_counts = first.tables, \
+                first.counts
+            self._windowed = tuple(g for g in self.page_groups
+                                   if g.window is not None)
+            # what one cached token costs, pad lanes included: a reader
+            # of the pool's counters need not know the model
+            self.kv_token_bytes = sum(kv.token_bytes
+                                      for kv in self.kv_groups)
+            # pages matched at admission time per slot, so the first-
+            # chunk extension match knows where to resume
+            self._admit_matched = {}
+            self.prefix_cache = (
+                PrefixCache(self.allocator, self.page_size)
+                if ic.prefix_caching else None)
             attrs["bytes"] = sum(int(kv.nbytes) for kv in self.kv_groups) \
                 + (int(self.state.nbytes) if self.state else 0)
 
@@ -350,21 +295,19 @@ class InferenceEngine:
                 "engine", self._flight_state)
         logger.info(
             "InferenceEngine: slots={} max_seq={} buckets={} dtype={} "
-            "layout={} kv_cache={:.1f} MB state_pool={:.1f} MB{}{}".format(
+            "kv_cache={:.1f} MB state_pool={:.1f} MB pages={}x{} "
+            "paged_attn={} prefill_attn={} token_bytes={}{}".format(
                 self.num_slots, self.max_seq_len, self.prefill_buckets,
-                self.dtype_name, self.kv_layout,
+                self.dtype_name,
                 sum(kv.nbytes for kv in self.kv_groups) / 2 ** 20,
                 self.state.nbytes / 2 ** 20 if self.state else 0.0,
-                " pages={}x{} paged_attn={} prefill_attn={} "
-                "token_bytes={}".format(
-                    "+".join("{}{}".format(
-                        g.allocator.num_pages,
-                        "" if g.window is None else "(window {}, table "
-                        "{})".format(g.window, g.max_pages))
-                        for g in self.page_groups), self.page_size,
-                    self.paged_attention_kernel,
-                    self.prefill_attention_kernel, self.kv_token_bytes)
-                if self.kv_layout == "paged" else "",
+                "+".join("{}{}".format(
+                    g.allocator.num_pages,
+                    "" if g.window is None else "(window {}, table "
+                    "{})".format(g.window, g.max_pages))
+                    for g in self.page_groups), self.page_size,
+                self.paged_attention_kernel,
+                self.prefill_attention_kernel, self.kv_token_bytes,
                 " spec_k={} drafter={}".format(
                     self.spec_k, type(self.drafter).__name__)
                 if self.drafter is not None else ""))
@@ -411,7 +354,6 @@ class InferenceEngine:
         stats, and the prefill/decode trace counts."""
         state = {
             "role": "serve",
-            "kv_layout": self.kv_layout,
             "num_slots": self.num_slots,
             "max_seq_len": self.max_seq_len,
             "lengths": [int(n) for n in self.lengths],
@@ -419,11 +361,10 @@ class InferenceEngine:
             "serving_record_steps": self.serving_record_steps,
             "page_pool": self.page_pool_stats(),
             "prefix": self.prefix_stats(),
+            "page_counts": [int(n) for n in self.page_counts],
         }
-        if self.kv_layout == "paged":
-            state["page_counts"] = [int(n) for n in self.page_counts]
-            if self._grouped:
-                state["page_groups"] = [g.stats() for g in self.page_groups]
+        if self._grouped:
+            state["page_groups"] = [g.stats() for g in self.page_groups]
         return state
 
     def debug_dump(self, reason="debug_dump"):
@@ -454,21 +395,10 @@ class InferenceEngine:
         """``inference.paged_attention_kernel`` tri-state -> the paged
         read path ("pallas" | "xla") of the decode family, and of the
         prefill family where the decoder has one (``_prefill_config``).
-        A "pallas" the slot layout cannot honor WARNS and runs XLA. On
-        a mesh the walk is shard_mapped, heads over ``model``."""
+        On a mesh the walk is shard_mapped, heads over ``model``."""
         key = self.inference_config.paged_attention_kernel
-        if self.kv_layout != "paged":
-            if key == "pallas":
-                logger.warning(
-                    "inference.paged_attention_kernel='pallas' has NO "
-                    "effect: kv_layout is %r — the slot layout has no "
-                    "page tables to walk (set inference.kv_layout: "
-                    "\"paged\")", self.kv_layout)
-            return "xla"
-        if key == "xla":
-            return "xla"
-        if key == "pallas":
-            return "pallas"
+        if key != "auto":
+            return key
         # "auto": the kernel earns its keep on TPU; off-TPU the
         # interpreter is a numerics-pinning vehicle, not a fast path
         # (ops/pallas/common.py owns the one backend predicate)
@@ -549,7 +479,7 @@ class InferenceEngine:
         return self.state.buffers() if self.state is not None else ()
 
     def _pools(self):
-        """The cache's page (or slot) pools, group after group."""
+        """The cache's page pools, group after group."""
         return sum((kv.buffers() for kv in self.kv_groups), ())
 
     def _after_eviction(self, take, group, slot, upto_tokens):
@@ -577,7 +507,7 @@ class InferenceEngine:
 
     def _update_cache(self, buffers):
         """What a program returned in place of its donated buffers: the
-        page (or slot) pools, then the recurrent state arrays."""
+        page pools, then the recurrent state arrays."""
         at = 0
         for kv in self.kv_groups:
             n = len(kv.buffers())
@@ -618,10 +548,7 @@ class InferenceEngine:
     def _kv_write_attr(self, tokens):
         """The ``kv_write`` attribute of a dispatch span: how the program
         of ``tokens`` new tokens a slot writes them into the page pools
-        (``kv_cache.write_path``, the branch its trace took); nothing on
-        the slot layout, which has no pages."""
-        if self.kv_layout != "paged":
-            return {}
+        (``kv_cache.write_path``, the branch its trace took)."""
         return {"kv_write": write_path(tokens, self.page_size)}
 
     def _get_prefill_fn(self, bucket, greedy, top_k):
@@ -634,77 +561,50 @@ class InferenceEngine:
         cfg = self._prefill_config()
         forward, head = self.decoder.forward_hidden, self.decoder.logits
         sampler = make_sampler(greedy, top_k)
-        paged, ps = self.kv_layout == "paged", self.page_size
+        ps = self.page_size
         n_kv, n_state = len(self._pools()), len(self._state_buffers())
         counted = {"counters": True} if self.counter_names else {}
         grouped = self._grouped
 
-        if paged:
-            def prefill(params, *rest):
-                # rest: the paged pools ((k, v), or the one pool a
-                # decoder lays out itself); the recurrent state arrays
-                # (none for a model without) and, with them, slot
-                # (scalar int32: whose
-                # state); then ids (1, bucket); page_row (max_pages,);
-                # start/length scalar int32 — the chunk covers positions
-                # [start, start+length); padded tokens are masked out of
-                # the cache write (kv_cache.write_tokens) and leave a
-                # recurrent state as it was (a decoder with page groups:
-                # page_row is (a row a group, each row's base)); rng,
-                # temperature, top_p;
-                # adapter args (when attached): (a_stack (n,r,d),
-                # b_stack (n,V,r), adapter_id scalar) — a per-tenant
-                # logits delta; the cache writes are adapter-independent.
-                pools, rest = rest[:n_kv], rest[n_kv:]
-                state, rest = rest[:n_state], rest[n_state:]
-                kwargs = dict(counted)
-                if n_state:
-                    kwargs["state_slot"], rest = rest[0], rest[1:]
-                ids, page_row, start, length, rng, temperature, top_p, \
-                    *adapter_args = rest
-                if grouped:
-                    rows, bases = page_row
-                    kwargs["page_bases"] = tuple(b[None] for b in bases)
-                    tables = tuple(row[None] for row in rows)
-                else:
-                    tables = page_row[None]
-                hidden, cache, *counters = forward(
-                    params, ids, cfg, cache=pools + state,
-                    positions=start[None], page_tables=tables,
-                    valid_lens=length[None], page_size=ps, **kwargs)
-                last = jnp.take(hidden[0], length - 1, axis=0)     # (d,)
-                logits = head(params, last[None])                  # (1, V)
-                if adapter_args:
-                    a_stack, b_stack, aid = adapter_args
-                    logits = logits + \
-                        (b_stack[aid] @ (a_stack[aid] @ last))[None]
-                token = sampler(logits, rng, temperature, top_p)[0]
-                return (*cache, token, *sum(counters, ()), logits[0])
-        else:
-            def prefill(params, k_cache, v_cache, ids, slot, start,
-                        length, rng, temperature, top_p, *adapter_args):
-                # ids (1, bucket); slot/start/length scalar int32. The
-                # request's cache rows are sliced out, filled from
-                # position `start`, and written back.
-                k_row = jax.lax.dynamic_slice_in_dim(k_cache, slot, 1,
-                                                     axis=0)
-                v_row = jax.lax.dynamic_slice_in_dim(v_cache, slot, 1,
-                                                     axis=0)
-                hidden, (k_row, v_row) = forward(
-                    params, ids, cfg, cache=(k_row, v_row),
-                    positions=start[None])
-                k_cache = jax.lax.dynamic_update_slice_in_dim(
-                    k_cache, k_row, slot, axis=0)
-                v_cache = jax.lax.dynamic_update_slice_in_dim(
-                    v_cache, v_row, slot, axis=0)
-                last = jnp.take(hidden[0], length - 1, axis=0)     # (d,)
-                logits = head(params, last[None])                  # (1, V)
-                if adapter_args:
-                    a_stack, b_stack, aid = adapter_args
-                    logits = logits + \
-                        (b_stack[aid] @ (a_stack[aid] @ last))[None]
-                token = sampler(logits, rng, temperature, top_p)[0]
-                return k_cache, v_cache, token, logits[0]
+        def prefill(params, *rest):
+            # rest: the paged pools ((k, v), or the one pool a decoder
+            # lays out itself); the recurrent state arrays (none for a
+            # model without) and, with them, slot (scalar int32: whose
+            # state); then ids (1, bucket); page_row (max_pages,);
+            # start/length scalar int32 — the chunk covers positions
+            # [start, start+length); padded tokens are masked out of
+            # the cache write (kv_cache.write_tokens) and leave a
+            # recurrent state as it was (a decoder with page groups:
+            # page_row is (a row a group, each row's base)); rng,
+            # temperature, top_p; adapter args (when attached):
+            # (a_stack (n,r,d), b_stack (n,V,r), adapter_id scalar) — a
+            # per-tenant logits delta; the cache writes are
+            # adapter-independent.
+            pools, rest = rest[:n_kv], rest[n_kv:]
+            state, rest = rest[:n_state], rest[n_state:]
+            kwargs = dict(counted)
+            if n_state:
+                kwargs["state_slot"], rest = rest[0], rest[1:]
+            ids, page_row, start, length, rng, temperature, top_p, \
+                *adapter_args = rest
+            if grouped:
+                rows, bases = page_row
+                kwargs["page_bases"] = tuple(b[None] for b in bases)
+                tables = tuple(row[None] for row in rows)
+            else:
+                tables = page_row[None]
+            hidden, cache, *counters = forward(
+                params, ids, cfg, cache=pools + state,
+                positions=start[None], page_tables=tables,
+                valid_lens=length[None], page_size=ps, **kwargs)
+            last = jnp.take(hidden[0], length - 1, axis=0)     # (d,)
+            logits = head(params, last[None])                  # (1, V)
+            if adapter_args:
+                a_stack, b_stack, aid = adapter_args
+                logits = logits + \
+                    (b_stack[aid] @ (a_stack[aid] @ last))[None]
+            token = sampler(logits, rng, temperature, top_p)[0]
+            return (*cache, token, *sum(counters, ()), logits[0])
 
         # the function's name is the program's in a profiler trace
         # (module `jit_prefill`): a contract, pinned by a test. Every
@@ -752,7 +652,7 @@ class InferenceEngine:
                                          self.paged_attention_kernel)
         forward, head = self.decoder.forward_hidden, self.decoder.logits
         sampler = make_sampler(greedy, top_k)
-        paged, ps = self.kv_layout == "paged", self.page_size
+        ps = self.page_size
         n_kv, n_state = len(self._pools()), len(self._state_buffers())
         counted = {"counters": True} if self.counter_names else {}
         grouped = self._grouped
@@ -766,51 +666,34 @@ class InferenceEngine:
             return jnp.einsum("swr,svr->swv", h,
                               b_stack[adapter_ids])    # (slots, width, V)
 
-        if paged:
-            def decode(params, *rest):
-                # rest: the paged pools; the recurrent state arrays and,
-                # with them,
-                # advance (slots,) bool (the slots whose state this
-                # step advances); then tokens (slots, width); lengths
-                # (slots,) int32; page_tables (a decoder with page groups:
-                # (a table a group, each table's bases)); rng,
-                # temperature, top_p; adapter args
-                pools, rest = rest[:n_kv], rest[n_kv:]
-                state, rest = rest[:n_state], rest[n_state:]
-                kwargs = dict(counted)
-                if n_state:
-                    kwargs["state_advance"], rest = rest[0], rest[1:]
-                tokens, lengths, page_tables, rng, temperature, top_p, \
-                    *adapter_args = rest
-                if grouped:
-                    page_tables, kwargs["page_bases"] = page_tables
-                hidden, cache, *counters = forward(
-                    params, tokens, cfg, cache=pools + state,
-                    positions=lengths, page_tables=page_tables,
-                    valid_lens=jnp.full_like(lengths, tokens.shape[1]),
-                    page_size=ps, **kwargs)
-                logits = head(params, hidden)
-                if adapter_args:
-                    logits = logits + _adapter_delta(hidden,
-                                                     *adapter_args)
-                flat = logits.reshape(-1, logits.shape[-1])
-                chosen = sampler(flat, rng, temperature,
-                                 top_p).reshape(tokens.shape)
-                return (*cache, chosen, *sum(counters, ()), logits)
-        else:
-            def decode(params, k_cache, v_cache, tokens, lengths, rng,
-                       temperature, top_p, *adapter_args):
-                hidden, (k_cache, v_cache) = forward(
-                    params, tokens, cfg, cache=(k_cache, v_cache),
-                    positions=lengths)
-                logits = head(params, hidden)
-                if adapter_args:
-                    logits = logits + _adapter_delta(hidden,
-                                                     *adapter_args)
-                flat = logits.reshape(-1, logits.shape[-1])
-                chosen = sampler(flat, rng, temperature,
-                                 top_p).reshape(tokens.shape)
-                return k_cache, v_cache, chosen, logits
+        def decode(params, *rest):
+            # rest: the paged pools; the recurrent state arrays and,
+            # with them, advance (slots,) bool (the slots whose state
+            # this step advances); then tokens (slots, width); lengths
+            # (slots,) int32; page_tables (a decoder with page groups:
+            # (a table a group, each table's bases)); rng, temperature,
+            # top_p; adapter args
+            pools, rest = rest[:n_kv], rest[n_kv:]
+            state, rest = rest[:n_state], rest[n_state:]
+            kwargs = dict(counted)
+            if n_state:
+                kwargs["state_advance"], rest = rest[0], rest[1:]
+            tokens, lengths, page_tables, rng, temperature, top_p, \
+                *adapter_args = rest
+            if grouped:
+                page_tables, kwargs["page_bases"] = page_tables
+            hidden, cache, *counters = forward(
+                params, tokens, cfg, cache=pools + state,
+                positions=lengths, page_tables=page_tables,
+                valid_lens=jnp.full_like(lengths, tokens.shape[1]),
+                page_size=ps, **kwargs)
+            logits = head(params, hidden)
+            if adapter_args:
+                logits = logits + _adapter_delta(hidden, *adapter_args)
+            flat = logits.reshape(-1, logits.shape[-1])
+            chosen = sampler(flat, rng, temperature,
+                             top_p).reshape(tokens.shape)
+            return (*cache, chosen, *sum(counters, ()), logits)
 
         # the function's name is the program's in a profiler trace:
         # module `jit_decode`, and its Mosaic call `%decode.N`, by which
@@ -859,10 +742,7 @@ class InferenceEngine:
         """``{num_pages, pages_in_use, occupancy}`` of the first page
         group, and for a decoder with page groups ``groups``: the same
         of each, a windowed one's table width, promises and the pages
-        it gave back as they slid out — None on the slot layout (it
-        has no pool to meter)."""
-        if self.allocator is None:
-            return None
+        it gave back as they slid out."""
         stats = self.allocator.stats()
         if self._grouped:
             stats["groups"] = [g.stats() for g in self.page_groups]
@@ -890,9 +770,7 @@ class InferenceEngine:
         hold the suffix — the caller keeps the request queued. A second
         match pass runs at first-chunk time (:meth:`match_prefix`) to
         pick up pages a same-step burst sibling registers between
-        admission and prefill. Slot layout: always True."""
-        if self.kv_layout != "paged":
-            return True
+        admission and prefill."""
         n = len(context)
         first, matched = self.page_groups[0], []
         if self.prefix_cache is not None:
@@ -930,10 +808,9 @@ class InferenceEngine:
         matched shared pages replace the slot's freshly-allocated ones,
         which return to the pool. Returns the TOTAL number of leading
         tokens already resident (the prefill start offset)."""
-        have = int(self._admit_matched.get(slot, 0)) \
-            if self.kv_layout == "paged" else 0
         if self.prefix_cache is None:
             return 0
+        have = int(self._admit_matched.get(slot, 0))
         extra, _ = self.prefix_cache.match(
             context, len(context) - 1, skip_pages=have,
             count_lookup=False, namespace=self._prefix_namespace(slot))
@@ -950,8 +827,6 @@ class InferenceEngine:
         is exhausted (after trying prefix-cache eviction): the
         scheduler preempts; the other groups keep what they took (the
         slot uses or frees it)."""
-        if self.kv_layout != "paged":
-            return True
         for group in self._windowed:
             group.slide(slot, int(self.lengths[slot]))
         ok = True
@@ -992,7 +867,7 @@ class InferenceEngine:
         sharing never appends into a shared page, so this is the safety
         net that makes sharing granularity a policy choice rather than
         a correctness constraint."""
-        if self.kv_layout != "paged" or not self.allocator.shared_pages:
+        if not self.allocator.shared_pages:
             # no page is held twice (only prefix sharing does that):
             # nothing to fork, and the walk over every slot's pages was
             # 1-2 ms of each decode step at 384 slots
@@ -1023,7 +898,7 @@ class InferenceEngine:
         """Embed ``tokens`` (one prompt chunk) into ``slot`` at absolute
         positions ``[start, start+len)`` and return the sampled token
         from the chunk's last position (only meaningful on the FINAL
-        chunk — earlier chunks' callers discard it). Paged slots must
+        chunk — earlier chunks' callers discard it). The slot must
         already hold pages covering the range (``try_admit``)."""
         assert 0 <= slot < self.num_slots
         n = len(tokens)
@@ -1062,18 +937,9 @@ class InferenceEngine:
                                for g in self.page_groups),
                          tuple(np.int32(g.base[slot] * self.page_size)
                                for g in self.page_groups))
-            elif self.kv_layout == "paged":
+            else:
                 self._cow_writes(slot, start, start + n - 1)
                 where = self.page_tables[slot].copy()
-            else:
-                # the slot layout writes the padded bucket with one
-                # dynamic_update_slice — paging.plan_chunks guarantees
-                # start + bucket <= max_seq so XLA's start clamping can
-                # never shift the write over live positions
-                assert start + bucket <= self.max_seq_len, \
-                    "chunk bucket {}@{} overruns max_seq_len {}".format(
-                        bucket, start, self.max_seq_len)
-                where = np.int32(slot)
             state = self._state_buffers()
             if state:
                 # the program of a request's first chunk (start 0)
@@ -1104,8 +970,7 @@ class InferenceEngine:
         assert n < self.max_seq_len, \
             "prompt length {} leaves no room to decode (max_seq_len " \
             "{})".format(n, self.max_seq_len)
-        if self.kv_layout == "paged" and \
-                int(self.page_counts[slot]) < self.pages_for(n):
+        if int(self.page_counts[slot]) < self.pages_for(n):
             assert self.ensure_pages(slot, n), "KV page pool exhausted"
         return self.prefill_chunk(slot, prompt, 0, sampling=sampling)
 
@@ -1135,13 +1000,11 @@ class InferenceEngine:
                 a_stack, b_stack = self._adapter_stack
                 extra = (a_stack, b_stack,
                          self.slot_adapters.astype(np.int32))
-            paged = self.kv_layout == "paged"
-            if paged:
-                for slot in range(self.num_slots):
-                    if self.lengths[slot] > 0:
-                        self._cow_writes(
-                            slot, int(self.lengths[slot]),
-                            int(self.lengths[slot]) + width - 1)
+            for slot in range(self.num_slots):
+                if self.lengths[slot] > 0:
+                    self._cow_writes(
+                        slot, int(self.lengths[slot]),
+                        int(self.lengths[slot]) + width - 1)
             state = self._state_buffers()
             if state:
                 advance = np.ones((self.num_slots,), bool)
@@ -1154,7 +1017,7 @@ class InferenceEngine:
                            tuple(g.base * np.int32(self.page_size)
                                  for g in self.page_groups)),)
             else:
-                tables = (self.page_tables.copy(),) if paged else ()
+                tables = (self.page_tables.copy(),)
             args = state + (tokens, self.lengths.copy()) + tables + (
                 self._next_rng(greedy), np.float32(temperature),
                 np.float32(top_p)) + extra
@@ -1195,10 +1058,9 @@ class InferenceEngine:
     def free_slot(self, slot):
         """Retire a slot: release its pages back to the pool (shared
         prefix pages just drop one reference) and zero its length."""
-        if self.kv_layout == "paged":
-            for group in self.page_groups:
-                group.release(slot)
-            self._admit_matched.pop(slot, None)
+        for group in self.page_groups:
+            group.release(slot)
+        self._admit_matched.pop(slot, None)
         self.lengths[slot] = 0
         self.slot_adapters[slot] = 0
 

@@ -80,9 +80,6 @@ def export_slice(engine, slot, context, pending_token, trace_id=None):
     """Lift ``slot``'s live pages out of a paged engine's pool into a
     host :class:`PageSlice`. The slot keeps its pages (the caller
     frees it after a successful handoff — export never mutates)."""
-    assert engine.kv_layout == "paged", \
-        "page-slice handoff needs kv_layout 'paged', engine runs " \
-        "{!r}".format(engine.kv_layout)
     n_pages = int(engine.page_counts[slot])
     length = int(engine.lengths[slot])
     assert n_pages >= 1 and length >= 1, \
@@ -205,8 +202,6 @@ def import_slice(engine, slot, sl):
     input). The caller checks capacity via :func:`can_import` first —
     exhaustion here raises (paging.PagePoolExhausted)."""
     import jax.numpy as jnp
-    assert engine.kv_layout == "paged", \
-        "page-slice import needs kv_layout 'paged'"
     assert engine.page_size == sl.page_size, \
         "page-size mismatch: engine {} vs slice {}".format(
             engine.page_size, sl.page_size)

@@ -17,7 +17,7 @@ imported page table at identical positions.
 """
 import time
 
-from ..decoder import refuse_recurrent
+from ..decoder import refuse
 from ..scheduler import ContinuousBatchingScheduler, InferenceRequest
 from ..paging import plan_chunks
 from .handoff import (DEFAULT_HANDOFF_BLOCK, can_import, export_slice,
@@ -31,10 +31,7 @@ class PrefillRole:
 
     def __init__(self, engine, sampling=None, quantize=False,
                  block_size=DEFAULT_HANDOFF_BLOCK):
-        assert engine.kv_layout == "paged", \
-            "the prefill role needs kv_layout 'paged' (page-table " \
-            "slices are its export format)"
-        refuse_recurrent(engine, "the fleet's page hand-off (prefill role)")
+        refuse(engine.decoder, engine.decoder.cache_spec(), "handoff")
         self.engine = engine
         self.sampling = sampling
         self.quantize = bool(quantize)
@@ -123,10 +120,7 @@ class DecodeRole:
     arrive as imported page slices instead of prompts."""
 
     def __init__(self, engine, metrics=None, sampling=None):
-        assert engine.kv_layout == "paged", \
-            "the decode role needs kv_layout 'paged' (it imports " \
-            "page-table slices)"
-        refuse_recurrent(engine, "the fleet's page hand-off (decode role)")
+        refuse(engine.decoder, engine.decoder.cache_spec(), "handoff")
         self.engine = engine
         engine.serving_role = "decode"
         self.sched = ContinuousBatchingScheduler(engine, metrics=metrics,

@@ -1,18 +1,12 @@
-"""Preallocated serving caches: keys and values in a slot or a paged
-layout; for a model with recurrent layers, a pool of per-slot state
-beside them (:class:`StatePool`); and, for a model with latent
-attention, pages whose rows are the latents themselves (one pool, no
-``v``). What a model keeps it declares itself (inference/decoder.py
-``CacheSpec``); no model is imported here.
+"""Preallocated serving caches: keys and values in pages; for a model
+with recurrent layers, a pool of per-slot state beside them
+(:class:`StatePool`); and, for a model with latent attention, pages
+whose rows are the latents themselves (one pool, no ``v``). What a
+model keeps it declares itself (inference/decoder.py ``CacheSpec``); no
+model is imported here.
 
-**Slot layout** (:class:`KVCache`, the numerics oracle and default): one
-buffer pair ``(k, v)`` of shape ``(slots, layers, heads, max_seq,
-d_head)`` holds every active request's attention state; a request owns one
-slot for its lifetime and its batch row in prefill/decode IS its slot
-index. Every admitted request pays ``max_seq`` worth of HBM regardless of
-its actual length.
-
-**Paged layout** (:class:`PagedKVCache`): a global pool of fixed-size
+**Pages** (:class:`PagedKVCache`, what every engine serves from): a
+global pool of fixed-size
 pages ``(pages, layers, page_size, heads * d_head)`` plus host-side
 per-sequence page tables (inference/paging.py). The heads ride PACKED in
 the minor dimension: a d_head-64 minor dimension is padded to the chip's
@@ -26,7 +20,7 @@ tokens, not with
 into many tables (prefix sharing). Physical page 0 is the reserved
 garbage page: never allocated, the target of every masked/padded write.
 
-**Latent pages** (``CacheSpec.page_lanes``, paged layout only): the same
+**Latent pages** (``CacheSpec.page_lanes``): the same
 pool, allocator, page tables and prefix sharing, but a token's row is
 what the decoder says: for latent attention (ops/mla.py) the ``kv_lora``
 latent and the rotated shared rope key, 576 values that all heads share,
@@ -35,11 +29,19 @@ multiple of the lanes stops the program on the chip), in ONE pool
 ``k``; ``v`` is None. The decoder writes the pad lanes as zeros with
 every row, because a recycled page may hold anything there.
 
-Reuse, per cache kind. Keys and values: freed slots and recycled pages
-are reused WITHOUT clearing — the absolute-position causal mask in the
+**The model drafter's contiguous cache** (:class:`KVCache`): one buffer
+pair ``(k, v)`` of shape ``(slots, layers, heads, max_seq, d_head)``; a
+request's batch row IS its slot index and every slot pays ``max_seq``
+worth of HBM. Its one user is the small draft model of
+``inference/speculative.py::ModelDrafter`` (a test pins that): no
+engine serves from it since PR 48.
+
+Reuse, per cache kind. Keys and values: recycled pages (and the
+drafter's freed slots) are reused WITHOUT clearing — the
+absolute-position causal mask in the
 model's cached attention (models/gpt2.py ``_attend_cache_rows``,
 models/jamba.py ``_attend``: ``k_pos <= q_pos``) makes stale entries
-unreachable in both layouts, for any garbage content including NaN
+unreachable, for any garbage content including NaN
 (pinned by tests/unit/test_serving.py poison tests). Recurrent state:
 NO mask hides what a slot held, so it is not reused as it is: the
 prefill program that runs a request's first chunk starts from zeros
@@ -47,10 +49,10 @@ whatever the slot holds (no clearing launch of its own), and a decode
 step advances only the slots that are decoding (pinned by
 tests/unit/test_jamba.py's NaN-poisoned state pool).
 
-Sharding: the heads carry the tensor-parallel partition in both layouts
-(the slot cache's ``heads`` axis, the paged pool's packed ``heads *
-d_head`` axis — contiguous per head, so an even split lands on head
-boundaries), matching ``models/gpt2.py::partition_spec_fn``'s Megatron
+Sharding: the heads carry the tensor-parallel partition (the page
+pool's packed ``heads * d_head`` axis — contiguous per head, so an even
+split lands on head boundaries; the drafter's cache its ``heads``
+axis), matching ``models/gpt2.py::partition_spec_fn``'s Megatron
 layout on the ``model`` mesh axis (QKV column-parallel => each model
 shard produces its own heads' K/V, so the cache entries it writes are
 exactly the entries it owns and decode inserts no cross-shard cache
@@ -64,8 +66,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..parallel.topology import MODEL_AXIS
 
-# (slots, layers, heads, max_seq, d_head): heads sharded over the model
-# axis.
+# the model drafter's contiguous cache (slots, layers, heads, max_seq,
+# d_head): heads sharded over the model axis.
 KV_CACHE_SPEC = P(None, None, MODEL_AXIS, None, None)
 # the paged pool (pages, layers, page_size, heads * d_head): the packed
 # heads axis is the minor one.
@@ -74,9 +76,10 @@ PAGED_KV_CACHE_SPEC = P(None, None, None, MODEL_AXIS)
 
 @dataclass
 class KVCache:
-    """The ``(k, v)`` buffer pair. Buffers are jax arrays updated
-    functionally: the engine's jitted prefill/decode donate them, so each
-    step writes in place at steady state."""
+    """The model drafter's contiguous ``(k, v)`` buffer pair
+    (inference/speculative.py, its one user). Buffers are jax arrays
+    updated functionally: the drafter's jitted programs donate them, so
+    each step writes in place at steady state."""
 
     k: object
     v: object

@@ -25,10 +25,10 @@ control flow, not math:
     back to the allocator when they slid out, and the table's width is
     bounded by the window and the largest chunk whatever
     ``max_seq_len`` is.
-  * :func:`plan_chunks` — chunked-prefill schedule with the slot-layout
-    write-safety guarantee (start + bucket never exceeds max_seq, or the
-    clamped ``dynamic_update_slice`` would shift the write window down
-    over live positions).
+  * :func:`plan_chunks` — chunked-prefill schedule (a plan whose
+    padded chunk would pass max_seq merges into one prefill where a
+    bucket holds it: a guard the removed slot layout needed and the
+    masked paged write does not; ROADMAP names it as a debt).
 """
 from collections import OrderedDict
 
@@ -397,14 +397,15 @@ def plan_chunks(n_tokens, chunk_tokens, bucket_for, max_seq, start=0,
     regardless of config: a preemption-resume context longer than every
     bucket always chunks, whatever ``prefill_chunk_tokens`` says.
 
-    Safety: the slot layout writes each chunk with a
-    ``dynamic_update_slice`` of the full PADDED bucket at ``start`` —
-    XLA clamps an out-of-range start so ``start + bucket > max_seq``
-    would silently shift the write DOWN over live positions. A plan
-    with such a chunk is merged back into one unchunked prefill when a
-    bucket covers the whole span; otherwise the chunked plan stands
-    (the paged layout's masked write, kv_cache.write_tokens, is safe
-    by construction, and the slot path keeps a LOUD overrun assert)."""
+    A plan with a chunk whose PADDED bucket would pass ``max_seq``
+    (``start + bucket > max_seq``) is merged back into one unchunked
+    prefill when a bucket covers the whole span; otherwise the chunked
+    plan stands. The merge was the slot layout's write safety (its
+    ``dynamic_update_slice`` of the padded bucket would have been
+    clamped DOWN over live positions); the paged write masks its pad
+    (kv_cache.write_tokens) and does not need it. It stays because it
+    decides which programs a long prompt near the cache's end runs
+    (ROADMAP, debt (e))."""
     if max_chunk is not None:
         chunk_tokens = min(chunk_tokens or max_chunk, max_chunk)
     if not chunk_tokens or n_tokens <= chunk_tokens:

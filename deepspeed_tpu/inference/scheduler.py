@@ -309,15 +309,13 @@ class ContinuousBatchingScheduler:
                             prompt_tokens=len(req.prompt))
                     req.span.event("admit", slot=slot, resumed=req.resumed,
                                    queue_wait_s=round(wait, 6))
-                    if self.engine.kv_layout == "paged":
-                        matched = int(
-                            self.engine._admit_matched.get(slot, 0))
-                        req.span.event(
-                            "page_alloc",
-                            pages=int(self.engine.page_counts[slot]),
-                            prefix_pages=matched)
-                        if matched:
-                            req.span.event("prefix_hit", pages=matched)
+                    matched = int(self.engine._admit_matched.get(slot, 0))
+                    req.span.event(
+                        "page_alloc",
+                        pages=int(self.engine.page_counts[slot]),
+                        prefix_pages=matched)
+                    if matched:
+                        req.span.event("prefix_hit", pages=matched)
                 # the chunk plan is built at FIRST-chunk time (below): the
                 # prefix match runs there, after same-step siblings have
                 # registered their pages, so bursts of one system prompt
@@ -398,8 +396,9 @@ class ContinuousBatchingScheduler:
         """Draft length this step: the configured k, or 0 (plain
         decode) whenever ANY occupied slot — decoding OR mid-prefill,
         the fused verify writes K/V for every slot — sits within k+1 of
-        max_seq: the slot layout's dynamic_update_slice would clamp an
-        out-of-range write start and corrupt live positions. All-or-
+        max_seq: a slot's table has no page past it, and the model
+        drafter's contiguous cache would clamp an out-of-range write
+        start and corrupt live positions. All-or-
         nothing (rather than shrinking k per step) bounds the decode
         program family to two widths, so one near-ceiling sequence
         can't trigger a cascade of mid-serving XLA recompiles."""

@@ -16,7 +16,8 @@ Two drafters, selected by ``inference.speculative.method``:
     and propose what followed. Free, surprisingly strong on the
     repetitive structure real traffic has (system prompts, code, JSON).
   * :class:`ModelDrafter` — a small config-selected GPT-2 target
-    sibling with its OWN slot-layout KV cache, proposing ``k`` greedy
+    sibling with its OWN contiguous KV cache (``kv_cache.KVCache``,
+    which nothing else uses), proposing ``k`` greedy
     tokens via one jitted ``lax.scan`` per scheduler step. Its cache
     advances in lockstep with the target's acceptance (rejected drafts
     become stale masked entries, exactly like the target's).
@@ -69,7 +70,9 @@ class NGramDrafter:
 
 
 class ModelDrafter:
-    """A small GPT-2 drafter with its own slot-layout KV cache.
+    """A small GPT-2 drafter with its own contiguous KV cache (one
+    ``(slots, layers, heads, max_seq, d_head)`` buffer pair; the target
+    serves from pages).
 
     The drafter model must share the target's tokenizer (vocab) and
     positional reach; everything else (depth/width/heads) is free —
@@ -85,10 +88,11 @@ class ModelDrafter:
         # the serving engine whose start-up record the drafter's
         # programs are rows of (``draft.prefill``, ``draft.propose``)
         self.engine = engine
-        from .decoder import refuse_recurrent
+        from .decoder import decoder_of, refuse
         from .kv_cache import KVCache
         self.module = as_model(model)
-        refuse_recurrent(self.module, "a draft model's slot cache")
+        decoder = decoder_of(model, self.module)
+        refuse(decoder, decoder.cache_spec(), "draft_cache")
         cfg = getattr(self.module, "config", None) or \
             getattr(model, "config", None)
         assert cfg is not None and hasattr(cfg, "n_heads"), \

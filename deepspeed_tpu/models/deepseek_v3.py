@@ -27,7 +27,7 @@ pair makes two, and the decode kernel's values are a lane slice of the
 block it fetched for the keys. The pages are the whole of a request's
 state, so prefix sharing works as it is; speculative decoding and the
 fleet's hand-off take a page for keys and values and are refused at
-construction (inference/decoder.py ``refuse_latent``). A prompt chunk
+construction (inference/decoder.py ``refuse``). A prompt chunk
 attends in the up-projected form, a decode step in the absorbed one
 (ops/mla.py says why they are the same function).
 
@@ -273,7 +273,7 @@ def forward_hidden(params, input_ids, config, cache=None, positions=None,
     eps, dims = config.norm_eps, config.mla
     if cache is not None:
         assert page_tables is not None, \
-            "latent pages are served from the paged layout only"
+            "latent pages are served through page tables only"
         (pool,) = cache
     load = jnp.zeros((2, config.n_experts), jnp.int32)
     for i, lp in enumerate(params["layers"]):

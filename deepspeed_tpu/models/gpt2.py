@@ -383,10 +383,10 @@ def _attend_cache_rows(q, k_rows, v_rows, positions, dh, valid_lens=None):
     makes every entry past a slot's live length unreachable — stale K/V
     from slot/page reuse and padded/garbage writes never contribute
     (NaN-poison pinned by tests/unit/test_serving.py). Shared verbatim
-    by the slot and paged layouts so paged decode is bit-compatible
-    with the slot-cache oracle. ``valid_lens`` (b,) is how many of the
-    ``s`` input tokens are real per row (default: all — the slot
-    layout's padded-bucket write overwrites the whole span)."""
+    by the paged read's XLA path and the model drafter's contiguous
+    cache. ``valid_lens`` (b,) is how many of the ``s`` input tokens
+    are real per row (default: all — the drafter's padded-bucket write
+    overwrites the whole span)."""
     s = q.shape[1]
     S = k_rows.shape[2]
     qf = q.astype(jnp.float32) * (1.0 / math.sqrt(dh))
@@ -413,7 +413,9 @@ def _attend_cache_rows(q, k_rows, v_rows, positions, dh, valid_lens=None):
 
 def _cached_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
                      positions):
-    """Incremental attention against the slot-based KV cache.
+    """Incremental attention against the model drafter's contiguous
+    cache (inference/speculative.py: no engine serves from it; the
+    serving path is :func:`_paged_attn_ctx`).
 
     ``x`` is the LN'd input for ``s`` NEW tokens per slot (batch row i IS
     cache slot i); the new K/V are written into the cache at
@@ -469,9 +471,9 @@ def _paged_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
     bucket-padded prefill can never touch another sequence's pages).
     Reads: the default "xla" path
     gathers the slot's full logical window back into contiguous (b, h,
-    max_pages*page_size, d_head) rows and runs the same masked
-    attention as the slot layout — identical values in identical order,
-    so paged decode is bit-compatible with the slot-cache oracle. That
+    max_pages*page_size, d_head) rows and runs the masked attention
+    of :func:`_attend_cache_rows` over them — the values a contiguous
+    cache would hold, in the same order. That
     gather touches the rows' own pages of this layer and nothing else
     of the pool (:func:`_gather_pages`); every prefill runs it. With
     ``config.paged_attention_kernel == "pallas"`` the read side runs
@@ -520,11 +522,12 @@ def _forward_hidden_cached(params, input_ids, config, cache, positions,
                            page_size=None):
     """Cache-threaded variant of :func:`forward_hidden` for serving.
 
-    ``cache`` is ``(k, v)``: the slot layout (slots, layers, heads,
-    max_seq, d_head) by default, or — when ``page_tables`` is given —
-    the paged pool (pages, layers, page_size, heads * d_head) indexed
-    per slot through ``page_tables`` (b, max_pages) with ``valid_lens``
-    (b,) masking padded writes (inference/kv_cache.py). ``positions``
+    ``cache`` is ``(k, v)``: the paged pool (pages, layers, page_size,
+    heads * d_head) indexed per slot through ``page_tables`` (b,
+    max_pages) with ``valid_lens`` (b,) masking padded writes
+    (inference/kv_cache.py) — what every engine passes; without
+    ``page_tables``, the model drafter's contiguous cache (slots,
+    layers, heads, max_seq, d_head). ``positions``
     (b,) int32 is the absolute position of input_ids[:, 0] per slot.
     Returns ``(hidden, (k, v))``.
     """
@@ -594,9 +597,10 @@ def forward_hidden(params, input_ids, config, rng=None, train=False,
 
     With ``cache`` (a ``(k, v)`` KV-cache buffer pair) and ``positions``
     (per-row absolute offset of the first token) the stack runs the
-    incremental serving path and returns ``(hidden, cache)`` instead;
-    ``page_tables``/``valid_lens``/``page_size`` switch the cache
-    indexing to the paged layout (see ``_paged_attn_ctx``).
+    incremental serving path and returns ``(hidden, cache)`` instead:
+    with ``page_tables``/``valid_lens``/``page_size`` over the paged
+    pool (see ``_paged_attn_ctx``), as every engine calls it; without
+    them over the model drafter's contiguous cache.
     """
     if cache is not None:
         if positions is None:

@@ -385,7 +385,7 @@ def forward_hidden(params, input_ids, config, cache=None, positions=None,
     eps = config.rms_norm_eps
     if cache is not None:
         assert page_tables is not None, \
-            "Jamba serves from the paged layout only"
+            "Jamba serves from pages only (page_tables=)"
         k_cache, v_cache, *state = cache
         state = tuple(state)
         if state_slot is None:

@@ -359,7 +359,7 @@ def forward_hidden(params, input_ids, config, cache=None, positions=None,
     eps = config.norm_eps
     if cache is not None:
         assert page_tables is not None, \
-            "LFM2 serves from the paged layout only"
+            "LFM2 serves from pages only (page_tables=)"
         k_cache, v_cache, *state = cache
         state = tuple(state)
         if state_slot is None:

@@ -44,7 +44,7 @@ second matmul in the pool's dtype, as the flash kernels' do
 (ops/transformer/flash_attention.py) and the families' XLA reads
 (models/jamba.py ``_attend``). With a float32 pool nothing is rounded.
 
-Masking contract (bit-compatible with the slot oracle,
+Masking contract (bit-compatible with the XLA read,
 ``_attend_cache_rows``):
 
 * absolute-position causality: key position ``k_pos`` contributes to

@@ -36,7 +36,7 @@ SERVING_STEP_KEYS = (
     # request-latency aggregates + the serving-memory/spec gauges
     # (null until the engine feature producing them has fired):
     # ttft/tpot {count, mean_s, p50_s, p95_s}; page_pool {num_pages,
-    # pages_in_use, occupancy} (paged layout only); prefix {lookups,
+    # pages_in_use, occupancy}; prefix {lookups,
     # hits, hit_rate, ...} (prefix_caching only); speculative
     # {proposed, accepted, acceptance_rate} (speculative only)
     "ttft", "tpot", "page_pool", "prefix", "speculative",

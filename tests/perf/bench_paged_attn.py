@@ -46,7 +46,7 @@ def _engine(kernel):
         model=gpt2.make_gpt2_model(config=cfg),
         config={"inference": {
             "max_batch_size": NUM_SLOTS, "prefill_buckets": [64],
-            "dtype": "fp32", "greedy": True, "kv_layout": "paged",
+            "dtype": "fp32", "greedy": True,
             "kv_block_size": PAGE_SIZE,
             "paged_attention_kernel": kernel}})
     assert eng.paged_attention_kernel == kernel

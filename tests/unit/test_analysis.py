@@ -165,7 +165,7 @@ def test_inference_spec_verify_program_audited():
         model=model, draft_model=model,
         config={"inference": {
             "max_batch_size": 2, "prefill_buckets": [8],
-            "dtype": "fp32", "greedy": True, "kv_layout": "paged",
+            "dtype": "fp32", "greedy": True,
             "kv_block_size": 4,
             "speculative": {"enabled": True, "method": "model",
                             "num_draft_tokens": 2}}})

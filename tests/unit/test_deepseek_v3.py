@@ -66,7 +66,7 @@ def _config(**overrides):
 
 def _inference(slots=3, buckets=(8, 16), num_pages=48, **more):
     return {"inference": dict({
-        "max_batch_size": slots, "dtype": "fp32", "kv_layout": "paged",
+        "max_batch_size": slots, "dtype": "fp32",
         "kv_block_size": 4, "num_pages": num_pages, "max_seq_len": WINDOW,
         "prefill_buckets": list(buckets), "greedy": True,
         "max_new_tokens": 8}, **more)}
@@ -469,11 +469,10 @@ def test_a_model_mesh_axis_refuses_the_family():
         deepspeed.init_inference(
             model=deepseek_v3.make_deepseek_v3_model(_config(), seed=SEED),
             mesh=mesh,
-            config={"inference": {"kv_layout": "paged", "dtype": "fp32"}})
+            config={"inference": {"dtype": "fp32"}})
 
 
 @pytest.mark.parametrize("what, more", [
-    ("the slot layout", {"kv_layout": "slot"}),
     ("speculative decoding", {"speculative": {"enabled": True,
                                               "method": "ngram",
                                               "num_draft_tokens": 2}}),

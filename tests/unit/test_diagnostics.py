@@ -111,7 +111,7 @@ def _serve_engine(tmp_path, paged=True, telemetry=None, max_new_tokens=3):
     inf = {"max_batch_size": 2, "prefill_buckets": [8, 16], "dtype": "fp32",
            "greedy": True, "max_new_tokens": max_new_tokens}
     if paged:
-        inf.update(kv_layout="paged", kv_block_size=4, prefix_caching=True)
+        inf.update(kv_block_size=4, prefix_caching=True)
     config = {"inference": inf}
     if telemetry is not None:
         config["telemetry"] = telemetry
@@ -295,7 +295,7 @@ def test_preemption_event_rides_request_span(tmp_path):
         model=gpt2.make_gpt2_model(config=cfg),
         config={"inference": {
             "max_batch_size": 3, "prefill_buckets": [8, 16, 32],
-            "dtype": "fp32", "greedy": True, "kv_layout": "paged",
+            "dtype": "fp32", "greedy": True,
             "kv_block_size": 8, "num_pages": 9},
             "telemetry": _diag_telemetry(tmp_path, watchdog={
                 "pool_exhaustion": {"every": 1, "action": "warn"}})})

@@ -66,45 +66,46 @@ def greedy_chain(model, prompt, n):
 # --------------------------------------------------------------- parity
 
 
-def test_decode_logits_match_full_forward(shared):
-    """Prefill + 6 greedy decode steps produce, at every step, the same
-    next-token logits as the full forward over the final sequence
-    (fp32, atol 1e-5)."""
+def tap_logits(monkeypatch, eng):
+    """Every launch's last output, its logits, in launch order: the
+    program makers wrapped by name, as the benchmark's check wraps
+    them."""
+    seen = []
+    for name in ("_get_prefill_fn", "_get_decode_fn"):
+        def tapped(*args, _make=getattr(eng, name), **kwargs):
+            fn = _make(*args, **kwargs)
+
+            def run(*operands):
+                out = fn(*operands)
+                seen.append(out[-1])
+                return out
+            return run
+        monkeypatch.setattr(eng, name, tapped)
+    return seen
+
+
+def test_decode_logits_match_full_forward(shared, monkeypatch):
+    """Prefill + 6 greedy decode steps through the public ``prefill`` /
+    ``decode_step`` produce, at every step, the same next-token logits
+    as the full forward over the final sequence (fp32, atol 1e-5)."""
     model, eng = shared
     rs = np.random.RandomState(0)
     prompt = rs.randint(0, 128, size=11).tolist()
     n = len(prompt)
+    seen = tap_logits(monkeypatch, eng)
 
-    greedy, top_k, _, _ = eng._sampling_key(None)
-    fn = eng._get_prefill_fn(eng.bucket_for(n), greedy, top_k)
-    ids = np.zeros((1, eng.bucket_for(n)), np.int32)
-    ids[0, :n] = prompt
-    k, v, token, p_logits = fn(
-        eng.params, eng.kv.k, eng.kv.v, jnp.asarray(ids), jnp.int32(0),
-        jnp.int32(0), jnp.int32(n), jax.random.PRNGKey(0),
-        jnp.float32(1.0), jnp.float32(1.0))
-    eng.kv.update((k, v))
-    eng.lengths[0] = n
-
-    seq = prompt + [int(token)]
-    step_logits = [np.asarray(p_logits)]
-    dfn = eng._get_decode_fn(greedy, top_k)
+    seq = prompt + [eng.prefill(0, prompt)]
+    step_logits = [np.asarray(seen[-1])]
     for _ in range(6):
-        tokens = np.zeros((eng.num_slots, 1), np.int32)
-        tokens[0, 0] = seq[-1]
-        k, v, nxt, d_logits = dfn(
-            eng.params, eng.kv.k, eng.kv.v, jnp.asarray(tokens),
-            jnp.asarray(eng.lengths), jax.random.PRNGKey(0),
-            jnp.float32(1.0), jnp.float32(1.0))
-        eng.kv.update((k, v))
-        # the program reads eng.lengths (on the CPU jnp.asarray may
-        # alias the host buffer): it must have run before advance()
-        # writes into it
-        jax.block_until_ready(d_logits)
+        assert eng.ensure_pages(0, int(eng.lengths[0]) + 1)
+        tokens = np.zeros((eng.num_slots,), np.int32)
+        tokens[0] = seq[-1]
+        nxt = eng.decode_step(tokens)
         eng.advance(0)
-        step_logits.append(np.asarray(d_logits[0, 0]))
-        seq.append(int(nxt[0, 0]))
+        step_logits.append(np.asarray(seen[-1][0, 0]))
+        seq.append(int(nxt[0]))
     eng.free_slot(0)
+    assert len(seen) == 7 and eng.allocator.pages_in_use == 0
 
     ref = full_forward_logits(model, seq)      # one dense pass at the end
     for t, got in enumerate(step_logits):
@@ -356,16 +357,20 @@ def test_unknown_inference_key_strict_raises():
 
 
 def test_kv_cache_sharded_over_heads_and_decode_parity():
-    """TP mesh: params placed with Megatron specs, KV cache heads-sharded,
-    and decode still matches the unsharded full forward."""
+    """TP mesh: params placed with Megatron specs; an engine built with
+    NO ``inference.kv_*`` key shards its pages' packed heads, and decode
+    still matches the unsharded full forward."""
     from deepspeed_tpu.parallel.topology import build_mesh
-    from deepspeed_tpu.inference.kv_cache import KV_CACHE_SPEC
+    from deepspeed_tpu.inference.kv_cache import PAGED_KV_CACHE_SPEC
     mesh = build_mesh(data=4, model=2)
     model = tiny_model()
     eng = deepspeed.init_inference(model=model, mesh=mesh, config={
         "inference": {"max_batch_size": 2, "prefill_buckets": [16],
                       "dtype": "fp32", "greedy": True}})
-    assert eng.kv.k.sharding.spec == KV_CACHE_SPEC
+    assert eng.kv.k.sharding.spec == eng.kv.v.sharding.spec == \
+        PAGED_KV_CACHE_SPEC
+    assert eng.page_pool_stats()["num_pages"] * eng.page_size == \
+        2 * TINY["max_seq_len"]
     assert "model" in str(
         eng.params["blocks"][0]["attn"]["qkv_kernel"].sharding.spec)
     prompt = [11, 3, 9, 60, 2]

@@ -44,7 +44,7 @@ def _engine(slots=3, buckets=(8, 16), num_pages=40,
     return deepspeed.init_inference(
         model=jamba.make_jamba_model(_config(**overrides), seed=SEED),
         config={"inference": {
-            "max_batch_size": slots, "dtype": "fp32", "kv_layout": "paged",
+            "max_batch_size": slots, "dtype": "fp32",
             "kv_block_size": 4, "num_pages": num_pages, "max_seq_len": 64,
             "paged_attention_kernel": paged_attention_kernel,
             "prefill_buckets": list(buckets), "greedy": True,
@@ -327,7 +327,7 @@ def test_the_engine_with_the_kernels_interpreted_matches_the_oracles():
 
 # --------------------------------------------------------------- refusals
 def _refused(match, **inference):
-    config = {"max_batch_size": 2, "dtype": "fp32", "kv_layout": "paged",
+    config = {"max_batch_size": 2, "dtype": "fp32",
               "kv_block_size": 4, "num_pages": 16, "max_seq_len": 64,
               "prefill_buckets": [8]}
     config.update(inference)
@@ -345,10 +345,6 @@ def test_prefix_cache_refuses_recurrent_layers():
 def test_drafter_refuses_recurrent_layers():
     _refused("speculative decoding .* recurrent layers",
              speculative={"enabled": True, "method": "ngram"})
-
-
-def test_slot_layout_refuses_recurrent_layers():
-    _refused("paged layout only", kv_layout="slot")
 
 
 def test_a_recurrent_draft_model_is_refused():
@@ -371,7 +367,7 @@ def test_a_model_mesh_axis_refuses_the_family():
     with pytest.raises(ValueError, match="no tensor-parallel layout"):
         deepspeed.init_inference(
             model=jamba.make_jamba_model(_config(), seed=SEED), mesh=mesh,
-            config={"inference": {"kv_layout": "paged", "dtype": "fp32"}})
+            config={"inference": {"dtype": "fp32"}})
 
 
 def test_the_engine_imports_no_model_module():
@@ -396,9 +392,10 @@ def test_gpt2_goes_through_the_same_protocol():
     assert (spec.kv_layers, spec.kv_heads, spec.d_head, spec.state) == \
         (2, 2, 8, ())
     engine = deepspeed.init_inference(model=model, config={"inference": {
-        "max_batch_size": 2, "dtype": "fp32", "kv_layout": "paged",
+        "max_batch_size": 2, "dtype": "fp32",
         "kv_block_size": 4, "prefill_buckets": [8]}})
-    assert engine.state is None and not engine.recurrent
+    assert engine.state is None and not getattr(
+        engine.decoder, "recurrent", False)
     assert "state_pool" not in engine.serving_metrics.snapshot()
 
 

@@ -50,7 +50,7 @@ def _engine(slots=3, buckets=(8, 16), num_pages=40,
     return deepspeed.init_inference(
         model=lfm2.make_lfm2_model(_config(**overrides), seed=SEED),
         config={"inference": {
-            "max_batch_size": slots, "dtype": "fp32", "kv_layout": "paged",
+            "max_batch_size": slots, "dtype": "fp32",
             "kv_block_size": 4, "num_pages": num_pages, "max_seq_len": 64,
             "paged_attention_kernel": paged_attention_kernel,
             "prefill_buckets": list(buckets), "greedy": True,
@@ -446,7 +446,7 @@ def test_a_model_mesh_axis_refuses_the_family():
     with pytest.raises(ValueError, match="no tensor-parallel layout"):
         deepspeed.init_inference(
             model=lfm2.make_lfm2_model(_config(), seed=SEED), mesh=mesh,
-            config={"inference": {"kv_layout": "paged", "dtype": "fp32"}})
+            config={"inference": {"dtype": "fp32"}})
 
 
 def test_prefix_cache_refuses_the_family():
@@ -454,6 +454,6 @@ def test_prefix_cache_refuses_the_family():
         deepspeed.init_inference(
             model=lfm2.make_lfm2_model(_config(), seed=SEED),
             config={"inference": {
-                "max_batch_size": 2, "dtype": "fp32", "kv_layout": "paged",
+                "max_batch_size": 2, "dtype": "fp32",
                 "kv_block_size": 4, "num_pages": 16, "max_seq_len": 64,
                 "prefill_buckets": [8], "prefix_caching": True}})

@@ -82,7 +82,7 @@ def _engine(slots=3, buckets=(8, 16), num_pages=(72, 30), inference=None,
     return deepspeed.init_inference(
         model=mellum.make_mellum_model(_config(**overrides), seed=SEED),
         config={"inference": dict({
-            "max_batch_size": slots, "dtype": "fp32", "kv_layout": "paged",
+            "max_batch_size": slots, "dtype": "fp32",
             "kv_block_size": PAGE, "num_pages": list(num_pages),
             "max_seq_len": SEQ, "prefill_buckets": list(buckets),
             "greedy": True, "max_new_tokens": 8}, **(inference or {}))})
@@ -602,11 +602,10 @@ def test_a_model_mesh_axis_refuses_the_family():
     with pytest.raises(ValueError, match="no tensor-parallel layout"):
         deepspeed.init_inference(
             model=mellum.make_mellum_model(_config(), seed=SEED), mesh=mesh,
-            config={"inference": {"kv_layout": "paged", "dtype": "fp32"}})
+            config={"inference": {"dtype": "fp32"}})
 
 
 @pytest.mark.parametrize("what, more", [
-    ("the slot layout", {"kv_layout": "slot", "num_pages": None}),
     ("prefix caching", {"prefix_caching": True}),
     ("speculative decoding", {"speculative": {"enabled": True,
                                               "method": "ngram",
@@ -622,24 +621,26 @@ def test_what_takes_a_page_for_a_positions_whole_state_refuses_a_window(
 
 
 def test_the_refusals_say_one_sentence():
+    import re
     from deepspeed_tpu.inference import decoder
-    spec = CacheSpec(kv_layers=2, kv_heads=1, d_head=576, page_lanes=640)
-    for refuse, subject, model in (
-            (decoder.refuse_latent, spec, "latent pages"),
-            (decoder.refuse_recurrent, type("D", (), {"recurrent": True}),
-             "recurrent layers"),
-            (decoder.refuse_windowed, mellum.MellumDecoder(
-                _config()).cache_spec(), "sliding-window layers")):
-        with pytest.raises(ValueError, match="^X cannot serve a model with "
-                           + model):
-            refuse(subject, "X")
-    decoder.refuse_windowed(CacheSpec(kv_layers=2, kv_heads=1, d_head=64),
-                            "X")
+    plain = CacheSpec(kv_layers=2, kv_heads=1, d_head=64)
+    keeps_all = type("D", (), {})
+    what = decoder.FEATURES["speculative"][0]
+    for subject, spec, model in (
+            (keeps_all, CacheSpec(kv_layers=2, kv_heads=1, d_head=576,
+                                  page_lanes=640), "latent pages"),
+            (type("D", (), {"recurrent": True}), plain, "recurrent layers"),
+            (keeps_all, mellum.MellumDecoder(_config()).cache_spec(),
+             "sliding-window layers")):
+        with pytest.raises(ValueError, match="^" + re.escape(what) +
+                           " cannot serve a model with " + model):
+            decoder.refuse(subject, spec, "speculative")
+    decoder.refuse(keeps_all, plain, "speculative")
     # two groups without a window have a table each all the same
     with pytest.raises(ValueError, match="several page groups"):
-        decoder.refuse_windowed(CacheSpec(
+        decoder.refuse(keeps_all, CacheSpec(
             kv_layers=2, kv_heads=1, d_head=64,
-            groups=(PageGroup(1), PageGroup(1))), "X")
+            groups=(PageGroup(1), PageGroup(1))), "speculative")
     with pytest.raises(AssertionError, match="make_mellum_model.*groups"):
         decoder.decoder_of(object())
 
@@ -665,8 +666,6 @@ def test_a_one_group_family_uploads_and_walks_one_table(tiny, pools, state):
         os.path.abspath(__file__))), "unit_benchmark", tiny)
     with open(path) as f:
         config = json.load(f)
-    if config["inference"].get("kv_layout") != "paged":
-        config["inference"].update(kv_layout="paged", kv_block_size=16)
     eng = manifest.plugin("models", config["family"]).build_serve_engine(
         config, 1)
     assert not eng._grouped and len(eng.page_groups) == 1

@@ -281,27 +281,37 @@ def _tiny_model():
 
 _PAGED_BASE = {"max_batch_size": 2, "prefill_buckets": [8, 16],
                "dtype": "fp32", "greedy": True, "max_new_tokens": 4,
-               "kv_layout": "paged", "kv_block_size": 4}
+               "kv_block_size": 4}
 
 
 def test_engine_greedy_streams_byte_identical():
     # the acceptance bit: greedy serving streams equal with the kernel
-    # on vs off (and both equal the slot-cache oracle)
+    # on vs off (and both equal the dense chain: the uncached forward
+    # over the whole sequence, argmax, no serving path at all)
     model = _tiny_model()
     rng = np.random.RandomState(2)
     prompts = [rng.randint(0, 128, size=n).tolist() for n in (5, 9, 3)]
     streams = {}
-    for name, inf in (
-            ("slot", {k: v for k, v in _PAGED_BASE.items()
-                      if k not in ("kv_layout", "kv_block_size")}),
-            ("paged_xla", dict(_PAGED_BASE, paged_attention_kernel="xla")),
-            ("paged_pallas", dict(_PAGED_BASE,
-                                  paged_attention_kernel="pallas"))):
-        eng = deepspeed.init_inference(model=model,
-                                       config={"inference": inf})
+    for name, kernel in (("paged_xla", "xla"), ("paged_pallas", "pallas")):
+        eng = deepspeed.init_inference(model=model, config={
+            "inference": dict(_PAGED_BASE, paged_attention_kernel=kernel)})
         streams[name] = eng.generate(prompts)
+
+    @jax.jit
+    def dense(ids):        # one program: the sequence rides zero-padded
+        return gpt2.forward_hidden(model.params, ids, model.config,
+                                   train=False)[0] @ model.params["wte"].T
+
+    def dense_chain(prompt):
+        seq = list(prompt)
+        for _ in range(_PAGED_BASE["max_new_tokens"]):
+            ids = np.zeros((1, model.config.max_seq_len), np.int32)
+            ids[0, :len(seq)] = seq
+            seq.append(int(np.asarray(dense(ids))[len(seq) - 1].argmax()))
+        return seq[len(prompt):]
+
     assert streams["paged_pallas"] == streams["paged_xla"]
-    assert streams["paged_pallas"] == streams["slot"]
+    assert streams["paged_pallas"] == [dense_chain(p) for p in prompts]
 
 
 def test_paged_attention_kernel_config_gate():
@@ -316,8 +326,7 @@ def test_paged_attention_kernel_config_gate():
     eng = deepspeed.init_inference(
         model=model, config={"inference": dict(_PAGED_BASE)})
     assert eng.paged_attention_kernel == "xla"
-    # explicit pallas resolves pallas (interpreter mode) on the paged
-    # layout...
+    # explicit pallas resolves pallas (interpreter mode)...
     eng = deepspeed.init_inference(
         model=model,
         config={"inference": dict(_PAGED_BASE,
@@ -325,14 +334,12 @@ def test_paged_attention_kernel_config_gate():
     assert eng.paged_attention_kernel == "pallas"
     # prefill stays on the oracle path even then
     assert eng.model_config.paged_attention_kernel == "xla"
-    # ...and falls back LOUDLY on the slot layout
-    with _capture_warnings() as messages:
-        eng = deepspeed.init_inference(
-            model=model,
-            config={"inference": {"max_batch_size": 2, "dtype": "fp32",
-                                  "paged_attention_kernel": "pallas"}})
-    assert eng.paged_attention_kernel == "xla"
-    assert any("has NO effect" in m for m in messages)
+    # ...with no kv_* key set too: every engine has page tables to walk
+    eng = deepspeed.init_inference(
+        model=model,
+        config={"inference": {"max_batch_size": 2, "dtype": "fp32",
+                              "paged_attention_kernel": "pallas"}})
+    assert eng.paged_attention_kernel == "pallas"
 
 
 def test_decode_program_carries_pallas_and_audits_clean():

@@ -65,7 +65,7 @@ def traced(tmp_path_factory):
         model=gpt2.make_gpt2_model(config=cfg, seed=0),
         config={"inference": {
             "max_batch_size": 2, "prefill_buckets": [8, 16, 32],
-            "dtype": "fp32", "greedy": True, "kv_layout": "paged",
+            "dtype": "fp32", "greedy": True,
             "kv_block_size": 8, "prefill_chunk_tokens": 16}})
     cwd = tmp_path_factory.mktemp("untraced_cwd")
     here = os.getcwd()

@@ -2,14 +2,14 @@
 
 The acceptance spec for ISSUE 7:
 
-  * paged decode is bit-compatible with the slot-cache oracle (logits
-    atol 1e-5 across mixed lengths and page boundaries);
+  * paged decode matches the uncached dense forward (logits atol 1e-5
+    across mixed lengths and page boundaries; token streams equal);
   * the page allocator's refcount/free-on-retire invariants hold,
     including copy-on-write forks of shared prefix pages;
   * greedy speculative decode emits the byte-identical token stream of
     the greedy autoregressive baseline (ngram AND model drafters);
   * stale K/V beyond a sequence's live length can never leak into
-    attention in either layout (NaN-poison tests);
+    attention (NaN-poison tests);
   * chunked prefill interleaves with the decode batch instead of
     stalling it; pool exhaustion preempts-and-recomputes correctly.
 """
@@ -47,7 +47,6 @@ def make_engine(model, **inference):
 
 
 def paged_engine(model, **inference):
-    inference.setdefault("kv_layout", "paged")
     inference.setdefault("kv_block_size", PS)
     return make_engine(model, **inference)
 
@@ -57,71 +56,111 @@ def model():
     return tiny_model()
 
 
-@pytest.fixture(scope="module")
-def oracle(model):
-    """The slot-layout engine: every paged/spec result is judged
-    against its streams."""
-    return make_engine(model)
+_DENSE = {}          # id(model) -> (model, its jitted dense forward)
+
+
+def dense_logits(model, seq):
+    """Every position's logits from the uncached forward over the
+    whole of ``seq``: the reference, which shares no cache, page table,
+    bucket or scheduler with what it judges. One program a model: the
+    sequence rides zero-padded to ``max_seq_len`` (causal attention:
+    no position sees the pad behind it)."""
+    if id(model) not in _DENSE:
+        config = model.config
+        _DENSE[id(model)] = model, jax.jit(
+            lambda params, ids: gpt2.forward_hidden(
+                params, ids, config, train=False)[0] @ params["wte"].T)
+    ids = np.zeros((1, model.config.max_seq_len), np.int32)
+    ids[0, :len(seq)] = seq
+    return np.asarray(_DENSE[id(model)][1](model.params,
+                                           jnp.asarray(ids)))[:len(seq)]
 
 
 def greedy_chain(model, prompt, n):
     seq = list(prompt)
     for _ in range(n):
-        ids = jnp.asarray(np.asarray(seq, np.int32)[None])
-        hidden = gpt2.forward_hidden(model.params, ids, model.config,
-                                     train=False)
-        seq.append(int(np.asarray(hidden[0, -1] @ model.params["wte"].T)
-                       .argmax()))
+        seq.append(int(dense_logits(model, seq)[-1].argmax()))
     return seq[len(prompt):]
 
 
-# ------------------------------------------------------- paged == slot
+class DenseReference:
+    """What the serving tests hold an engine's greedy streams to: the
+    dense chain of each prompt. No serving path at all."""
+
+    def __init__(self, model):
+        self.model = model
+
+    def generate(self, prompts, max_new_tokens):
+        return [greedy_chain(self.model, p, max_new_tokens)
+                for p in prompts]
+
+    def prefill(self, prompt):
+        """The first token after ``prompt``."""
+        return greedy_chain(self.model, prompt, 1)[0]
 
 
-def test_paged_decode_logits_match_slot_across_page_boundaries(model,
-                                                               oracle):
+@pytest.fixture(scope="module")
+def oracle(model):
+    return DenseReference(model)
+
+
+# ------------------------------------------------------ paged == dense
+
+
+def test_paged_decode_logits_match_dense_forward_across_page_boundaries(
+        model):
     """Mixed prompt lengths straddling page boundaries (PS-1, PS, PS+5):
-    per-step decode LOGITS from the paged engine match the slot oracle
-    within 1e-5 while sequences cross page boundaries as they grow."""
+    per-step decode LOGITS from the paged engine match the dense
+    forward's at the same positions within 1e-5 while sequences cross
+    page boundaries as they grow."""
     eng = paged_engine(model)
     rs = np.random.RandomState(3)
     prompts = [rs.randint(0, 128, size=n).tolist()
                for n in (PS - 1, PS, PS + 5)]
-
-    def run(engine):
-        logits = []
-        for slot, p in enumerate(prompts):
-            engine.prefill(slot, p)
-        for _ in range(2 * PS + 3):      # decode across >= 2 boundaries
-            if engine.kv_layout == "paged":
-                for slot in range(len(prompts)):
-                    assert engine.ensure_pages(
-                        slot, int(engine.lengths[slot]) + 1)
-            greedy, top_k, t, tp = engine._sampling_key(None)
-            fn = engine._get_decode_fn(greedy, top_k)
-            tokens = jnp.asarray(
-                np.full((engine.num_slots, 1), 5, np.int32))
-            args = [engine.params, engine.kv.k, engine.kv.v, tokens,
-                    jnp.asarray(engine.lengths)]
-            if engine.kv_layout == "paged":
-                args.append(jnp.asarray(engine.page_tables))
-            k, v, _, step_logits = fn(*args, jax.random.PRNGKey(0),
-                                      jnp.float32(t), jnp.float32(tp))
-            engine.kv.update((k, v))
-            logits.append(np.asarray(step_logits)[:, 0])
-            for slot in range(len(prompts)):
-                engine.advance(slot)
+    steps = 2 * PS + 3                   # decode across >= 2 boundaries
+    fed = rs.randint(0, 128, size=(steps, len(prompts)))
+    got = []
+    for slot, p in enumerate(prompts):
+        eng.prefill(slot, p)
+    for step in range(steps):
         for slot in range(len(prompts)):
-            engine.free_slot(slot)
-        return logits
+            assert eng.ensure_pages(slot, int(eng.lengths[slot]) + 1)
+        greedy, top_k, t, tp = eng._sampling_key(None)
+        fn = eng._get_decode_fn(greedy, top_k)
+        k, v, _, step_logits = fn(
+            eng.params, eng.kv.k, eng.kv.v,
+            jnp.asarray(fed[step, :, None], jnp.int32),
+            jnp.asarray(eng.lengths), jnp.asarray(eng.page_tables),
+            jax.random.PRNGKey(0), jnp.float32(t), jnp.float32(tp))
+        eng.kv.update((k, v))
+        got.append(np.asarray(step_logits)[:, 0])
+        for slot in range(len(prompts)):
+            eng.advance(slot)
+    for slot, p in enumerate(prompts):
+        eng.free_slot(slot)
+        # the token fed at step i sits at position len(p) + i
+        want = dense_logits(model, p + fed[:, slot].tolist())[len(p):]
+        for step in range(steps):
+            np.testing.assert_allclose(
+                got[step][slot], want[step], atol=1e-5,
+                err_msg="slot {} step {}".format(slot, step))
 
-    got, want = run(eng), run(oracle)
-    for step, (g, w) in enumerate(zip(got, want)):
-        np.testing.assert_allclose(g, w, atol=1e-5,
-                                   err_msg="step {}".format(step))
+
+def test_one_page_a_slot_is_the_contiguous_cache_as_a_case(model, oracle):
+    """A second ENGINE's opinion, where one is wanted: ``kv_block_size
+    == max_seq_len`` is one page a slot, the contiguous cache's
+    numerics as a case of the one path. Same streams as small pages and
+    as the reference."""
+    rs = np.random.RandomState(0)
+    prompts = [rs.randint(0, 128, size=n).tolist() for n in (5, 11, 14, 26)]
+    one_page = paged_engine(model, kv_block_size=TINY["max_seq_len"])
+    assert one_page.max_pages == 1
+    out = one_page.generate(prompts, max_new_tokens=12)
+    assert out == paged_engine(model).generate(prompts, max_new_tokens=12)
+    assert out == oracle.generate(prompts, max_new_tokens=12)
 
 
-def test_paged_generate_matches_slot_streams(model, oracle):
+def test_paged_generate_matches_dense_streams(model, oracle):
     rs = np.random.RandomState(0)
     prompts = [rs.randint(0, 128, size=n).tolist() for n in (5, 11, 14, 26)]
     eng = paged_engine(model)
@@ -225,8 +264,7 @@ def test_engine_cow_forks_shared_partial_page(model, oracle):
     assert eng.allocator.refcount(shared_partial) == 2
 
     # both slots decode at position 12 — INSIDE the shared partial page
-    first = int(oracle.prefill(0, prompt))
-    oracle.free_slot(0)
+    first = oracle.prefill(prompt)
     tokens = np.zeros(eng.num_slots, np.int32)
     tokens[0] = tokens[1] = first
     nxt = eng.decode_step(tokens)
@@ -330,7 +368,7 @@ def test_spec_greedy_model_drafter_byte_identical(model, oracle):
         model=model, draft_model=tiny_model(),
         config={"inference": {
             "max_batch_size": 2, "prefill_buckets": [8, 16, 32],
-            "dtype": "fp32", "greedy": True, "kv_layout": "paged",
+            "dtype": "fp32", "greedy": True,
             "kv_block_size": PS,
             "speculative": {"enabled": True, "method": "model",
                             "num_draft_tokens": 3}}})
@@ -344,7 +382,7 @@ def test_spec_greedy_model_drafter_byte_identical(model, oracle):
         model=model, draft_model=tiny_model(seed=123, n_layers=1),
         config={"inference": {
             "max_batch_size": 2, "prefill_buckets": [8, 16, 32],
-            "dtype": "fp32", "greedy": True, "kv_layout": "paged",
+            "dtype": "fp32", "greedy": True,
             "kv_block_size": PS,
             "speculative": {"enabled": True, "method": "model",
                             "num_draft_tokens": 3}}})
@@ -367,18 +405,17 @@ def test_spec_respects_eos_and_budget(model, oracle):
     assert eng.lengths.tolist() == [0] * eng.num_slots
 
 
-def test_spec_slot_layout_and_cache_end(model, oracle):
-    """Speculation composes with the SLOT layout too, and k_eff clamps
-    near the cache ceiling (no write past max_seq)."""
+def test_spec_verify_pass_at_the_cache_end(model):
+    """k_eff clamps near the cache ceiling: no verify pass writes past
+    max_seq (an engine built with no ``kv_*`` key: the default pool)."""
     eng = make_engine(model, prefill_buckets=[8, 16, 32, 64],
                       speculative={
                           "enabled": True, "method": "ngram",
                           "num_draft_tokens": 4})
     long_prompt = list(range(30)) * 2                   # 60 of 64
     out = eng.generate([long_prompt], max_new_tokens=50)[0]
-    # the oracle fixture's buckets stop at 32; judge against the dense
-    # greedy chain instead (decode stops when the cache fills: 60 -> 64
-    # leaves 4 writes + the final sampled-but-not-embedded token)
+    # decode stops when the cache fills: 60 -> 64 leaves 4 writes + the
+    # final sampled-but-not-embedded token
     n_new = TINY["max_seq_len"] - len(long_prompt) + 1
     assert out == greedy_chain(model, long_prompt, n_new)
     assert len(out) == n_new
@@ -394,7 +431,7 @@ def test_model_drafter_survives_plain_decode_interludes(model, oracle):
         model=model, draft_model=model,
         config={"inference": {
             "max_batch_size": 2, "prefill_buckets": [8, 16, 32, 64],
-            "dtype": "fp32", "greedy": True, "kv_layout": "paged",
+            "dtype": "fp32", "greedy": True,
             "kv_block_size": PS,
             "speculative": {"enabled": True, "method": "model",
                             "num_draft_tokens": 3}}})
@@ -417,7 +454,7 @@ def test_spec_sampled_acceptance_reproducible(model):
     """Non-greedy speculative decode: same seed -> same stream, right
     lengths (sequential-sampling semantics through the verify pass)."""
     kw = dict(max_batch_size=1, prefill_buckets=[8], greedy=False,
-              top_k=8, temperature=0.9, kv_layout="paged",
+              top_k=8, temperature=0.9,
               kv_block_size=PS,
               speculative={"enabled": True, "method": "ngram",
                            "num_draft_tokens": 3})
@@ -470,7 +507,8 @@ def test_plan_chunks_covers_and_respects_bounds():
     assert plan_chunks(5, 8, bucket_for, 64) == [(0, 5)]
     assert plan_chunks(20, None, bucket_for, 64) == [(0, 20)]
     # a chunk whose padded bucket would overrun max_seq merges back
-    # into one unchunked prefill (slot-layout write safety): with
+    # into one unchunked prefill (once the slot layout's write safety;
+    # it still decides the programs a prompt runs): with
     # max_seq 60, the final chunk (48, 11) pads to bucket 16 -> 64 > 60
     assert plan_chunks(59, 16, bucket_for, 60) == [(0, 59)]
     # ... while max_seq 64 fits every padded chunk and stays chunked
@@ -481,33 +519,26 @@ def test_plan_chunks_covers_and_respects_bounds():
 # ------------------------------------------------- stale-KV poisoning
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
-def test_stale_kv_beyond_length_never_leaks(model, oracle, layout):
-    """Freed slots/pages are reused WITHOUT clearing: poison everything
-    past the live lengths with NaN and decode must be unaffected — the
+@pytest.mark.parametrize("layout", ["paged"])
+def test_stale_kv_beyond_length_never_leaks(model, layout):
+    """Freed pages are reused WITHOUT clearing: poison everything past
+    the live lengths with NaN and decode must be unaffected — the
     absolute-position mask (models/gpt2.py _attend_cache_rows) is the
-    only thing standing between stale K/V and the softmax, for both
-    layouts."""
-    eng = make_engine(model) if layout == "slot" else paged_engine(model)
+    only thing standing between stale K/V and the softmax."""
+    eng = paged_engine(model)
     prompt = [9, 4, 2, 8, 1]
     first = eng.prefill(0, prompt)
-    if layout == "slot":
-        # poison every position past the live length in every slot
-        k, v = eng.kv.buffers()
-        k = k.at[:, :, :, len(prompt):, :].set(jnp.nan)
-        v = v.at[:, :, :, len(prompt):, :].set(jnp.nan)
-    else:
-        # poison every UNALLOCATED page (incl. garbage page 0) and the
-        # allocated tail beyond the live length
-        k, v = eng.kv.buffers()
-        live = [int(eng.page_tables[0, j])
-                for j in range(int(eng.page_counts[0]))]
-        dead = [p for p in range(eng.kv.k.shape[0]) if p not in live]
-        k = k.at[jnp.asarray(dead)].set(jnp.nan)
-        v = v.at[jnp.asarray(dead)].set(jnp.nan)
-        off = len(prompt) % PS
-        k = k.at[live[-1], :, off:, :].set(jnp.nan)
-        v = v.at[live[-1], :, off:, :].set(jnp.nan)
+    # poison every UNALLOCATED page (incl. garbage page 0) and the
+    # allocated tail beyond the live length
+    k, v = eng.kv.buffers()
+    live = [int(eng.page_tables[0, j])
+            for j in range(int(eng.page_counts[0]))]
+    dead = [p for p in range(eng.kv.k.shape[0]) if p not in live]
+    k = k.at[jnp.asarray(dead)].set(jnp.nan)
+    v = v.at[jnp.asarray(dead)].set(jnp.nan)
+    off = len(prompt) % PS
+    k = k.at[live[-1], :, off:, :].set(jnp.nan)
+    v = v.at[live[-1], :, off:, :].set(jnp.nan)
     eng.kv.update((k, v))
     tokens = np.zeros(eng.num_slots, np.int32)
     tokens[0] = first
@@ -576,8 +607,8 @@ def test_pool_exhaustion_preempts_and_recovers(model, oracle):
 @pytest.mark.parametrize("kernel", ["auto", "pallas"])
 def test_paged_cache_sharded_over_heads_decode_parity(model, oracle, kernel):
     """TP mesh: the paged pool shards its packed heads axis over the
-    model axis like the slot cache shards its heads axis, and paged+spec
-    decode on the mesh still matches the unsharded slot oracle — on the
+    model axis, and paged+spec decode on the mesh still matches the
+    dense reference — on the
     XLA gather path (``auto`` off a TPU) and with the Pallas kernel
     shard_mapped over the mesh, heads split over ``model``."""
     from deepspeed_tpu.parallel.topology import build_mesh
@@ -586,7 +617,7 @@ def test_paged_cache_sharded_over_heads_decode_parity(model, oracle, kernel):
     eng = deepspeed.init_inference(model=model, mesh=mesh, config={
         "inference": {"max_batch_size": 2, "prefill_buckets": [16, 32],
                       "dtype": "fp32", "greedy": True,
-                      "kv_layout": "paged", "kv_block_size": PS,
+                      "kv_block_size": PS,
                       "paged_attention_kernel": kernel,
                       "prefix_caching": True,
                       "speculative": {"enabled": True, "method": "ngram",
@@ -611,14 +642,20 @@ def test_paged_config_validation():
         "prefix_caching": True, "prefill_chunk_tokens": 64,
         "speculative": {"enabled": True, "method": "ngram",
                         "num_draft_tokens": 5}}})
-    assert ic.kv_layout == "paged" and ic.resolve_num_pages(4, 64) == 32
-    # fraction-of-slot-footprint sizing (default fraction 1.0)
+    assert not hasattr(ic, "kv_layout")     # read, and selects nothing
+    assert ic.resolve_num_pages(4, 64) == 32
+    # fraction-of-slots*max_seq sizing (default fraction 1.0)
     frac = DeepSpeedInferenceConfig({"inference": {
-        "kv_layout": "paged", "kv_block_size": 8,
+        "kv_block_size": 8,
         "kv_pool_fraction": 0.5}})
     assert frac.resolve_num_pages(4, 64) == 16      # 0.5 * 4*64 / 8
+    # prefix caching and a fleet role need nothing else said: every
+    # engine has pages
+    assert DeepSpeedInferenceConfig(
+        {"inference": {"prefix_caching": True}}).prefix_caching
+    assert DeepSpeedInferenceConfig({"inference": {
+        "fleet": {"role": "prefill"}}}).fleet_role == "prefill"
     for bad in ({"kv_layout": "blocked"},
-                {"prefix_caching": True},                    # needs paged
                 {"kv_block_size": 0},
                 {"num_pages": 4, "kv_pool_fraction": 0.5},   # pick one
                 {"prefill_chunk_tokens": 0},
@@ -629,8 +666,39 @@ def test_paged_config_validation():
             DeepSpeedInferenceConfig({"inference": bad})
     with pytest.raises(DeepSpeedInferenceConfigError, match="cannot hold"):
         DeepSpeedInferenceConfig({"inference": {
-            "kv_layout": "paged", "kv_block_size": 8,
+            "kv_block_size": 8,
             "num_pages": 2}}).resolve_num_pages(4, 64)
+
+
+@pytest.mark.parametrize("value", ["slot", "Slot", "blocked", None, 1])
+def test_kv_layout_names_the_removal(value):
+    """The key is read so that configs which say "paged" keep loading;
+    any other value is told, in one sentence, that the layout went."""
+    from deepspeed_tpu.inference.config import (DeepSpeedInferenceConfig,
+                                                DeepSpeedInferenceConfigError)
+    with pytest.raises(DeepSpeedInferenceConfigError,
+                       match="the slot layout was removed in PR 48; "
+                       "every engine serves from pages; drop the key"):
+        DeepSpeedInferenceConfig({"inference": {"kv_layout": value}})
+
+
+def test_init_inference_with_nothing_set_serves_from_pages(model, oracle):
+    """``init_inference(model)`` and no ``inference`` section: the path
+    the benchmark measures. The pool holds ``max_batch_size *
+    max_seq_len`` tokens, and the streams are the dense chain's."""
+    eng = deepspeed.init_inference(model=model)
+    ic = eng.inference_config
+    stats = eng.page_pool_stats()
+    assert stats["num_pages"] * eng.page_size == \
+        ic.max_batch_size * TINY["max_seq_len"]
+    assert stats["pages_in_use"] == 0
+    assert eng.kv.k.shape[0] == stats["num_pages"] + 1   # + garbage page
+    assert eng.page_tables.shape == (ic.max_batch_size, eng.max_pages)
+    rs = np.random.RandomState(12)
+    prompts = [rs.randint(0, 128, size=n).tolist() for n in (5, 17, 33)]
+    assert eng.generate(prompts, max_new_tokens=8) == \
+        oracle.generate(prompts, max_new_tokens=8)
+    assert eng.allocator.pages_in_use == 0
 
 
 def test_model_drafter_requires_draft_model(model):
@@ -652,7 +720,7 @@ def test_serving_records_carry_new_fields(model, tmp_path):
         model=model,
         config={"inference": {
             "max_batch_size": 2, "prefill_buckets": [8, 16, 32],
-            "dtype": "fp32", "greedy": True, "kv_layout": "paged",
+            "dtype": "fp32", "greedy": True,
             "kv_block_size": PS, "prefix_caching": True,
             "speculative": {"enabled": True, "method": "ngram",
                             "num_draft_tokens": 3}},
@@ -754,7 +822,7 @@ _FAMILY = {
             draft_model=tiny_model(seed=123, n_layers=1),
             config={"inference": {
                 "max_batch_size": 2, "prefill_buckets": [8, 16, 32],
-                "dtype": "fp32", "greedy": True, "kv_layout": "paged",
+                "dtype": "fp32", "greedy": True,
                 "kv_block_size": PS, "speculative": _MODEL_DRAFT}}),
         9, (6, 13, 29), (4, 15, 25)),
     # 58 of 64 positions: while that slot lives, every step is plain

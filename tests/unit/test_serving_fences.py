@@ -51,7 +51,7 @@ def _gpt2_engine(seed=0, draft_model=None, **inference):
         model=_tiny_gpt2(), seed=seed, draft_model=draft_model,
         config={"inference": {
             "max_batch_size": 2, "prefill_buckets": [8, 16, 32],
-            "dtype": "fp32", "greedy": True, "kv_layout": "paged",
+            "dtype": "fp32", "greedy": True,
             "kv_block_size": 8, "prefill_chunk_tokens": 16,
             **inference}})
 
@@ -63,7 +63,7 @@ def _jamba_engine():
     return deepspeed.init_inference(
         model=jamba.make_jamba_model(cfg, seed=5),
         config={"inference": {
-            "max_batch_size": 2, "dtype": "fp32", "kv_layout": "paged",
+            "max_batch_size": 2, "dtype": "fp32",
             "kv_block_size": 4, "num_pages": 40, "max_seq_len": 64,
             "paged_attention_kernel": "xla", "greedy": True,
             "prefill_buckets": [8, 16]}})

@@ -68,7 +68,6 @@ def make_engine(model, **inference):
 
 
 def paged_engine(model, **inference):
-    inference.setdefault("kv_layout", "paged")
     inference.setdefault("kv_block_size", PS)
     return make_engine(model, **inference)
 

@@ -41,7 +41,7 @@ def _build(kind):
         return deepspeed_tpu.init_inference(
             model=model, config={"inference": {
                 "max_batch_size": 2, "prefill_buckets": [8, 32],
-                "dtype": "fp32", "greedy": True, "kv_layout": "paged",
+                "dtype": "fp32", "greedy": True,
                 "kv_block_size": 8}})
     engine, _, _, _ = deepspeed_tpu.initialize(
         model=model, config_params={

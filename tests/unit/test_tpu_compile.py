@@ -440,7 +440,7 @@ def serving_engine():
     return deepspeed.init_inference(
         model=gpt2.make_gpt2_model(config=cfg, seed=0),
         config={"inference": {
-            "max_batch_size": 2, "dtype": "bf16", "kv_layout": "paged",
+            "max_batch_size": 2, "dtype": "bf16",
             "kv_block_size": 16, "num_pages": 64, "greedy": True,
             "paged_attention_kernel": "pallas",
             "prefill_buckets": [128, 1024]}})

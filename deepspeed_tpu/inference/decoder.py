@@ -2,8 +2,8 @@
 
 The serving engine imports no model module. The model handed to it
 carries a ``decoder`` (``make_gpt2_model``, ``make_jamba_model``,
-``make_lfm2_model``, ``make_deepseek_v3_model`` and ``make_mellum_model``
-attach one) with:
+``make_lfm2_model``, ``make_deepseek_v3_model``, ``make_mellum_model`` and
+``make_cohere2_moe_model`` attach one) with:
 
 * ``config``: ``vocab_size``, ``max_seq_len``, ``d_model``;
 * ``cache_spec()`` -> :class:`CacheSpec`: what it keeps. One of three
@@ -139,8 +139,9 @@ def decoder_of(model, module=None):
         "(inference/decoder.py; e.g. models.gpt2.make_gpt2_model, "
         "models.jamba.make_jamba_model, models.lfm2.make_lfm2_model, "
         "models.deepseek_v3.make_deepseek_v3_model, "
-        "models.mellum.make_mellum_model; its cache_spec() may put the "
-        "paged layers in groups)")
+        "models.mellum.make_mellum_model, "
+        "models.cohere2_moe.make_cohere2_moe_model; its cache_spec() may "
+        "put the paged layers in groups)")
 
 
 # What a serving feature needs of a cache, each need as (what the model

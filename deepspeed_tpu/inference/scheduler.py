@@ -346,7 +346,7 @@ class ContinuousBatchingScheduler:
             first = start == 0
             with annotate("sched.prefill.chunk", uid=req.uid, tokens=ln,
                           padded=self.engine.bucket_for(ln),
-                          first=first) as span:
+                          first=first, start=start) as span:
                 if first and self.engine.state is not None:
                     self._account("record_state_reset")
                 chunk = req.context[start:start + ln]

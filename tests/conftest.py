@@ -86,6 +86,19 @@ _EXACT_METRIC_SETS = ("test_lfm2_reference.py", "test_moonlight_reference.py")
 # cell of the manifest.
 _SIX_CELLS = "test_manifest_entry_lists_the_six_cells"
 
+# And tests/unit_benchmark/test_mellum2_reference.py, the benchmark's
+# too, pins the manifest to the seven cells of ITS PR: `len(MANIFEST[
+# "workloads"]) == 7` (one test) and `cells[-1] == CELL` (the six cases
+# of test_the_start_up_metrics_list_every_cell). ISSUE 50 adds the
+# eighth. tests/unit_benchmark/test_command_a_plus_reference.py holds
+# what they mean to hold for ANY number of cells, so that the next cell
+# does not pay again: every `setup_*` entry as PR 40 left it, its list
+# naming every cell in the manifest's order; every cell on one chip with
+# its files found by name; the ide cell's metrics as ISSUE 42 named them.
+_SEVEN_CELLS = {"test_mellum2_reference.py": (
+    "test_the_new_cell_reports_every_metric_the_issue_names",
+    "test_the_start_up_metrics_list_every_cell")}
+
 
 def pytest_collection_modifyitems(config, items):
     for item in items:
@@ -100,6 +113,12 @@ def pytest_collection_modifyitems(config, items):
                 strict=True,
                 reason="pins the start-up metrics' lists to the six cells "
                        "of its own PR; the file is the benchmark's"))
+        if getattr(item, "originalname", item.name) in _SEVEN_CELLS.get(
+                item.path.name, ()):
+            item.add_marker(pytest.mark.xfail(
+                strict=True,
+                reason="pins the manifest to the seven cells of its own "
+                       "PR; the file is the benchmark's"))
         named = _GPT2_ONLY.get(getattr(item, "originalname", None))
         if named and not named(item.callspec.params).startswith("gpt2-"):
             item.add_marker(pytest.mark.xfail(

@@ -1,11 +1,11 @@
 """Device time of the grouped page walk (``paged_attention_grouped``,
 ops/pallas/paged_attention.py) and of the latent one (``mla_decode``)
-for one layer's decode step at the shapes of the four cells that run
+for one layer's decode step at the shapes of the five cells that run
 them, ONE chip, bfloat16 pools, read from a profiler trace (not a host
 clock). Needs a TPU.
 
     chiprun -- python tests/perf/paged_walk_microbench.py \
-        [--shapes ide_full,ide_window,extract,rollouts,reasoning] \
+        [--shapes ide_full,ide_window,extract,rollouts,rag_full,rag_window,reasoning] \
         [--blocks 0,8,16,32,64] \
         [--module label=path/to/paged_attention.py[@chunk=16]]
 
@@ -54,6 +54,10 @@ SHAPES = {
     # lfm2-8b-a1b-serve.extract, jamba2-3b-serve.rollouts
     "extract": (384, 32, 8, 64, 192, None, (300, 1300)),
     "rollouts": (384, 20, 1, 128, 192, None, (300, 1500)),
+    # command-a-plus-serve.rag: 1 full layer, 3 sliding; 16 query heads
+    # a key-value head, a decode query 128 rows over 1,024 packed lanes
+    "rag_full": (40, 128, 8, 128, 2048, None, (2300, 25000)),
+    "rag_window": (40, 128, 8, 128, 385, 4096, (4096, 4112)),
 }
 # moonlight-16b-a3b-serve.reasoning: slots, heads, a row's lanes, the
 # lanes that are its value, its useful lanes, table columns, layers in

@@ -156,7 +156,11 @@ def test_step_and_request_attributes(traced):
     # and nothing is written that nothing reads
     assert {k for ev in lines[0] for k in ev[3]} == {
         "step", "uid", "queue_wait_us", "resumed", "tokens", "padded",
-        "first", "kv_write"}
+        "first", "start", "kv_write"}
+    # where each chunk begins (`chunk_attention_roofline.*` prices a
+    # chunk's attention by it)
+    assert sorted((c["uid"], c["start"], c["tokens"]) for c in chunks) == \
+        [(0, 0, 7), (1, 0, 16), (1, 16, 4), (2, 0, 3)]
     # the bucket each chunk was padded to, and whose first chunk it was
     # (the one whose program starts a recurrent state from zeros)
     buckets = traced[5].engine.prefill_buckets

@@ -1053,6 +1053,114 @@ def test_mellum_programs_run_the_kernels_and_alias_both_groups(
         14.5 * 2 ** 30
 
 
+@pytest.fixture(scope="module")
+def command_a_plus_engine():
+    """A tiny Cohere2-MoE engine on the CPU whose programs are lowered
+    at the published widths and the cell's share (``jamba_engine`` says
+    how)."""
+    import json
+    import os
+    import deepspeed_tpu as deepspeed
+    from deepspeed_tpu.models import cohere2_moe
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "command-a-plus-serve.json")) as f:
+        cell = json.load(f)
+    tiny = dict(cell["model"], hidden_size=64, intermediate_size=32,
+                num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+                padded_vocab_size=128, num_experts=2, router_num_experts=8,
+                experts_held=[0, 2], num_experts_per_tok=2,
+                num_shared_experts=2)
+    eng = deepspeed.init_inference(
+        model=cohere2_moe.make_cohere2_moe_model(
+            cohere2_moe.config_from_hf(tiny), seed=0),
+        config={"inference": dict(cell["inference"], max_batch_size=2,
+                                  num_pages=[4096, 512],
+                                  paged_attention_kernel="pallas")})
+    eng.model_config = cohere2_moe.config_from_hf(cell["model"],
+                                                  moe_kernel="pallas")
+    return eng, cell
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_command_a_plus_programs_run_the_kernels_at_the_cells_share(
+        one_chip, no_persistent_cache, command_a_plus_engine, monkeypatch,
+        program):
+    """``jit_prefill`` (the largest bucket) and ``jit_decode`` (every
+    slot) of command-a-plus-05-2026's first four layers at the cell's
+    share (16 of 128 experts, 32,768 rows of the tied embedding) and pool
+    shapes: shapes no other cell has. 16 query heads a key-value head:
+    a decode query is 128 rows over 1,024 packed lanes in the grouped
+    walk, the sliding layers over their table of 385 columns with
+    ``window=4096``; a chunk's attention in ``chunk_attention`` in all 4
+    layers; two grouped matmuls a layer whose groups are the 16 experts
+    held, an eighth of the rows real; both groups' pool pairs come back
+    in place; and it fits the chip."""
+    from deepspeed_tpu.models import cohere2_moe
+    eng, cell = command_a_plus_engine
+    cfg = eng.model_config
+    inference = cell["inference"]
+    slots, pages = inference["max_batch_size"], inference["num_pages"]
+    bucket, ps = inference["prefill_buckets"][-1], eng.page_size
+    rows = (inference["max_seq_len"] // ps, eng.page_groups[1].max_pages)
+    assert rows[1] == (cfg.window + bucket) // ps + 1 == 385
+    assert cfg.held == (0, 16) and cfg.n_experts == 128
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.eval_shape(
+        lambda: cohere2_moe.Cohere2MoeDecoder(cfg).serving_params(
+            cohere2_moe.init_params(cfg, 0), BF16))
+    assert sum(int(np.prod(x.shape)) for x in
+               jax.tree_util.tree_leaves(params)) == 4_733_292_544
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), params)
+    lanes = cfg.n_kv_heads * cfg.d_head
+    layers = (len(cfg.full_layers), len(cfg.sliding_layers))
+    pools = [sds((n + 1, l, ps, lanes), BF16)
+             for n, l in zip(pages, layers) for _ in "kv"]
+    rng = jax.random.PRNGKey(0)
+    tail = (sds(rng.shape, rng.dtype), sds((), F32), sds((), F32))
+    if program == "prefill":
+        fn = eng._get_prefill_fn(bucket, True, 0)
+        args = (sds((1, bucket), I32),
+                (tuple(sds((r,), I32) for r in rows),
+                 (sds((), I32), sds((), I32))), sds((), I32), sds((), I32))
+    else:
+        fn = eng._get_decode_fn(True, 0)
+        args = (sds((slots, 1), I32), sds((slots,), I32),
+                (tuple(sds((slots, r), I32) for r in rows),
+                 (sds((slots,), I32), sds((slots,), I32))))
+    compiled = fn.lower(params, *pools, *args, *tail).compile()
+    text = compiled.as_text()
+
+    assert text.startswith("HloModule jit_" + program)
+    assert eng.prefill_attention_kernel == "pallas"
+    chunk_calls = len(re.findall(
+        r"%chunk_attention(?:\.\d+)? = .*tpu_custom_call", text))
+    assert chunk_calls == (cfg.n_layers if program == "prefill" else 0)
+    assert text.count("tpu_custom_call") == 3 * cfg.n_layers + chunk_calls
+    assert ("paged_attention_grouped" in text) == (program == "decode")
+    # the sliding group's pool by its shape (XLA drops the one-layer
+    # full group's unit dimension); the page writes are counted over all
+    assert _page_writes(text, program, (pages[1] + 1, layers[1], ps,
+                                        lanes)) == \
+        (cfg.n_layers if program == "prefill" else 0)
+    aliased = {int(out): int(arg) for out, arg in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+        text.split("\n", 1)[0])}
+    n_params = len(jax.tree_util.tree_leaves(params))
+    assert aliased == {i: n_params + i for i in range(4)}
+    memory = compiled.memory_analysis()
+    print("command_a_plus {}: arguments {:.3f} GB, temporaries {:.3f} GB"
+          .format(program, memory.argument_size_in_bytes / 1e9,
+                  memory.temp_size_in_bytes / 1e9))
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < \
+        14.6 * 2 ** 30
+
+
 def test_pallas_compiler_params_construct():
     """Every ``compiler_params`` a pallas_call site passes must construct
     under the installed jax — the sites are only reached with

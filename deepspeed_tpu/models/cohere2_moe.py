@@ -46,7 +46,10 @@ two kernels.
 The serving programs return, beside the hidden states, the expert
 layers' summed load (``counters``: ``moe.load``) with, in a third row,
 the (token, choice) pairs the launch routed ANYWHERE (``routed``), so
-that the share that landed on the experts held here can be read.
+that the share that landed on the experts held here can be read, and
+the passes its expert layers made over their capacity (``passes``: one
+a layer unless the router sent this chip more than twice its even
+share; ops/moe.py ``share_capacity``).
 
 Serving only; a ``model`` mesh axis is refused.
 """
@@ -284,7 +287,8 @@ def _rotary(x, positions, theta):
 def _experts(u, lp, config):
     """-> (the routed experts HELD here plus the shared experts' mean, of
     ``u`` (.., d); the load (3, E): ops/moe.py's two rows and, at [2,
-    0], the (token, choice) pairs routed anywhere)."""
+    0], the (token, choice) pairs routed anywhere, at [2, 1] the passes
+    the layer made over its share's capacity)."""
     flat = u.reshape(-1, u.shape[-1])
     chosen, weights = moe.route(
         flat, lp["router"], None, config.top_k, config.norm_topk_prob,
@@ -300,7 +304,8 @@ def _experts(u, lp, config):
         out = out + (shared.astype(jnp.float32) /
                      config.n_shared).astype(out.dtype)
     routed = jnp.zeros((1, config.n_experts), jnp.int32).at[0, 0].set(
-        chosen.size)
+        chosen.size).at[0, 1].set(moe.share_passes(
+            load, chosen.size, config.held, config.n_experts))
     return out.reshape(u.shape), jnp.concatenate([load, routed])
 
 
@@ -480,7 +485,8 @@ class Cohere2MoeDecoder:
     @staticmethod
     def counter_attrs(name, value):
         value = np.asarray(value)
-        return dict(moe.load_attrs(value[:2]), routed=int(value[2, 0]))
+        return dict(moe.load_attrs(value[:2]), routed=int(value[2, 0]),
+                    passes=int(value[2, 1]))
 
     forward_hidden = staticmethod(forward_hidden)
 

@@ -25,7 +25,12 @@ real ones repeat the last real item's indices (no fetch) and skip the
 body.
 
 Shapes come from the arguments: one kernel serves a decode step's 48
-rows an expert and a prefill chunk's 64-256. ``name="moe_gmm"`` is the
+rows an expert and a prefill chunk's 64-256. The item slots are ``m //
+tm + E - 1``, so they follow the rows the caller hands over: a chip
+that holds a share of the experts hands over its capacity of held rows
+(ops/moe.py ``share_capacity``: 4,096 of a chunk's 16,384 routed rows
+at 16 of 128 experts, 47 slots for 143), a pass at a time, each pass
+with the group sizes of its own rows. ``name="moe_gmm"`` is the
 device trace's event name, by which the benchmark's roofline finds it.
 ``moe_gmm_xla`` (``lax.ragged_dot``) is the oracle, and what runs off
 the TPU unless a test asks for the interpreter.

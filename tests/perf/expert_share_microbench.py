@@ -5,18 +5,26 @@ read from a profiler trace (not a host clock). Needs a TPU.
 
     chiprun -- python tests/perf/expert_share_microbench.py \
         [--tokens 2048,40] [--width 4096] [--experts 128] [--held 16] \
-        [--top-k 8]
+        [--top-k 8] [--skew 0.25] [--root DIR]
 
 For each token count two lines. ``share``: ``tokens x top_k`` rows are
-sorted and gathered, of which the ``held / experts`` that land here are
-real, and ``moe_gmm``'s grid carries an item slot for every row tile of
-them all (command-a-plus-serve.rag: a chunk's 16,384 rows, 2,048 real).
-``all_real``: the same number of REAL rows a held expert (``tokens x
-top_k x held / experts``, evenly) with every row routed to an expert held
-here, which is the layer an exchange in front of it would hand this chip.
-Each line: the whole call's device ms, the ``moe_gmm`` kernel's (both
-matmuls), the rest (sort, gather, activation, combine) and the least time
-the held experts' matrices take to stream from HBM.
+routed and sorted, of which the ``held / experts`` that land here are
+real; since PR 52 only those are gathered, multiplied, activated and
+combined, ``share_capacity`` rows at a time (command-a-plus-serve.rag: a
+chunk's 16,384 rows, 2,048 real, a capacity of 4,096: ``moe_gmm``'s grid
+carries 32 + 15 item slots, where until PR 52 it carried one for every
+row tile of the 16,384). ``all_real``: the same number of REAL rows a
+held expert (``tokens x top_k x held / experts``, evenly) with every row
+routed to an expert held here, which is the layer an exchange in front
+of it would hand this chip. ``--skew S`` forces that share of the tokens
+wholly onto held experts in the ``share`` line, so that a launch past
+its capacity (two passes at 0.25 where 16 of 128 are held) has a device
+time too; the line's ``passes`` says how many it took. ``--root`` runs
+another checkout's ``deepspeed_tpu`` (the parent commit unpacked under
+``_chip_checkout/``). Each line: the whole call's device ms, the
+``moe_gmm`` kernel's (both matmuls, every pass), the rest (sort, gather,
+activation, combine) and the least time the held experts' matrices take
+to stream from HBM.
 """
 import argparse
 import json
@@ -37,9 +45,11 @@ def main():
     ap.add_argument("--experts", type=int, default=128)
     ap.add_argument("--held", type=int, default=16)
     ap.add_argument("--top-k", type=int, default=8)
+    ap.add_argument("--skew", type=float, default=0.0)
+    ap.add_argument("--root", default=os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
     ns = ap.parse_args()
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))))
+    sys.path.insert(0, os.path.abspath(ns.root))
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -59,6 +69,9 @@ def main():
         # share: top_k distinct experts of all, a token
         chosen = np.stack([rng.permutation(ns.experts)[:ns.top_k]
                            for _ in range(tokens)]).astype(np.int32)
+        # skew: the first tokens' choices all among the held
+        for t in range(int(round(ns.skew * tokens))):
+            chosen[t] = rng.permutation(ns.held)[:ns.top_k]
         real = int((chosen < ns.held).sum())
         # all_real: as many rows, each token's choices among the held
         k_real = max(1, round(ns.top_k * ns.held / ns.experts))
@@ -69,10 +82,13 @@ def main():
             weights = jnp.full(picks.shape, 1.0 / picks.shape[1],
                                jnp.float32)
             fn = jax.jit(lambda x, c, w, experts=experts: moe.expert_ffn(
-                x, c, w, w13, w2, (0, ns.held), experts, kernel="pallas")[0])
+                x, c, w, w13, w2, (0, ns.held), experts, kernel="pallas"))
             args = (x, jnp.asarray(picks), weights)
             jax.block_until_ready(fn(*args))
-            jax.block_until_ready(fn(*args))
+            load = jax.block_until_ready(fn(*args))[1]
+            passes = int(moe.share_passes(load, picks.size, (0, ns.held),
+                                          experts)) \
+                if hasattr(moe, "share_passes") else 1
             with tempfile.TemporaryDirectory() as tmp:
                 jax.profiler.start_trace(tmp)
                 for _ in range(ITERS):
@@ -84,6 +100,7 @@ def main():
             print(json.dumps(dict(
                 case=label, tokens=tokens, rows=int(picks.size),
                 real_rows=real if label == "share" else int(picks.size),
+                passes=passes,
                 call_ms=busy_ms, moe_gmm_ms=gmm,
                 rest_ms=round(busy_ms - gmm, 4),
                 weights_stream_ms=round(stream_ms, 4), largest_others=others,

@@ -31,6 +31,9 @@ ITERS = 5
 B, S, H, DH = 20, 1024, 16, 64        # gpt2-350m-train.seq1024, a layer
 
 
+_CONTAINER = re.compile(r" (while|conditional|call)\(")
+
+
 def kernel_ms(trace_dir, iters):
     """{kernel name: device ms a call of the traced function} over the
     Mosaic custom calls of the trace."""
@@ -47,6 +50,8 @@ def kernel_ms(trace_dir, iters):
             if line.name != "XLA Ops":
                 continue
             for ev in line.events:
+                if _CONTAINER.search(ev.name):
+                    continue       # its body's operations are events too
                 busy += ev.duration_ns
                 if "tpu_custom_call" in ev.name:
                     name = re.sub(r"\.\d+$", "", ev.name.split(" = ")[0])

@@ -241,7 +241,8 @@ def test_the_eight_shares_add_up_to_the_uncut_references_layer():
         assert load[0, :first].sum() == load[0, first + 2:].sum() == 0
         landed += int(load[0].sum())
         routed.append(int(load[2, 0]))
-        assert not load[2, 1:].any()
+        # one pass over the share's capacity, and nothing else there
+        assert load[2, 1] == 1 and not load[2, 2:].any()
     np.testing.assert_allclose(alike + total, whole, atol=2e-5)
     # every (token, choice) pair landed on exactly one of the eight
     assert routed == [40 * 4] * 8 and landed == 40 * 4
@@ -328,7 +329,7 @@ def test_the_audit_lowers_the_programs_with_a_table_a_group():
 # --------------------------------------------------- spans and the record
 def test_the_spans_say_where_a_chunk_starts_and_what_landed_here(tmp_path):
     """``sched.prefill.chunk`` carries ``start``; ``moe.load`` of a model
-    that holds a share carries ``routed`` beside ``rows``."""
+    that holds a share carries ``routed`` and ``passes`` beside ``rows``."""
     eng = _engine()
     jax.profiler.start_trace(str(tmp_path))
     eng.generate([_ids(40, salt=7).tolist()], max_new_tokens=12)
@@ -353,16 +354,35 @@ def test_the_spans_say_where_a_chunk_starts_and_what_landed_here(tmp_path):
     assert all(0 <= s["rows"] <= s["routed"] for s in loads)
     assert all(set(s) >= {"rows", "experts_hit", "hottest_rows", "routed"}
                for s in loads)
+    # every expert layer of every launch stayed inside its capacity
+    assert [s["passes"] for s in loads] == [4] * 14
     # two of sixteen experts: about an eighth of what was routed
     assert 0.02 < sum(s["rows"] for s in loads) / \
         sum(s["routed"] for s in loads) < 0.35
 
 
+def test_the_passes_are_summed_into_the_schedulers_metrics():
+    """``passes`` takes the road ``routed`` took: the scheduler sums a
+    launch's attributes into ``program_counters`` by name."""
+    from deepspeed_tpu.inference.scheduler import ContinuousBatchingScheduler
+    sched = ContinuousBatchingScheduler(_engine())
+    sched.submit(_ids(20, salt=2).tolist(), max_new_tokens=4,
+                 eos_token_id=None)
+    sched.run()
+    counted = sched.metrics.program_counters["moe.load"]
+    assert counted["launches"] == 2 + 3          # two chunks, three steps
+    assert counted["passes"] == 4 * counted["launches"]
+    assert counted["routed"] == (16 + 8 + 3 * 3) * 4 * 4
+    shown = sched.metrics.snapshot()["program_counters"]["moe.load"]
+    assert shown["passes"] == counted["passes"]
+
+
 def test_the_counter_attrs_of_a_fetched_load():
     load = np.zeros((3, 16), np.int32)
-    load[0, 4:6], load[1, 4:6], load[2, 0] = (5, 0), (1, 0), 64
+    load[0, 4:6], load[1, 4:6], load[2, :2] = (5, 0), (1, 0), (64, 4)
     assert cohere2_moe.Cohere2MoeDecoder.counter_attrs("moe.load", load) == \
-        {"rows": 5, "experts_hit": 1, "hottest_rows": 5, "routed": 64}
+        {"rows": 5, "experts_hit": 1, "hottest_rows": 5, "routed": 64,
+         "passes": 4}
     # ops/moe.py's own attributes are what they were
     assert moe.load_attrs(load[:2]) == {"rows": 5, "experts_hit": 1,
                                         "hottest_rows": 5}

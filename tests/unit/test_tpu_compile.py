@@ -1034,6 +1034,11 @@ def test_mellum_programs_run_the_kernels_and_alias_both_groups(
         r"%chunk_attention(?:\.\d+)? = .*tpu_custom_call", text))
     assert chunk_calls == (cfg.n_layers if program == "prefill" else 0)
     assert text.count("tpu_custom_call") == 3 * cfg.n_layers + chunk_calls
+    # every expert held: the grouped matmuls take every routed row
+    gmm_rows = {int(m) for m in re.findall(
+        r"%moe_gmm(?:\.\d+)? = bf16\[(\d+),\d+\]\S* custom-call", text)}
+    assert gmm_rows == {(bucket if program == "prefill" else slots) *
+                        cfg.top_k}
     assert ("paged_attention_grouped" in text) == (program == "decode")
     # XLA's loop over key blocks carried f32[1,4,8,bucket,128] and made
     # f32[1,4,8,bucket,512] scores a turn
@@ -1094,10 +1099,16 @@ def test_command_a_plus_programs_run_the_kernels_at_the_cells_share(
     a decode query is 128 rows over 1,024 packed lanes in the grouped
     walk, the sliding layers over their table of 385 columns with
     ``window=4096``; a chunk's attention in ``chunk_attention`` in all 4
-    layers; two grouped matmuls a layer whose groups are the 16 experts
-    held, an eighth of the rows real; both groups' pool pairs come back
-    in place; and it fits the chip."""
+    layers; the grouped matmuls of a layer over the share's CAPACITY of
+    rows (4,096 of a chunk's 16,384 routed, 128 of a decode step's 320:
+    twice the part of 16 experts of 128), in a loop that a launch past
+    its capacity goes round again; both groups' pool pairs come back in
+    place; and it fits the chip. Temporaries (the chip's compiler, PR
+    52): prefill 0.455 GB, decode 0.143 (0.454 / 0.143 at PR 50, when
+    every routed row was gathered: the largest live set is not the
+    expert layer's)."""
     from deepspeed_tpu.models import cohere2_moe
+    from deepspeed_tpu.ops import moe
     eng, cell = command_a_plus_engine
     cfg = eng.model_config
     inference = cell["inference"]
@@ -1142,6 +1153,12 @@ def test_command_a_plus_programs_run_the_kernels_at_the_cells_share(
         r"%chunk_attention(?:\.\d+)? = .*tpu_custom_call", text))
     assert chunk_calls == (cfg.n_layers if program == "prefill" else 0)
     assert text.count("tpu_custom_call") == 3 * cfg.n_layers + chunk_calls
+    # the share's grouped matmuls, in the loop over its capacity's rows
+    gmm_rows = {int(m) for m in re.findall(
+        r"%moe_gmm(?:\.\d+)? = bf16\[(\d+),\d+\]\S* custom-call", text)}
+    assert gmm_rows == {moe.share_capacity(
+        (bucket if program == "prefill" else slots) * cfg.top_k,
+        cfg.held[1] - cfg.held[0], cfg.n_experts)}
     assert ("paged_attention_grouped" in text) == (program == "decode")
     # the sliding group's pool by its shape (XLA drops the one-layer
     # full group's unit dimension); the page writes are counted over all

@@ -10,38 +10,50 @@ heads x 512 keys) and passes over that array four times: about 1 ms a
 block where its two matmuls cost the MXU 0.09 (PERF.md section 5, PR 42).
 Here the grid is (slot, query tile). A tile is ``tq`` queries x the
 ``group`` query heads of a key-value head as the rows of one matmul (rows
-ordered (query, head of the group), ``_grouped_paged_attention``'s
-layout) against a block of ``tk`` keys that streams HBM -> VMEM page by
-page, one ``pltpu.make_async_copy`` a page and pool by the slot's row of
-the page table in scalar memory, into a double buffer (the next block's
-copies are in flight while this one is on the MXU); every key-value head
-folds the block into its float32 accumulator (flash-attention's
-recurrence) before the next block is waited for.
+ordered (query, head of the group)) against a block of ``tk`` keys that
+streams HBM -> VMEM into a double buffer, one ``pltpu.make_async_copy`` a
+page and pool by the slot's row of the page table in scalar memory: the
+starts of ALL the block's pages in a straight line behind ONE wait a pool
+for the block's bytes (the next block's copies are in flight while this
+one is on the MXU); every key-value head folds the block into its float32
+accumulator (flash-attention's recurrence) before the next block is
+waited for. A block's pages past the live length are fetched like the
+others, by the table's own entries and, past its last column, by the
+garbage page the wrapper pads the row with: what they hold is masked, and
+the value side zeroed, as a partly live page's dead rows always were.
 
 What a tile does not do:
 
-* it visits only the blocks that hold a key one of its queries can see:
-  table positions ``[max(0, q0 - window + 1), min(q1, live)]`` for queries
-  ``[q0, q1]`` (from 0 without a window), so a sliding layer's tile walks
-  at most ``(window + tq) / tk + 1`` blocks whatever the context, and a
-  tile wholly past ``valid_lens`` fetches nothing and writes zeros. The
-  walk's length is data (``positions``, ``valid_lens``): one program a
-  bucket and kind of layer, none a context length;
+* it visits only the keys one of its queries can see: table positions
+  from the PAGE of ``max(0, q0 - window + 1)`` (0 without a window) to
+  ``min(q1, live)`` for queries ``[q0, q1]``, in whole blocks from
+  there, so a sliding layer's tile past its window walks ``window / tk``
+  blocks and its own ``tq`` keys, whatever the context, and a tile wholly
+  past ``valid_lens`` fetches nothing and writes zeros. Where the last
+  block holds no more than the tile's own keys (a quarter of a block or
+  less: :func:`_short_block`) it is fetched and folded at that length.
+  The walk's length is data (``positions``, ``valid_lens``): one program
+  a bucket and kind of layer, none a context length;
 * it masks only edge blocks: a block whose every key every query of the
   tile sees (``k_hi <= q0``, which is no later than ``live`` in a tile
   that walks at all, and ``q1 - k_lo < window``) takes a body without
-  iotas, compares and selects.
+  iotas, compares and selects; an edge block's mask is made once a turn
+  of the rows' loop and serves every key-value head.
 
-Tiles come from the shapes (:func:`tiles`): ``tk`` is what
-``_KV_BLOCK_VMEM_BYTES`` holds of K and V double-buffered at the pool's
-lanes and itemsize (half a window at most), ``tq`` what
-``_TILE_VMEM_BYTES`` holds of a tile's queries, accumulator and
-statistics, ``sub`` (the rows a turn of the tile's inner loop scores:
-the body is compiled once whatever the tile holds) what
-``_TURN_SCORES_BYTES`` holds of float32 scores against a block, and
-``vmem_limit_bytes`` is counted from the three. The layer is DATA (a
-scalar in SMEM, as ``kv_page_write`` carries it) and the call is jitted,
-so a program's layers of one kind share ONE traced and lowered kernel.
+Tiles come from the shapes (:func:`tiles`): ``tk`` is ``_BLOCK_KEYS``
+(what ``_KV_BLOCK_VMEM_BYTES`` holds of K and V double-buffered at the
+pool's lanes and itemsize, if that is less; half a window at most),
+``tq`` the queries whose rows of one key-value head are ``_TILE_ROWS``
+(what ``_TILE_VMEM_BYTES`` holds of a tile's queries, accumulator and
+statistics, if that is less), ``sub`` (the rows a turn of the tile's
+inner loop scores: the body is compiled once whatever the tile holds)
+what ``_TURN_SCORES_BYTES`` holds of float32 scores against a block, and
+``vmem_limit_bytes`` is counted from the three: 256 queries against 1,024
+keys (512 under a window of 1,024) at Mellum's 8 heads a key-value head
+over 512 lanes, 128 against 1,024 at Command A+'s 16 over 1,024. The
+layer is DATA (a scalar in SMEM, as ``kv_page_write`` carries it) and the
+call is jitted, so a program's layers of one kind share ONE traced and
+lowered kernel.
 
 Masking contract and precision are the page walk's
 (ops/pallas/paged_attention.py) and the XLA loop's: ``k_pos <= q_pos``,
@@ -67,25 +79,59 @@ from jax.experimental.pallas import tpu as pltpu
 from ..chunk_attention import NEG_INF
 from .common import default_interpret
 
-# VMEM a block of keys and values may take (two pools, each
-# double-buffered): 1,024 keys at 4 key-value heads of 128 bf16 lanes.
-# What a block costs beside its matmuls is paid a ROW of scores (the
-# running max and sum, their lane reductions and broadcasts), so a
-# longer row is cheaper a key: 6.4 ms at 1,024 keys where 512 take 8.3
-# (a chunk of 2,048 at 22,528 in a full layer; my chip runs, PR 43).
-_KV_BLOCK_VMEM_BYTES = 4 << 20
-# VMEM a tile's resident arrays may take: its queries and its float32
-# accumulator (both double-buffered by the pipeline), the running max
-# and sum (a 128-lane tile a row each): 256 queries x 32 heads of 128.
-_TILE_VMEM_BYTES = 24 << 20
+# Keys a block, at most, and the VMEM its keys and values may take (two
+# pools, each double-buffered). What a block costs beside its matmuls is
+# paid a ROW of scores (the running max and sum, their lane reductions
+# and broadcasts, the accumulator's rescaling), so a longer row is
+# cheaper a key: at ide's shape (8 heads a key-value head, 512 lanes) a
+# chunk of 2,048 at 22,528 in a full layer took 6.4 ms at 1,024 keys
+# where 512 take 8.3 (my chip runs, PR 43); at rag's (16 heads, 1,024
+# lanes) a chunk at 8,192 took 10.7 ms at 1,024 where 512 take 13.4
+# (PR 53: the compiler's schedule of a turn of 512 K scores is 2,475
+# bundles at 512 rows x 1,024 keys and 3,341 at 1,024 x 512, where its
+# matmuls are 2,048).
+_BLOCK_KEYS = 1024
+_KV_BLOCK_VMEM_BYTES = 8 << 20
+# Rows of ONE key-value head a tile holds (queries x the heads of a
+# group), at most, and the VMEM the tile's resident arrays may take: its
+# queries and its float32 accumulator (both double-buffered by the
+# pipeline), the running max and sum (a 128-lane tile a row each). A
+# block's fetch (two DMA starts a page) and an edge block's iotas are
+# paid a tile, whatever rows it holds: 2,048 rows are 256 queries at 8
+# heads a group (ide) and 128 at 16 (rag: 40 MiB over 8 key-value
+# heads), where the byte budget alone gave rag's tile 64 queries.
+_TILE_ROWS = 2048
+_TILE_VMEM_BYTES = 48 << 20
 # float32 scores a turn of the rows' loop holds against a block: 512
 # rows x 1,024 keys. A block's keys and values are the MXU's latched
 # operand, so more rows a turn are fewer latches a row (256 rows: 10.5
-# ms where 1,024 take 8.3, at 512 keys), until a turn's arrays outgrow
-# what the compiler keeps close (2,048 rows: 11.1 ms), and its compile
-# time grows with them (4 s a kernel at 256 rows, 10 at 1,024, 17-25 at
-# 2,048).
+# ms where 1,024 take 8.3, at 512 keys and ide's shape), until a turn's
+# arrays outgrow what the compiler keeps close (2,048 rows: 11.1 ms),
+# and its compile time grows with them (4 s a kernel at 256 rows, 10 at
+# 1,024, 17-25 at 2,048; at rag's 8 key-value heads 11 s at 512 rows x
+# 1,024 keys and 19 at 1,024 x 1,024, which also ran slower: 12.8 ms for
+# 10.7).
 _TURN_SCORES_BYTES = 2 << 20
+
+
+# Key-value heads a turn of the heads' loop folds in a straight line
+# (the loop's index picks their 128-lane slices of the block). Every head
+# unrolled, Command A+'s 8 made a kernel of 71,000 bundles that the
+# chip's compiler took 15 s over, and a third body (the short block)
+# then cost more than it saved: 5.19 ms a sliding layer's chunk where
+# the loop reads 5.11 (and 4 s to compile); one head a turn loses what
+# the straight line overlaps between heads (5.17; at Mellum's 4 heads
+# 0.605 ms where all 4 in line read 0.579) (my chip runs, PR 53).
+_HEADS_TOGETHER = 4
+
+
+# Pages whose DMA starts (one a pool) a turn of a block's fetch issues in
+# a straight line: 10 bundles a start where a loop turn a page took 22 a
+# start and 7 a wait (the compiler's bundle dump, PR 53). Every page of
+# a block in line (64) reads no faster, and TRACING its 256 starts a
+# fetch made a prefill program's trace 6.5 s where the parent's was 2.5
+# (rag's traced runs, PR 53: `setup_s` +13%).
+_PAGES_IN_LINE = 8
 
 
 def _largest(n, fit, unit):
@@ -118,148 +164,218 @@ def tiles(s, group, d_head, lanes, itemsize, table_tokens, page_size,
     """-> ``(tq, tk, sub)`` for a chunk of ``s`` queries whose ``group``
     heads share a key-value head of ``d_head`` lanes, over pools of
     ``lanes`` lanes a token and a table of ``table_tokens`` positions.
-    ``tk``: keys a block, whole pages, no more than the table has nor
+    ``tk``: keys a block, whole pages, ``_BLOCK_KEYS`` if their double
+    buffers fit ``_KV_BLOCK_VMEM_BYTES``, no more than the table has nor
     than half a ``window`` (a tile sees ``window + tq`` keys and walks
-    whole blocks: at 1,024 keys a block a sliding layer's chunk took
-    0.75 ms where 512 take 0.61); ``tq``: queries a tile, a divisor of
-    ``s``; ``sub``: rows (query, head of the group) a turn of a tile's
-    loop, whole queries and whole sublane tiles of the pool's dtype."""
-    pages = max(1, _KV_BLOCK_VMEM_BYTES // (4 * page_size * lanes * itemsize))
+    whole blocks: at a window of 1,024 and 1,024 keys a block a sliding
+    layer's chunk took 0.75 ms where 512 take 0.61, PR 43; at a window
+    of 4,096 the half is 2,048 and the block 1,024: 5.4 ms where 512
+    take 6.7, PR 53); ``tq``: queries a tile, a divisor of ``s``,
+    ``_TILE_ROWS`` rows of a key-value head if they fit
+    ``_TILE_VMEM_BYTES``; ``sub``: rows (query, head of the group) a
+    turn of a tile's loop, whole queries and whole sublane tiles of the
+    pool's dtype."""
+    pages = max(1, min(_BLOCK_KEYS // page_size, _KV_BLOCK_VMEM_BYTES
+                       // (4 * page_size * lanes * itemsize)))
     if window is not None:
         pages = min(pages, max(1, window // (2 * page_size)))
     tk = min(pages, -(-table_tokens // page_size)) * page_size
     sublanes = 8 * 4 // itemsize
-    fit = _TILE_VMEM_BYTES // _query_bytes(group, d_head, lanes, itemsize)
+    fit = min(_TILE_ROWS // group, _TILE_VMEM_BYTES
+              // _query_bytes(group, d_head, lanes, itemsize))
     tq = _largest(s, max(1, fit), sublanes // math.gcd(sublanes, group))
     sub = _largest(tq * group, max(1, _TURN_SCORES_BYTES // (4 * tk)),
                    group * sublanes // math.gcd(sublanes, group))
     return tq, tk, sub
 
 
+def _short_block(tq, tk, page_size):
+    """Keys of a tile's short last block, or 0 for none. A walk ends
+    with the tile's own ``tq`` keys (and up to a page before them where
+    it starts at a window's first page): where those are the whole of
+    its last block, the block is fetched and folded at this length, in
+    whole lane tiles of scores, if that is a quarter of a block or
+    less."""
+    short = -(-(tq + page_size - 1) // 128) * 128
+    return short if 4 * short <= tk and short % page_size == 0 else 0
+
+
 def _kernel(pos_ref, vlen_ref, layer_ref, pt_ref, q_ref, k_pool_ref,
             v_pool_ref, o_ref, k_buf, v_buf, m_ref, l_ref, k_sem, v_sem, *,
             page_size, kv_heads, group, d_head, sm_scale, tq, tk, sub,
-            window):
+            short, window):
     """One tile of one slot's chunk. In SMEM: pos_ref / vlen_ref (b,)
-    and layer_ref (1,), scalar prefetch; pt_ref (1, 1, max_pages), the
-    slot's own row of the page table. q_ref (1, kv_heads, tq * group,
-    d_head), rows ordered (query, head of the group); the pools (pages
-    + 1, layers, page_size, kv_heads * d_head) left in HBM; o_ref like
-    q_ref, float32: the accumulator, normalised at the end. k/v_buf (2,
-    tk, kv_heads * d_head), one DMA semaphore a half; m_ref / l_ref
-    (kv_heads, tq * group, 1) float32."""
+    and layer_ref (1,), scalar prefetch; pt_ref (1, 1, columns), the
+    slot's own row of the page table, a block of the garbage page past
+    its own columns. q_ref (1, kv_heads, tq * group, d_head), rows
+    ordered (query, head of the group); the pools (pages + 1, layers,
+    page_size, kv_heads * d_head) left in HBM; o_ref like q_ref,
+    float32: the accumulator, normalised at the end. k/v_buf (2, tk,
+    kv_heads * d_head), one DMA semaphore a half; m_ref / l_ref
+    (kv_heads, tq * group, 1) float32. ``short``: the keys of a short
+    last block (0: none, :func:`_short_block`)."""
     # non-negative ints throughout: ``lax.div`` / ``rem`` stand for
     # ``//`` / ``%``, which lower through ``sign`` (PERF.md, PR 33)
     i, t = pl.program_id(0), pl.program_id(1)
     layer = layer_ref[0]
     pos = pos_ref[i]
     live = pos + vlen_ref[i] - 1           # last live table position
-    n_pages = jnp.minimum(jax.lax.div(jnp.maximum(live, 0), page_size) + 1,
-                          pt_ref.shape[2])
     q0 = pos + t * tq
     q1 = q0 + tq - 1
-    first_key = 0 if window is None else jnp.maximum(q0 - window + 1, 0)
-    c_lo = jax.lax.div(first_key, tk)
-    c_hi = jax.lax.div(jnp.maximum(jnp.minimum(q1, live), 0), tk)
+    # the walk starts at the page of the first key the tile's first
+    # query sees (column 0 without a window) and ends at the last key
+    # its last query sees: whole blocks from there, so a sliding layer's
+    # tile past its window walks window / tk blocks and a short one
+    first_page = 0 if window is None else \
+        jax.lax.div(jnp.maximum(q0 - window + 1, 0), page_size)
+    first_key = first_page * page_size
+    keys = jnp.maximum(jnp.minimum(q1, live) - first_key + 1, 0)
+    whole = jax.lax.div(keys, tk)
+    rest = keys - whole * tk
     # a tile wholly past the live length walks nothing
-    n_blocks = jnp.where(q0 <= live, c_hi - c_lo + 1, 0)
+    n_blocks = jnp.where(q0 <= live, whole + (rest > 0), 0)
+    last_is_short = jnp.logical_and(rest > 0, rest <= short)
     rows, per_block = tq * group, tk // page_size
+    line = math.gcd(per_block, short // page_size, _PAGES_IN_LINE)
 
-    def transfer(c, half, start):
-        # a block's last pages may lie past the live length: no copy,
-        # and what the buffer holds there is masked below
-        first = c * per_block
+    def is_short(step):
+        return jnp.logical_and(last_is_short, step == n_blocks - 1)
 
-        def page(j, carry):
-            phys = pt_ref[0, 0, first + j]
-            dst = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
-            for pool, buf, sem in ((k_pool_ref, k_buf, k_sem),
-                                   (v_pool_ref, v_buf, v_sem)):
-                copy = pltpu.make_async_copy(
-                    pool.at[phys, layer], buf.at[half, dst], sem.at[half])
-                copy.start() if start else copy.wait()
+    def fetch(step, half):
+        # ALL the block's pages, ``line`` starts of each pool in a
+        # straight line a turn, so that one wait a pool covers them:
+        # past the live length the table's own entry or its padding
+        # (the garbage page), masked
+        first = first_page + step * per_block
+
+        def pages(g, carry):
+            base = pl.multiple_of(g * (line * page_size), line * page_size)
+            for j in range(line):
+                phys = pt_ref[0, 0, first + g * line + j]
+                dst = pl.ds(base + j * page_size, page_size)
+                for pool, buf, sem in ((k_pool_ref, k_buf, k_sem),
+                                       (v_pool_ref, v_buf, v_sem)):
+                    pltpu.make_async_copy(
+                        pool.at[phys, layer], buf.at[half, dst],
+                        sem.at[half]).start()
             return carry
 
-        jax.lax.fori_loop(0, jnp.clip(n_pages - first, 0, per_block),
-                          page, 0)
+        turns = per_block // line
+        if short:
+            turns = jnp.where(is_short(step), short // page_size // line,
+                              turns)
+        jax.lax.fori_loop(0, turns, pages, 0)
+
+    def wait(step, half):
+        # ONE wait a pool, for the bytes the block's fetch started
+        def block_of(n):
+            for buf, sem in ((k_buf, k_sem), (v_buf, v_sem)):
+                at = buf.at[half, pl.ds(0, n)]
+                pltpu.make_async_copy(at, at, sem.at[half]).wait()
+
+        if short:
+            pl.when(is_short(step))(lambda: block_of(short))
+            pl.when(jnp.logical_not(is_short(step)))(lambda: block_of(tk))
+        else:
+            block_of(tk)
 
     @pl.when(n_blocks > 0)
     def _first_block():
-        transfer(c_lo, 0, True)
+        fetch(0, 0)
 
     o_ref[...] = jnp.zeros_like(o_ref)
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
 
-    def fold(half, k_lo, masked):
+    def fold(half, k_lo, masked, width=tk):
         """The block in ``k/v_buf[half]``, keys from table position
         ``k_lo``, into every key-value head's accumulator, ``sub`` rows
         of the tile a turn (a loop, so that the body is compiled once
-        whatever the tile holds)."""
+        whatever the tile holds). An edge block's mask is made once a
+        turn and serves every head. ``width``: the keys it holds (a
+        short block's are the buffer's first)."""
         if masked:
             # q_pos - k_pos = ahead - (k_lo - q0) for the tile's first
             # rows: the block's, the tile's and the turn's places stay
             # on the scalar side of every compare
-            col = jax.lax.broadcasted_iota(jnp.int32, (sub, tk), 1)
+            col = jax.lax.broadcasted_iota(jnp.int32, (sub, width), 1)
             ahead = jax.lax.div(
-                jax.lax.broadcasted_iota(jnp.int32, (sub, tk), 0),
+                jax.lax.broadcasted_iota(jnp.int32, (sub, width), 0),
                 group) - col
             alive = col <= live - k_lo
-            token = jax.lax.broadcasted_iota(jnp.int32, (tk, 1), 0)
-        for h in range(kv_heads):
-            sl = slice(h * d_head, (h + 1) * d_head)
-            k_h, v_h = k_buf[half, :, sl], v_buf[half, :, sl]
+            token = jax.lax.broadcasted_iota(jnp.int32, (width, 1), 0)
+
+        def head_turn(h, r, mask):
+            at = pl.ds(pl.multiple_of(r * sub, sub), sub)
+            sl = pl.ds(pl.multiple_of(h * d_head, d_head), d_head)
+            v_h = v_buf[half, :width, sl]
+            scores = jax.lax.dot_general(
+                q_ref[0, h, at, :], k_buf[half, :width, sl],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
             if masked:
                 v_h = jnp.where(token <= live - k_lo, v_h,
                                 jnp.zeros_like(v_h))
+                # below the running max's first value: a query that
+                # sees no key of the block (the window) weighs none
+                scores = jnp.where(mask, scores, 2 * NEG_INF)
+            m = m_ref[h, at, :]
+            m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+            pexp = jnp.exp(scores - m_new)
+            corr = jnp.exp(m - m_new)
+            o_ref[0, h, at, :] = o_ref[0, h, at, :] * corr + \
+                jax.lax.dot_general(
+                    pexp.astype(v_h.dtype), v_h, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            l_ref[h, at, :] = l_ref[h, at, :] * corr + \
+                jnp.sum(pexp, axis=-1, keepdims=True)
+            m_ref[h, at, :] = m_new
 
-            def rows_turn(r, carry):
-                at = pl.ds(pl.multiple_of(r * sub, sub), sub)
-                scores = jax.lax.dot_general(
-                    q_ref[0, h, at, :], k_h, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * sm_scale
-                if masked:
-                    behind = k_lo - q0 - r * (sub // group)
-                    mask = jnp.logical_and(ahead >= behind, alive)
-                    if window is not None:
-                        mask = jnp.logical_and(mask, ahead < behind + window)
-                    scores = jnp.where(mask, scores, NEG_INF)
-                m = m_ref[h, at, :]
-                m_new = jnp.maximum(
-                    m, jnp.max(scores, axis=-1, keepdims=True))
-                pexp = jnp.exp(scores - m_new)
-                if masked:
-                    # a query may see no key of a block (the window)
-                    pexp = jnp.where(mask, pexp, 0.0)
-                corr = jnp.exp(m - m_new)
-                o_ref[0, h, at, :] = o_ref[0, h, at, :] * corr + \
-                    jax.lax.dot_general(
-                        pexp.astype(v_h.dtype), v_h, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                l_ref[h, at, :] = l_ref[h, at, :] * corr + \
-                    jnp.sum(pexp, axis=-1, keepdims=True)
-                m_ref[h, at, :] = m_new
-                return carry
+        turns = rows // sub
+        together = _largest(kv_heads, _HEADS_TOGETHER, 1)
 
-            jax.lax.fori_loop(0, rows // sub, rows_turn, 0)
+        def heads_turn(g, r, mask, carry):
+            for j in range(together):
+                head_turn(g * together + j, r, mask)
+            return carry
+
+        def rows_turn(r, carry):       # an edge block: the heads inside
+            behind = k_lo - q0 - r * (sub // group)
+            mask = jnp.logical_and(ahead >= behind, alive)
+            if window is not None:
+                mask = jnp.logical_and(mask, ahead < behind + window)
+            return jax.lax.fori_loop(
+                0, kv_heads // together,
+                lambda g, c: heads_turn(g, r, mask, c), carry)
+
+        if masked:
+            jax.lax.fori_loop(0, turns, rows_turn, 0)
+        else:
+            jax.lax.fori_loop(
+                0, kv_heads // together * turns,
+                lambda x, c: heads_turn(jax.lax.div(x, turns),
+                                        jax.lax.rem(x, turns), None, c), 0)
 
     def body(step, carry):
-        c, half = c_lo + step, jax.lax.rem(step, 2)
+        half = jax.lax.rem(step, 2)
 
         @pl.when(step + 1 < n_blocks)
         def _prefetch():
-            transfer(c + 1, 1 - half, True)
+            fetch(step + 1, 1 - half)
 
-        transfer(c, half, False)
-        k_lo = c * tk
-        k_hi = k_lo + tk - 1
+        wait(step, half)
+        k_lo = first_key + step * tk
         # every key of the block seen by every query of the tile (a
         # tile that walks has q0 <= live)
-        interior = k_hi <= q0
+        interior = k_lo + tk - 1 <= q0
         if window is not None:
             interior = jnp.logical_and(interior, q1 - k_lo < window)
+        edge = jnp.logical_not(interior)
+        if short:
+            pl.when(is_short(step))(lambda: fold(half, k_lo, True, short))
+            edge = jnp.logical_and(edge, jnp.logical_not(is_short(step)))
         pl.when(interior)(lambda: fold(half, k_lo, False))
-        pl.when(jnp.logical_not(interior))(lambda: fold(half, k_lo, True))
+        pl.when(edge)(lambda: fold(half, k_lo, True))
         return carry
 
     jax.lax.fori_loop(0, n_blocks, body, 0)
@@ -311,13 +427,17 @@ def _call(q, k_pool, v_pool, layer, page_tables, positions, valid_lens, *,
     tq, tk, sub = tile or tiles(s, group, dh, lanes, itemsize,
                                 max_pages * page_size, page_size, window)
     rows = tq * group
+    # a block of the garbage page past the table's own columns: a
+    # block's fetch takes every page of it
+    page_tables = jnp.pad(page_tables, ((0, 0), (0, tk // page_size)))
     # rows of one key-value head: (query, head of its group)
     q = q.reshape(b, s, kvh, group, dh).transpose(0, 2, 1, 3, 4) \
         .reshape(b, kvh, s * group, dh)
     block = pl.BlockSpec((1, kvh, rows, dh), lambda i, t, *_: (i, 0, t, 0))
     # the slot's row (b, 1, max_pages): a block's last two dimensions
     # are the array's
-    table = pl.BlockSpec((1, 1, max_pages), lambda i, t, *_: (i, 0, 0),
+    table = pl.BlockSpec((1, 1, page_tables.shape[1]),
+                         lambda i, t, *_: (i, 0, 0),
                          memory_space=pltpu.SMEM)
     anywhere = pl.BlockSpec(memory_space=pl.ANY)
     span = max_pages * page_size if window is None \
@@ -326,7 +446,7 @@ def _call(q, k_pool, v_pool, layer, page_tables, positions, valid_lens, *,
         functools.partial(
             _kernel, page_size=page_size, kv_heads=kvh, group=group,
             d_head=dh, sm_scale=1.0 / math.sqrt(dh), tq=tq, tk=tk, sub=sub,
-            window=window),
+            short=_short_block(tq, tk, page_size), window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(b, s // tq),
             in_specs=[table, block, anywhere, anywhere],

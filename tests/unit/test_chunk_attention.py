@@ -14,14 +14,16 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.ops.chunk_attention import paged_blocked_attention
 from deepspeed_tpu.ops.pallas import chunk_attention as kernel_module
-from deepspeed_tpu.ops.pallas.chunk_attention import chunk_attention, tiles
+from deepspeed_tpu.ops.pallas.chunk_attention import (
+    _short_block, _vmem_limit, chunk_attention, tiles)
 
 pytestmark = pytest.mark.pallas
 
 PAGE = 8
 # (query heads, key-value heads, d_head): Mellum's group of 8 on 4 heads
-# of 128, and a smaller shape of group 1
-WIDE, NARROW = (32, 4, 128), (2, 2, 32)
+# of 128, a smaller shape of group 1, and Command A+'s group of 16 on 8
+# heads at a small width
+WIDE, NARROW, MANY = (32, 4, 128), (2, 2, 32), (128, 8, 16)
 
 
 def _inputs(seed, b, s, shape, max_pages, layers=2, dtype=jnp.float32):
@@ -54,24 +56,51 @@ def _both(q, pools, tables, positions, valid, window, tile=None, layer=1):
 @pytest.mark.parametrize("start", [0, 11, 40],
                          ids=["at_0", "inside_window", "past_window"])
 @pytest.mark.parametrize("b", [1, 2])
-def test_the_kernel_matches_the_loop(window, start, b):
+@pytest.mark.parametrize("shape, sub", [(NARROW, 4), (MANY, 32)],
+                         ids=["group1", "group16"])
+def test_the_kernel_matches_the_loop(window, start, b, shape, sub):
     """Shuffled tables, every slot at its own start, tiles smaller than
     the chunk and blocks smaller than the table, so that a tile walks
-    some blocks, skips others and takes both bodies."""
+    some blocks, skips others and takes both bodies; at one head a
+    key-value head and at Command A+'s 16 on 8 key-value heads (two
+    queries a turn of the rows' loop)."""
     s, max_pages = 32, 12
-    q, pools, tables = _inputs(1, b, s, NARROW, max_pages)
+    q, pools, tables = _inputs(1, b, s, shape, max_pages)
     positions = [start, max(start - 3, 0)][:b]
     got, want = _both(q, pools, tables, positions, [s] * b, window,
-                      tile=(8, 16, 4))
+                      tile=(8, 16, sub))
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
-@pytest.mark.parametrize("shape", [WIDE, NARROW], ids=["group8", "group1"])
+# tiles of 8 queries over pages of 8: their own keys (and a page before
+# them) are 128 keys of scores at most, a quarter of a block of 512
+@pytest.mark.parametrize("window", [None, 520, 700, 37],
+                         ids=["none", "a_block", "blocks", "in_short"])
+@pytest.mark.parametrize("start", [0, 509, 1000, 1100],
+                         ids=["at_0", "at_a_block", "short_last", "long_last"])
+def test_a_short_last_block_is_the_loops(window, start):
+    """A walk that starts at the page of the first visible key and ends
+    in a short block (its last keys a quarter of a block or less:
+    fetched and folded at that length), in a whole edge block, or in
+    the short block alone."""
+    s, max_pages = 32, 160
+    assert _short_block(8, 512, PAGE) == 128
+    q, pools, tables = _inputs(9, 2, s, NARROW, max_pages)
+    valid = [s, s - 5]
+    got, want = _both(q, pools, tables, [start, max(start - 3, 0)], valid,
+                      window, tile=(8, 512, 4))
+    for slot, n in enumerate(valid):
+        np.testing.assert_allclose(got[slot, :n], want[slot, :n], atol=2e-5)
+
+
+@pytest.mark.parametrize("shape", [WIDE, NARROW, MANY],
+                         ids=["group8", "group1", "group16"])
 @pytest.mark.parametrize("window", [None, 24], ids=["full", "window"])
 def test_tiles_come_from_the_shapes(shape, window):
     """No tile handed in: what :func:`tiles` makes of the shapes, at
-    Mellum's head layout (8 query heads a key-value head of 128) and at
-    a smaller one of group 1."""
+    Mellum's head layout (8 query heads a key-value head of 128), at a
+    smaller one of group 1 and at Command A+'s (16 query heads a
+    key-value head, 8 of those)."""
     b, s, max_pages = 2, 16, 6
     h, kvh, dh = shape
     tq, tk, sub = tiles(s, h // kvh, dh, kvh * dh, 4, max_pages * PAGE,
@@ -83,29 +112,61 @@ def test_tiles_come_from_the_shapes(shape, window):
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
-def test_tiles_at_the_cells_shapes():
-    """Mellum's chunk of 2,048 queries over bfloat16 pools of 512 lanes:
-    256 queries x 8 heads a tile; a full layer's blocks are the 1,024
-    keys the module's budget holds, 512 rows a turn; a sliding layer's
-    half its window, 1,024 rows a turn; a bucket shorter than a tile is
-    one tile."""
-    full, sliding = 2048 * 16, 193 * 16
-    assert tiles(2048, 8, 128, 512, 2, full, 16) == (256, 1024, 512)
-    assert tiles(2048, 8, 128, 512, 2, sliding, 16, 1024) == (256, 512, 1024)
-    assert tiles(128, 8, 128, 512, 2, sliding, 16, 1024) == (128, 512, 1024)
-    # float32 pools: half the keys a block, twice the rows a turn
-    assert tiles(2048, 8, 128, 512, 4, full, 16) == (256, 512, 1024)
+@pytest.mark.parametrize("bucket", [512, 1024, 2048])
+@pytest.mark.parametrize("cell, want", [
+    # Mellum (8 heads a key-value head, 512 lanes): 256 queries x 8
+    # heads a tile; a full layer's blocks are 1,024 keys, 512 rows a
+    # turn; a sliding layer's half its window, 1,024 rows a turn
+    (("ide", None), (256, 1024, 512)),
+    (("ide", 1024), (256, 512, 1024)),
+    # Command A+ (16 heads a key-value head, 1,024 lanes): the same
+    # 2,048 rows of a key-value head a tile are 128 queries (the
+    # parent's byte budgets gave 64 queries against 512 keys in every
+    # bucket and both kinds of layer)
+    (("rag", None), (128, 1024, 512)),
+    (("rag", 4096), (128, 1024, 512)),
+], ids=["ide_full", "ide_sliding", "rag_full", "rag_sliding"])
+def test_tiles_at_the_cells_shapes(cell, want, bucket):
+    """A chunk of every bucket over bfloat16 pools at the two cells that
+    run the kernel, a full layer's table of 2,048 columns and a sliding
+    layer's own: the tile is the same in every bucket, and what the call
+    asks of VMEM for it is inside the 100 MiB a kernel may."""
+    (name, window), full = cell, 2048 * 16
+    group, lanes, sliding = {"ide": (8, 512, 193 * 16),
+                             "rag": (16, 1024, 385 * 16)}[name]
+    table = full if window is None else sliding
+    got = tiles(bucket, group, 128, lanes, 2, table, 16, window)
+    assert got == want
+    assert _vmem_limit(*got, group, 128, lanes, 2) <= 100 << 20
+
+
+def test_tiles_of_other_shapes():
+    """A bucket shorter than a tile is one tile; float32 pools at 512
+    lanes still hold 1,024 keys a block in 8 MiB; a tile's short last
+    block where its own keys are a quarter of a block or less."""
+    assert tiles(128, 8, 128, 512, 2, 193 * 16, 16, 1024) == (128, 512, 1024)
+    assert tiles(2048, 8, 128, 512, 4, 2048 * 16, 16) == (256, 1024, 512)
+    assert _short_block(128, 1024, 16) == 256        # rag, both kinds
+    assert _short_block(256, 1024, 16) == 0          # ide, full: 384 keys
+    assert _short_block(256, 512, 16) == 0           # ide, sliding
+    assert _short_block(8, 512, 8) == 128
 
 
 @pytest.mark.parametrize("window", [None, 20], ids=["full", "window"])
-def test_padded_rows_are_finite_and_a_tile_past_them_is_zero(window):
+@pytest.mark.parametrize("positions, valid", [([21, 0], [13, 8]),
+                                              ([0, 40], [5, 1])],
+                         ids=["pages", "one_live_page"])
+def test_padded_rows_are_finite_and_a_tile_past_them_is_zero(
+        window, positions, valid):
     """``valid_lens`` short of ``s``: the live rows are the loop's, the
     padded rows of a tile that holds a live one are finite, and a tile
-    wholly past the live length fetched nothing and wrote zeros."""
+    wholly past the live length fetched nothing and wrote zeros; a slot
+    of one live page, whose block is fetched whole all the same, and one
+    whose only live query is the first of a page."""
     s, max_pages = 32, 12
     q, pools, tables = _inputs(3, 2, s, NARROW, max_pages)
-    valid = [13, 8]
-    got, want = _both(q, pools, tables, [21, 0], valid, window, tile=(8, 16, 4))
+    got, want = _both(q, pools, tables, positions, valid, window,
+                      tile=(8, 16, 4))
     for slot, n in enumerate(valid):
         np.testing.assert_allclose(got[slot, :n], want[slot, :n], atol=2e-5)
         assert np.isfinite(got[slot]).all()
@@ -114,17 +175,25 @@ def test_padded_rows_are_finite_and_a_tile_past_them_is_zero(window):
         assert got[slot, :n].any()
 
 
-@pytest.mark.parametrize("window", [None, 20], ids=["full", "window"])
-def test_nan_past_the_live_length_reaches_no_query(window):
+@pytest.mark.parametrize("window", [None, 20, 12],
+                         ids=["full", "window", "past_the_table"])
+@pytest.mark.parametrize("start, n", [(9, 13), (32, 16)],
+                         ids=["inside", "to_the_end"])
+def test_nan_past_the_live_length_reaches_no_query(window, start, n):
     """A slot's last page partly live, NaN in every pool row past the
-    live length (a recycled page): masked scores weigh nothing, and the
-    value side is zeroed so that ``0 * NaN`` is never formed."""
-    s, max_pages, start, n = 16, 6, 9, 13
+    live length (a recycled page) and in the garbage page: a block is
+    fetched WHOLE, its dead pages by the table's own entries and, where
+    a window's walk runs past the table's last column (window 12: the
+    last tile's second block starts at key 40 of 48), by the padding's
+    garbage page. Masked scores weigh nothing, and the value side is
+    zeroed so that ``0 * NaN`` is never formed."""
+    s, max_pages = 16, 6
     q, pools, tables = _inputs(4, 1, s, NARROW, max_pages)
     live = start + n                       # tokens the slot holds
     clean = _both(q, pools, tables, [start], [n], window, tile=(8, 16, 4))[0]
     poisoned = []
     for pool in pools:
+        pool = pool.at[0].set(jnp.nan)     # the garbage page
         rows = np.array(pool[tables[0]])   # (max_pages, layers, PAGE, lanes)
         flat = rows.transpose(1, 0, 2, 3).reshape(
             rows.shape[1], max_pages * PAGE, -1)

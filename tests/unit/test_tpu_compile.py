@@ -938,6 +938,33 @@ def test_chunk_attention_compiles_at_mellum_widths(
                     ((1,), I32)) == 1
 
 
+@pytest.mark.parametrize("bucket", [512, 1024, 2048])
+@pytest.mark.parametrize("window, row", [(None, 2048), (4096, 385)],
+                         ids=["full", "window"])
+def test_chunk_attention_compiles_at_command_a_plus_widths(
+        one_chip, no_persistent_cache, window, row, bucket):
+    """The same kernel at command-a-plus-05-2026's attention (128 query
+    heads on 8 key-value heads of 128: 16 heads a key-value head over
+    pools of 1,024 lanes, pages of 16) for every bucket of the rag cell:
+    the full layer over its slot's row of 2,048 pages, a sliding layer
+    over its sliding table of 385 columns with ``window=4096``. The tile
+    the shapes give is 128 queries against blocks of 1,024 keys with a
+    short last block of 256 (three bodies), and what it asks of VMEM
+    (the 100 MiB a call may) is granted."""
+    from deepspeed_tpu.ops.pallas.chunk_attention import chunk_attention
+
+    def fn(q, k_pool, v_pool, layer, page_tables, positions, valid_lens):
+        return chunk_attention(q, k_pool, v_pool, layer, page_tables,
+                               positions, valid_lens, 16, window,
+                               interpret=False)
+
+    pages, layers = (36001, 1) if window is None else (10409, 3)
+    pool = ((pages, layers, 16, 1024), BF16)
+    assert _compile(fn, one_chip, ((1, bucket, 128, 128), BF16), pool, pool,
+                    ((), I32), ((1, row), I32), ((1,), I32),
+                    ((1,), I32)) == 1
+
+
 @pytest.mark.parametrize("engine", ["serving_engine", "jamba_engine",
                                     "lfm2_engine", "moonlight_engine"])
 def test_the_other_families_prefill_closes_over_what_it_did(request, engine):

@@ -2,8 +2,8 @@
 
 The serving engine imports no model module. The model handed to it
 carries a ``decoder`` (``make_gpt2_model``, ``make_jamba_model``,
-``make_lfm2_model``, ``make_deepseek_v3_model``, ``make_mellum_model`` and
-``make_cohere2_moe_model`` attach one) with:
+``make_lfm2_model``, ``make_deepseek_v3_model``, ``make_mellum_model``,
+``make_cohere2_moe_model`` and ``make_olmo_hybrid_model`` attach one) with:
 
 * ``config``: ``vocab_size``, ``max_seq_len``, ``d_model``;
 * ``cache_spec()`` -> :class:`CacheSpec`: what it keeps. One of three
@@ -140,7 +140,8 @@ def decoder_of(model, module=None):
         "models.jamba.make_jamba_model, models.lfm2.make_lfm2_model, "
         "models.deepseek_v3.make_deepseek_v3_model, "
         "models.mellum.make_mellum_model, "
-        "models.cohere2_moe.make_cohere2_moe_model; its cache_spec() may "
+        "models.cohere2_moe.make_cohere2_moe_model, "
+        "models.olmo_hybrid.make_olmo_hybrid_model; its cache_spec() may "
         "put the paged layers in groups)")
 
 

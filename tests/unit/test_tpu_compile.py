@@ -1115,6 +1115,189 @@ def command_a_plus_engine():
     return eng, cell
 
 
+# -------------------------- Olmo Hybrid: the gated delta rule's state pool
+OLMO = {"heads": 30, "d_k": 96, "d_v": 192, "linear_layers": 12}
+
+
+@pytest.mark.parametrize("slots", [64, 80])
+def test_gated_delta_step_compiles_in_place_on_the_pool(
+        one_chip, no_persistent_cache, slots):
+    """One decode step of one linear layer of Olmo-Hybrid-7B over every
+    slot at the published widths (30 heads, a float32 state of 96 x 192
+    a head, held 96 x 5,760 a slot): the pool of 12 layers comes back
+    in place and no layer's slab (141 MB at 64 slots) is made of it."""
+    from deepspeed_tpu.ops.pallas.gated_delta import gated_delta_step
+    H, dk, dv, layers = (OLMO["heads"], OLMO["d_k"], OLMO["d_v"],
+                         OLMO["linear_layers"])
+
+    def fn(pool, q, k, v, a, beta):
+        return gated_delta_step(pool, q, k, v, a, beta, 3, interpret=False)
+
+    args = [jax.ShapeDtypeStruct(s, F32, sharding=one_chip) for s in (
+        (layers, slots, dk, H * dv), (slots, H, dk), (slots, H, dk),
+        (slots, H, dv), (slots, H), (slots, H))]
+    compiled = jax.jit(fn, donate_argnums=0).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert re.search(r"\{1\}: \(0, \{\}, (?:may|must)-alias\)",
+                     text.split("\n", 1)[0])
+    assert "f32[{},{},{}]".format(slots, dk, H * dv) not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_paged_attention_compiles_at_30_heads_of_128(
+        one_chip, no_persistent_cache):
+    """The page walk at Olmo-Hybrid-7B's full layers: one query head a
+    key-value head, 30 of 128, 3,840 packed lanes a row (GPT-2 medium:
+    16 of 64, 1,024), 64 slots over rows of 192 pages of 16."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+
+    def fn(q, k_pool, v_pool, page_tables, positions, valid_lens):
+        return paged_attention(q, k_pool, v_pool, page_tables, positions,
+                               valid_lens, layer_idx=1, page_size=16,
+                               interpret=False)
+
+    pool = ((4001, 4, 16, 3840), BF16)
+    assert _compile(fn, one_chip, ((64, 1, 30, 128), BF16), pool, pool,
+                    ((64, 192), I32), ((64,), I32), ((64,), I32)) == 1
+
+
+@pytest.mark.parametrize("bucket", [128, 256, 512])
+def test_chunk_attention_compiles_at_30_heads_of_128(
+        one_chip, no_persistent_cache, bucket):
+    """A prompt chunk's attention at Olmo-Hybrid-7B's full layers (30
+    heads on 30 key-value heads of 128: a group of ONE over 3,840
+    lanes) for every bucket of the evals cell, over a slot's row of 192
+    pages."""
+    from deepspeed_tpu.ops.pallas.chunk_attention import chunk_attention
+
+    def fn(q, k_pool, v_pool, layer, page_tables, positions, valid_lens):
+        return chunk_attention(q, k_pool, v_pool, layer, page_tables,
+                               positions, valid_lens, 16, None,
+                               interpret=False)
+
+    pool = ((4001, 4, 16, 3840), BF16)
+    assert _compile(fn, one_chip, ((1, bucket, 30, 128), BF16), pool, pool,
+                    ((), I32), ((1, 192), I32), ((1,), I32),
+                    ((1,), I32)) == 1
+
+
+@pytest.fixture(scope="module")
+def olmo_engine():
+    """A tiny Olmo Hybrid engine on the CPU whose programs are lowered
+    at the published widths (``jamba_engine`` says how)."""
+    import json
+    import os
+    import deepspeed_tpu as deepspeed
+    from deepspeed_tpu.models import olmo_hybrid
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "olmo-hybrid-7b-serve.json")) as f:
+        cell = json.load(f)
+    tiny = dict(cell["model"], hidden_size=64, intermediate_size=128,
+                num_attention_heads=4, num_key_value_heads=4,
+                vocab_size=128, linear_num_key_heads=4,
+                linear_num_value_heads=4, linear_key_head_dim=8,
+                linear_value_head_dim=32, num_hidden_layers=4,
+                layer_types=cell["model"]["layer_types"][:4])
+    eng = deepspeed.init_inference(
+        model=olmo_hybrid.make_olmo_hybrid_model(
+            olmo_hybrid.config_from_hf(tiny), seed=0),
+        config={"inference": dict(cell["inference"], max_batch_size=2,
+                                  num_pages=256,
+                                  paged_attention_kernel="pallas")})
+    eng.model_config = olmo_hybrid.config_from_hf(cell["model"],
+                                                  gdn_kernel="pallas")
+    return eng, cell
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_olmo_programs_copy_no_state_slab_and_alias_both_pools(
+        one_chip, no_persistent_cache, olmo_engine, monkeypatch, program):
+    """``jit_prefill`` (the largest bucket, one slot) and ``jit_decode``
+    (every slot) of Olmo-Hybrid-7B at the cell's pool shapes and depth
+    (16 layers: 12 linear, 4 full): no instruction makes an array of a
+    layer's whole state slab (``[slots, 96, 5760]``, 141 MB of float32
+    at 64 slots), of a layer's pages or (decode) of every slot's whole
+    window; the page pool AND the state pool, four donated buffers,
+    come back in place; a decode step runs one state kernel a linear
+    layer and one page walk a full layer, a chunk one page write and
+    one ``chunk_attention`` a full layer (its delta rule is XLA); and
+    the whole of it fits the chip."""
+    from deepspeed_tpu.models import olmo_hybrid
+    eng, cell = olmo_engine
+    cfg = eng.model_config
+    inference = cell["inference"]
+    slots, pages = inference["max_batch_size"], inference["num_pages"]
+    bucket, ps = inference["prefill_buckets"][-1], eng.page_size
+    row = inference["max_seq_len"] // ps
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    decoder = olmo_hybrid.OlmoHybridDecoder(cfg)
+    params = jax.eval_shape(lambda: decoder.serving_params(
+        olmo_hybrid.init_params(cfg, 0), BF16))
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), params)
+    n_linear, n_full = len(cfg.linear_layers), len(cfg.full_layers)
+    lanes = cfg.n_kv_heads * cfg.d_head
+    pool = sds((pages + 1, n_full, ps, lanes), BF16)
+    conv = sds((n_linear, slots, (cfg.d_conv - 1) * cfg.conv_channels), BF16)
+    gdn = sds((n_linear, slots, cfg.d_k, cfg.linear_heads * cfg.d_v), F32)
+    assert [(s.name, s.dtype) for s in decoder.cache_spec().state] == \
+        [("conv", BF16), ("gdn", F32)]
+    # a slot's state is 27.4 MB with no lane of padding
+    assert (conv.size * 2 + gdn.size * 4) // slots == 27371520
+    rng = jax.random.PRNGKey(0)
+    tail = (sds(rng.shape, rng.dtype), sds((), F32), sds((), F32))
+    if program == "prefill":
+        fn = eng._get_prefill_fn(bucket, True, 0)
+        args = (sds((), I32), sds((1, bucket), I32), sds((row,), I32),
+                sds((), I32), sds((), I32))
+    else:
+        fn = eng._get_decode_fn(True, 0)
+        args = (sds((slots,), jnp.bool_), sds((slots, 1), I32),
+                sds((slots,), I32), sds((slots, row), I32))
+    compiled = fn.lower(params, pool, pool, conv, gdn, *args,
+                        *tail).compile()
+    text = compiled.as_text()
+
+    assert text.startswith("HloModule jit_" + program)
+    slabs = ["f32[{},{},{}]".format(slots, cfg.d_k,
+                                    cfg.linear_heads * cfg.d_v),
+             "bf16[{},{},{}]".format(pages + 1, ps, lanes),
+             "bf16[{},{},{}]".format(slots * row, ps, lanes)]
+    if program == "prefill":
+        slabs += ["bf16[{},{}]".format(
+            slots, (cfg.d_conv - 1) * cfg.conv_channels)]
+    for slab in slabs:
+        assert not [line.strip()[:160] for line in text.splitlines()
+                    if slab in line][:3], slab
+    kernels = {name: len(re.findall(
+        r"%" + name + r"(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)) for name in ("gated_delta_step", "paged_attention",
+                            "chunk_attention")}
+    if program == "decode":
+        assert kernels == {"gated_delta_step": n_linear,
+                           "paged_attention": n_full, "chunk_attention": 0}
+        assert text.count("tpu_custom_call") == n_linear + n_full
+    else:
+        assert kernels == {"gated_delta_step": 0, "paged_attention": 0,
+                           "chunk_attention": n_full}
+        assert _page_writes(text, program,
+                            (pages + 1, n_full, ps, lanes)) == n_full
+    aliased = {int(out): int(arg) for out, arg in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+        text.split("\n", 1)[0])}
+    n_params = len(jax.tree_util.tree_leaves(params))
+    assert aliased == {i: n_params + i for i in range(4)}
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < \
+        15.2 * 2 ** 30
+
+
 @pytest.mark.parametrize("program", ["prefill", "decode"])
 def test_command_a_plus_programs_run_the_kernels_at_the_cells_share(
         one_chip, no_persistent_cache, command_a_plus_engine, monkeypatch,

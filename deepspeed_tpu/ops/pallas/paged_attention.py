@@ -9,22 +9,31 @@ dense masked attention reads it again. This kernel walks each slot's
 page table inside the kernel instead, a BLOCK of pages a loop turn:
 the block's physical pages stream HBM -> VMEM as one
 ``pltpu.make_async_copy`` each into a double buffer (the next block's
-copies are in flight while this one is on the MXU, and after a slot's
+copies are in flight while this one is on the MXU, and during a slot's
 last block the next slot's first), and an online-softmax accumulator
 (flash-attention style, fp32) folds the block in, every head at once.
 Bytes touched per step drop from ``2 * max_pages * page_size`` rows per
 slot to ``2 * ceil(live_len / page_size)`` pages — and nothing is ever
 re-materialized contiguously.
 
-Pages a block (:func:`_pages_per_block`): what ``_KV_BLOCK_VMEM_BYTES``
-holds of K and V, double-buffered, at the pool's ``page_size * heads *
-d_head * itemsize`` a page, and no more than a row has — from static
-shapes only: 8 pages (128 tokens, 256 KB a buffer half) at GPT-2
-medium's 1,024 bf16 lanes, 32 where a tensor-parallel shard holds a
-quarter of the heads. A page fetched alone (32 KB) leaves the loop
-waiting on a DMA's latency every 16 tokens. The grouped walk
-(:func:`_grouped_block`) takes 512 tokens a turn and fetches the block
-WHOLE, dead pages too: straight-line copies and one wait a pool.
+Pages a block (:func:`_pages_per_block`), from static shapes only:
+``_BLOCK_TOKENS`` (512) of tokens, no more than ``_KV_BLOCK_VMEM_BYTES``
+(8 MiB) holds of K and V, double-buffered, at the pool's ``page_size *
+heads * d_head * itemsize`` a page, and no more than a row has: 32 pages
+(4 MiB of buffers) at GPT-2 medium's 1,024 bf16 lanes and where a
+tensor-parallel shard holds a quarter of the heads, 16 (7.5 MiB) at
+Olmo-Hybrid's 3,840. How a block comes, all three walks: the pages'
+copies started in a straight line and ONE wait a pool on a descriptor of
+the whole buffer half (a start and a wait a page in loops left a turn
+waiting on scalar work: 72 -> 87% of the live pages' HBM time at the
+document cell's shape, 64 -> 91% at Olmo's, PERF.md section 6, PR 55).
+:func:`_kernel` takes every FULL block so and a slot's last block by its
+live pages alone, a start and a wait each (whole with its dead pages the
+walk read 8-26% slower), and a DEAD slot (no query, or a row that begins
+on the garbage page) fetches and folds nothing and writes zeros: a grid
+step, where it walked the garbage page for 0.6 us. The grouped walk
+(:func:`_grouped_block`) and the latent one take 512 tokens a turn and
+fetch every block WHOLE, dead pages too.
 
 One pass over the packed lanes folds every head (:func:`_kernel`, and
 :func:`_grouped_kernel` where query heads share key-value heads): the
@@ -61,7 +70,8 @@ Masking contract (bit-compatible with the XLA read,
   window, and the page walk stops at ``ceil((positions + valid_lens) /
   page_size)`` — the garbage page's content is only ever reached by
   inactive slots, whose outputs the scheduler ignores (exactly as on
-  the oracle path).
+  the oracle path; :func:`_kernel` reads it not even for them: a slot
+  whose row begins on it is written zeros).
 
 Pool layout: ``(pages + 1, layers, page_size, heads * d_head)`` — heads
 PACKED in the minor dimension, so one page of one layer is a contiguous
@@ -72,14 +82,16 @@ d_head)`` minor pair is refused by the chip's compiler at d_head 64:
 64" — and padded to 128 lanes in HBM.)
 
 The grid is one step a slot, in order (a slot's first block is fetched
-during the slot before it, so the axis is ``arbitrary``, not
-``parallel``); the page tables, positions and valid lengths ride
-``PrefetchScalarGridSpec`` scalar prefetch so the DMA source indices, of
-this slot and the next, are known before the body runs (the grouped
-walk's table comes a slot's ROW and the next slot's at a time, two
-blocks of one array in scalar memory: 128 x 2,048 entries are all of
-it). Off-TPU the kernels run under the Pallas interpreter, the
-numerics-pinning vehicle for tier-1/dryrun, not a serving configuration
+during the slot before it, in :func:`_kernel` the live slot before it,
+so the axis is ``arbitrary``, not ``parallel``); the page tables,
+positions and valid lengths ride ``PrefetchScalarGridSpec`` scalar
+prefetch so the DMA source indices, of this slot and the next, are known
+before the body runs (:func:`_kernel`'s and the latent walk's table flat,
+a start's entry a base plus a constant; the grouped walk's a slot's ROW
+and the next slot's at a time, two blocks of one array in scalar memory:
+128 x 2,048 entries are all of it). Off-TPU the kernels run under the
+Pallas interpreter, the numerics-pinning vehicle for tier-1/dryrun, not
+a serving configuration
 (``paged_attention_kernel: "auto"`` keeps CPU on the XLA gather path).
 Flops are pinned to the dense math via ``pl.CostEstimate``.
 """
@@ -96,151 +108,226 @@ from .common import default_interpret, shard_kernel, split_axes
 NEG_INF = -1e30
 
 
-# VMEM the page walk's K and V block buffers may take together (two
-# pools, each double-buffered): 8 pages of 16 tokens a block at GPT-2
-# medium's 1,024 bf16 lanes, 256 KB a buffer half.
-_KV_BLOCK_VMEM_BYTES = 1 << 20
+# The garbage page (inference/paging.py GARBAGE_PAGE; a test pins the two
+# equal): a slot whose row BEGINS on it holds no page at all, which is
+# how the walk sees a slot the scheduler has not filled. The decode
+# program hands every slot its width as ``valid_lens``, the dead ones too.
+_GARBAGE_PAGE = 0
+
+# What a turn of the walk fetches, in tokens and in the bytes of its K
+# and V buffers (two pools, each double-buffered): 32 pages of 16 at GPT-2
+# medium's 1,024 bf16 lanes (4 MiB), 16 at Olmo-Hybrid's 3,840 (7.5 MiB).
+# One layer's call by pages a turn (my chip runs, PR 55, PERF.md section
+# 6; before: 8 and 2 pages, a start and a wait a live page in loops):
+# docs' shape 0.698 -> 8: 0.671, 16: 0.596, 32: 0.576 ms (87% of the live
+# pages' HBM time); evals' 3.264 -> 8: 2.277, 16: 2.280, 32: 2.284 ms (91%);
+# chat's 0.061 -> 0.022 ms (57 of 64 slots dead).
+_BLOCK_TOKENS = 512
+_KV_BLOCK_VMEM_BYTES = 8 << 20
 
 
 def _pages_per_block(max_pages, page_size, packed, itemsize):
-    """Pages one turn of the walk fetches and folds: as many as the
-    block buffers' VMEM budget holds, and no more than a row has."""
-    page_bytes = page_size * packed * itemsize
-    return max(1, min(max_pages, _KV_BLOCK_VMEM_BYTES // (4 * page_bytes)))
+    """Pages one turn of the walk fetches and folds: ``_BLOCK_TOKENS`` of
+    them, no more than ``_KV_BLOCK_VMEM_BYTES`` holds of K and V
+    double-buffered (in whole lane tiles of tokens where one fits), and
+    no more than a row has. From static shapes."""
+    held = _KV_BLOCK_VMEM_BYTES // (4 * page_size * packed * itemsize)
+    tile = max(1, 128 // page_size)
+    return max(1, min(max_pages, _BLOCK_TOKENS // page_size,
+                      held // tile * tile or held))
 
 
-def _kernel(pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref, v_pool_ref,
-            o_ref, k_buf, v_buf, k_sem, v_sem, half_ref, *, layer_idx,
-            page_size, num_heads, d_head, sm_scale, seq, block):
+def _kernel(layer_ref, pt_ref, pos_ref, vlen_ref, q_ref, k_pool_ref,
+            v_pool_ref, o_ref, k_buf, v_buf, k_sem, v_sem, half_ref, *,
+            page_size, num_heads, d_head, sm_scale, seq, block, max_pages):
     """One slot's page-table walk, ``block`` pages and every head a
     loop turn. Refs:
 
-    pt_ref (b, max_pages) / pos_ref (b,) / vlen_ref (b,): SMEM scalar
-    prefetch; q_ref (1, s, h*dh) VMEM block; k/v_pool_ref the whole
-    paged pools (pages+1, L, page_size, h*dh) left in HBM; o_ref
-    (1, s, h*dh) fp32; k/v_buf (2, block * page_size, h*dh) double
-    buffers, one DMA semaphore a half; half_ref (1,) SMEM: the buffer
-    half that holds this slot's first block.
+    layer_ref (1,): the pools' layer / pt_ref (b * max_pages,): the
+    table's rows end to end (a start's entry is a base plus a constant)
+    / pos_ref (b,) / vlen_ref (b,): SMEM scalar prefetch; q_ref (1, s,
+    h*dh) VMEM block; k/v_pool_ref the whole paged pools (pages+1, L,
+    page_size, h*dh) left in HBM; o_ref (1, s, h*dh) fp32; k/v_buf (2,
+    block * page_size, h*dh) double buffers, one DMA semaphore a half;
+    half_ref (1,) SMEM: the buffer half that holds the next live slot's
+    first block.
 
-    The scratch outlives a grid step and the grid is sequential, so
-    the copies of slot ``i + 1``'s first block start during slot
-    ``i``'s last (slot 0 starts its own; the last slot starts none).
+    A FULL block (every page live: all but a slot's last) is fetched by
+    straight-line starts and awaited by ONE wait a pool on a descriptor
+    of the whole buffer half; a slot's last block by its live pages only,
+    a start and a wait each in loops (whole with its dead pages it read
+    8-26% slower at the cells' shapes, PERF.md section 6, PR 55). A DEAD
+    slot (no query, or a row that begins on the garbage page) fetches and
+    folds nothing and writes zeros: it costs a grid step.
+
+    The scratch outlives a grid step and the grid is sequential, so the
+    copies of the NEXT LIVE slot's first block start during a slot's
+    last, across the dead slots between them (the first grid step starts
+    the first live slot's; the last live slot starts none).
     """
     # Index arithmetic is on non-negative ints, so ``lax.div`` / ``rem``
     # stand for ``//`` / ``%``: those lower through ``sign``, 4 s of a
     # 24-layer decode program's lowering on every start (PERF.md, PR 33).
     i = pl.program_id(0)
     num_slots = pl.num_programs(0)
-    max_pages = pt_ref.shape[1]
+    layer_idx = layer_ref[0]
     rows, lanes = seq * num_heads, num_heads * d_head
     tokens = block * page_size
+    pools = ((k_pool_ref, k_buf, k_sem), (v_pool_ref, v_buf, v_sem))
+
+    def dead(slot):
+        return jnp.logical_or(vlen_ref[slot] == 0,
+                              pt_ref[slot * max_pages] == _GARBAGE_PAGE)
+
+    def live_from(slot):
+        # the first slot from ``slot`` on that has a walk (num_slots: none)
+        return jax.lax.while_loop(
+            lambda s: jnp.logical_and(
+                s < num_slots, dead(jnp.minimum(s, num_slots - 1))),
+            lambda s: s + 1, slot)
 
     def pages_of(slot):
-        # ceil((positions + valid_lens) / page_size), at least the one
-        # page an empty slot's table redirects to the garbage page
+        # ceil((positions + valid_lens) / page_size)
         live = pos_ref[slot] + vlen_ref[slot] - 1
         return jnp.minimum(
             jax.lax.div(jnp.maximum(live, 0), page_size) + 1, max_pages)
 
-    def transfer(slot, c, half, start):
-        # a block's last pages may lie past the live window: no copy,
-        # and what the buffer holds there is masked below
-        first = c * block
+    def live_pages(slot, c):
+        # of block ``c`` of the slot's row; a block the walk takes has one
+        return jnp.minimum(pages_of(slot) - c * block, block)
+
+    def fetch(slot, c, half, rolled=False):
+        first = slot * max_pages + c * block   # an entry: first + a constant
+        n = live_pages(slot, c)
 
         def page(j, carry):
-            phys = pt_ref[slot, first + j]
+            phys = pt_ref[first + j]
             dst = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
-            for pool, buf, sem in ((k_pool_ref, k_buf, k_sem),
-                                   (v_pool_ref, v_buf, v_sem)):
-                copy = pltpu.make_async_copy(
-                    pool.at[phys, layer_idx], buf.at[half, dst],
-                    sem.at[half])
-                copy.start() if start else copy.wait()
+            for pool, buf, sem in pools:
+                pltpu.make_async_copy(pool.at[phys, layer_idx],
+                                      buf.at[half, dst], sem.at[half]).start()
             return carry
 
-        jax.lax.fori_loop(0, jnp.clip(pages_of(slot) - first, 0, block),
-                          page, 0)
+        if rolled:
+            return jax.lax.fori_loop(0, n, page, 0)
+
+        @pl.when(n == block)
+        def _whole():
+            jax.lax.fori_loop(0, block, page, 0, unroll=True)  # traced once
+
+        @pl.when(n < block)
+        def _by_page():
+            jax.lax.fori_loop(0, n, page, 0)
+
+    def await_block(n, half):
+        def wait(pages):
+            for _, buf, sem in pools:
+                rows_of = buf.at[half, pl.ds(0, pages * page_size)]
+                pltpu.make_async_copy(rows_of, rows_of, sem.at[half]).wait()
+
+        pl.when(n == block)(lambda: wait(block))    # the whole block
+
+        @pl.when(n < block)
+        def _by_page():
+            jax.lax.fori_loop(0, n, lambda j, carry: (wait(1), carry)[1], 0)
 
     @pl.when(i == 0)
     def _first_slot():
         half_ref[0] = 0
-        transfer(0, 0, 0, True)
+        slot = live_from(0)
 
-    pos = pos_ref[i]
-    live = pos + vlen_ref[i] - 1           # last live absolute position
-    n_blocks = jax.lax.div(pages_of(i) + block - 1, block)
-    first_half = half_ref[0]
+        @pl.when(slot < num_slots)
+        def _its_first_block():
+            fetch(slot, 0, 0, rolled=True)     # once a call
 
-    # the slot's queries block-diagonal over the packed lanes: row
-    # (query, head) holds that head's d_head lanes of the query and
-    # zeros elsewhere, so ONE matmul over all h*dh lanes scores every
-    # head against a block of keys
-    row = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
-    row_query = jax.lax.div(row, num_heads)
-    own_lanes = jax.lax.div(lane, d_head) == jax.lax.rem(row, num_heads)
-    q = q_ref[0].astype(jnp.float32)                      # (s, h*dh)
-    q_rows = q[0:1]
-    for j in range(1, seq):
-        q_rows = jnp.where(row_query == j, q[j:j + 1], q_rows)
-    q_bd = jnp.where(own_lanes, q_rows, 0.0).astype(k_buf.dtype)
+    is_dead = dead(i)
 
-    q_pos = pos + jax.lax.div(
-        jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 0), num_heads)
-    col = jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 1)
-    token = jax.lax.broadcasted_iota(jnp.int32, (tokens, 1), 0)
+    @pl.when(is_dead)
+    def _dead_slot():
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    def body(c, carry):
-        acc, m, l = carry              # (rows, h*dh), (rows, 1) x 2 fp32
-        half = jax.lax.rem(first_half + c, 2)
+    @pl.when(jnp.logical_not(is_dead))
+    def _walk():
+        pos = pos_ref[i]
+        live = pos + vlen_ref[i] - 1       # last live absolute position
+        n_blocks = jax.lax.div(pages_of(i) + block - 1, block)
+        first_half = half_ref[0]
+        nxt_live = live_from(i + 1)
 
-        # next in flight while this block is on the MXU: this slot's
-        # next block, or after its last the next slot's first
-        last = c + 1 == n_blocks
-        nxt_slot = jnp.where(last, i + 1, i)
+        # the slot's queries block-diagonal over the packed lanes: row
+        # (query, head) holds that head's d_head lanes of the query and
+        # zeros elsewhere, so ONE matmul over all h*dh lanes scores every
+        # head against a block of keys
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
+        row_query = jax.lax.div(row, num_heads)
+        own_lanes = jax.lax.div(lane, d_head) == jax.lax.rem(row, num_heads)
+        q = q_ref[0].astype(jnp.float32)                      # (s, h*dh)
+        q_rows = q[0:1]
+        for j in range(1, seq):
+            q_rows = jnp.where(row_query == j, q[j:j + 1], q_rows)
+        q_bd = jnp.where(own_lanes, q_rows, 0.0).astype(k_buf.dtype)
 
-        @pl.when(nxt_slot < num_slots)
-        def _prefetch():
-            transfer(nxt_slot, jnp.where(last, 0, c + 1), 1 - half, True)
+        q_pos = pos + jax.lax.div(jax.lax.broadcasted_iota(
+            jnp.int32, (rows, tokens), 0), num_heads)
+        col = jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 1)
+        token = jax.lax.broadcasted_iota(jnp.int32, (tokens, 1), 0)
 
-        transfer(i, c, half, False)
+        def body(c, carry):
+            acc, m, l = carry          # (rows, h*dh), (rows, 1) x 2 fp32
+            half = jax.lax.rem(first_half + c, 2)
 
-        # only a slot's last block reaches past its live window: zero
-        # V there in place, before it meets a weight
-        @pl.when(last)
-        def _zero_dead_values():
-            v_blk = v_buf[half]
-            v_buf[half] = jnp.where(c * tokens + token <= live, v_blk,
-                                    jnp.zeros_like(v_blk))
+            # next in flight while this block is on the MXU: this slot's
+            # next block, or after its last the next live slot's first
+            last = c + 1 == n_blocks
+            nxt_slot = jnp.where(last, nxt_live, i)
 
-        scores = jax.lax.dot_general(
-            q_bd, k_buf[half], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # (rows, tokens)
-        k_pos = c * tokens + col
-        scores = jnp.where(
-            jnp.logical_and(k_pos <= q_pos, k_pos <= live), scores, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
-        pexp = jnp.exp(scores - m_new)
-        corr = jnp.exp(m - m_new)
-        # every head's weights over ALL h*dh value lanes (h times the
-        # useful flops, every operand lane-dense); a row's own head's
-        # lanes are picked out after the walk
-        acc = acc * corr + jax.lax.dot_general(
-            pexp.astype(v_buf.dtype), v_buf[half], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return acc, m_new, l * corr + jnp.sum(pexp, axis=-1, keepdims=True)
+            @pl.when(nxt_slot < num_slots)
+            def _prefetch():
+                fetch(nxt_slot, jnp.where(last, 0, c + 1), 1 - half)
 
-    init = (jnp.zeros((rows, lanes), jnp.float32),
-            jnp.full((rows, 1), NEG_INF, jnp.float32),
-            jnp.zeros((rows, 1), jnp.float32))
-    # a walk has a block at least, so every row's l counts a token
-    acc, _, l = jax.lax.fori_loop(0, n_blocks, body, init)
-    half_ref[0] = jax.lax.rem(first_half + n_blocks, 2)
+            await_block(live_pages(i, c), half)
 
-    out = jnp.where(own_lanes, acc / l, 0.0)
-    for j in range(seq):
-        mine = out if seq == 1 else jnp.where(row_query == j, out, 0.0)
-        o_ref[0, j:j + 1, :] = jnp.sum(mine, axis=0, keepdims=True)
+            # only a slot's last block reaches past its live window: zero
+            # V there in place, before it meets a weight (the rows of a
+            # page that was not fetched hold what an older block left)
+            @pl.when(last)
+            def _zero_dead_values():
+                v_blk = v_buf[half]
+                v_buf[half] = jnp.where(c * tokens + token <= live, v_blk,
+                                        jnp.zeros_like(v_blk))
+
+            scores = jax.lax.dot_general(
+                q_bd, k_buf[half], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            k_pos = c * tokens + col                       # (rows, tokens)
+            scores = jnp.where(
+                jnp.logical_and(k_pos <= q_pos, k_pos <= live), scores,
+                NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+            pexp = jnp.exp(scores - m_new)
+            corr = jnp.exp(m - m_new)
+            # every head's weights over ALL h*dh value lanes (h times the
+            # useful flops, every operand lane-dense); a row's own head's
+            # lanes are picked out after the walk
+            acc = acc * corr + jax.lax.dot_general(
+                pexp.astype(v_buf.dtype), v_buf[half],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            return acc, m_new, l * corr + jnp.sum(pexp, axis=-1,
+                                                  keepdims=True)
+
+        init = (jnp.zeros((rows, lanes), jnp.float32),
+                jnp.full((rows, 1), NEG_INF, jnp.float32),
+                jnp.zeros((rows, 1), jnp.float32))
+        # a live slot's walk has a block at least, and position 0 is a
+        # key every query sees: every row's l counts a token
+        acc, _, l = jax.lax.fori_loop(0, n_blocks, body, init)
+        half_ref[0] = jax.lax.rem(first_half + n_blocks, 2)
+
+        out = jnp.where(own_lanes, acc / l, 0.0)
+        for j in range(seq):
+            mine = out if seq == 1 else jnp.where(row_query == j, out, 0.0)
+            o_ref[0, j:j + 1, :] = jnp.sum(mine, axis=0, keepdims=True)
 
 
 # Tokens a turn of the grouped walk fetches and folds: of 128 / 256 / 512
@@ -679,12 +766,32 @@ def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
         raise ValueError(
             "paged_attention wants pools (pages+1, layers, page_size {}, "
             "heads*d_head {}), got {}".format(page_size, hd, k_pool.shape))
+    block = _pages_per_block(page_tables.shape[1], page_size, hd,
+                             k_pool.dtype.itemsize)
+    return _walk(q, k_pool, v_pool, page_tables.astype(jnp.int32),
+                 positions.astype(jnp.int32), valid_lens.astype(jnp.int32),
+                 jnp.full((1,), layer_idx, jnp.int32), page_size=page_size,
+                 block=block, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("page_size", "block",
+                                             "interpret"))
+def _walk(q, k_pool, v_pool, page_tables, positions, valid_lens, layer, *,
+          page_size, block, interpret):
+    """:func:`_kernel` over ``block`` pages a turn. The layer rides in
+    scalar memory and the call is a jitted function, so a program's
+    layers share ONE traced and lowered kernel (as ``kv_page_write``'s
+    do): GPT-2's decode program holds 24 calls, and with the layer static
+    each was traced and lowered on its own, 7 s of every start with the
+    parent's kernel and 11 s with a block's 64 unrolled starts (my chip
+    runs, PR 55)."""
+    b, s, h, dh = q.shape
+    hd = h * dh
     max_pages = page_tables.shape[1]
     full_window = max_pages * page_size
-    block = _pages_per_block(max_pages, page_size, hd,
-                             k_pool.dtype.itemsize)
+    itemsize = k_pool.dtype.itemsize
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, s, hd), lambda i, *_: (i, 0, 0)),
@@ -700,16 +807,16 @@ def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
             pltpu.SMEM((1,), jnp.int32),
         ])
     kernel = functools.partial(
-        _kernel, layer_idx=layer_idx, page_size=page_size, num_heads=h,
-        d_head=dh, sm_scale=1.0 / math.sqrt(dh), seq=s, block=block)
+        _kernel, page_size=page_size, num_heads=h, d_head=dh,
+        sm_scale=1.0 / math.sqrt(dh), seq=s, block=block,
+        max_pages=max_pages)
     # flops pinned to the dense math over the full logical window (qk^T
     # + p@v), the same count the XLA gather path's dots report — keeps
     # the cost-analysis pricing seam (telemetry/programs.py) honest.
     cost = pl.CostEstimate(
         flops=4 * b * s * full_window * hd,
         bytes_accessed=(q.size * q.dtype.itemsize
-                        + 2 * b * full_window * hd
-                        * k_pool.dtype.itemsize
+                        + 2 * b * full_window * hd * itemsize
                         + b * s * hd * 4),
         transcendentals=b * s * full_window * h)
     out = pl.pallas_call(
@@ -718,10 +825,13 @@ def paged_attention(q, k_pool, v_pool, page_tables, positions, valid_lens,
         out_shape=jax.ShapeDtypeStruct((b, s, hd), jnp.float32),
         cost_estimate=cost,
         interpret=interpret,
-        # a slot's first block is fetched during the slot before it
+        # a slot's first block is fetched during the live slot before it;
+        # the buffers' bytes twice over, beside the 16 MiB a call has
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=(16 << 20)
+            + 8 * block * page_size * hd * itemsize),
         name="paged_attention",
-    )(page_tables.astype(jnp.int32), positions.astype(jnp.int32),
-      valid_lens.astype(jnp.int32), q.reshape(b, s, hd), k_pool, v_pool)
+    )(layer, page_tables.reshape(-1), positions, valid_lens,
+      q.reshape(b, s, hd), k_pool, v_pool)
     return out.reshape(b, s, h, dh)

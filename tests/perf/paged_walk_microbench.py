@@ -1,11 +1,11 @@
-"""Device time of the grouped page walk (``paged_attention_grouped``,
-ops/pallas/paged_attention.py) and of the latent one (``mla_decode``)
-for one layer's decode step at the shapes of the five cells that run
-them, ONE chip, bfloat16 pools, read from a profiler trace (not a host
-clock). Needs a TPU.
+"""Device time of the three page walks of ops/pallas/paged_attention.py
+(``paged_attention``, ``paged_attention_grouped``, ``mla_decode``) for one
+layer's decode step at the shapes of the cells that run them, ONE chip,
+bfloat16 pools, read from a profiler trace (not a host clock). Needs a
+TPU.
 
     chiprun -- python tests/perf/paged_walk_microbench.py \
-        [--shapes ide_full,ide_window,extract,rollouts,rag_full,rag_window,reasoning] \
+        [--shapes docs,chat,evals,ide_full,ide_window,extract,rollouts,rag_full,rag_window,reasoning] \
         [--blocks 0,8,16,32,64] \
         [--module label=path/to/paged_attention.py[@chunk=16]]
 
@@ -30,6 +30,18 @@ brings that lie past a slot's live ones (``@live_only=1`` after a
 ``--module``'s path: that kernel fetches live pages only, the parent's
 before PR 47). ``--blocks`` and ``@block=`` set the module's
 ``_MLA_BLOCK_TOKENS`` there: the block follows from it.
+
+The shapes ``docs``, ``chat`` and ``evals`` are those of the walk for
+pools with a key-value head a query head (``_kernel``, the
+``%paged_attention`` events): GPT-2 medium's 16 heads of 64 over 1,024
+lanes in the two GPT-2 cells, Olmo-Hybrid's 30 of 128 over 3,840. Chat's
+57 dead slots come as the decode program hands them: position 0, ONE
+query (``valid_lens`` is the launch's width for every slot) and a row of
+the garbage page. ``--blocks`` and ``@block=`` set the module's
+``_BLOCK_TOKENS`` there (a file without it, the parent's before PR 55,
+takes no block); ``@walks_dead=1`` says that the file's kernel walks a
+dead slot's garbage page, which is all a walk that fetches a slot's live
+pages only can have in ``dead_fetched_share``.
 """
 import argparse
 import importlib
@@ -58,6 +70,15 @@ SHAPES = {
     # a key-value head, a decode query 128 rows over 1,024 packed lanes
     "rag_full": (40, 128, 8, 128, 2048, None, (2300, 25000)),
     "rag_window": (40, 128, 8, 128, 385, 4096, (4096, 4112)),
+}
+# cell: slots, heads, d_head, table columns, live slots, (fewest, most)
+# live tokens of a live slot: the walk of ``_kernel``
+FULL = {
+    # gpt2-350m-serve-batch.docs, gpt2-350m-serve.chat
+    "docs": (128, 16, 64, 64, 128, (528, 1008)),
+    "chat": (64, 16, 64, 48, 7, (32, 768)),
+    # olmo-hybrid-7b-serve.evals: 4 full layers of 30 heads of 128
+    "evals": (64, 30, 128, 192, 64, (160, 3072)),
 }
 # moonlight-16b-a3b-serve.reasoning: slots, heads, a row's lanes, the
 # lanes that are its value, its useful lanes, table columns, layers in
@@ -151,9 +172,63 @@ def latent(name, kernels, rng):
         print(json.dumps(line), flush=True)
 
 
+def full(name, kernels, rng):
+    """One line a kernel and block for ``_kernel``'s walk at ``name``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    b, h, dh, columns, alive, (low, high) = FULL[name]
+    live = np.zeros(b, np.int64)
+    live[rng.permutation(b)[:alive]] = rng.integers(low, high, alive)
+    pages = -(-live // PAGE)
+    table, total = table_of(rng, pages, columns)
+    shape = (total + 1, 2, PAGE, h * dh)
+    k_pool, v_pool = (jax.random.normal(jax.random.PRNGKey(i), shape,
+                                        jnp.bfloat16) for i in (1, 2))
+    q = jax.random.normal(jax.random.PRNGKey(3), (b, 1, h, dh), jnp.bfloat16)
+    args = (q, k_pool, v_pool, jnp.asarray(table),
+            jnp.asarray(np.maximum(live - 1, 0), jnp.int32),
+            jnp.ones((b,), jnp.int32))
+    floor_s = total * 2 * PAGE * h * dh * 2 / PEAK_BYTES_PER_S
+    own = {id(m): getattr(m, "_BLOCK_TOKENS", None) for _, m, _ in kernels}
+    first = None
+    for label, module, more in kernels:
+        more = dict(more)
+        walks_dead = more.pop("walks_dead", 0)
+        line = dict(shape=name, kernel=label, pages_read=total,
+                    live_slots=alive, **more)
+        try:
+            if own[id(module)] is not None:
+                module._BLOCK_TOKENS = more.get("block", 0) * PAGE \
+                    or own[id(module)]
+            elif "block" in more:
+                raise ValueError("this file's walk takes no block")
+            fetched = total + (b - alive) * walks_dead
+            line.update(
+                pages_a_turn=module._pages_per_block(columns, PAGE, h * dh,
+                                                     2),
+                dead_fetched_share=round(1 - total / fetched, 4))
+            fn = jax.jit(lambda *a: module.paged_attention(
+                *a, layer_idx=1, page_size=PAGE, interpret=False))
+            out, walk_ms, call_ms = traced(fn, args, "paged_attention")
+        except Exception as e:  # noqa: BLE001 - a refused block
+            line["error"] = str(e)[-300:]
+            print(json.dumps(line), flush=True)
+            continue
+        # a dead slot's row is read by nobody: compare the live ones
+        out = out[live > 0]
+        first = out if first is None else first
+        line.update(
+            kernel_ms=walk_ms, call_ms=call_ms,
+            hbm_roofline_share=round(floor_s / (walk_ms * 1e-3), 4),
+            max_abs_diff=float(jnp.max(jnp.abs(out - first))),
+            device=jax.devices()[0].device_kind)
+        print(json.dumps(line), flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--shapes", default=",".join([*SHAPES, *LATENT]))
+    ap.add_argument("--shapes", default=",".join([*FULL, *SHAPES, *LATENT]))
     ap.add_argument("--blocks", default="0")
     ap.add_argument("--module", action="append", default=[])
     ns = ap.parse_args()
@@ -179,6 +254,9 @@ def main():
     for name in ns.shapes.split(","):
         if name in LATENT:
             latent(name, kernels, rng)
+            continue
+        if name in FULL:
+            full(name, kernels, rng)
             continue
         b, h, kvh, dh, columns, window, (low, high) = SHAPES[name]
         live = rng.integers(low, high, b)

@@ -142,12 +142,19 @@ def test_paged_attention_padded_valid_lens_stay_clean():
                                    rtol=1e-5)
 
 
-# The block walk: h 8 x dh 64 = 512 packed lanes and pages of 16 tokens
-# make a page 32 KB in float32, so the kernel's VMEM budget gives 8
-# pages (128 tokens) a block and a row of 20 pages is walked as 8, 8, 4
-# (a bf16 pool's pages are half that: 16 a block, walked as 16, 4).
+# The block walk: h 8 x dh 64 = 512 packed lanes and pages of 16 tokens;
+# the kernel takes _BLOCK_TOKENS (512: 32 pages) a block whatever the
+# pool's dtype, so a row of 20 pages is ONE block, never whole unless the
+# slot fills the row, a row of 40 is walked as 32, 8 and one of 72 as 32,
+# 32, 8. A full block comes whole behind one wait, a slot's last by its
+# live pages, a dead slot (no query, or a row of the garbage page)
+# fetches nothing and writes zeros.
 _WALK_H, _WALK_DH, _WALK_PS = 8, 64, 16
-# name: ((position, valid_len) per slot, s, max_pages, pool dtype)
+# a slot the scheduler has not filled, as the DECODE program hands it:
+# position 0, the launch's width of queries, a row of the garbage page
+_UNFILLED = (0, 1, "unfilled")
+# name: ((position, valid_len) per slot, s, max_pages, pool dtype[,
+# (heads, d_head)])
 _WALK_CASES = {
     "ends_in_first_page_of_second_block":
         ([(131, 1), (140, 1)], 1, 20, np.float32),
@@ -175,6 +182,41 @@ _WALK_CASES = {
         ([(296, 1), (127, 1), (0, 0), (31, 1)], 1, 20, jnp.bfloat16),
     "bf16_pool_verify_width":
         ([(126, 3), (250, 5), (14, 1)], 5, 20, jnp.bfloat16),
+    # the prefetch is handed ACROSS dead slots: first, last, between
+    "dead_slots_first":
+        ([(0, 0), (0, 0), (600, 1), (17, 1)], 1, 40, np.float32),
+    "dead_slots_first_last_and_between":
+        ([(0, 0), (530, 1), (0, 0), _UNFILLED, (511, 1), (40, 1), (0, 0)],
+         1, 40, np.float32),
+    "unfilled_slots_of_a_decode_launch":
+        ([_UNFILLED, (700, 1), _UNFILLED, _UNFILLED, (90, 1), _UNFILLED],
+         1, 48, np.float32),
+    "unfilled_slots_of_a_verify_launch":
+        ([(508, 5), (0, 5, "unfilled"), (30, 2), (0, 5, "unfilled")],
+         5, 40, np.float32),
+    "every_slot_dead":
+        ([(0, 0), _UNFILLED, (0, 0)], 1, 20, np.float32),
+    "one_live_slot_alone":
+        ([(1000, 1)], 1, 72, np.float32),
+    # 512 tokens a block: the last live position 511 ends the first
+    # block, 512 opens the second, 1,023 / 1,024 likewise a block on
+    "ends_on_a_block_edge_and_a_token_past_it":
+        ([(511, 1), (512, 1), (1023, 1), (1024, 1)], 1, 72, np.float32),
+    "verify_width_across_a_block_edge":
+        ([(510, 5), (1020, 5), (507, 5)], 5, 72, np.float32),
+    "one_page_beside_a_full_row":
+        ([(3, 1), (1151, 1), (15, 1), (1151, 1)], 1, 72, np.float32),
+    "full_rows_not_a_multiple_of_the_block":
+        ([(639, 1), (639, 1)], 1, 40, np.float32),
+    # slot 0's last page leaves NaN rows 131.. in a buffer half that slot
+    # 2's shorter block does not overwrite
+    "stale_nan_rows_left_in_a_buffer_half":
+        ([(130, 1), (20, 1), (99, 1)], 1, 20, np.float32),
+    # Olmo-Hybrid's full layers: 30 heads of 128 over 3,840 lanes, where
+    # the buffers' bytes give 8 float32 pages a block
+    "30_heads_over_3840_lanes":
+        ([(300, 1), (127, 1), _UNFILLED, (128, 1)], 1, 24, np.float32,
+         (30, 128)),
 }
 # A bf16 pool against the float32 oracle: the weights enter the second
 # matmul rounded to bf16, as the one-page float32 kernel's did on the
@@ -185,28 +227,35 @@ _WALK_CASES = {
 _WALK_BF16_ATOL = {"bf16_pool": 8.56e-4, "bf16_pool_verify_width": 1.7308e-3}
 
 
-def _walk_setup(slots, s, max_pages, dtype, seed=0):
+def _walk_setup(slots, s, max_pages, dtype, seed=0, heads=(_WALK_H, _WALK_DH)):
     """A pool where everything a slot must not see is NaN: the garbage
     page, every page no slot owns, and the tail of a slot's last page
     past its live window (a recycled page's stale content). Pages are
     dealt out of order, so a walk that trusts anything but the table
-    fails."""
+    fails; what a live slot's row holds PAST its pages names poisoned
+    pages no slot owns (a recycled row's stale entries), an unfilled
+    slot's row the garbage page."""
     rng = np.random.RandomState(seed)
-    b, h, dh, ps = len(slots), _WALK_H, _WALK_DH, _WALK_PS
-    owned = [-(-(pos + n) // ps) for pos, n in slots]
+    b, (h, dh), ps = len(slots), heads, _WALK_PS
+    owned = [0 if len(slot) == 3 else -(-(slot[0] + slot[1]) // ps)
+             for slot in slots]
     pages = sum(owned) + 3
     k_pool = np.full((pages + 1, 2, ps, h * dh), np.nan, np.float32)
     v_pool = np.full((pages + 1, 2, ps, h * dh), np.nan, np.float32)
     free = list(rng.permutation(np.arange(1, pages + 1)))
     page_tables = np.zeros((b, max_pages), np.int32)
-    for i, (pos, n) in enumerate(slots):
+    for i, (pos, n, *_) in enumerate(slots):
         for j in range(owned[i]):
             page = page_tables[i, j] = free.pop()
             live = min(ps, pos + n - j * ps)
             k_pool[page, :, :live] = rng.randn(2, live, h * dh)
             v_pool[page, :, :live] = rng.randn(2, live, h * dh)
+    for i in range(b):
+        if owned[i]:
+            page_tables[i, owned[i]:] = free[i % 3]
     q = rng.randn(b, s, h, dh).astype(np.float32)
-    positions, valid_lens = np.array(slots, np.int32).reshape(b, 2).T
+    positions, valid_lens = np.array(
+        [slot[:2] for slot in slots], np.int32).reshape(b, 2).T
     return (jnp.asarray(q, dtype), jnp.asarray(k_pool, dtype),
             jnp.asarray(v_pool, dtype), jnp.asarray(page_tables),
             jnp.asarray(positions), jnp.asarray(valid_lens))
@@ -215,11 +264,14 @@ def _walk_setup(slots, s, max_pages, dtype, seed=0):
 @pytest.mark.parametrize("case", sorted(_WALK_CASES))
 def test_paged_attention_block_walk(case):
     from deepspeed_tpu.ops.pallas.paged_attention import _pages_per_block
-    slots, s, max_pages, dtype = _WALK_CASES[case]
+    slots, s, max_pages, dtype, *heads = _WALK_CASES[case]
+    h, dh = heads[0] if heads else (_WALK_H, _WALK_DH)
     ps, itemsize = _WALK_PS, jnp.dtype(dtype).itemsize
-    assert _pages_per_block(max_pages, ps, _WALK_H * _WALK_DH, itemsize) \
-        == min(32 // itemsize, max_pages)
-    q, kp, vp, pt, pos, vl = _walk_setup(slots, s, max_pages, dtype)
+    # 512 tokens, or what 8 MiB hold of K and V double-buffered
+    assert _pages_per_block(max_pages, ps, h * dh, itemsize) == min(
+        max_pages, 8 if h * dh * itemsize > 4096 else 32)
+    q, kp, vp, pt, pos, vl = _walk_setup(slots, s, max_pages, dtype,
+                                         heads=(h, dh))
     got = np.asarray(paged_attention(q, kp, vp, pt, pos, vl, layer_idx=1,
                                      page_size=ps))
     # no NaN of a dead page, a dead tail or a dead slot reaches a row
@@ -228,9 +280,19 @@ def test_paged_attention_block_walk(case):
     want = np.asarray(_gather_oracle(*f32, pt, pos, vl, ps, max_pages, 1))
     atol, rtol = (1e-5, 1e-5) if dtype == np.float32 else \
         (_WALK_BF16_ATOL[case], 0.0)
-    for i, (_, n) in enumerate(slots):
+    for i, (_, n, *unfilled) in enumerate(slots):
+        if unfilled or n == 0:
+            assert not got[i].any()            # a dead slot writes zeros
+            continue
         np.testing.assert_allclose(got[i, :n], want[i, :n], atol=atol,
                                    rtol=rtol)
+
+
+def test_paged_attention_knows_the_garbage_page():
+    """The walk sees an unfilled slot by the page its row begins on."""
+    from deepspeed_tpu.inference.paging import GARBAGE_PAGE
+    from deepspeed_tpu.ops.pallas.paged_attention import _GARBAGE_PAGE
+    assert _GARBAGE_PAGE == GARBAGE_PAGE
 
 
 def test_paged_attn_ctx_dispatch_parity_and_shared_writes():

@@ -172,9 +172,13 @@ def test_paged_attention_compiles_at_d_head_64(one_chip,
                     ((b, 64), I32), ((b,), I32), ((b,), I32)) == 1
 
 
-# (slots, pages a row, pages + 1) of the two GPT-2 serving cells' decode
-# programs: 64 slots x 768 tokens, 128 slots x 1008 tokens, pages of 16
-_DECODE_CELLS = {"chat": (64, 48, 3073), "docs": (128, 63, 8501)}
+# (slots, pages a row, pages + 1, layers, heads, d_head, pages a block)
+# of the decode programs that run ``_kernel``: the two GPT-2 serving
+# cells' (64 slots x 768 tokens, 128 slots x 1008 tokens, pages of 16)
+# and Olmo-Hybrid's four full layers (64 slots x 3,072 tokens)
+_DECODE_CELLS = {"chat": (64, 48, 3073, 24, 16, 64, 32),
+                 "docs": (128, 63, 8501, 24, 16, 64, 32),
+                 "evals": (64, 192, 4001, 4, 30, 128, 16)}
 
 
 @pytest.mark.parametrize("width", [1, 5], ids=["decode", "spec_verify"])
@@ -182,15 +186,18 @@ _DECODE_CELLS = {"chat": (64, 48, 3073), "docs": (128, 63, 8501)}
 def test_paged_attention_block_walk_compiles_at_the_cells_shapes(
         one_chip, no_persistent_cache, cell, width):
     """The block walk at the shapes the benchmark runs it at: 16 heads
-    of 64 packed in 1,024 bf16 lanes, 24 layers, 8 pages (128 tokens) a
-    block; a row of 63 pages is not a multiple of the block."""
+    of 64 packed in 1,024 bf16 lanes, 24 layers, 32 pages (512 tokens) a
+    block, a row of 63 pages not a multiple of it; 30 heads of 128 in
+    3,840 lanes, 16 pages a block (7.5 MiB of K and V buffers: the call
+    asks for its ``vmem_limit_bytes``)."""
     from deepspeed_tpu.ops.pallas.paged_attention import (
         _pages_per_block, paged_attention)
-    (b, row, pages), (h, dh, ps) = _DECODE_CELLS[cell], (16, 64, 16)
-    assert _pages_per_block(row, ps, h * dh, 2) == 8
-    pool = ((pages, 24, ps, h * dh), BF16)
-    fn = functools.partial(paged_attention, layer_idx=23, page_size=ps,
-                           interpret=False)
+    b, row, pages, layers, h, dh, block = _DECODE_CELLS[cell]
+    ps = 16
+    assert _pages_per_block(row, ps, h * dh, 2) == block
+    pool = ((pages, layers, ps, h * dh), BF16)
+    fn = functools.partial(paged_attention, layer_idx=layers - 1,
+                           page_size=ps, interpret=False)
     assert _compile(fn, one_chip, ((b, width, h, dh), BF16), pool, pool,
                     ((b, row), I32), ((b,), I32), ((b,), I32)) == 1
 
@@ -304,20 +311,22 @@ def test_paged_attention_compiles_on_a_tensor_parallel_mesh(
 
 
 @pytest.mark.parametrize("width", [1, 5], ids=["decode", "spec_verify"])
-@pytest.mark.parametrize("cell", sorted(_DECODE_CELLS))
+@pytest.mark.parametrize("cell", ["chat", "docs"])
 def test_paged_attention_block_walk_compiles_on_a_tensor_parallel_mesh(
         four_chips, no_persistent_cache, cell, width):
-    """The cells' shapes with the heads split four ways: a shard's 4
-    heads are 256 lanes, so its block is worked out anew (32 pages,
-    512 tokens) and its score rows are 4 a query."""
+    """The GPT-2 cells' shapes with the heads split four ways: a shard's
+    4 heads are 256 lanes, its block the same 32 pages (512 tokens: the
+    cap in tokens binds, not the buffers' bytes) and its score rows 4 a
+    query."""
     from deepspeed_tpu.inference.kv_cache import PAGED_KV_CACHE_SPEC
     from deepspeed_tpu.ops.pallas.paged_attention import (
         _pages_per_block, paged_attention)
-    (b, row, pages), (h, dh, ps) = _DECODE_CELLS[cell], (16, 64, 16)
+    b, row, pages, layers, h, dh, _ = _DECODE_CELLS[cell]
+    ps = 16
     assert _pages_per_block(row, ps, h * dh // 4, 2) == 32
-    fn = functools.partial(paged_attention, layer_idx=23, page_size=ps,
-                           interpret=False, mesh=four_chips)
-    pool = ((pages, 24, ps, h * dh), BF16, PAGED_KV_CACHE_SPEC)
+    fn = functools.partial(paged_attention, layer_idx=layers - 1,
+                           page_size=ps, interpret=False, mesh=four_chips)
+    pool = ((pages, layers, ps, h * dh), BF16, PAGED_KV_CACHE_SPEC)
     assert _mesh_compile(
         fn, four_chips, ((b, width, h, dh), BF16, P(None, None, "model")),
         pool, pool, ((b, row), I32, P()), ((b,), I32, P()),

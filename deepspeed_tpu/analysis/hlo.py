@@ -55,6 +55,11 @@ _PAIRS_RE = re.compile(r"source_target_pairs=\{([\d,{}\s]*)\}")
 _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 
 
+_MODULE_RE = re.compile(r"^HloModule\s+([\w.\-]+)", re.M)
+_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+_LAYOUT_RE = re.compile(r"\{[^}]*\}")
+
+
 def _element_bytes(shape_text):
     """One HLO shape (or tuple-of-shapes) -> per-element byte sizes."""
     sizes = []
@@ -235,6 +240,81 @@ def collective_census(hlo_text, axis_groups=None,
         "total_bytes": int(sum(op["wire_bytes"] for op in ops)),
         "below_threshold_bytes": int(round(below)),
     }
+
+
+def _instruction_head(line):
+    """One line of a module's text -> (instruction name, result shape
+    with its layout taken off, opcode), or None where the line defines
+    no instruction. A result is one shape or a tuple of them, and a
+    tuple may nest and carry tiled layouts (``{1,0:T(8,128)(2,1)}``),
+    so its end is found by counting brackets and not by an
+    expression."""
+    text = line.strip()
+    if text.startswith("ROOT "):
+        text = text[5:]
+    name, eq, rest = text.partition(" = ")
+    if not eq or " " in name or not rest:
+        return None
+    if rest[0] == "(":
+        depth = 0
+        for end, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        shape, rest = rest[:end + 1], rest[end + 1:]
+    else:
+        shape, _, rest = rest.partition(" ")
+    opcode = rest.lstrip().partition("(")[0]
+    if not opcode or not re.fullmatch(r"[a-z][a-z0-9\-]*", opcode):
+        return None
+    return name.lstrip("%"), _LAYOUT_RE.sub("", shape), opcode
+
+
+def instruction_table(hlo_text):
+    """-> (module name, {instruction name: (op_name, result shape,
+    opcode, called computation or None)}) for every instruction of
+    every computation of a compiled module's text
+    (``compile().as_text()``), and {computation name: [the names of
+    its instructions]}. The instructions of a fused computation are
+    among them: a fusion's own ``op_name`` is ONE of its members'
+    (``calls=`` says which computation holds them), so a fusion that
+    runs two mechanisms' operations can be told from its members'
+    names. An instruction's name is unique in its module, and a device
+    event of a profiler's trace begins with it
+    (``%fusion.12 = bf16[20,1024]{...} fusion(...)``)."""
+    found = _MODULE_RE.search(hlo_text)
+    module = found.group(1) if found else None
+    table, computations, members = {}, {}, None
+    for line in hlo_text.splitlines():
+        if line.endswith("{") and "->" in line and " = " not in line:
+            # `%fused_computation.3 (param_0: f32[8]) -> f32[8] {`
+            head = line.strip()
+            if head.startswith("ENTRY "):
+                head = head[6:]
+            members = computations.setdefault(
+                head.partition(" ")[0].lstrip("%"), [])
+            continue
+        head = _instruction_head(line)
+        if head is None:
+            continue
+        name, shape, opcode = head
+        op_name = _OP_NAME_RE.search(line)
+        calls = _CALLS_RE.search(line) if opcode == "fusion" else None
+        table[name] = (op_name.group(1) if op_name else "", shape, opcode,
+                       calls.group(1) if calls else None)
+        if members is not None:
+            members.append(name)
+    return module, table, computations
+
+
+def instruction_scopes(hlo_text):
+    """-> {instruction name: ``op_name``} for every instruction of
+    every computation of a compiled module's text, fused computations'
+    members too: the scope path each was traced under
+    (``jit(decode)/gdn.chunk/dot_general``; "" where XLA made the
+    instruction itself). docs/telemetry.md, "Device scopes"."""
+    return {name: row[0]
+            for name, row in instruction_table(hlo_text)[1].items()}
 
 
 def census_classes(census, data_labels, normalize_allreduce=False):

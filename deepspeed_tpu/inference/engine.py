@@ -41,6 +41,7 @@ from ..runtime.executor.jit import (first_call, first_call_over,
                                     jit_program)
 from ..utils.annotate import (annotate, engine_tag, setup_span,
                               startup_line, startup_report)
+from ..utils.compile_cache import program_scopes
 from ..utils.logging import logger
 from .config import DeepSpeedInferenceConfig
 from .decoder import decoder_of, refuse
@@ -101,6 +102,7 @@ class InferenceEngine:
         self.startup_tag = engine_tag("inference")
         self.launches = 0
         self._first_calls = []
+        self._first_operands = {}  # id(program) -> its first call's
         self.module = as_model(model)
         self.decoder = decoder_of(model, self.module)
         model_config = self.decoder.config
@@ -341,6 +343,18 @@ class InferenceEngine:
     def startup_line(self):
         return startup_line(self.startup_tag)
 
+    def program_scopes(self):
+        """Which scope each instruction of this engine's compiled
+        programs was traced under, one entry a program that has run
+        (docs/telemetry.md, "Device scopes"). Lowers and compiles (or
+        loads) each once more: seconds a program, for after a trace
+        window and not inside one; an error inside a step."""
+        if self._first_calls:
+            raise RuntimeError("program_scopes() inside a step: a "
+                               "program's first call is not over")
+        self.wait()
+        return program_scopes(self.startup_tag)
+
     def telemetry_snapshot(self):
         """Rolling serving aggregate (occupancy/queue-depth p50/p95,
         token rates) — ``{}`` when telemetry is disabled."""
@@ -522,6 +536,10 @@ class InferenceEngine:
         decoder's counters (``counter_names``) and the logits. ->
         (tokens, counters), still on the device."""
         pools = self._pools()
+        if self._first_calls:
+            # made and not called yet: what its first call is given
+            # (docs/telemetry.md, "Device scopes")
+            self._first_operands[id(fn)] = (self.params, *pools, *args)
         out = fn(self.params, *pools, *args)
         n_cache = len(pools) + len(self._state_buffers())
         self._update_cache(out[:n_cache])
@@ -632,8 +650,12 @@ class InferenceEngine:
 
     def _first_calls_over(self, discard=False):
         for opened in self._first_calls:
-            first_call_over(opened, discard=discard)
+            first_call_over(opened, discard=discard,
+                            operands=self._first_operands.pop(
+                                id(opened[-1]), None))
         self._first_calls = []
+        if not discard:
+            self._first_operands.clear()
 
     def _get_decode_fn(self, greedy, top_k, width=1):
         """The fused all-slot decode program: ``width`` new tokens per

@@ -227,6 +227,15 @@ def write_path(s, page_size):
     return "pages" if s >= page_size else "rows"
 
 
+def read_scope(s, page_size):
+    """The device scope (docs/telemetry.md, "Device scopes") of a paged
+    model's read of its keys beside that write: ``attn.prefill`` for a
+    prompt chunk, ``attn.decode`` for a decode or verify step, told
+    apart as :func:`write_path` tells them."""
+    return "attn.prefill" if write_path(s, page_size) == "pages" \
+        else "attn.decode"
+
+
 def write_tokens(pools, news, layer_idx, page_tables, positions,
                  valid_lens, page_size, mesh=None):
     """THE write of new cache rows into the paged pools: every paged
@@ -260,11 +269,12 @@ def write_tokens(pools, news, layer_idx, page_tables, positions,
     there is no page to move: a row a token, the scatter as it was.
     ``mesh``: the mesh the program spans, for the kernel's shard_map
     (the scatter follows GSPMD)."""
-    if write_path(news[0].shape[1], page_size) == "pages":
-        return _write_pages(pools, news, layer_idx, page_tables,
-                            positions, valid_lens, page_size, mesh)
-    return _write_rows(pools, news, layer_idx, page_tables, positions,
-                       valid_lens, page_size)
+    with jax.named_scope("kv.write"):
+        if write_path(news[0].shape[1], page_size) == "pages":
+            return _write_pages(pools, news, layer_idx, page_tables,
+                                positions, valid_lens, page_size, mesh)
+        return _write_rows(pools, news, layer_idx, page_tables, positions,
+                           valid_lens, page_size)
 
 
 def _write_rows(pools, news, layer_idx, page_tables, positions,

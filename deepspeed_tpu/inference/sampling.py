@@ -24,23 +24,28 @@ def make_sampler(greedy, top_k=0):
     if greedy:
         def sample(logits, rng, temperature, top_p):
             del rng, temperature, top_p
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return sample
 
     def sample(logits, rng, temperature, top_p):
-        logits = logits.astype(jnp.float32) / jnp.maximum(temperature, 1e-6)
-        if top_k and top_k > 0:
-            kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
-            logits = jnp.where(logits < kth, NEG_INF, logits)
-        order = jnp.argsort(-logits, axis=-1)
-        sorted_logits = jnp.take_along_axis(logits, order, axis=-1)
-        probs = jax.nn.softmax(sorted_logits, axis=-1)
-        # keep tokens whose cumulative mass BEFORE them is < top_p — the
-        # head token always survives, so the distribution never empties
-        cum_before = jnp.cumsum(probs, axis=-1) - probs
-        sorted_logits = jnp.where(cum_before < top_p, sorted_logits, NEG_INF)
-        idx = jax.random.categorical(rng, sorted_logits, axis=-1)
-        token = jnp.take_along_axis(order, idx[..., None], axis=-1)[..., 0]
-        return token.astype(jnp.int32)
+        with jax.named_scope("sample"):
+            logits = logits.astype(jnp.float32) / \
+                jnp.maximum(temperature, 1e-6)
+            if top_k and top_k > 0:
+                kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
+                logits = jnp.where(logits < kth, NEG_INF, logits)
+            order = jnp.argsort(-logits, axis=-1)
+            sorted_logits = jnp.take_along_axis(logits, order, axis=-1)
+            probs = jax.nn.softmax(sorted_logits, axis=-1)
+            # keep tokens whose cumulative mass BEFORE them is < top_p:
+            # the head token always survives, so the distribution never
+            # empties
+            cum_before = jnp.cumsum(probs, axis=-1) - probs
+            sorted_logits = jnp.where(cum_before < top_p, sorted_logits,
+                                      NEG_INF)
+            idx = jax.random.categorical(rng, sorted_logits, axis=-1)
+            token = jnp.take_along_axis(order, idx[..., None], axis=-1)[..., 0]
+            return token.astype(jnp.int32)
 
     return sample

@@ -419,9 +419,10 @@ def forward_hidden(params, input_ids, config, cache=None, positions=None,
 
 def logits(params, hidden, logit_scale=1.0):
     """The tied head: ``logit_scale * hidden @ embed.T``."""
-    out = jnp.einsum("...d,vd->...v", hidden,
-                     params["embed"].astype(hidden.dtype))
-    return out if logit_scale == 1.0 else out * logit_scale
+    with jax.named_scope("head"):
+        out = jnp.einsum("...d,vd->...v", hidden,
+                         params["embed"].astype(hidden.dtype))
+        return out if logit_scale == 1.0 else out * logit_scale
 
 
 def lm_loss(params, input_ids, labels, config):

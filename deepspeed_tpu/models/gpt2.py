@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..inference.kv_cache import write_tokens
+from ..inference.kv_cache import read_scope, write_tokens
 from ..parallel.topology import MODEL_AXIS
 
 
@@ -217,8 +217,9 @@ def _attn_ctx(x, block, config, train):
     paths share one copy of everything downstream of the context)."""
     b, s, d = x.shape
     h, dh = config.n_heads, config.d_head
-    qkv = _column_matmul(x, block["qkv_kernel"].astype(x.dtype), config) + \
-        block["qkv_bias"].astype(x.dtype)
+    with jax.named_scope("attn.proj"):
+        qkv = _column_matmul(x, block["qkv_kernel"].astype(x.dtype),
+                             config) + block["qkv_bias"].astype(x.dtype)
     q, k, v = jnp.split(qkv, 3, axis=-1)
     reshape = lambda t: t.reshape(b, s, h, dh)
     q, k, v = reshape(q), reshape(k), reshape(v)
@@ -253,6 +254,9 @@ def _attn_ctx(x, block, config, train):
             q, k, v, config.sp_mesh, impl=config.sequence_parallel,
             attn_fn=attn_fn)
     else:
+        # no scope around the flash kernels: an unnamed ``pallas_call``
+        # takes its trace event's name from the innermost one
+        # (docs/telemetry.md, "Device scopes")
         ctx = causal_attention(q, k, v,
                                use_flash=config.use_flash_attention,
                                backend=config.flash_attention_backend,
@@ -262,11 +266,12 @@ def _attn_ctx(x, block, config, train):
 
 def _mlp(x, block, config, rng, train):
     from ..ops.transformer.fused_ops import fused_bias_gelu
-    h = fused_bias_gelu(
-        _column_matmul(x, block["fc_kernel"].astype(x.dtype), config),
-        block["fc_bias"].astype(x.dtype))
-    out = _row_matmul(h, block["proj_kernel"].astype(x.dtype), config) + \
-        block["proj_bias"].astype(x.dtype)
+    with jax.named_scope("mlp"):
+        h = fused_bias_gelu(
+            _column_matmul(x, block["fc_kernel"].astype(x.dtype), config),
+            block["fc_bias"].astype(x.dtype))
+        out = _row_matmul(h, block["proj_kernel"].astype(x.dtype),
+                          config) + block["proj_bias"].astype(x.dtype)
     if train and config.dropout > 0.0 and rng is not None:
         keep = jax.random.bernoulli(rng, 1.0 - config.dropout, out.shape)
         out = jnp.where(keep, out / (1.0 - config.dropout), 0.0)
@@ -340,8 +345,9 @@ def _block_rest(x, ctx, block_params, config, rng, train):
     is the single biggest avoidable cost at bench shapes)."""
     r1, r2 = (None, None) if rng is None else jax.random.split(rng)
     attn = block_params["attn"]
-    out = _row_matmul(ctx, attn["proj_kernel"].astype(x.dtype), config) + \
-        attn["proj_bias"].astype(x.dtype)
+    with jax.named_scope("attn.proj"):
+        out = _row_matmul(ctx, attn["proj_kernel"].astype(x.dtype),
+                          config) + attn["proj_bias"].astype(x.dtype)
     if train and config.dropout > 0.0 and r1 is not None:
         keep = jax.random.bernoulli(r1, 1.0 - config.dropout, out.shape)
         out = jnp.where(keep, out / (1.0 - config.dropout), 0.0)
@@ -368,8 +374,9 @@ def _qkv_for_cache(x, block, config):
     -> q (b, s, h, dh), k/v (b, h, s, dh)."""
     b, s, d = x.shape
     h, dh = config.n_heads, config.d_head
-    qkv = x @ block["qkv_kernel"].astype(x.dtype) + \
-        block["qkv_bias"].astype(x.dtype)
+    with jax.named_scope("attn.proj"):
+        qkv = x @ block["qkv_kernel"].astype(x.dtype) + \
+            block["qkv_bias"].astype(x.dtype)
     q, k, v = jnp.split(qkv, 3, axis=-1)
     q = q.reshape(b, s, h, dh)
     k = k.reshape(b, s, h, dh).transpose(0, 2, 1, 3)     # (b, h, s, dh)
@@ -435,12 +442,13 @@ def _cached_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
         # row (h, S, dh), new (h, s, dh): in-place update at seq offset pos
         return jax.lax.dynamic_update_slice(row, new, (0, pos, 0))
 
-    k_rows = jax.vmap(write_row)(k_cache[:, layer_idx],
-                                 k.astype(k_cache.dtype), positions)
-    v_rows = jax.vmap(write_row)(v_cache[:, layer_idx],
-                                 v.astype(v_cache.dtype), positions)
-    k_cache = k_cache.at[:, layer_idx].set(k_rows)
-    v_cache = v_cache.at[:, layer_idx].set(v_rows)
+    with jax.named_scope("kv.write"):
+        k_rows = jax.vmap(write_row)(k_cache[:, layer_idx],
+                                     k.astype(k_cache.dtype), positions)
+        v_rows = jax.vmap(write_row)(v_cache[:, layer_idx],
+                                     v.astype(v_cache.dtype), positions)
+        k_cache = k_cache.at[:, layer_idx].set(k_rows)
+        v_cache = v_cache.at[:, layer_idx].set(v_rows)
     ctx = _attend_cache_rows(q, k_rows, v_rows, positions, dh)
     return ctx.astype(x.dtype).reshape(b, s, d), k_cache, v_cache
 
@@ -498,22 +506,25 @@ def _paged_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
         layer_idx, page_tables, positions, valid_lens, page_size,
         mesh=config.kernel_mesh)
 
-    if config.paged_attention_kernel == "pallas":
-        from ..ops.pallas.paged_attention import paged_attention
-        ctx = paged_attention(q, k_cache, v_cache, page_tables,
-                              positions, valid_lens,
-                              layer_idx=layer_idx, page_size=page_size,
-                              mesh=config.kernel_mesh)
-    else:
-        def rows_of(cache):
-            # (P, L, ps, h*dh) --gather--> (b, max_pages, ps, h*dh)
-            # -> contiguous logical rows (b, h, max_pages*ps, dh)
-            gathered = _gather_pages(cache, page_tables, layer_idx)
-            return gathered.reshape(
-                b, max_pages * page_size, -1, dh).transpose(0, 2, 1, 3)
+    # a prompt chunk's read or a decode step's, as the write tells them
+    # apart (docs/telemetry.md, "Device scopes")
+    with jax.named_scope(read_scope(s, page_size)):
+        if config.paged_attention_kernel == "pallas":
+            from ..ops.pallas.paged_attention import paged_attention
+            ctx = paged_attention(q, k_cache, v_cache, page_tables,
+                                  positions, valid_lens,
+                                  layer_idx=layer_idx, page_size=page_size,
+                                  mesh=config.kernel_mesh)
+        else:
+            def rows_of(cache):
+                # (P, L, ps, h*dh) --gather--> (b, max_pages, ps, h*dh)
+                # -> contiguous logical rows (b, h, max_pages*ps, dh)
+                gathered = _gather_pages(cache, page_tables, layer_idx)
+                return gathered.reshape(
+                    b, max_pages * page_size, -1, dh).transpose(0, 2, 1, 3)
 
-        ctx = _attend_cache_rows(q, rows_of(k_cache), rows_of(v_cache),
-                                 positions, dh, valid_lens=valid_lens)
+            ctx = _attend_cache_rows(q, rows_of(k_cache), rows_of(v_cache),
+                                     positions, dh, valid_lens=valid_lens)
     return ctx.astype(x.dtype).reshape(b, s, d), k_cache, v_cache
 
 
@@ -540,10 +551,11 @@ def _forward_hidden_cached(params, input_ids, config, cache, positions,
     b, s = input_ids.shape
     k_cache, v_cache = cache
     compute_dtype = params["ln_f"]["scale"].dtype
-    tok = jnp.take(params["wte"], input_ids, axis=0)
-    pos_ids = positions[:, None] + jnp.arange(s)[None, :]
-    pos = jnp.take(params["wpe"], pos_ids, axis=0)
-    x = tok.astype(compute_dtype) + pos.astype(compute_dtype)
+    with jax.named_scope("embed"):
+        tok = jnp.take(params["wte"], input_ids, axis=0)
+        pos_ids = positions[:, None] + jnp.arange(s)[None, :]
+        pos = jnp.take(params["wpe"], pos_ids, axis=0)
+        x = tok.astype(compute_dtype) + pos.astype(compute_dtype)
     for i, bp in enumerate(params["blocks"]):
         ln1 = _layer_norm(x, bp["ln1"]["scale"], bp["ln1"]["bias"])
         if page_tables is not None:
@@ -611,13 +623,15 @@ def forward_hidden(params, input_ids, config, rng=None, train=False,
                                       page_size=page_size)
     b, s = input_ids.shape
     compute_dtype = params["ln_f"]["scale"].dtype
-    if config.sparse_embedding_grads:
-        from ..ops.sparse_grads import sparse_embedding_lookup
-        tok = sparse_embedding_lookup(params["wte"], input_ids,
-                                      mesh=config.embedding_grad_mesh)
-    else:
-        tok = jnp.take(params["wte"], input_ids, axis=0)
-    x = tok.astype(compute_dtype) + params["wpe"][:s].astype(compute_dtype)
+    with jax.named_scope("embed"):
+        if config.sparse_embedding_grads:
+            from ..ops.sparse_grads import sparse_embedding_lookup
+            tok = sparse_embedding_lookup(params["wte"], input_ids,
+                                          mesh=config.embedding_grad_mesh)
+        else:
+            tok = jnp.take(params["wte"], input_ids, axis=0)
+        x = tok.astype(compute_dtype) + \
+            params["wpe"][:s].astype(compute_dtype)
 
     block_fn = make_block_fn(config, train)
 
@@ -690,8 +704,10 @@ def chunked_causal_lm_loss(hidden, wte, labels, chunk):
         return (tot + (ll * mask).sum(),
                 cnt + mask.sum().astype(jnp.float32)), None
 
-    (tot, cnt), _ = jax.lax.scan(jax.checkpoint(body),
-                                 (jnp.float32(0), jnp.float32(0)), (h, lab))
+    with jax.named_scope("head.loss"):
+        (tot, cnt), _ = jax.lax.scan(
+            jax.checkpoint(body), (jnp.float32(0), jnp.float32(0)),
+            (h, lab))
     return -tot / jnp.maximum(cnt, 1.0)
 
 
@@ -701,8 +717,10 @@ def lm_loss(params, input_ids, labels, config, rng=None, train=True):
     chunk = config.loss_chunk
     if chunk and hidden.shape[1] % chunk == 0 and hidden.shape[1] > chunk:
         return chunked_causal_lm_loss(hidden, params["wte"], labels, chunk)
-    logits = hidden @ params["wte"].astype(hidden.dtype).T  # tied embedding
-    return causal_lm_cross_entropy(logits, labels)
+    with jax.named_scope("head.loss"):
+        # tied embedding
+        logits = hidden @ params["wte"].astype(hidden.dtype).T
+        return causal_lm_cross_entropy(logits, labels)
 
 
 def stream_spec_for(config):
@@ -914,7 +932,8 @@ class GPT2Decoder:
     @staticmethod
     def logits(params, hidden):
         # tied-embedding LM head (lm_loss's convention)
-        return hidden @ params["wte"].astype(hidden.dtype).T
+        with jax.named_scope("head"):
+            return hidden @ params["wte"].astype(hidden.dtype).T
 
 
 def num_params(config):

@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from ..inference.decoder import CacheSpec, StateSpec
-from ..inference.kv_cache import write_tokens
+from ..inference.kv_cache import read_scope, write_tokens
 
 INIT_STD = 0.02
 DT_MIN, DT_MAX = 1e-3, 1e-1
@@ -202,8 +202,9 @@ def _rms_norm(x, weight, eps):
 
 
 def _mlp(x, lp, config):
-    u = _rms_norm(x, lp["norm2"], config.rms_norm_eps)
-    return (jax.nn.silu(u @ lp["gate"]) * (u @ lp["up"])) @ lp["down"]
+    with jax.named_scope("mlp"):
+        u = _rms_norm(x, lp["norm2"], config.rms_norm_eps)
+        return (jax.nn.silu(u @ lp["gate"]) * (u @ lp["up"])) @ lp["down"]
 
 
 def _use_pallas(config):
@@ -216,12 +217,13 @@ def _use_pallas(config):
 def _dt_b_c(xc, lp, config):
     """-> dt (.., d_inner) f32 after softplus, B, C (.., d_state) f32."""
     r, n, eps = config.dt_rank, config.d_state, config.rms_norm_eps
-    dt, B, C = jnp.split(xc @ lp["x_proj"], [r, r + n], axis=-1)
-    dt = _rms_norm(dt, lp["dt_norm"], eps)
-    B = _rms_norm(B, lp["B_norm"], eps).astype(jnp.float32)
-    C = _rms_norm(C, lp["C_norm"], eps).astype(jnp.float32)
-    dt = jax.nn.softplus((dt @ lp["dt_proj"]).astype(jnp.float32) +
-                         lp["dt_bias"].astype(jnp.float32))
+    with jax.named_scope("mamba.proj"):
+        dt, B, C = jnp.split(xc @ lp["x_proj"], [r, r + n], axis=-1)
+        dt = _rms_norm(dt, lp["dt_norm"], eps)
+        B = _rms_norm(B, lp["B_norm"], eps).astype(jnp.float32)
+        C = _rms_norm(C, lp["C_norm"], eps).astype(jnp.float32)
+        dt = jax.nn.softplus((dt @ lp["dt_proj"]).astype(jnp.float32) +
+                             lp["dt_bias"].astype(jnp.float32))
     return dt, B, C
 
 
@@ -232,7 +234,8 @@ def _mamba_sequence(u, lp, config, tail0, h0, valid_len):
     as they are after ``valid_len`` tokens)."""
     from ..ops.pallas import mamba as kernels
     s, kc = u.shape[0], config.d_conv
-    x, z = jnp.split(u @ lp["in_proj"], 2, axis=-1)
+    with jax.named_scope("mamba.proj"):
+        x, z = jnp.split(u @ lp["in_proj"], 2, axis=-1)
     padded = jnp.concatenate([tail0.astype(x.dtype), x], axis=0)
     conv = sum(padded[k:k + s].astype(jnp.float32) *
                lp["conv_w"][k].astype(jnp.float32) for k in range(kc))
@@ -244,16 +247,18 @@ def _mamba_sequence(u, lp, config, tail0, h0, valid_len):
     A = -jnp.exp(lp["A_log"].astype(jnp.float32))
     scan = kernels.mamba_scan if _use_pallas(config) and s % 8 == 0 \
         else kernels.mamba_scan_xla
-    y, h = scan(xc, dt, B, C, A, h0, valid_len)
+    with jax.named_scope("mamba.scan"):
+        y, h = scan(xc, dt, B, C, A, h0, valid_len)
     return _gate_and_project(y, xc, z, lp), tail, h
 
 
 def _gate_and_project(y, xc, z, lp):
     """``W_out((y + D x) * silu(z))``: the scan's output, the skip, the
     gate, the output projection (y float32; xc, z in compute dtype)."""
-    y = y + lp["D"].astype(jnp.float32) * xc.astype(jnp.float32)
-    y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(xc.dtype)
-    return y @ lp["out_proj"]
+    with jax.named_scope("mamba.proj"):
+        y = y + lp["D"].astype(jnp.float32) * xc.astype(jnp.float32)
+        y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(xc.dtype)
+        return y @ lp["out_proj"]
 
 
 def _mamba_prefill(u, lp, config, state, m, slot, start, valid_len):
@@ -277,7 +282,8 @@ def _mamba_decode(u, lp, config, state, m, advance):
     from ..ops.pallas import mamba as kernels
     conv, ssm = state
     di = config.d_inner
-    x, z = jnp.split(u[:, 0] @ lp["in_proj"], 2, axis=-1)     # (slots, di)
+    with jax.named_scope("mamba.proj"):
+        x, z = jnp.split(u[:, 0] @ lp["in_proj"], 2, axis=-1)  # (slots, di)
     # a slot's row: its d_conv - 1 last inputs, then the new one
     window = jnp.concatenate([conv[m], x.astype(conv.dtype)], axis=1)
     acc = sum(window[:, k * di:(k + 1) * di].astype(jnp.float32) *
@@ -295,7 +301,8 @@ def _mamba_decode(u, lp, config, state, m, advance):
     A = -jnp.exp(lp["A_log"].astype(jnp.float32))
     step = kernels.mamba_step if _use_pallas(config) \
         else kernels.mamba_step_xla
-    y, ssm = step(ssm, m, xs, dt, B, C, A)
+    with jax.named_scope("mamba.step"):
+        y, ssm = step(ssm, m, xs, dt, B, C, A)
     return _gate_and_project(y, xc, z, lp)[:, None], (conv, ssm)
 
 
@@ -331,30 +338,33 @@ def _attention_paged(u, lp, config, k_cache, v_cache, a, positions,
     b, s, _ = u.shape
     h, kvh, dh = config.n_heads, config.n_kv_heads, config.d_head
     max_pages = page_tables.shape[1]
-    q = (u @ lp["q"]).reshape(b, s, h, dh)
-    k, v = u @ lp["k"], u @ lp["v"]                    # (b, s, kvh*dh)
+    with jax.named_scope("attn.proj"):
+        q = (u @ lp["q"]).reshape(b, s, h, dh)
+        k, v = u @ lp["k"], u @ lp["v"]                # (b, s, kvh*dh)
     k_cache, v_cache = write_tokens(
         (k_cache, v_cache), (k.reshape(b, s, -1), v.reshape(b, s, -1)),
         a, page_tables, positions, valid_lens, page_size,
         mesh=config.kernel_mesh)
 
-    if config.paged_attention_kernel == "pallas":
-        # the page-table walk in the kernel: the live pages and no
-        # others (ops/pallas/paged_attention.py, the grouped kernel)
-        from ..ops.pallas.paged_attention import paged_attention
-        ctx = paged_attention(q, k_cache, v_cache, page_tables, positions,
-                              valid_lens, layer_idx=a,
-                              page_size=page_size).reshape(b, s, h * dh)
-    else:
-        def rows_of(cache):
-            # one gather on (page, layer): the slot's whole logical
-            # window, max_pages pages, live or not
-            return cache[page_tables, a].reshape(
-                b, max_pages * page_size, kvh, dh)
+    with jax.named_scope(read_scope(s, page_size)):
+        if config.paged_attention_kernel == "pallas":
+            # the page-table walk in the kernel: the live pages and no
+            # others (ops/pallas/paged_attention.py, the grouped kernel)
+            from ..ops.pallas.paged_attention import paged_attention
+            ctx = paged_attention(q, k_cache, v_cache, page_tables,
+                                  positions, valid_lens, layer_idx=a,
+                                  page_size=page_size).reshape(b, s, h * dh)
+        else:
+            def rows_of(cache):
+                # one gather on (page, layer): the slot's whole logical
+                # window, max_pages pages, live or not
+                return cache[page_tables, a].reshape(
+                    b, max_pages * page_size, kvh, dh)
 
-        ctx = _attend(q, rows_of(k_cache), rows_of(v_cache), positions,
-                      valid_lens, config)
-    return ctx.astype(u.dtype) @ lp["o"], k_cache, v_cache
+            ctx = _attend(q, rows_of(k_cache), rows_of(v_cache), positions,
+                          valid_lens, config)
+    with jax.named_scope("attn.proj"):
+        return ctx.astype(u.dtype) @ lp["o"], k_cache, v_cache
 
 
 def _attention_dense(u, lp, config):
@@ -381,7 +391,8 @@ def forward_hidden(params, input_ids, config, cache=None, positions=None,
     real tokens); otherwise decode, one token for every slot,
     ``state_advance`` (slots,) bool marking the slots whose recurrent
     state this step advances."""
-    x = jnp.take(params["embed"], input_ids, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], input_ids, axis=0)
     eps = config.rms_norm_eps
     if cache is not None:
         assert page_tables is not None, \
@@ -431,7 +442,8 @@ def forward_hidden(params, input_ids, config, cache=None, positions=None,
 
 def logits(params, hidden):
     """The tied head."""
-    return hidden @ params["embed"].astype(hidden.dtype).T
+    with jax.named_scope("head"):
+        return hidden @ params["embed"].astype(hidden.dtype).T
 
 
 def lm_loss(params, input_ids, labels, config):

@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from ..inference.decoder import CacheSpec, StateSpec
-from ..inference.kv_cache import write_tokens
+from ..inference.kv_cache import read_scope, write_tokens
 from ..ops import moe
 from .jamba import _attend, _rms_norm
 
@@ -320,18 +320,19 @@ def _attention_paged(u, lp, config, k_cache, v_cache, a, positions,
         (k_cache, v_cache), (k.reshape(b, s, -1), v.reshape(b, s, -1)),
         a, page_tables, positions, valid_lens, page_size)
 
-    if config.paged_attention_kernel == "pallas":
-        from ..ops.pallas.paged_attention import paged_attention
-        ctx = paged_attention(q, k_cache, v_cache, page_tables, positions,
-                              valid_lens, layer_idx=a,
-                              page_size=page_size).reshape(b, s, h * dh)
-    else:
-        def rows_of(cache):
-            return cache[page_tables, a].reshape(
-                b, max_pages * page_size, kvh, dh)
+    with jax.named_scope(read_scope(s, page_size)):
+        if config.paged_attention_kernel == "pallas":
+            from ..ops.pallas.paged_attention import paged_attention
+            ctx = paged_attention(q, k_cache, v_cache, page_tables,
+                                  positions, valid_lens, layer_idx=a,
+                                  page_size=page_size).reshape(b, s, h * dh)
+        else:
+            def rows_of(cache):
+                return cache[page_tables, a].reshape(
+                    b, max_pages * page_size, kvh, dh)
 
-        ctx = _attend(q, rows_of(k_cache), rows_of(v_cache), positions,
-                      valid_lens, config)
+            ctx = _attend(q, rows_of(k_cache), rows_of(v_cache), positions,
+                          valid_lens, config)
     return ctx.astype(u.dtype) @ lp["o"], k_cache, v_cache
 
 
@@ -408,7 +409,8 @@ def forward_hidden(params, input_ids, config, cache=None, positions=None,
 
 def logits(params, hidden):
     """The tied head."""
-    return hidden @ params["embed"].astype(hidden.dtype).T
+    with jax.named_scope("head"):
+        return hidden @ params["embed"].astype(hidden.dtype).T
 
 
 def lm_loss(params, input_ids, labels, config):

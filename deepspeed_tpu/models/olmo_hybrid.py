@@ -475,7 +475,8 @@ def forward_hidden(params, input_ids, config, cache=None, positions=None,
 
 def logits(params, hidden):
     """The head, a matrix of its own."""
-    return hidden @ params["head"].astype(hidden.dtype)
+    with jax.named_scope("head"):
+        return hidden @ params["head"].astype(hidden.dtype)
 
 
 def lm_loss(params, input_ids, labels, config):

@@ -10,7 +10,10 @@ heads x 512 keys) and passes over that array four times: about 1 ms a
 block where its two matmuls cost the MXU 0.09 (PERF.md section 5, PR 42).
 Here the grid is (slot, query tile). A tile is ``tq`` queries x the
 ``group`` query heads of a key-value head as the rows of one matmul (rows
-ordered (query, head of the group)) against a block of ``tk`` keys that
+ordered (query, head of the group), each key-value head against its own
+``d_head`` lanes of the block: the grouped page walk's layout until PR 46,
+which since folds every head in one matmul, block-diagonal over the
+packed lanes) against a block of ``tk`` keys that
 streams HBM -> VMEM into a double buffer, one ``pltpu.make_async_copy`` a
 page and pool by the slot's row of the page table in scalar memory: the
 starts of ALL the block's pages in a straight line behind ONE wait a pool

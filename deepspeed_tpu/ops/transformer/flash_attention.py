@@ -1465,9 +1465,13 @@ def _lnqkv(x, ln_scale, ln_bias, qkv_w, qkv_b, eps):
     """Block input -> packed (b, s, h*d) q, k, v (the model's natural
     layout; heads stay merged in the minor dim)."""
     from .fused_ops import fused_layer_norm
-    ln = fused_layer_norm(x, ln_scale, ln_bias, eps)
-    qkv = ln @ qkv_w.astype(ln.dtype) + qkv_b.astype(ln.dtype)
-    return jnp.split(qkv, 3, axis=-1)
+    # the kernels beside it stay outside every scope: an unnamed
+    # ``pallas_call`` takes its trace event's name (``%jvp__.N``) from
+    # the innermost one (docs/telemetry.md, "Device scopes")
+    with jax.named_scope("attn.proj"):
+        ln = fused_layer_norm(x, ln_scale, ln_bias, eps)
+        qkv = ln @ qkv_w.astype(ln.dtype) + qkv_b.astype(ln.dtype)
+        return jnp.split(qkv, 3, axis=-1)
 
 
 def fused_ln_qkv_attention(x, ln_scale, ln_bias, qkv_w, qkv_b, num_heads,

@@ -32,6 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..parallel.topology import MeshGrid, DATA_AXIS, build_mesh
 from ..utils.annotate import (engine_tag, setup_span, startup_line,
                               startup_report)
+from ..utils.compile_cache import program_scopes, release_programs
 from ..utils.logging import logger, log_dist
 from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 from . import checkpointing as ckpt
@@ -106,6 +107,7 @@ class DeepSpeedEngine:
         # "Start-up record") carry it
         self.startup_tag = engine_tag("train")
         self._first_call_row = None   # of a program made and not yet run
+        self._first_call_operands = None    # what its first call is given
         self.client_optimizer = optimizer
         self.client_lr_scheduler = lr_scheduler
         self.training_data = training_data
@@ -1617,8 +1619,9 @@ class DeepSpeedEngine:
     def _first_call_over(self):
         from .executor.jit import first_call_over
         jax.block_until_ready(self.state)
-        first_call_over(self._first_call_row)
-        self._first_call_row = None
+        first_call_over(self._first_call_row,
+                        operands=self._first_call_operands)
+        self._first_call_row = self._first_call_operands = None
 
     def startup_report(self):
         """This engine's rows of the start-up record (docs/telemetry.md,
@@ -1630,6 +1633,18 @@ class DeepSpeedEngine:
 
     def startup_line(self):
         return startup_line(self.startup_tag)
+
+    def program_scopes(self):
+        """Which scope each instruction of this engine's compiled step
+        programs was traced under, one entry a program that has run
+        (docs/telemetry.md, "Device scopes"). Lowers and compiles (or
+        loads) each once more: seconds a program where the executable
+        is found again, its whole compile where not; for after a trace
+        window and not inside one; an error inside a step."""
+        if self._first_call_row is not None or \
+                getattr(self, "_pending_backward", False):
+            raise RuntimeError("program_scopes() inside a step")
+        return program_scopes(self.startup_tag)
 
     # -------------------------------------------------------------- telemetry
     def _check_memory_breakdown(self):
@@ -1727,6 +1742,10 @@ class DeepSpeedEngine:
         (zero/stream.py's ``_run`` is the offload twin) or
         ``_window_flops`` silently undercounts and MFU deflates."""
         fn = self._get_jit(key, builder, donate=donate)
+        if self._first_call_row is not None:
+            # made just now: what its first call is given
+            # (docs/telemetry.md, "Device scopes")
+            self._first_call_operands = args
         self._tele_add_flops(key, fn, *args)
         return fn
 
@@ -2610,7 +2629,8 @@ class DeepSpeedEngine:
 
             state, losses = jax.lax.scan(scan_body, state,
                                          (rngs, *leaves), length=gas)
-            state, metrics = apply_step(state, hyper)
+            with jax.named_scope("optim.step"):
+                state, metrics = apply_step(state, hyper)
             return state, (jnp.mean(losses), metrics)
 
         return fused
@@ -3031,6 +3051,8 @@ class DeepSpeedEngine:
         if getattr(self, "_closed", False):
             return
         self._closed = True
+        # a step program closes over this engine and its state
+        release_programs(self.startup_tag)
         try:
             self._drain_ckpt_writes()
             ckpt.wait_pending_writes()

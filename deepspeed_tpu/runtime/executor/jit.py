@@ -43,7 +43,7 @@ def first_call(fn, program, key, engine, step):
     what :func:`first_call_over` closes it with."""
     return open_program_row(
         program, key, engine, int(step),
-        getattr(getattr(fn, "__wrapped__", None), "__name__", None))
+        getattr(getattr(fn, "__wrapped__", None), "__name__", None), fn)
 
 
 first_call_over = close_program_row

@@ -36,6 +36,24 @@ def annotate(name, **attrs):
     return _TraceAnnotation(name, **attrs)
 
 
+# -------------------------------------------------------- device scopes
+# The names the program's ``jax.named_scope`` blocks give its mechanisms
+# (docs/telemetry.md, "Device scopes": what each wraps and which families
+# have it). A scope ends up as a component of the ``op_name`` of every
+# instruction traced under it; ``utils/compile_cache.program_scopes()``
+# reads them back from the compiled programs. A test holds every
+# ``named_scope`` literal of the package to this list.
+DEVICE_SCOPES = (
+    "embed", "head", "head.loss", "sample", "optim.step", "mlp",
+    "attn.proj", "attn.prefill", "attn.decode", "kv.write",
+    "attn.window", "attn.full", "attn.chunk_blocks",
+    "mamba.proj", "mamba.scan", "mamba.step", "short_conv",
+    "moe.route", "moe.dispatch", "moe.combine", "moe.shared",
+    "mla.project", "mla.kv_up", "mla.prefill_attn", "mla.absorb",
+    "gdn.proj", "gdn.conv", "gdn.chunk", "gdn.step", "gdn.norm",
+)
+
+
 # ------------------------------------------------------ start-up record
 SETUP_ROWS_MAX = 1024      # the last of them counts the rows dropped
 _setup_rows = []

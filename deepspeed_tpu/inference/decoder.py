@@ -24,8 +24,8 @@ carries a ``decoder`` (``make_gpt2_model``, ``make_jamba_model``,
   program family's variant of it for the engine's resolved read path;
   optionally ``prefill_config(config, paged_attention_kernel)`` -> the
   prefill family's (a decoder whose chunks have a kernel of their own:
-  Mellum's ``chunk_attention``); without it prefill closes over
-  ``serving_config``'s, the XLA path;
+  ``chunk_attention`` in Mellum, Command A+, Olmo Hybrid and GPT-2);
+  without it prefill closes over ``serving_config``'s, the XLA path;
 * ``serving_params(params, dtype)`` -> the weights as served;
 * ``forward_hidden(params, ids, config, cache=, positions=,
   page_tables=, valid_lens=, page_size=[, state_slot= |

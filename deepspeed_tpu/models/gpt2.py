@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..inference.kv_cache import read_scope, write_tokens
+from ..inference.kv_cache import read_scope, write_path, write_tokens
 from ..parallel.topology import MODEL_AXIS
 
 
@@ -89,10 +89,11 @@ class GPT2Config:
     # Paged-attention read path: "xla" (the jnp.take gather-back — the
     # numerics oracle and default) or "pallas" (ops/pallas/
     # paged_attention: in-kernel page-table walk with double-buffered
-    # page fetches and online softmax). The serving engine resolves the
+    # page fetches and online softmax; for a prompt chunk ops/pallas/
+    # chunk_attention). The serving engine resolves the
     # inference.paged_attention_kernel tri-state into this field on the
-    # DECODE program family only (docs/pallas_kernels.md); training and
-    # prefill never read it.
+    # DECODE program family and, on one chip, the PREFILL family
+    # (docs/pallas_kernels.md); training never reads it.
     paged_attention_kernel: str = "xla"
 
     @property
@@ -369,19 +370,14 @@ def _fused_attn_ctx(x, block_params, config):
         mesh=config.kernel_mesh)
 
 
-def _qkv_for_cache(x, block, config):
+def _qkv_rows(x, block):
     """Shared QKV projection for the cached (serving) attention paths:
-    -> q (b, s, h, dh), k/v (b, h, s, dh)."""
-    b, s, d = x.shape
-    h, dh = config.n_heads, config.d_head
+    -> q, k, v (b, s, h * dh), one packed row a token: what the paged
+    pool holds and ``write_tokens`` takes."""
     with jax.named_scope("attn.proj"):
         qkv = x @ block["qkv_kernel"].astype(x.dtype) + \
             block["qkv_bias"].astype(x.dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(b, s, h, dh)
-    k = k.reshape(b, s, h, dh).transpose(0, 2, 1, 3)     # (b, h, s, dh)
-    v = v.reshape(b, s, h, dh).transpose(0, 2, 1, 3)
-    return q, k, v
+    return jnp.split(qkv, 3, axis=-1)
 
 
 def _attend_cache_rows(q, k_rows, v_rows, positions, dh, valid_lens=None):
@@ -435,8 +431,11 @@ def _cached_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
     Returns ``(ctx, k_cache, v_cache)`` — caches are functionally updated.
     """
     b, s, d = x.shape
-    dh = config.d_head
-    q, k, v = _qkv_for_cache(x, block, config)
+    h, dh = config.n_heads, config.d_head
+    q, k, v = _qkv_rows(x, block)
+    q = q.reshape(b, s, h, dh)
+    k = k.reshape(b, s, h, dh).transpose(0, 2, 1, 3)     # (b, h, s, dh)
+    v = v.reshape(b, s, h, dh).transpose(0, 2, 1, 3)
 
     def write_row(row, new, pos):
         # row (h, S, dh), new (h, s, dh): in-place update at seq offset pos
@@ -476,46 +475,48 @@ def _paged_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
     each slot's logical page j to a physical page (entry 0 = the
     reserved garbage page). The new keys and values are written first,
     by ``kv_cache.write_tokens`` (the masked write and its contract: a
-    bucket-padded prefill can never touch another sequence's pages).
+    bucket-padded prefill can never touch another sequence's pages),
+    which takes the projection's packed rows as they are.
     Reads: the default "xla" path
     gathers the slot's full logical window back into contiguous (b, h,
     max_pages*page_size, d_head) rows and runs the masked attention
     of :func:`_attend_cache_rows` over them — the values a contiguous
     cache would hold, in the same order. That
     gather touches the rows' own pages of this layer and nothing else
-    of the pool (:func:`_gather_pages`); every prefill runs it. With
-    ``config.paged_attention_kernel == "pallas"`` the read side runs
-    the ops/pallas/paged_attention kernel instead (in-kernel page walk,
-    a block of pages fetched and every head folded a loop turn, K, V
-    and the softmax weights on the MXU in the pool's dtype, online
-    softmax in float32 — same masking contract, ctx within 1e-5 of the
-    gather path under a float32 pool, greedy streams byte-identical;
-    docs/pallas_kernels.md). The WRITE is
-    shared by both paths, so the cache bits never diverge.
+    of the pool (:func:`_gather_pages`); it is the CPU path, the oracle
+    and the drafter's read, and a chunk's read on a mesh. With
+    ``config.paged_attention_kernel == "pallas"`` the read side is a
+    kernel, picked as the write picks its own (``kv_cache.write_path``):
+    a launch that wrote rows (a decode or verify step) runs the
+    ops/pallas/paged_attention page walk (a block of pages fetched and
+    every head folded a loop turn), one that wrote pages (a prompt
+    chunk, wherever it starts) ops/pallas/chunk_attention (tiles of
+    queries against blocks of keys: the causal triangle's upper half and
+    the bucket's padding are not visited; q goes in and ctx comes out as
+    packed rows, nothing is transposed). Both: K, V and the softmax
+    weights on the MXU in the pool's dtype, the softmax in float32 (a
+    running one over blocks; plain over GPT-2's one-block table), the
+    same masking contract, ctx within 1e-5 of the gather path under
+    a float32 pool, greedy streams byte-identical
+    (docs/pallas_kernels.md). The chunk kernel has no ``shard_map``
+    wrapper: on a mesh the prefill family keeps the gather
+    (``GPT2Decoder.prefill_config``) and a verify step of a page or more
+    the walk. The WRITE is shared by every path, so the cache bits never
+    diverge.
     """
     b, s, d = x.shape
-    dh = config.d_head
+    h, dh = config.n_heads, config.d_head
     max_pages = page_tables.shape[1]
-    q, k, v = _qkv_for_cache(x, block, config)
-
-    # one packed (h*dh) row per token
+    q, k, v = _qkv_rows(x, block)
     k_cache, v_cache = write_tokens(
-        (k_cache, v_cache),
-        (k.transpose(0, 2, 1, 3).reshape(b, s, -1),
-         v.transpose(0, 2, 1, 3).reshape(b, s, -1)),
-        layer_idx, page_tables, positions, valid_lens, page_size,
-        mesh=config.kernel_mesh)
+        (k_cache, v_cache), (k, v), layer_idx, page_tables, positions,
+        valid_lens, page_size, mesh=config.kernel_mesh)
+    q = q.reshape(b, s, h, dh)
 
     # a prompt chunk's read or a decode step's, as the write tells them
     # apart (docs/telemetry.md, "Device scopes")
     with jax.named_scope(read_scope(s, page_size)):
-        if config.paged_attention_kernel == "pallas":
-            from ..ops.pallas.paged_attention import paged_attention
-            ctx = paged_attention(q, k_cache, v_cache, page_tables,
-                                  positions, valid_lens,
-                                  layer_idx=layer_idx, page_size=page_size,
-                                  mesh=config.kernel_mesh)
-        else:
+        if config.paged_attention_kernel != "pallas":
             def rows_of(cache):
                 # (P, L, ps, h*dh) --gather--> (b, max_pages, ps, h*dh)
                 # -> contiguous logical rows (b, h, max_pages*ps, dh)
@@ -525,6 +526,18 @@ def _paged_attn_ctx(x, block, config, k_cache, v_cache, layer_idx,
 
             ctx = _attend_cache_rows(q, rows_of(k_cache), rows_of(v_cache),
                                      positions, dh, valid_lens=valid_lens)
+        elif write_path(s, page_size) == "pages" \
+                and config.kernel_mesh is None:
+            from ..ops.pallas.chunk_attention import chunk_attention
+            ctx = chunk_attention(q, k_cache, v_cache, layer_idx,
+                                  page_tables, positions, valid_lens,
+                                  page_size, out_dtype=x.dtype)
+        else:
+            from ..ops.pallas.paged_attention import paged_attention
+            ctx = paged_attention(q, k_cache, v_cache, page_tables,
+                                  positions, valid_lens,
+                                  layer_idx=layer_idx, page_size=page_size,
+                                  mesh=config.kernel_mesh)
     return ctx.astype(x.dtype).reshape(b, s, d), k_cache, v_cache
 
 
@@ -904,12 +917,18 @@ class GPT2Decoder:
             paged_attention_kernel="xla", kernel_mesh=mesh)
 
     def decode_config(self, config, paged_attention_kernel):
-        # decode is the ONE family that may run the Pallas paged-
-        # attention kernel (docs/pallas_kernels.md dispatch rules); the
-        # serving config keeps "xla" so prefill and every oracle
-        # comparison stay on the gather path
+        # the decode and prefill families may read the pages with a
+        # Pallas kernel (the page walk, a chunk's chunk_attention:
+        # docs/pallas_kernels.md dispatch rules); the serving config
+        # keeps "xla" so every oracle comparison stays on the gather path
         return dataclasses.replace(
             config, paged_attention_kernel=paged_attention_kernel)
+
+    def prefill_config(self, config, paged_attention_kernel):
+        # a prompt chunk's read as the engine resolved decode's; on a
+        # mesh the gather (chunk_attention has no shard_map wrapper)
+        return config if config.kernel_mesh is not None else \
+            self.decode_config(config, paged_attention_kernel)
 
     def serving_params(self, params, dtype):
         if self.config.scan_blocks:

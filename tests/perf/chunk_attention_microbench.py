@@ -5,10 +5,10 @@ shape, one layer, ONE chip: the ``chunk_attention`` kernel
 clock). Needs a TPU.
 
     chiprun -- python tests/perf/chunk_attention_microbench.py \
-        [--shape ide|rag] [--s 512,1024,2048] \
-        [--starts 0,4096,8192,22528] [--windows W,0] [--no-loop] \
-        [--seed 0] [--module label=path/to/chunk_attention.py] \
-        [TQ,TK,SUB ...]
+        [--shape ide|rag|docs|chat] [--s 512,1024,2048] \
+        [--starts 0,4096,8192,22528] [--windows W,0] [--valid N] \
+        [--no-loop] [--seed 0] \
+        [--module label=path/to/chunk_attention.py] [TQ,TK,SUB ...]
 
 ``--shape``: ``ide`` is ``mellum2-12b-a2.5b-serve`` (32 query heads on 4
 key-value heads of 128: 8 heads a group, 512 lanes a pool row, a sliding
@@ -19,7 +19,15 @@ absolute position ``start``; without a window (``0``) its table is the
 full group's 2,048 columns, with one (the shape's unless ``--windows``
 names others) the sliding group's, whose column 0 is the first page with
 a visible key (inference/paging.py), so the chunk sits at ``start -
-base`` in it. A tile given as ``TQ,TK,SUB`` takes the place of the
+base`` in it. ``docs`` and ``chat`` are GPT-2's two cells
+(``gpt2-350m-serve-batch``, ``gpt2-350m-serve``: 16 heads of 64, one a
+key-value head, 1,024 lanes, the cells' own table of 64 columns, no
+window): q goes in and the result comes out as the projection's packed
+``(1, s, 1024)`` rows in bfloat16, as ``models/gpt2.py`` calls the
+kernel, and "the loop" beside it is the gather read the kernel stands in
+for there (``_attend_cache_rows`` over ``_gather_pages``). ``--valid``:
+the real rows of a prompt shorter than its bucket ``s`` (the rest is the
+bucket's padding). A tile given as ``TQ,TK,SUB`` takes the place of the
 kernel's own (``tiles``); none: its own only. ``--module``: another
 file's kernel beside the tree's on the same inputs (the parent commit's
 under ``_chip_checkout/parent``), every tile run on both. One JSON line
@@ -43,9 +51,13 @@ import tempfile
 from flash_attention_microbench import kernel_ms
 
 ITERS = 5
-D_HEAD, PAGE, FULL_COLUMNS = 128, 16, 2048
-# query heads, key-value heads, the sliding table's columns, the window
-SHAPES = {"ide": (32, 4, 193, 1024), "rag": (128, 8, 385, 4096)}
+PAGE = 16
+# query heads, key-value heads, d_head, the full table's columns, the
+# sliding table's columns, the window
+SHAPES = {"ide": (32, 4, 128, 2048, 193, 1024),
+          "rag": (128, 8, 128, 2048, 385, 4096),
+          "docs": (16, 16, 64, 64, None, None),
+          "chat": (16, 16, 64, 64, None, None)}
 PEAK_FLOPS = 197e12       # benchmark/peaks.json, TPU v5e bf16
 
 
@@ -74,32 +86,35 @@ def traced(fn, args):
         return out, (kernels.get("chunk_attention", 0.0), busy_ms)
 
 
-def pairs_visited(at, s, tq, tk, window, short=None):
-    """(tile, block) pairs the kernel visits for a chunk of ``s`` live
-    queries at table position ``at``, the dense rectangle's, and the
-    keys the visited blocks hold. ``short``: the walk starts at the page
-    of a tile's first visible key and may end in a block of ``short``
-    keys (PR 53; 0: no short block); None: blocks at multiples of ``tk``
-    (the kernel before it)."""
-    live = at + s - 1
+def pairs_visited(at, s, tq, tk, window, widths=None, valid=None):
+    """(tile, block) pairs the kernel visits for a chunk of ``s``
+    queries, ``valid`` of them live (all), at table position ``at``, the
+    dense rectangle's, and the keys the visited blocks hold. ``widths``:
+    the walk starts at the page of a tile's first visible key and its
+    last block may hold one of these lengths short of ``tk`` (PR 53's
+    short block, PR 57's steps of a table of one block; ``()``: whole
+    blocks only); None: blocks at multiples of ``tk`` (the kernel before
+    PR 53)."""
+    live = at + (valid or s) - 1
     visited = keys = 0
-    for q0 in range(at, at + s, tq):
+    for q0 in range(at, min(at + s, live + 1), tq):
         first = 0 if window is None else max(q0 - window + 1, 0)
         last = min(q0 + tq - 1, live)
-        if short is None:
+        if widths is None:
             blocks = last // tk - first // tk + 1
             keys += blocks * tk
         else:
             whole, rest = divmod(last - first // PAGE * PAGE + 1, tk)
             blocks = whole + (rest > 0)
-            keys += whole * tk + (0 if not rest else
-                                  short if rest <= short else tk)
+            keys += whole * tk + (rest and min(
+                [w for w in widths if w >= rest], default=tk))
         visited += blocks
     return visited, (s // tq) * (live // tk + 1), keys
 
 
 def keys_needed(at, s, window):
-    """Keys the chunk's queries must visit, summed over the queries."""
+    """Keys the chunk's ``s`` live queries must visit, summed over the
+    queries."""
     return sum(min(p + 1, window or p + 1) for p in range(at, at + s))
 
 
@@ -109,6 +124,7 @@ def main():
     ap.add_argument("--s", default="512,1024,2048")
     ap.add_argument("--starts", default="0,4096,8192,22528")
     ap.add_argument("--windows", default=None)
+    ap.add_argument("--valid", type=int, default=None)
     ap.add_argument("--no-loop", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--module", action="append", default=[])
@@ -124,15 +140,20 @@ def main():
     from deepspeed_tpu.ops.chunk_attention import paged_blocked_attention
     from deepspeed_tpu.ops.pallas import chunk_attention as tree
 
-    heads, kv_heads, window_columns, shape_window = SHAPES[ns.shape]
-    windows = [shape_window, None] if ns.windows is None else \
+    heads, kv_heads, d_head, full_columns, window_columns, shape_window = \
+        SHAPES[ns.shape]
+    packed = window_columns is None        # GPT-2's cells
+    windows = [None] if packed else [shape_window, None] \
+        if ns.windows is None else \
         [int(v) or None for v in ns.windows.split(",")]
     kernels = [(label, load(path)) for label, path in
                (m.split("=", 1) for m in ns.module)] + [("tree", tree)]
     rng = np.random.default_rng(ns.seed)
-    lanes, group = kv_heads * D_HEAD, heads // kv_heads
+    lanes, group = kv_heads * d_head, heads // kv_heads
     pools = {}
-    for windowed, columns in ((False, FULL_COLUMNS), (True, window_columns)):
+    for windowed, columns in ((False, full_columns), (True, window_columns)):
+        if columns is None:
+            continue
         shape = (columns + 1, 2, PAGE, lanes)
         pools[windowed] = tuple(
             jnp.asarray(rng.standard_normal(shape, np.float32), jnp.bfloat16)
@@ -142,13 +163,29 @@ def main():
     asked = [tuple(int(v) for v in t.split(",")) for t in ns.tiles]
 
     for s in (int(v) for v in ns.s.split(",")):
-        q = jnp.asarray(rng.standard_normal((1, s, heads, D_HEAD),
-                                            np.float32), jnp.bfloat16) * 0.3
-        valid = jnp.full((1,), s, jnp.int32)
+        # GPT-2's cells: the projection's packed rows in, packed rows out
+        q = jnp.asarray(rng.standard_normal(
+            (1, s, lanes) if packed else (1, s, heads, d_head), np.float32),
+            jnp.bfloat16) * 0.3
+        live = min(ns.valid or s, s)
+        valid = jnp.full((1,), live, jnp.int32)
         for window in windows:
             k_pool, v_pool, table = pools[window is not None]
-            loop = jax.jit(lambda q, at: paged_blocked_attention(
-                q, k_pool, v_pool, 1, table, at, valid, PAGE, window))
+            if packed:
+                from deepspeed_tpu.models import gpt2
+
+                def rows_of(pool):
+                    return gpt2._gather_pages(pool, table, 1).reshape(
+                        1, table.shape[1] * PAGE, -1, d_head) \
+                        .transpose(0, 2, 1, 3)
+
+                loop = jax.jit(lambda q, at: gpt2._attend_cache_rows(
+                    q.reshape(1, s, heads, d_head), rows_of(k_pool),
+                    rows_of(v_pool), at, d_head, valid_lens=valid)
+                    .astype(q.dtype).reshape(q.shape))
+            else:
+                loop = jax.jit(lambda q, at: paged_blocked_attention(
+                    q, k_pool, v_pool, 1, table, at, valid, PAGE, window))
             # one compile a (kernel, tile): the start is an argument
             fns = {}
             for start in (int(v) for v in ns.starts.split(",")):
@@ -161,22 +198,28 @@ def main():
                 want = loop_ms = None
                 if not ns.no_loop:
                     want, (_, loop_ms) = traced(loop, (q, pos))
-                needed = keys_needed(at, s, window) * 4 * heads * D_HEAD
+                needed = keys_needed(at, live, window) * 4 * heads * d_head
                 for label, module in kernels:
-                    own = module.tiles(s, group, D_HEAD, lanes, 2,
+                    own = module.tiles(s, group, d_head, lanes, 2,
                                        table.shape[1] * PAGE, PAGE, window)
                     for tile in [None] + [t for t in asked if s % t[0] == 0]:
                         tq, tk, sub = tile or own
                         line = dict(
-                            kernel=label, shape=ns.shape, s=s, window=window,
+                            kernel=label, shape=ns.shape, s=s, valid=live,
+                            window=window,
                             start=start, table_position=at,
                             tile=[tq, tk, sub], own_tile=tile is None)
                         if (label, tile) not in fns:
+                            # (GPT-2's cells: the packed rows reshaped
+                            # as ``models/gpt2.py`` does, bfloat16 out)
+                            extra = {"out_dtype": "bfloat16"} if packed \
+                                else {}
                             fns[label, tile] = jax.jit(
                                 lambda q, at, call=module._call, tile=tile:
-                                call(q, k_pool, v_pool, layer, table, at,
-                                     valid, window=window, interpret=False,
-                                     tile=tile))
+                                call(q.reshape(1, s, heads, d_head), k_pool,
+                                     v_pool, layer, table, at, valid,
+                                     window=window, interpret=False,
+                                     tile=tile, **extra).reshape(q.shape))
                         if fns[label, tile] is None:
                             continue       # refused at an earlier start
                         try:
@@ -187,11 +230,17 @@ def main():
                             line["error"] = str(e)[:300]
                             print(json.dumps(line), flush=True)
                             continue
-                        short = getattr(module, "_short_block", None)
+                        if hasattr(module, "_last_widths"):
+                            widths = module._last_widths(
+                                tq, tk, PAGE, table.shape[1] * PAGE)
+                        elif hasattr(module, "_short_block"):
+                            short = module._short_block(tq, tk, PAGE)
+                            widths = (short,) if short else ()
+                        else:
+                            widths = None
                         visited, dense, keys = pairs_visited(
-                            at, s, tq, tk, window,
-                            short and short(tq, tk, PAGE))
-                        flops = 4 * tq * keys * heads * D_HEAD
+                            at, s, tq, tk, window, widths, live)
+                        flops = 4 * tq * keys * heads * d_head
                         line.update(
                             pairs_visited=visited, pairs_dense=dense,
                             kernel_ms=round(kernel, 4),
@@ -203,7 +252,7 @@ def main():
                                 needed / PEAK_FLOPS / (kernel * 1e-3), 4))
                         if want is not None:
                             line["max_abs_diff"] = float(jnp.max(jnp.abs(
-                                got - want)))
+                                (got - want)[:, :live].astype(jnp.float32))))
                             if tile is None and label == "tree":
                                 line.update(
                                     loop_ms=round(loop_ms, 4),

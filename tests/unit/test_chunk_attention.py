@@ -3,7 +3,9 @@ Pallas interpreter against its oracle, the XLA loop it stands in for on
 the chip (ops/chunk_attention.py::paged_blocked_attention): shuffled
 page tables, windows inside a block, across blocks and wider than every
 key, chunks that start at 0, inside and past the window, padded rows,
-NaN past the live length, and tiles that really come from the shapes.
+NaN past the live length, tiles that really come from the shapes, and
+heads of 64 lanes, two to a lane tile (GPT-2's and LFM2's), over tables
+no longer than a block.
 The interpreter proves numerics, not compilability: the chip's compiler
 has its cases in test_tpu_compile.py."""
 import numpy as np
@@ -24,6 +26,9 @@ PAGE = 8
 # of 128, a smaller shape of group 1, and Command A+'s group of 16 on 8
 # heads at a small width
 WIDE, NARROW, MANY = (32, 4, 128), (2, 2, 32), (128, 8, 16)
+# heads of 64 lanes, two to a 128-lane tile of the pool's row: GPT-2's 16
+# over 1,024 lanes at a group of 1, LFM2's 8 over 512 at a group of 4
+GPT2_HEADS, LFM2_HEADS = (16, 16, 64), (32, 8, 64)
 
 
 def _inputs(seed, b, s, shape, max_pages, layers=2, dtype=jnp.float32):
@@ -112,6 +117,54 @@ def test_tiles_come_from_the_shapes(shape, window):
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
+@pytest.mark.parametrize("shape", [GPT2_HEADS, LFM2_HEADS],
+                         ids=["gpt2_16x64", "lfm2_8x64_group4"])
+@pytest.mark.parametrize("max_pages", [64, 48])
+@pytest.mark.parametrize("start, valid", [(0, 256), (0, 150), (120, 256),
+                                          (128, 70)],
+                         ids=["at_0", "at_0_padded", "past_0",
+                              "past_0_padded"])
+def test_heads_of_64_share_a_lane_tile(shape, max_pages, start, valid):
+    """Heads narrower than a lane tile, two folded together over the
+    tile's 128 lanes (q and the result in the pool's packed rows), over
+    a shuffled table no longer than a block (64 pages, GPT-2's row, and
+    48): the table is ONE block, folded at the length a tile's queries
+    can see in steps of 128 keys (a quarter of it in whole lane tiles),
+    or not at all (a chunk at 0, one past it as after a prefix hit,
+    ``valid_lens`` short of the bucket)."""
+    s = 256
+    h, kvh, dh = shape
+    tq, tk, sub = tiles(s, h // kvh, dh, kvh * dh, 4, max_pages * PAGE,
+                        PAGE)
+    assert kernel_module._heads_a_lane_tile(dh, kvh * dh) == 2
+    assert (tq, tk) == (128, max_pages * PAGE)
+    assert kernel_module._last_widths(tq, tk, PAGE, tk) == \
+        tuple(range(128, tk, 128))
+    q, pools, tables = _inputs(11, 2, s, shape, max_pages)
+    valid = [valid, s]
+    got, want = _both(q, pools, tables, [start, max(start - 5, 0)], valid,
+                      None)
+    for slot, n in enumerate(valid):
+        np.testing.assert_allclose(got[slot, :n], want[slot, :n], atol=2e-5)
+        assert np.isfinite(got[slot]).all()
+        assert not got[slot, -(-n // tq) * tq:].any()
+
+
+def test_heads_of_64_leave_the_kernel_in_the_callers_dtype():
+    """bfloat16 pools as the cells hold them: the result is the caller's
+    dtype straight from the kernel's float32 accumulator, within
+    bfloat16's rounding of the loop's."""
+    q, pools, tables = _inputs(12, 1, 64, GPT2_HEADS, 64, dtype=jnp.bfloat16)
+    positions, valid = jnp.asarray([40], jnp.int32), jnp.asarray([50], jnp.int32)
+    got = chunk_attention(q, *pools, 1, tables, positions, valid, PAGE,
+                          out_dtype=jnp.bfloat16, interpret=True)
+    want = paged_blocked_attention(q, *pools, 1, tables, positions, valid,
+                                   PAGE, None)
+    assert got.dtype == jnp.bfloat16 and got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got[0, :50], np.float32),
+                               np.asarray(want[0, :50]), atol=3e-2)
+
+
 @pytest.mark.parametrize("bucket", [512, 1024, 2048])
 @pytest.mark.parametrize("cell, want", [
     # Mellum (8 heads a key-value head, 512 lanes): 256 queries x 8
@@ -138,6 +191,31 @@ def test_tiles_at_the_cells_shapes(cell, want, bucket):
     got = tiles(bucket, group, 128, lanes, 2, table, 16, window)
     assert got == want
     assert _vmem_limit(*got, group, 128, lanes, 2) <= 100 << 20
+
+
+@pytest.mark.parametrize("bucket", [128, 256, 512])
+def test_tiles_at_evals_shape(bucket):
+    """Olmo-Hybrid's full layers (a group of ONE over 3,840 lanes, a
+    table of 192 columns): what 8 MiB holds of K and V double-buffered
+    is 272 keys a block, and the bucket is one tile."""
+    assert tiles(bucket, 1, 128, 3840, 2, 192 * 16, 16) == \
+        (bucket, 272, bucket)
+
+
+@pytest.mark.parametrize("bucket, want", [(128, (128, 1024, 128)),
+                                          (256, (256, 1024, 256)),
+                                          (512, (256, 1024, 256)),
+                                          (1024, (256, 1024, 256))])
+def test_tiles_at_gpt2s_shape(bucket, want):
+    """GPT-2 medium in docs and chat (16 heads of 64, a group of 1 over
+    1,024 lanes, a table of 64 columns: ONE block): the block is the
+    table, fetched and folded at 256, 512 or 768 keys where a tile's
+    queries see no more, under tiles of 256 queries, 256 rows of each
+    of a lane tile's two heads a turn."""
+    assert tiles(bucket, 1, 64, 1024, 2, 64 * 16, 16) == want
+    assert kernel_module._last_widths(*want[:2], 16, 64 * 16) == \
+        (256, 512, 768)
+    assert _vmem_limit(*want, 1, 64, 1024, 2) <= 100 << 20
 
 
 def test_tiles_of_other_shapes():
@@ -175,11 +253,13 @@ def test_padded_rows_are_finite_and_a_tile_past_them_is_zero(
         assert got[slot, :n].any()
 
 
+@pytest.mark.parametrize("shape", [NARROW, GPT2_HEADS],
+                         ids=["group1", "gpt2_16x64"])
 @pytest.mark.parametrize("window", [None, 20, 12],
                          ids=["full", "window", "past_the_table"])
 @pytest.mark.parametrize("start, n", [(9, 13), (32, 16)],
                          ids=["inside", "to_the_end"])
-def test_nan_past_the_live_length_reaches_no_query(window, start, n):
+def test_nan_past_the_live_length_reaches_no_query(window, start, n, shape):
     """A slot's last page partly live, NaN in every pool row past the
     live length (a recycled page) and in the garbage page: a block is
     fetched WHOLE, its dead pages by the table's own entries and, where
@@ -188,7 +268,7 @@ def test_nan_past_the_live_length_reaches_no_query(window, start, n):
     garbage page. Masked scores weigh nothing, and the value side is
     zeroed so that ``0 * NaN`` is never formed."""
     s, max_pages = 16, 6
-    q, pools, tables = _inputs(4, 1, s, NARROW, max_pages)
+    q, pools, tables = _inputs(4, 1, s, shape, max_pages)
     live = start + n                       # tokens the slot holds
     clean = _both(q, pools, tables, [start], [n], window, tile=(8, 16, 4))[0]
     poisoned = []

@@ -394,7 +394,9 @@ def test_paged_attention_kernel_config_gate():
         config={"inference": dict(_PAGED_BASE,
                                   paged_attention_kernel="pallas")})
     assert eng.paged_attention_kernel == "pallas"
-    # prefill stays on the oracle path even then
+    # ...for a prompt chunk's read too (chunk_attention); the serving
+    # config, which every oracle comparison closes over, stays the gather
+    assert eng.prefill_attention_kernel == "pallas"
     assert eng.model_config.paged_attention_kernel == "xla"
     # ...with no kv_* key set too: every engine has page tables to walk
     eng = deepspeed.init_inference(
@@ -405,9 +407,10 @@ def test_paged_attention_kernel_config_gate():
 
 
 def test_decode_program_carries_pallas_and_audits_clean():
-    # the decode family runs the attention kernel; prefill does not: its
-    # one kernel a layer is the page write (kv_cache.write_tokens); the
-    # IR walker classifies the calls as compute segments; audit is clean
+    # the decode family runs the page walk, a kernel a layer; prefill
+    # two: the page write (kv_cache.write_tokens) and the chunk's read
+    # (chunk_attention); the IR walker classifies the calls as compute
+    # segments; audit is clean
     from deepspeed_tpu.analysis.ir import walk
     from deepspeed_tpu.analysis.programs import collect_inference_programs
     eng = deepspeed.init_inference(
@@ -423,9 +426,12 @@ def test_decode_program_carries_pallas_and_audits_clean():
     prefill = walk(jax.make_jaxpr(specs["prefill/b8"].build())
                    (*specs["prefill/b8"].args))
     calls = [e for e in prefill.eqns if e.prim == "pallas_call"]
-    assert len(calls) == eng.model_config.n_layers
-    assert all(e.kind == "compute" and "kv_page_write" in str(e.eqn)
-               for e in calls)
+    assert len(calls) == 2 * eng.model_config.n_layers
+    assert all(e.kind == "compute" for e in calls)
+    assert [name for e in calls for name in ("kv_page_write",
+                                             "chunk_attention")
+            if name in str(e.eqn)] == \
+        ["kv_page_write", "chunk_attention"] * eng.model_config.n_layers
     report = eng.audit()
     assert report.findings == [], [f.key for f in report.findings]
 

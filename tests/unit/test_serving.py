@@ -601,6 +601,41 @@ def test_pool_exhaustion_preempts_and_recovers(model, oracle):
     assert eng.allocator.pages_in_use == 0
 
 
+# ------------------------------------------- a chunk's read, a kernel
+
+
+@pytest.mark.parametrize("d_model", [32, 128],
+                         ids=["a_slice_a_head", "two_heads_a_lane_tile"])
+def test_a_chunks_kernel_read_gives_the_gather_reads_streams(d_model):
+    """``paged_attention_kernel: pallas`` on one chip sends a prompt
+    chunk through ``chunk_attention`` (``GPT2Decoder.prefill_config``)
+    and a decode or verify step through the page walk: with a float32
+    pool the greedy streams are the gather read's byte for byte, with
+    the prefix cache hit (a chunk that starts past 0), a prompt in
+    chunks and the n-gram drafter's verify steps on; at two heads of 16
+    lanes (a slice a head) and of 64 (the two folded over one lane
+    tile, GPT-2's layout on the chip)."""
+    model = tiny_model(d_model=d_model)
+    rs = np.random.RandomState(21)
+    system = rs.randint(0, 128, size=2 * PS).tolist()
+    prompts = [system + rs.randint(0, 128, size=n).tolist()
+               for n in (3, 21, 9)] + [rs.randint(0, 128, size=5).tolist()]
+    streams = {}
+    for kernel in ("xla", "pallas"):
+        eng = paged_engine(
+            model, max_batch_size=2, paged_attention_kernel=kernel,
+            prefix_caching=True, prefill_chunk_tokens=16,
+            speculative={"enabled": True, "method": "ngram",
+                         "num_draft_tokens": 3})
+        assert (eng.paged_attention_kernel,
+                eng.prefill_attention_kernel) == (kernel, kernel)
+        streams[kernel] = eng.generate(prompts, max_new_tokens=6)
+        assert eng.prefix_stats()["hits"] >= 1
+    assert streams["pallas"] == streams["xla"]
+    assert streams["xla"] == DenseReference(model).generate(
+        prompts, max_new_tokens=6)
+
+
 # ----------------------------------------------------------- sharding
 
 
@@ -610,7 +645,10 @@ def test_paged_cache_sharded_over_heads_decode_parity(model, oracle, kernel):
     model axis, and paged+spec decode on the mesh still matches the
     dense reference — on the
     XLA gather path (``auto`` off a TPU) and with the Pallas kernel
-    shard_mapped over the mesh, heads split over ``model``."""
+    shard_mapped over the mesh, heads split over ``model``. A prompt
+    chunk's read stays the gather there: ``chunk_attention`` has no
+    ``shard_map`` wrapper, so the prefill family keeps the serving
+    config."""
     from deepspeed_tpu.parallel.topology import build_mesh
     from deepspeed_tpu.inference.kv_cache import PAGED_KV_CACHE_SPEC
     mesh = build_mesh(data=4, model=2)
@@ -624,6 +662,8 @@ def test_paged_cache_sharded_over_heads_decode_parity(model, oracle, kernel):
                                       "num_draft_tokens": 3}}})
     assert eng.paged_attention_kernel == \
         ("pallas" if kernel == "pallas" else "xla")
+    assert eng.prefill_attention_kernel == "xla"
+    assert eng._prefill_config() is eng.model_config
     assert eng.kv.k.sharding.spec == PAGED_KV_CACHE_SPEC
     rs = np.random.RandomState(8)
     prompts = [rs.randint(0, 128, size=n).tolist() for n in (7, 12)]

@@ -216,9 +216,13 @@ def test_export_import_roundtrip_through_engines(model):
 # ----------------------------------------------- disaggregated server
 
 
-def test_disagg_streams_byte_identical_to_monolith(model):
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_disagg_streams_byte_identical_to_monolith(model, kernel):
     """Greedy streams through prefill -> serialized handoff -> decode
-    equal the monolithic paged scheduler's streams token for token."""
+    equal the monolithic paged scheduler's streams token for token; with
+    ``pallas`` the prefill host's chunks read their keys in
+    ``chunk_attention`` and the decode hosts walk the pages, against the
+    gather read's monolith."""
     rs = np.random.RandomState(3)
     prompts = [rs.randint(0, 128, size=n).tolist()
                for n in (5, 11, 17, 26)]
@@ -229,9 +233,12 @@ def test_disagg_streams_byte_identical_to_monolith(model):
 
     server = DisaggServer(
         {"pre0": paged_engine(model, max_batch_size=2,
-                              prefill_chunk_tokens=8)},
-        {"dec0": paged_engine(model, max_batch_size=2),
-         "dec1": paged_engine(model, max_batch_size=2)})
+                              prefill_chunk_tokens=8,
+                              paged_attention_kernel=kernel)},
+        {"dec0": paged_engine(model, max_batch_size=2,
+                              paged_attention_kernel=kernel),
+         "dec1": paged_engine(model, max_batch_size=2,
+                              paged_attention_kernel=kernel)})
     for p in prompts:
         server.submit(p, max_new_tokens=6)
     out = server.run()
@@ -472,12 +479,15 @@ def test_adapter_set_registry_and_oracle():
     assert delta.shape == (3, 128) and np.abs(delta).sum() > 0
 
 
-def test_adapter_zero_is_byte_identical_base(model):
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_adapter_zero_is_byte_identical_base(model, kernel):
     """Attaching adapters switches the engine onto the adapter-aware
     program family; adapter id 0 (the all-zero BASE row) must still be
-    byte-identical to the adapter-free engine."""
+    byte-identical to the adapter-free engine (the gather read's), with
+    the cache path's kernels too: they never see an adapter."""
     plain = paged_engine(model, max_batch_size=2)
-    adapted = paged_engine(model, max_batch_size=2)
+    adapted = paged_engine(model, max_batch_size=2,
+                           paged_attention_kernel=kernel)
     ads = AdapterSet(d_model=TINY["d_model"],
                      vocab_size=TINY["vocab_size"], rank=4)
     ads.add("tenant-a")

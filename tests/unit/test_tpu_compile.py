@@ -466,15 +466,20 @@ _SERVING_CELLS = {"chat": (3072, 128, 64), "docs": (8500, 1024, 128)}
 def test_serving_programs_copy_no_layer_slab(
         one_chip, no_persistent_cache, serving_engine, monkeypatch,
         program, cell):
-    """``jit_prefill`` (the XLA read, one row) and ``jit_decode`` (the
-    Pallas kernel, every slot) at the cells' pool shapes: no
-    instruction makes a ``[pages + 1, page, heads * d_head]`` array — a
-    layer's whole slab of the pool, 101 MB in chat and 279 MB in docs,
-    which prefill copied twice a layer while its read sliced the layer
-    out before gathering (a row's 64 pages, 2 MB, were then taken from
-    the copy; 42-44% of docs' busy device time, ledger PR 26) — both
-    donated pools come back in place, and the new rows are written as
-    :func:`_page_writes` says."""
+    """``jit_prefill`` (one row: the page write and ``chunk_attention``
+    a layer) and ``jit_decode`` (the page walk, every slot) at the
+    cells' pool shapes: no instruction makes a ``[pages + 1, page, heads
+    * d_head]`` array — a layer's whole slab of the pool, 101 MB in chat
+    and 279 MB in docs, which prefill copied twice a layer while its
+    read sliced the layer out before gathering (a row's 64 pages, 2 MB,
+    were then taken from the copy; 42-44% of docs' busy device time,
+    ledger PR 26) — both donated pools come back in place, and the new
+    rows are written as :func:`_page_writes` says. Since PR 57 a chunk
+    reads its keys in the kernel: the float32 scores of the bucket
+    against the row's 1,024 keys (``f32[16,1024,1024]`` in docs, 67 MB
+    a layer), the gather of the row's 64 pages and the transposes
+    between the packed rows and ``[1, bucket, 16, 64]`` are gone from
+    ``jit_prefill``."""
     eng = serving_engine
     (pages, bucket, slots), row = _SERVING_CELLS[cell], eng.max_pages
     cfg = eng.model_config
@@ -504,9 +509,20 @@ def test_serving_programs_copy_no_layer_slab(
     slab = "bf16[{},{},{}]".format(pages + 1, ps, hd)
     assert not [line.strip()[:160] for line in text.splitlines()
                 if slab in line][:3]
-    # a kernel a layer either way: decode's page walk, prefill's page
-    # write (both pools in one call)
-    assert text.count("tpu_custom_call") == layers
+    # decode: the page walk a layer; prefill: the page write (both
+    # pools in one call) and the chunk's read
+    reads = len(re.findall(
+        r"%chunk_attention(?:\.\d+)? = .*tpu_custom_call", text))
+    assert (text.count("tpu_custom_call"), reads) == \
+        ((2 * layers, layers) if program == "prefill" else (layers, 0))
+    if program == "prefill":
+        gone = ["f32[{},{},{}]".format(cfg.n_heads, bucket, row * ps),
+                "bf16[{},{},{},{}]".format(1, row, ps, hd),
+                "bf16[{},{},{}]".format(row, ps, hd),
+                "bf16[1,{},{},{}]".format(bucket, cfg.n_heads, cfg.d_head),
+                "bf16[1,{},{},{}]".format(cfg.n_heads, bucket, cfg.d_head)]
+        assert not [line.strip()[:160] for line in text.splitlines()
+                    if any(shape in line for shape in gone)][:3]
     assert _page_writes(text, program, (pages + 1, layers, ps, hd)) == \
         (layers if program == "prefill" else 0)
     aliased = {int(out): int(arg) for out, arg in re.findall(
@@ -977,17 +993,24 @@ def test_chunk_attention_compiles_at_command_a_plus_widths(
 @pytest.mark.parametrize("engine", ["serving_engine", "jamba_engine",
                                     "lfm2_engine", "moonlight_engine"])
 def test_the_other_families_prefill_closes_over_what_it_did(request, engine):
-    """GPT-2, Jamba, LFM2 and Moonlight under ``paged_attention_kernel:
+    """Jamba, LFM2 and Moonlight under ``paged_attention_kernel:
     pallas``: their decoders have no prefill variant of the config, so
     ``_get_prefill_fn`` closes over ``model_config`` itself, the XLA
     path, and their prefill programs are the programs they were (the
-    cases above that count each program's kernels hold the text)."""
+    cases above that count each program's kernels hold the text).
+    GPT-2's has one since PR 57 (a chunk reads its keys in
+    ``chunk_attention``); the serving config stays the gather."""
     eng = request.getfixturevalue(engine)
     eng = eng[0] if isinstance(eng, tuple) else eng
     assert eng.paged_attention_kernel == "pallas"
+    assert eng.model_config.paged_attention_kernel == "xla"
+    if engine == "serving_engine":
+        assert eng.prefill_attention_kernel == "pallas"
+        assert eng._prefill_config() == eng.decoder.decode_config(
+            eng.model_config, "pallas")
+        return
     assert eng._prefill_config() is eng.model_config
     assert eng.prefill_attention_kernel == "xla"
-    assert eng.model_config.paged_attention_kernel == "xla"
 
 
 @pytest.fixture(scope="module")
@@ -1187,6 +1210,70 @@ def test_chunk_attention_compiles_at_30_heads_of_128(
 
     pool = ((4001, 4, 16, 3840), BF16)
     assert _compile(fn, one_chip, ((1, bucket, 30, 128), BF16), pool, pool,
+                    ((), I32), ((1, 192), I32), ((1,), I32),
+                    ((1,), I32)) == 1
+
+
+# (pool pages + 1, the buckets) of GPT-2's two serving cells; a row is
+# the model's 1,024 positions: 64 columns, as ``_SERVING_CELLS`` says
+_GPT2_CHUNKS = [(cell, pages, bucket)
+                for cell, pages, buckets in (("docs", 8501, (512, 1024)),
+                                             ("chat", 3073, (128, 256, 512)))
+                for bucket in buckets]
+
+
+@pytest.mark.parametrize("cell, pages, bucket", _GPT2_CHUNKS,
+                         ids=["{}-{}".format(c, b)
+                              for c, _, b in _GPT2_CHUNKS])
+def test_chunk_attention_compiles_at_16_heads_of_64(
+        one_chip, no_persistent_cache, cell, pages, bucket):
+    """A prompt chunk's attention at GPT-2 medium's heads (16 of 64
+    lanes, one a key-value head, 1,024 lanes a pool row) for every
+    bucket of docs and chat over the row its engine has (64 columns):
+    two heads share a lane tile and are folded together over its 128
+    lanes, where a head's own 64 at a traced ``h * d_head`` were refused
+    ("cannot statically prove that index in dimension 2 is a multiple
+    of 128", PR 57). q goes in and the result comes out as the
+    projection's packed rows in bfloat16: nothing but the kernel and
+    its scalar operands is left of the call."""
+    from deepspeed_tpu.ops.pallas.chunk_attention import chunk_attention
+
+    def fn(q, k_pool, v_pool, layer, page_tables, positions, valid_lens):
+        return chunk_attention(
+            q.reshape(1, bucket, 16, 64), k_pool, v_pool, layer,
+            page_tables, positions, valid_lens, 16, out_dtype=BF16,
+            interpret=False).reshape(q.shape)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((pages, 24, 16, 1024), BF16)
+    text = jax.jit(fn).lower(
+        sds((1, bucket, 1024), BF16), pool, pool, sds((), I32),
+        sds((1, 64), I32), sds((1,), I32), sds((1,), I32)) \
+        .compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    # the packed rows are the kernel's own layout: no copy of them
+    assert not [line.strip()[:160] for line in text.splitlines()
+                if re.search(r" (copy|transpose)\(", line)
+                and "bf16[1,{},".format(bucket) in line][:3]
+
+
+def test_chunk_attention_compiles_at_8_heads_of_64_in_groups_of_4(
+        one_chip, no_persistent_cache):
+    """The same fold at LFM2-8B-A1B's attention (32 query heads on 8
+    key-value heads of 64: 4 heads a group over 512 lanes), a bucket of
+    512 over a slot's row of 192 pages: the shape its chunk can opt in
+    with (its read is still the XLA loop)."""
+    from deepspeed_tpu.ops.pallas.chunk_attention import chunk_attention
+
+    def fn(q, k_pool, v_pool, layer, page_tables, positions, valid_lens):
+        return chunk_attention(q, k_pool, v_pool, layer, page_tables,
+                               positions, valid_lens, 16, None,
+                               interpret=False)
+
+    pool = ((8001, 3, 16, 512), BF16)
+    assert _compile(fn, one_chip, ((1, 512, 32, 64), BF16), pool, pool,
                     ((), I32), ((1, 192), I32), ((1,), I32),
                     ((1,), I32)) == 1
 

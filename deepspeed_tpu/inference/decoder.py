@@ -3,7 +3,8 @@
 The serving engine imports no model module. The model handed to it
 carries a ``decoder`` (``make_gpt2_model``, ``make_jamba_model``,
 ``make_lfm2_model``, ``make_deepseek_v3_model``, ``make_mellum_model``,
-``make_cohere2_moe_model`` and ``make_olmo_hybrid_model`` attach one) with:
+``make_cohere2_moe_model``, ``make_olmo_hybrid_model`` and
+``make_granite_moe_hybrid_model`` attach one) with:
 
 * ``config``: ``vocab_size``, ``max_seq_len``, ``d_model``;
 * ``cache_spec()`` -> :class:`CacheSpec`: what it keeps. One of three
@@ -141,8 +142,9 @@ def decoder_of(model, module=None):
         "models.deepseek_v3.make_deepseek_v3_model, "
         "models.mellum.make_mellum_model, "
         "models.cohere2_moe.make_cohere2_moe_model, "
-        "models.olmo_hybrid.make_olmo_hybrid_model; its cache_spec() may "
-        "put the paged layers in groups)")
+        "models.olmo_hybrid.make_olmo_hybrid_model, "
+        "models.granite_moe_hybrid.make_granite_moe_hybrid_model; its "
+        "cache_spec() may put the paged layers in groups)")
 
 
 # What a serving feature needs of a cache, each need as (what the model

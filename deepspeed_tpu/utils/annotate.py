@@ -38,11 +38,10 @@ def annotate(name, **attrs):
 
 # -------------------------------------------------------- device scopes
 # The names the program's ``jax.named_scope`` blocks give its mechanisms
-# (docs/telemetry.md, "Device scopes": what each wraps and which families
+# (docs/telemetry.md, "Device scopes": what each wraps, and which families
 # have it). A scope ends up as a component of the ``op_name`` of every
-# instruction traced under it; ``utils/compile_cache.program_scopes()``
-# reads them back from the compiled programs. A test holds every
-# ``named_scope`` literal of the package to this list.
+# instruction traced under it; ``utils/compile_cache.program_scopes()`` reads
+# them back. A test holds every ``named_scope`` literal to this list.
 DEVICE_SCOPES = (
     "embed", "head", "head.loss", "sample", "optim.step", "mlp",
     "attn.proj", "attn.prefill", "attn.decode", "kv.write",
@@ -51,6 +50,7 @@ DEVICE_SCOPES = (
     "moe.route", "moe.dispatch", "moe.combine", "moe.shared",
     "mla.project", "mla.kv_up", "mla.prefill_attn", "mla.absorb",
     "gdn.proj", "gdn.conv", "gdn.chunk", "gdn.step", "gdn.norm",
+    "ssd.proj", "ssd.conv", "ssd.chunk", "ssd.step", "ssd.norm",
 )
 
 

@@ -1484,6 +1484,235 @@ def test_command_a_plus_programs_run_the_kernels_at_the_cells_share(
         14.6 * 2 ** 30
 
 
+# ------------------------------------------------ granite-4.0-h-small
+GRANITE = {"heads": 128, "d_head": 64, "d_state": 128, "mamba_layers": 9,
+           "slots": 96}
+
+
+def test_ssd_step_compiles_in_place_on_the_pool(one_chip,
+                                                no_persistent_cache):
+    """One decode step of one Mamba-2 layer of granite-4.0-h-small over
+    every slot at the published widths (128 heads of 64, a float32 state
+    of 128 x 8,192 a slot: 4.19 MB) and the cell's 96 slots: the pool of
+    9 layers (3.6 GB) comes back in place and no layer's slab (403 MB)
+    is made of it."""
+    from deepspeed_tpu.ops.pallas.mamba2 import ssd_step
+    H, p, n = GRANITE["heads"], GRANITE["d_head"], GRANITE["d_state"]
+    layers, slots = GRANITE["mamba_layers"], GRANITE["slots"]
+
+    def fn(pool, x, dt, B, C, a, D):
+        return ssd_step(pool, 4, x, dt, B, C, a, D, interpret=False)
+
+    args = [jax.ShapeDtypeStruct(s, F32, sharding=one_chip) for s in (
+        (layers, slots, n, H * p), (slots, H * p), (slots, H), (slots, n),
+        (slots, n), (slots, H), (H,))]
+    compiled = jax.jit(fn, donate_argnums=0).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert re.search(r"%ssd_step(?:\.\d+)? = ", text)
+    assert re.search(r"\{1\}: \(0, \{\}, (?:may|must)-alias\)",
+                     text.split("\n", 1)[0])
+    assert "f32[{},{},{}]".format(slots, n, H * p) not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_paged_attention_compiles_at_32_on_8_heads_of_128(
+        one_chip, no_persistent_cache):
+    """The grouped page walk at granite-4.0-h-small's attention layer:
+    4 query heads a key-value head, 32 rows a slot over 1,024 packed
+    lanes, 96 slots over rows of 640 pages of 16 (10,240 positions), a
+    pool of ONE layer."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_attention
+
+    def fn(q, k_pool, v_pool, page_tables, positions, valid_lens):
+        return paged_attention(q, k_pool, v_pool, page_tables, positions,
+                               valid_lens, layer_idx=0, page_size=16,
+                               interpret=False)
+
+    pool = ((16001, 1, 16, 1024), BF16)
+    compiled = jax.jit(fn).lower(*[
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+            ((96, 1, 32, 128), BF16), pool, pool, ((96, 640), I32),
+            ((96,), I32), ((96,), I32))]).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "%paged_attention_grouped" in text
+
+
+@pytest.mark.parametrize("bucket", [256, 512, 1024, 2048])
+def test_chunk_attention_compiles_at_32_on_8_heads_of_128(
+        one_chip, no_persistent_cache, bucket):
+    """A prompt chunk's attention at granite-4.0-h-small's attention
+    layer (32 heads on 8 key-value heads of 128: a group of 4 over 1,024
+    lanes) for every bucket of the support cell, over a slot's row of
+    640 pages."""
+    from deepspeed_tpu.ops.pallas.chunk_attention import chunk_attention
+
+    def fn(q, k_pool, v_pool, layer, page_tables, positions, valid_lens):
+        return chunk_attention(q, k_pool, v_pool, layer, page_tables,
+                               positions, valid_lens, 16, None,
+                               interpret=False)
+
+    pool = ((16001, 1, 16, 1024), BF16)
+    assert _compile(fn, one_chip, ((1, bucket, 32, 128), BF16), pool, pool,
+                    ((), I32), ((1, 640), I32), ((1,), I32),
+                    ((1,), I32)) == 1
+
+
+@pytest.mark.parametrize("rows, k, n", [
+    (1024, 4096, 1536), (1024, 768, 4096),
+    (20480, 4096, 1536), (20480, 768, 4096)],
+    ids=["decode_w13", "decode_w2", "chunk_w13", "chunk_w2"])
+def test_moe_gmm_compiles_at_36_groups_of_width_768(
+        one_chip, no_persistent_cache, rows, k, n):
+    """The grouped matmul over granite-4.0-h-small's 36 held experts of
+    width 768 at the rows of a decode step of 96 slots and of the
+    largest chunk, 10 experts a token (a half share: the capacity is all
+    the rows, 960 in whole tiles of 128): gate and up side by side (4,096 -> 2 x 768), then down."""
+    from deepspeed_tpu.ops.pallas.moe import moe_gmm
+
+    def fn(lhs, rhs, sizes):
+        return moe_gmm(lhs, rhs, sizes, interpret=False)
+
+    assert _compile(fn, one_chip, ((rows, k), BF16), ((36, k, n), BF16),
+                    ((36,), I32)) == 1
+
+
+@pytest.fixture(scope="module")
+def granite_engine():
+    """A tiny Granite-MoE-Hybrid engine on the CPU whose programs are
+    lowered at the published widths (``jamba_engine`` says how)."""
+    import json
+    import os
+    import deepspeed_tpu as deepspeed
+    from deepspeed_tpu.models import granite_moe_hybrid as granite
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "granite-4.0-h-small-serve.json")) as f:
+        cell = json.load(f)
+    tiny = dict(cell["model"], hidden_size=64, intermediate_size=32,
+                shared_intermediate_size=48, num_attention_heads=4,
+                num_key_value_heads=2, vocab_size=128,
+                padded_vocab_size=128, mamba_n_heads=8, mamba_d_head=16,
+                mamba_d_state=16, num_local_experts=4,
+                router_num_experts=8, experts_held=[0, 4],
+                num_experts_per_tok=3, num_hidden_layers=3,
+                layer_types=["mamba", "attention", "mamba"])
+    eng = deepspeed.init_inference(
+        model=granite.make_granite_moe_hybrid_model(
+            granite.config_from_hf(tiny), seed=0),
+        config={"inference": dict(cell["inference"], max_batch_size=2,
+                                  num_pages=1300,
+                                  paged_attention_kernel="pallas")})
+    eng.model_config = granite.config_from_hf(
+        cell["model"], ssd_kernel="pallas", moe_kernel="pallas")
+    return eng, cell
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_granite_programs_copy_no_state_slab_and_alias_both_pools(
+        one_chip, no_persistent_cache, granite_engine, monkeypatch,
+        program):
+    """``jit_prefill`` (the largest bucket, one slot) and ``jit_decode``
+    (every slot) of granite-4.0-h-small at the cell's share (36 of 72
+    experts, 50,176 rows of the tied embedding), pool shapes and depth
+    (10 layers: 9 Mamba-2, 1 attention): no instruction makes an array
+    of a layer's whole state slab (``[96, 128, 8192]``, 403 MB of
+    float32) or of every slot's whole window; the page pool AND the state pool,
+    four donated buffers, come back in place; a decode step runs one
+    state kernel a Mamba-2 layer, one grouped page walk and two grouped
+    matmuls a layer over ALL its routed rows (a half share: the capacity
+    is all the rows); a chunk one page write and one ``chunk_attention``
+    (its recurrence is XLA); and the whole of it fits the chip."""
+    from deepspeed_tpu.models import granite_moe_hybrid as granite
+    from deepspeed_tpu.ops import moe
+    eng, cell = granite_engine
+    cfg = eng.model_config
+    inference = cell["inference"]
+    slots, pages = inference["max_batch_size"], inference["num_pages"]
+    bucket, ps = inference["prefill_buckets"][-1], eng.page_size
+    row = inference["max_seq_len"] // ps
+    assert cfg.held == (0, 36) and cfg.n_experts == 72
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    decoder = granite.GraniteMoeHybridDecoder(cfg)
+    params = jax.eval_shape(lambda: decoder.serving_params(
+        granite.init_params(cfg, 0), BF16))
+    assert sum(int(np.prod(x.shape)) for x in
+               jax.tree_util.tree_leaves(params)) == 4_757_211_776
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), params)
+    n_mamba, n_attn = len(cfg.mamba_layers), len(cfg.attention_layers)
+    lanes = cfg.n_kv_heads * cfg.d_head
+    pool = sds((pages + 1, n_attn, ps, lanes), BF16)
+    conv = sds((n_mamba, slots, (cfg.d_conv - 1) * cfg.conv_channels), BF16)
+    ssd = sds((n_mamba, slots, cfg.d_state, cfg.d_inner), F32)
+    assert [(s.name, s.dtype) for s in decoder.cache_spec().state] == \
+        [("conv", BF16), ("ssd", F32)]
+    assert (conv.size * 2 + ssd.size * 4) // slots == 38204928
+    rng = jax.random.PRNGKey(0)
+    tail = (sds(rng.shape, rng.dtype), sds((), F32), sds((), F32))
+    if program == "prefill":
+        fn = eng._get_prefill_fn(bucket, True, 0)
+        args = (sds((), I32), sds((1, bucket), I32), sds((row,), I32),
+                sds((), I32), sds((), I32))
+    else:
+        fn = eng._get_decode_fn(True, 0)
+        args = (sds((slots,), jnp.bool_), sds((slots, 1), I32),
+                sds((slots,), I32), sds((slots, row), I32))
+    compiled = fn.lower(params, pool, pool, conv, ssd, *args,
+                        *tail).compile()
+    text = compiled.as_text()
+
+    assert text.startswith("HloModule jit_" + program)
+    # (a layer's pages are the whole pool here: XLA drops the one-layer
+    # pool's unit dimension, and a decode step scatters its rows into it
+    # in place)
+    slabs = ["f32[{},{},{}]".format(slots, cfg.d_state, cfg.d_inner),
+             "bf16[{},{},{}]".format(slots * row, ps, lanes)]
+    if program == "prefill":
+        slabs += ["bf16[{},{}]".format(
+            slots, (cfg.d_conv - 1) * cfg.conv_channels)]
+    for slab in slabs:
+        assert not [line.strip()[:160] for line in text.splitlines()
+                    if slab in line][:3], slab
+    kernels = {name: len(re.findall(
+        r"%" + name + r"(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)) for name in ("ssd_step", "paged_attention_grouped",
+                            "chunk_attention", "moe_gmm")}
+    if program == "decode":
+        assert kernels == {"ssd_step": n_mamba,
+                           "paged_attention_grouped": n_attn,
+                           "chunk_attention": 0,
+                           "moe_gmm": 2 * cfg.n_layers}
+    else:
+        assert kernels == {"ssd_step": 0, "paged_attention_grouped": 0,
+                           "chunk_attention": n_attn,
+                           "moe_gmm": 2 * cfg.n_layers}
+        assert _page_writes(text, program,
+                            (pages + 1, n_attn, ps, lanes)) == n_attn
+    # the half share's grouped matmuls run over ALL the routed rows
+    tokens = bucket if program == "prefill" else slots
+    gmm_rows = {int(m) for m in re.findall(
+        r"%moe_gmm(?:\.\d+)? = bf16\[(\d+),\d+\]\S* custom-call", text)}
+    assert gmm_rows == {moe.share_capacity(tokens * cfg.top_k, 36, 72)}
+    assert gmm_rows.pop() >= tokens * cfg.top_k
+    aliased = {int(out): int(arg) for out, arg in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+        text.split("\n", 1)[0])}
+    n_params = len(jax.tree_util.tree_leaves(params))
+    assert aliased == {i: n_params + i for i in range(4)}
+    memory = compiled.memory_analysis()
+    print("granite {}: arguments {:.3f} GB, temporaries {:.3f} GB"
+          .format(program, memory.argument_size_in_bytes / 1e9,
+                  memory.temp_size_in_bytes / 1e9))
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < \
+        15.2 * 2 ** 30
+
+
 def test_pallas_compiler_params_construct():
     """Every ``compiler_params`` a pallas_call site passes must construct
     under the installed jax — the sites are only reached with
